@@ -6,13 +6,12 @@ per head and HBM traffic grows quadratically while flash streams KV
 blocks through VMEM at O(S) activation memory (ops/attention.py).
 This benchmark measures single-chip training throughput of the
 flagship decoder at S in {4096, 8192} with attn in {xla, pallas} and
-prints one JSON line per point — the measured basis for the second
-headline row in docs/PERF.md (or the kernel's honest retirement).
+prints one JSON line per point, each naming the device.
 
-ONE TPU client at a time (docs/OPS.md): never run concurrently with
-bench.py / bench_sweep.py. `PBST_LONGCTX_TINY=1` smokes the harness on
-CPU with toy shapes (xla column only — interpreter-mode pallas is too
-slow to smoke).
+One process (a chip belongs to one process at a time); exits non-zero
+when JAX's default device is not a TPU. `PBST_LONGCTX_TINY=1` rehearses
+the harness with toy shapes (xla column only — interpret-mode pallas
+is too slow to rehearse).
 """
 
 from __future__ import annotations
@@ -23,8 +22,7 @@ import os
 import sys
 import time
 
-from bench_common import PEAK_FLOPS  # bf16, TPU v5e — one copy
-from bench_common import abandon_if_unavailable
+from bench_common import bench_device, mfu
 
 # (seq, batch): batch shrinks as S grows to hold tokens/step roughly
 # constant and fit HBM; global batch is the dp axis's job in training.
@@ -33,7 +31,8 @@ ATTN = ["xla", "pallas"]
 STEPS = 6  # per timed chunk (one dispatch)
 
 
-def run_point(cfg_base, seq, batch, attn, warm_chunks=1, timed_chunks=2):
+def run_point(cfg_base, device, seq, batch, attn, warm_chunks=1,
+              timed_chunks=2):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -77,15 +76,17 @@ def run_point(cfg_base, seq, batch, attn, warm_chunks=1, timed_chunks=2):
     # longer negligible, so report attn-inclusive MFU too.
     dense = 6 * n_params
     attn_flops = 12 * cfg.n_layers * cfg.d_model * seq  # per token
-    mfu = toks_per_s * dense / PEAK_FLOPS
-    mfu_attn = toks_per_s * (dense + attn_flops) / PEAK_FLOPS
+    util = mfu(toks_per_s, dense, device)
+    util_attn = mfu(toks_per_s, dense + attn_flops, device)
     return {
+        **device,
         "seq": seq,
         "batch": batch,
         "attn": attn,
         "tokens_per_s": round(toks_per_s, 1),
-        "mfu_dense": round(mfu, 4),
-        "mfu_incl_attn": round(mfu_attn, 4),
+        **({"mfu_dense": round(util, 4),
+            "mfu_incl_attn": round(util_attn, 4)}
+           if util is not None else {}),
         "step_ms": round(1e3 * dt / n_steps, 1),
         "compile_s": round(compile_s, 1),
         "loss": round(final_loss, 3),
@@ -94,34 +95,25 @@ def run_point(cfg_base, seq, batch, attn, warm_chunks=1, timed_chunks=2):
 
 def main() -> int:
     tiny = os.environ.get("PBST_LONGCTX_TINY", "").lower() in ("1", "true")
-    if tiny:
-        import jax
+    from pbs_tpu.models import flagship_config
 
-        jax.config.update("jax_platforms", "cpu")
-    from bench_common import setup_compilation_cache
-
-    setup_compilation_cache()
-    from __graft_entry__ import _flagship_cfg
-
-    cfg_base = _flagship_cfg(tiny=tiny)
+    device = bench_device(rehearsal=tiny)
+    cfg_base = flagship_config(tiny=tiny)
     global POINTS, STEPS, ATTN
     if tiny:
         POINTS, STEPS, ATTN = [(256, 1)], 2, ["xla"]
 
     results = []
     for (seq, batch), attn in [(p, a) for p in POINTS for a in ATTN]:
-        fatal = None
         try:
-            r = run_point(cfg_base, seq, batch, attn)
-        except Exception as e:  # noqa: BLE001 — OOM etc. is a result
-            r = {"seq": seq, "batch": batch, "attn": attn,
+            r = run_point(cfg_base, device, seq, batch, attn)
+        except Exception as e:  # noqa: BLE001 — a point that does not
+            # fit (an OOM, say) is a result; the script still exits
+            # non-zero unless some point ran.
+            r = {**device, "seq": seq, "batch": batch, "attn": attn,
                  "error": f"{type(e).__name__}: {str(e)[:120]}"}
-            fatal = e
         print(json.dumps(r), flush=True)
         results.append(r)
-        if fatal is not None and abandon_if_unavailable(
-                fatal, "the remaining long-context points"):
-            break
     ok = [r for r in results if "error" not in r]
     for seq, _ in POINTS:
         cols = {r["attn"]: r for r in ok if r["seq"] == seq}

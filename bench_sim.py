@@ -19,9 +19,8 @@ import argparse
 import json
 import sys
 
-# No platform pin needed: pbs_tpu.sim never imports jax — the whole run
-# is host-side python on a virtual clock (so this bench can never become
-# a chip client, test_chip_invariants discipline).
+# pbs_tpu.sim never imports jax: the whole run is host-side python on a
+# virtual clock, on any machine.
 
 
 def main(argv=None) -> int:

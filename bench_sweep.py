@@ -1,14 +1,14 @@
 """Flagship throughput sweep: justify the benchmarked configuration.
 
 Runs the flagship decoder across remat policy x batch x attention
-implementation in ONE process (the chip tolerates exactly one client —
-never run this concurrently with bench.py), timing a short on-device
-`lax.scan` training chunk per point. Output: one JSON line per point
-plus a final `best` line; paste the table into docs/PERF.md.
+implementation in ONE process (a chip belongs to one process at a
+time), timing a short on-device `lax.scan` training chunk per point.
+Output: one JSON line per point, each naming the device, plus a final
+`best` line. Exits non-zero when JAX's default device is not a TPU.
 
 Usage:
-    python bench_sweep.py                 # full grid on the real TPU
-    PBST_SWEEP_TINY=1 python bench_sweep.py   # smoke the harness on CPU
+    chiprun -- python bench_sweep.py          # full grid on the chip
+    PBST_SWEEP_TINY=1 JAX_PLATFORMS=cpu python bench_sweep.py  # rehearsal
 """
 
 from __future__ import annotations
@@ -20,8 +20,7 @@ import os
 import sys
 import time
 
-from bench_common import PEAK_FLOPS  # bf16, TPU v5e — one copy
-from bench_common import abandon_if_unavailable
+from bench_common import bench_device, mfu, parse_mu_dtype
 
 REMAT = [("none", False, "full"), ("dots", True, "dots"),
          ("full", True, "full")]
@@ -31,7 +30,7 @@ SEQ = 1024
 STEPS = 8  # per timed chunk (one dispatch)
 
 
-def run_point(cfg_base, remat_name, remat, policy, batch, attn,
+def run_point(cfg_base, device, remat_name, remat, policy, batch, attn,
               warm_chunks=1, timed_chunks=2, mu_dtype=None):
     import jax
     import jax.numpy as jnp
@@ -72,13 +71,14 @@ def run_point(cfg_base, remat_name, remat, policy, batch, attn,
 
     n_steps = timed_chunks * STEPS
     toks_per_s = batch * (SEQ - 1) * n_steps / dt
-    mfu = toks_per_s * 6 * n_params / PEAK_FLOPS
+    util = mfu(toks_per_s, 6 * n_params, device)
     return {
+        **device,
         "remat": remat_name,
         "batch": batch,
         "attn": attn,
         "tokens_per_s": round(toks_per_s, 1),
-        "mfu": round(mfu, 4),
+        **({"mfu": round(util, 4)} if util is not None else {}),
         "step_ms": round(1e3 * dt / n_steps, 1),
         "compile_s": round(compile_s, 1),
         "loss": round(final_loss, 3),
@@ -88,34 +88,22 @@ def run_point(cfg_base, remat_name, remat, policy, batch, attn,
 
 def main() -> int:
     tiny = os.environ.get("PBST_SWEEP_TINY", "").lower() in ("1", "true")
-    if tiny:
-        import jax
+    from pbs_tpu.models import flagship_config
 
-        jax.config.update("jax_platforms", "cpu")
-    from bench_common import setup_compilation_cache
-
-    setup_compilation_cache()
-    from __graft_entry__ import _flagship_cfg
-
-    cfg_base = _flagship_cfg(tiny=tiny)
+    cfg_base = flagship_config(tiny=tiny)
     global SEQ, STEPS, BATCHES, ATTN, REMAT
     if tiny:
         SEQ, STEPS, BATCHES = 128, 2, [2]
     # Env-restricted grids for follow-up runs (e.g. the pallas column
-    # alone after a kernel fix, chip_queue.sh stages 4/4c/4d/4e).
+    # alone after a kernel fix).
     lc_env = os.environ.get("PBST_SWEEP_LOSS_CHUNKS")
     if lc_env:
         # Chunked cross-entropy: the (B, S, vocab) fp32 logits tensor
-        # never materializes — the hypothesis is that freeing ~0.8 GB
-        # of loss-tail activation unlocks the batch-8 points that
-        # failed to compile in r02.
+        # never materializes, freeing ~0.8 GB of loss-tail activation.
         cfg_base = dataclasses.replace(cfg_base, loss_chunks=int(lc_env))
-    # Reduced-precision Adam moments (models.default_optimizer):
-    # frees 2.8 GB of optimizer HBM at the flagship shape — the
-    # second batch-8 unlock hypothesis next to chunked CE. One parser
-    # shared with bench.py (bench_common) so labels never diverge.
-    from bench_common import parse_mu_dtype
-
+    # Reduced-precision Adam moments (models.default_optimizer) free
+    # 2.8 GB of optimizer HBM at the flagship shape. One parser shared
+    # with bench.py (bench_common) so labels never diverge.
     try:
         mu_dtype, mu_label = parse_mu_dtype(
             os.environ.get("PBST_SWEEP_MU_DTYPE"))
@@ -145,39 +133,31 @@ def main() -> int:
     if attn_env:
         ATTN = attn_env.split(",")
         # flash attention frees the S^2 probs memory, so remat=none
-        # and batch 8 may compile where the xla column could not
-        # (r02: batch 8 under remat("dots") failed) — keep them in:
-        # that unlock is the MFU-push hypothesis the sweep must test.
+        # and batch 8 may compile where the xla column could not.
         REMAT = [r for r in REMAT if r[0] in ("none", "dots")]
 
+    # Knobs are validated above, before the backend comes up.
+    device = bench_device(rehearsal=tiny)
     results = []
     grid = list(itertools.product(REMAT, BATCHES, ATTN))
     for (rname, remat, policy), batch, attn in grid:
-        # Interpreter-mode pallas smokes fine at the tiny shape
-        # (~10 s/point on CPU) — the r2-era skip here would silently
-        # empty the pallas-only queue stages in tiny mode.
-        fatal = None
         try:
-            r = run_point(cfg_base, rname, remat, policy, batch, attn,
-                          mu_dtype=mu_dtype)
+            r = run_point(cfg_base, device, rname, remat, policy, batch,
+                          attn, mu_dtype=mu_dtype)
             if cfg_base.loss_chunks > 1:
                 r["loss_chunks"] = cfg_base.loss_chunks
             if mu_dtype is not None:
                 r["mu_dtype"] = mu_label
-        except Exception as e:  # noqa: BLE001 — a failing point (OOM,
-            r = {"remat": rname, "batch": batch, "attn": attn,  # eg)
+        except Exception as e:  # noqa: BLE001 — a point that does not
+            # fit or lower (an OOM, say) is a result of the sweep; the
+            # script still exits non-zero unless some point ran.
+            r = {**device, "remat": rname, "batch": batch, "attn": attn,
                  "error": f"{type(e).__name__}: {str(e)[:120]}"}
-            fatal = e
         print(json.dumps(r), flush=True)
         results.append(r)
-        if fatal is not None and abandon_if_unavailable(
-                fatal, "the remaining sweep points"):
-            break
     if not results:
-        # A sweep that emitted NOTHING must say so on stdout — a
-        # silent rc=1 from a queue stage reads like a crash in
-        # chip_logs (r5 rehearsal finding: an in-loop skip left
-        # stages 4/4e/4f with zero rows for three rounds).
+        # A sweep that emitted NOTHING must say so on stdout: a
+        # silent exit 1 reads like a crash.
         print(json.dumps({"error": "sweep emitted no points "
                           f"(grid had {len(grid)})"}), flush=True)
         return 1

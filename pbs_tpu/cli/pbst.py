@@ -2009,11 +2009,11 @@ def cmd_quantize(args) -> int:
     reads a checkpoint holding a transformer/MoE param tree, writes a
     new checkpoint with int8 {'q','s'} leaves (models.quant layout)
     for the serving forwards, and prints the byte accounting."""
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from pbs_tpu.ckpt import load_checkpoint, save_checkpoint
     from pbs_tpu.models.quant import quantize_weights, quantized_nbytes
+    from pbs_tpu.utils.compile_cache import setup_compilation_cache
+
+    setup_compilation_cache()
 
     state, meta = load_checkpoint(args.src)
     params = None
@@ -2038,22 +2038,17 @@ def cmd_quantize(args) -> int:
 
 
 def cmd_serve_demo(args) -> int:
-    """Continuous-batching serving demo on a tiny model (CPU-safe):
-    submits a request mix THROUGH the gateway front door (admission +
-    fair queue + routing; docs/GATEWAY.md), drains the engine, prints
-    both surfaces — gateway stats and the engine's SLO stats (incl.
-    prefix-cache hits)."""
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    """Continuous-batching serving demo on a tiny model, on JAX's
+    default device: submits a request mix THROUGH the gateway front
+    door (admission + fair queue + routing; docs/GATEWAY.md), drains
+    the engine, prints both surfaces — gateway stats and the engine's
+    SLO stats (incl. prefix-cache hits)."""
     import jax
-
-    try:
-        jax.config.update("jax_platforms",
-                          os.environ["JAX_PLATFORMS"].split(",")[0])
-    except RuntimeError:
-        pass
     import jax.numpy as jnp
+
+    from pbs_tpu.utils.compile_cache import setup_compilation_cache
+
+    setup_compilation_cache()
 
     from pbs_tpu.gateway import BatcherBackend, Gateway, TenantQuota
     from pbs_tpu.models import TransformerConfig, init_params
@@ -2093,7 +2088,7 @@ def cmd_serve_demo(args) -> int:
 
 
 def _serve_tiny_cfg():
-    """The serve CLI's tiny CPU-safe model (docs/SERVING.md): small
+    """The serve CLI's tiny model (docs/SERVING.md): small
     enough that construction + a full demo stays inside the tier-1
     smoke budget, big enough that every partition rule family (embed /
     norms / attention / mlp / head) has a leaf to place."""
@@ -2121,17 +2116,11 @@ def cmd_serve(args) -> int:
       shadowed / uncovered — all must be empty; the serve-discipline
       pass gates the same facts in CI).
     """
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
-    try:
-        jax.config.update("jax_platforms",
-                          os.environ["JAX_PLATFORMS"].split(",")[0])
-    except RuntimeError:
-        pass
+    from pbs_tpu.utils.compile_cache import setup_compilation_cache
 
+    setup_compilation_cache()
     cfg = _serve_tiny_cfg()
     if args.action == "stats":
         import re

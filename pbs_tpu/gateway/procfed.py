@@ -93,7 +93,11 @@ def _member_main(spec: dict) -> None:
     one Gateway + its journal + an RpcServer; everything stateful is
     driven by parent ops — the child never reads a wall clock into its
     books."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # The one place the package pins a platform: a member hosts
+    # SimServeBackend only and must never claim the chip, which belongs
+    # to the parent's process — so the pin is forced, not a default the
+    # parent's JAX_PLATFORMS=tpu would override.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     from pbs_tpu.gateway.backends import SimServeBackend
     from pbs_tpu.gateway.gateway import Gateway
     from pbs_tpu.gateway.journal import GatewayJournal, read_journal

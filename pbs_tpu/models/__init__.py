@@ -1,3 +1,4 @@
+from pbs_tpu.models.flagship import flagship_config
 from pbs_tpu.models.generate import (
     forward_with_cache,
     init_cache,
@@ -41,6 +42,7 @@ __all__ = [
     "SpeculativeBatcher",
     "MoEConfig",
     "TransformerConfig",
+    "flagship_config",
     "forward",
     "make_continuous_serve_step",
     "forward_with_cache",

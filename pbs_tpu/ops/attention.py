@@ -24,8 +24,9 @@ masks padded keys in-kernel, and slices padded query rows off.  The
 backward kernels rely on the padded rows' output cotangent being zero,
 which the wrapper's slice guarantees.
 
-Falls back to interpreter mode off-TPU so the same code paths are
-tested on CPU CI (the fake-backend pattern, SURVEY.md §4).
+On the CPU the kernels run in Pallas interpret mode, so the same code
+paths are tested in CI (the fake-backend pattern, SURVEY.md §4);
+everywhere else they compile through Mosaic (``ops/interpret.py``).
 """
 
 from __future__ import annotations
@@ -37,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from pbs_tpu.ops.interpret import resolve_interpret
 from pbs_tpu.utils.params import integer_param
 
 # Block-shape defaults, env-tunable so the on-chip sweep can explore
@@ -77,8 +79,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal: bool,
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
+        ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[0, 0, ks, :]
+        v = v_ref[0, 0, ks, :]
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32),
             dimension_numbers=(((1,), (1,)), ((), ())),
@@ -175,8 +178,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dlse_ref,
     i = pl.program_id(2)
 
     def body(j, acc):
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[0, 0, ks, :].astype(jnp.float32)
+        v = v_ref[0, 0, ks, :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -217,15 +221,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dlse_ref,
 
     def body(i, carry):
         dk, dv = carry
+        qs = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         # GQA: this kv head serves `group` q heads — reduce in-kernel.
         for r in range(group):
-            q = q_ref[0, r, pl.ds(i * block_q, block_q), :].astype(
-                jnp.float32) * sm_scale
-            do = do_ref[0, r, pl.ds(i * block_q, block_q), :].astype(
-                jnp.float32)
-            lse = lse_ref[0, r, pl.ds(i * block_q, block_q), :]   # (BQ, 1)
-            delta = (dl_ref[0, r, pl.ds(i * block_q, block_q), :]
-                     - dlse_ref[0, r, pl.ds(i * block_q, block_q), :])
+            q = q_ref[0, r, qs, :].astype(jnp.float32) * sm_scale
+            do = do_ref[0, r, qs, :].astype(jnp.float32)
+            lse = lse_ref[0, r, qs, :]   # (BQ, 1)
+            delta = dl_ref[0, r, qs, :] - dlse_ref[0, r, qs, :]
             s = jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # (BQ, BK)
@@ -402,8 +404,7 @@ def _flash_padded(q, k, v, causal, block_q, block_k, interpret,
     if H % Hkv:
         raise ValueError(f"H={H} not a multiple of Hkv={Hkv}")
     bq, bk, S_pad = plan_blocks(S, block_q, block_k)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
 
     if S_pad != S:
         pad = [(0, 0), (0, S_pad - S), (0, 0), (0, 0)]

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from pbs_tpu.ops.interpret import resolve_interpret
+
 DEFAULT_BLOCK = 256
 
 # Stat vector slots (i32; tile counts, not raw flops — the host scales,
@@ -129,8 +131,7 @@ def instrumented_matmul(
         raise ValueError(
             f"shape ({M},{K})x({K},{N}) not divisible by blocks "
             f"({bm},{bn},{bk})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n_k = K // bk
 
     out, stats = pl.pallas_call(

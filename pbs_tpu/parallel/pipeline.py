@@ -32,6 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pbs_tpu.models.transformer import (
@@ -42,11 +43,6 @@ from pbs_tpu.models.transformer import (
     rope_tables,
     token_xent,
 )
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def pipeline_layer_specs(tp: bool = False) -> dict:
@@ -240,10 +236,7 @@ def _pipe_blocks(cfg: TransformerConfig, mesh: Mesh, n_micro: int):
         in_specs=(pipeline_layer_specs(tp > 1), P(None, "dp", s, None)),
         out_specs=P("pp", "dp", s, None),
     )
-    try:  # replication-check kwarg was renamed check_rep -> check_vma
-        return shard_map(pipe, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover - older jax
-        return shard_map(pipe, check_rep=False, **kwargs)
+    return shard_map(pipe, check_vma=False, **kwargs)
 
 
 def make_pipelined_loss(cfg: TransformerConfig, mesh: Mesh, n_micro: int):
@@ -470,10 +463,7 @@ def _moe_pipe_blocks(cfg, mesh: Mesh, n_micro: int):
                   P(None, "dp", s, None)),
         out_specs=(P("pp", "dp", s, None), P("dp"), P("dp")),
     )
-    try:
-        return shard_map(pipe, check_vma=False, **kwargs)
-    except TypeError:  # pragma: no cover - older jax
-        return shard_map(pipe, check_rep=False, **kwargs)
+    return shard_map(pipe, check_vma=False, **kwargs)
 
 
 def make_pipelined_moe_train(

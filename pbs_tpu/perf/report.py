@@ -41,9 +41,9 @@ def baseline_path() -> str:
 
 
 def native_info() -> dict:
-    """The mode/availability stamp every report (and the serving
-    fallback in bench.py) carries, so BENCH_r* rounds stay comparable
-    across machines with and without a toolchain. ``native_tier``
+    """The mode/availability stamp every report (and chip_smoke.py's
+    header) carries, so runs stay comparable across machines with and
+    without a toolchain. ``native_tier``
     says WHICH binding executed (fastcall needs Python.h at build
     time); ``native_error`` carries the cached build/load failure."""
     from pbs_tpu.runtime import native

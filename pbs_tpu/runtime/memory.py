@@ -54,15 +54,13 @@ def nbytes_of(tree: Any) -> int:
 
 
 def device_memory_stats(device=None) -> dict:
-    """Live HBM numbers from the runtime (bytes_in_use / bytes_limit),
-    empty when the backend doesn't expose them (CPU sim)."""
-    try:
-        import jax
+    """Live HBM numbers from the runtime (bytes_in_use / bytes_limit)
+    for ``device`` (default: the first JAX device), empty when the
+    backend doesn't expose them (the CPU returns None)."""
+    import jax
 
-        dev = device if device is not None else jax.devices()[0]
-        return dict(dev.memory_stats() or {})
-    except Exception:
-        return {}
+    dev = device if device is not None else jax.devices()[0]
+    return dict(dev.memory_stats() or {})
 
 
 @dataclasses.dataclass
@@ -90,9 +88,20 @@ class MemoryManager:
 
     @classmethod
     def for_device(cls, device=None,
-                   default_capacity: int = 16 << 30) -> "MemoryManager":
+                   default_capacity: int | None = None) -> "MemoryManager":
+        """Capacity from the runtime's own ``bytes_limit``; where the
+        backend reports none, ``default_capacity`` if given, else the
+        device's entry in the peak table (telemetry/peaks.py — an
+        unlisted TPU kind raises rather than being taken for 16 GiB)."""
         stats = device_memory_stats(device)
-        cap = int(stats.get("bytes_limit", default_capacity))
+        if "bytes_limit" in stats:
+            cap = int(stats["bytes_limit"])
+        elif default_capacity is not None:
+            cap = int(default_capacity)
+        else:
+            from pbs_tpu.telemetry.peaks import device_peaks
+
+            cap = device_peaks(device).hbm_bytes
         used = int(stats.get("bytes_in_use", 0))
         return cls(cap, reserve_bytes=used)
 

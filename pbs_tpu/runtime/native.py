@@ -4,14 +4,18 @@ The reference's hot paths are C compiled into the hypervisor/guest
 kernel; ours is a small C++ shared library over flat u64 buffers —
 seqlock ledger writes/snapshots and the lockless trace ring — bound via
 ctypes (no pybind11 in this image; the ABI is flat by design). The
-library is built on demand with the in-tree Makefile and cached;
-everything degrades to the pure-Python implementations when a toolchain
-is unavailable, so nothing upstack depends on native availability.
+binaries are not tracked: each is built from ``native/*.cc`` with the
+in-tree Makefile on first use and rebuilt whenever its sources change
+(a stamp file beside it holds the hash of the sources it was built
+from — mtimes do not survive a checkout or a copy). Everything degrades
+to the pure-Python implementations when a toolchain is unavailable, so
+nothing upstack depends on native availability.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 
@@ -24,8 +28,8 @@ _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 #: ABI — the sanitizer tier (libpbst_runtime_{asan,ubsan}.so) runs the
 #: whole ctypes surface under ASan/UBSan in a subprocess with nothing
 #: but this env var changed. An override path is used as-is: no
-#: mtime-vs-source rebuild (the override names a specific artifact,
-#: and `make asan` owns its freshness).
+#: freshness check and no rebuild (the override names a specific
+#: artifact, and `make asan` owns its freshness).
 _LIB_OVERRIDE = os.environ.get("PBST_NATIVE_LIB") or None
 _LIB_PATH = os.path.abspath(
     _LIB_OVERRIDE if _LIB_OVERRIDE
@@ -53,23 +57,60 @@ def _note_failure(reason: str) -> None:
                 f"paths in use ({reason})")
 
 
-def _build() -> bool:
+#: What each artifact is built from (its freshness stamp hashes these).
+_RUNTIME_SOURCES = ("pbst_runtime.cc", "Makefile")
+_FASTCALL_SOURCES = ("pbst_fastcall.cc", "pbst_runtime.cc", "Makefile")
+
+
+def _source_hash(sources: tuple[str, ...]) -> str:
+    h = hashlib.sha256()
+    for name in sources:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(name.encode() + b"\0" + f.read() + b"\0")
+    return h.hexdigest()
+
+
+def _fresh(artifact: str, sources: tuple[str, ...]) -> bool:
+    """True when ``artifact`` exists and its stamp names the sources
+    now in the tree."""
     try:
+        with open(artifact + ".srchash") as f:
+            return (os.path.exists(artifact)
+                    and f.read().strip() == _source_hash(sources))
+    except OSError:
+        return False
+
+
+def _build(artifact: str, sources: tuple[str, ...],
+           target: str | None = None) -> bool:
+    """``make -B`` the artifact (its mtime says nothing after a copy)
+    and stamp it with the hash of what it was built from."""
+    stamp = artifact + ".srchash"
+    try:
+        # A failed build must not leave a stamp vouching for an old .so.
+        if os.path.exists(stamp):
+            os.unlink(stamp)
+        want = _source_hash(sources)
         proc = subprocess.run(
-            ["make", "-C", os.path.abspath(_NATIVE_DIR)],
+            ["make", "-B", "-C", os.path.abspath(_NATIVE_DIR)]
+            + ([target] if target else []),
             capture_output=True, text=True, timeout=120,
         )
     except Exception as e:  # no make, sandboxed exec, timeout, ...
         _note_failure(f"build not attempted: {type(e).__name__}: {e}")
         return False
-    if proc.returncode == 0:
-        return True
-    # The actionable part of a failed make is the stderr tail (the
-    # compiler error), not the whole transcript.
-    tail = " | ".join(
-        (proc.stderr or proc.stdout or "").strip().splitlines()[-4:])
-    _note_failure(f"make exited {proc.returncode}: {tail[:400]}")
-    return False
+    if proc.returncode != 0 or not os.path.exists(artifact):
+        # The actionable part of a failed make is the stderr tail (the
+        # compiler error), not the whole transcript.
+        tail = " | ".join(
+            (proc.stderr or proc.stdout or "").strip().splitlines()[-4:])
+        _note_failure(f"make exited {proc.returncode}: {tail[:400]}")
+        return False
+    tmp = f"{stamp}.tmp.{os.getpid()}"
+    with open(tmp, "w") as f:
+        f.write(want + "\n")
+    os.replace(tmp, stamp)
+    return True
 
 
 def _declare(lib: ctypes.CDLL) -> None:
@@ -152,32 +193,23 @@ def load() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH):
-            if _LIB_OVERRIDE:
+        if _LIB_OVERRIDE:
+            if not os.path.exists(_LIB_PATH):
                 # make only knows how to produce the default artifact;
                 # an override names exactly one file, so a missing one
                 # is the caller's bug, not a build trigger.
                 _note_failure(
                     f"PBST_NATIVE_LIB={_LIB_PATH} does not exist")
                 return None
-            if not _build():
+        elif not _fresh(_LIB_PATH, _RUNTIME_SOURCES):
+            if not _build(_LIB_PATH, _RUNTIME_SOURCES):
                 return None
-        for attempt in (0, 1):
-            try:
-                lib = ctypes.CDLL(_LIB_PATH)
-                _declare(lib)
-                _lib = lib
-                break
-            except (OSError, AttributeError) as e:
-                # AttributeError = stale .so missing a newer symbol;
-                # rebuild once, then degrade to the Python paths.
-                _lib = None
-                if attempt == 1 or _LIB_OVERRIDE:
-                    _note_failure(
-                        f"load failed: {type(e).__name__}: {e}")
-                    break
-                if not _build():
-                    break
+        try:
+            lib = ctypes.CDLL(_LIB_PATH)
+            _declare(lib)
+            _lib = lib
+        except (OSError, AttributeError) as e:
+            _note_failure(f"load failed: {type(e).__name__}: {e}")
         return _lib
 
 
@@ -188,19 +220,6 @@ def available() -> bool:
 _FC_PATH = os.path.abspath(os.path.join(_NATIVE_DIR, "pbst_fastcall.so"))
 _fc = None
 _fc_tried = False
-
-
-def _fresh(artifact: str, sources: tuple[str, ...]) -> bool:
-    """True when ``artifact`` exists and is no older than any source —
-    the cheap stand-in for a make invocation."""
-    try:
-        amt = os.path.getmtime(artifact)
-        return all(
-            amt >= os.path.getmtime(
-                os.path.join(os.path.abspath(_NATIVE_DIR), s))
-            for s in sources)
-    except OSError:
-        return False
 
 
 def fastcall():
@@ -221,23 +240,15 @@ def fastcall():
     # Build OUTSIDE the lock: a 120 s make held under it would convoy
     # every ring/ledger constructor. make is idempotent, so a racing
     # duplicate build is wasteful but harmless; the import below is
-    # serialized again. The mtime pre-check keeps the common case
-    # (fresh committed .so) free of a per-process subprocess spawn
-    # while still rebuilding when a source outlives the artifact (the
-    # conftest _build_native contract).
-    if not _fresh(_FC_PATH, ("pbst_fastcall.cc", "pbst_runtime.cc")):
-        try:
-            subprocess.run(
-                ["make", "-C", os.path.abspath(_NATIVE_DIR),
-                 "fastcall"],
-                capture_output=True, text=True, timeout=120)
-        except Exception:
-            pass  # missing make: the exists() check below decides
+    # serialized again. A failed build (no Python.h) leaves no stamp
+    # and the freshness check below decides.
+    if not _fresh(_FC_PATH, _FASTCALL_SOURCES):
+        _build(_FC_PATH, _FASTCALL_SOURCES, target="fastcall")
     with _lock:
         if _fc is not None or _fc_tried:
             return _fc
         _fc_tried = True
-        if not os.path.exists(_FC_PATH):
+        if not _fresh(_FC_PATH, _FASTCALL_SOURCES):
             _note_failure("fastcall tier unavailable (Python.h or "
                           "toolchain missing); ctypes tier in use")
             return None

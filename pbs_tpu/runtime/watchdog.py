@@ -5,7 +5,7 @@ Reference mapping (SURVEY.md §5 "failure detection"):
 - The hypervisor's NMI watchdog drives a PMU counter so it can fire even
   when a CPU is wedged with interrupts off (``xen/arch/x86/nmi.c:38,
   249-302``). The TPU analog of "wedged with interrupts off" is a step
-  that never returns (hung collective, tunnel loss): the cooperative run
+  that never returns (hung collective, lost device): the cooperative run
   loop cannot observe it, so :class:`WallWatchdog` watches progress from
   its own thread — out-of-band by construction, like the NMI.
 - Per-domain watchdogs (``tools/misc/xenwatchdogd.c``) require the guest

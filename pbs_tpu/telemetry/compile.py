@@ -67,14 +67,11 @@ class CompileMeter:
     def _register(self) -> None:
         if self._installed:
             return
-        try:
-            import jax
+        import jax
 
-            jax.monitoring.register_event_duration_secs_listener(
-                self._on_event)
-            self._installed = True
-        except Exception:  # noqa: BLE001 — metering must never break jobs
-            self._installed = False
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_event)
+        self._installed = True
 
     # -- listener ---------------------------------------------------------
 

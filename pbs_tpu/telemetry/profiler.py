@@ -232,7 +232,8 @@ class XlaQuantumProfiler:
     def profile(self, fn: Callable[[], Any]) -> tuple[Any, TraceStats | None]:
         """Run ``fn`` under the profiler; returns (fn(), stats|None).
         Never raises on profiler trouble — the quantum's result always
-        comes back; a failed sample just leaves stats None."""
+        comes back; a failed sample leaves stats None and is counted
+        in ``failures``. What ``fn`` itself raises propagates."""
         if not _PROFILE_LOCK.acquire(blocking=False):
             return fn(), None  # another quantum holds the one session
         logdir = self.keep_logdir or tempfile.mkdtemp(prefix="pbst_prof_")
@@ -267,6 +268,9 @@ class XlaQuantumProfiler:
                 stats = parse_trace_dir(logdir)
                 if stats is not None:
                     self.samples += 1
+                else:
+                    self.failures += 1
+                    self.last_error = f"no trace file under {logdir}"
                 return out, stats
             except Exception as e:
                 self.failures += 1

@@ -27,18 +27,9 @@ from typing import Any, Callable, Protocol
 
 import numpy as np
 
-from pbs_tpu import knobs
 from pbs_tpu.faults import injector as _faults
 from pbs_tpu.telemetry.counters import NUM_COUNTERS, Counter
 from pbs_tpu.utils.clock import Clock, MonotonicClock, VirtualClock
-
-# Per-chip peaks used by the roofline stall estimator. Defaults are TPU
-# v5e-class; override per deployment via the knob registry
-# (telemetry.source.*). (The reference equivalently bakes in per-family
-# PMU capabilities, asm-x86/perfctr.h:40-65.)
-DEFAULT_PEAK_FLOPS = knobs.default("telemetry.source.peak_flops")
-DEFAULT_PEAK_HBM_BW = knobs.default("telemetry.source.peak_hbm_bw")
-
 
 #: Channels a ``telemetry.counters`` 'stall' fault freezes: the
 #: PMC-grade measurements a dead readout stops delivering. Progress
@@ -366,16 +357,9 @@ class SimBackend:
 
 
 def cost_analysis_of(compiled) -> tuple[int, int]:
-    """(flops, hbm_bytes) from an XLA compiled executable, best-effort."""
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = int(ca.get("flops", 0.0))
-        nbytes = int(ca.get("bytes accessed", 0.0))
-        return flops, nbytes
-    except Exception:
-        return 0, 0
+    """(flops, hbm_bytes) from an XLA compiled executable."""
+    ca = compiled.cost_analysis()
+    return int(ca.get("flops", 0.0)), int(ca.get("bytes accessed", 0.0))
 
 
 class TpuBackend:
@@ -391,16 +375,32 @@ class TpuBackend:
     def __init__(
         self,
         clock: Clock | None = None,
-        peak_flops: float = DEFAULT_PEAK_FLOPS,
-        peak_hbm_bw: float = DEFAULT_PEAK_HBM_BW,
+        peak_flops: float | None = None,
+        peak_hbm_bw: float | None = None,
         profile_every: int = 0,
         profiler=None,
     ):
         self.clock = clock or MonotonicClock()
+        # Roofline peaks: an explicit argument wins; otherwise the
+        # device's own entry in the peak table (telemetry/peaks.py —
+        # an unlisted TPU kind raises here, at construction).
+        if peak_flops is None or peak_hbm_bw is None:
+            from pbs_tpu.telemetry.peaks import device_peaks
+
+            peaks = device_peaks()
+            if peak_flops is None:
+                peak_flops = peaks.flops
+            if peak_hbm_bw is None:
+                peak_hbm_bw = peaks.hbm_bw
         self.peak_flops = peak_flops
         self.peak_hbm_bw = peak_hbm_bw
         # per-job (flops, bytes) from cost analysis, captured at first run
         self._costs: dict[str, tuple[int, int]] = {}
+        #: Executables whose cost analysis the backend refused. Such a
+        #: job runs without a roofline stall estimate, so the count is
+        #: kept where a caller (chip_smoke.py) can require it to be 0.
+        self.cost_failures = 0
+        self.last_cost_error: str | None = None
         # Measured-telemetry sampling: every N-th invocation per job runs
         # under the XLA profiler; the parsed per-op time fills the stall/
         # collective counters and its fractions carry forward until the
@@ -432,23 +432,23 @@ class TpuBackend:
                 # guest's XLA cost analysis). Attributed compile spend
                 # lands in the job's own COMPILE_* counters.
                 fn, a, k = job._foreign_spec
-                try:
+                # A callable with no .lower is not a jit stage: it gets
+                # profiler telemetry only. A jit stage that fails to
+                # lower or compile is the tenant's fault and propagates
+                # (the executor contains it to the job).
+                if hasattr(fn, "lower"):
                     with self.compile_meter.attribute(job.name):
                         compiled = fn.lower(*a, **k).compile()
                     job.compiled = compiled
-                except Exception:
-                    compiled = None  # not a jit stage: profiler only
-            c = cost_analysis_of(compiled) if compiled is not None else (0, 0)
+            c = (0, 0)
+            if compiled is not None:
+                try:
+                    c = cost_analysis_of(compiled)
+                except Exception as e:  # noqa: BLE001 — counted, see init
+                    self.cost_failures += 1
+                    self.last_cost_error = f"{type(e).__name__}: {e}"
             self._costs[job.name] = c
         return c
-
-    def _block(self, out) -> None:
-        try:
-            import jax
-
-            jax.block_until_ready(out)
-        except Exception:
-            pass
 
     _METRIC_KEYS = (
         ("collective_wait_ns", Counter.COLLECTIVE_WAIT_NS),
@@ -486,6 +486,7 @@ class TpuBackend:
         starve it for the equivalent share — compile spend is tracked
         in its own counters and governed by the admission budget
         (runtime/compile_gate.py), not by the runtime scheduler."""
+        import jax
 
         def run():
             out = fn(job.state)
@@ -495,7 +496,10 @@ class TpuBackend:
                 st, metrics = out
             else:
                 st = out
-            self._block(st)
+            # A device error (an OOM, a failed execution) surfaces
+            # here and must reach the executor: a swallowed one is a
+            # step that "ran".
+            jax.block_until_ready(st)
             return st, metrics
 
         t0 = time.monotonic_ns()

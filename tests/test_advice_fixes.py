@@ -1,4 +1,4 @@
-"""Regression tests for the round-1 advisor findings (ADVICE.md).
+"""Regression tests for the round-1 advisor findings.
 
 1. RPC subjects: privileged ("system") labels over the wire require a
    token-authenticated connection (agent.py finding, medium).

@@ -1,11 +1,10 @@
 """bench.py candidate-config knobs: fail fast, before any backend.
 
-The headline protocol (bench.py) accepts PBST_BENCH_* env knobs so a
-sweep-validated configuration can be proven under the exact driver
-protocol before becoming the committed default. A typo in a knob must
-die in milliseconds with a clean message — never after TPU init or a
-20-40 s compile (the chip-claim discipline in docs/OPS.md makes every
-wasted chip client expensive).
+bench.py accepts PBST_BENCH_* env knobs that select a candidate
+configuration. A typo in a knob must die in milliseconds with a clean
+message — never after TPU init or a 700M-step compile (chip minutes
+are budgeted). bench.py is one process; its tiny mode is a rehearsal
+that says so in the row it prints.
 
 Reference analog: boot-param validation at scheduler init
 (xen-4.2.1/xen/common/sched_credit.c:2000-2031 clamps a bad
@@ -26,14 +25,14 @@ BENCH = os.path.join(REPO, "bench.py")
 
 
 def _run_worker(env_extra: dict, timeout: float = 60.0):
-    """Run the bench WORKER directly (no supervisor indirection) with
-    tiny mode pinned to CPU, returning (rc, stdout, stderr, seconds)."""
+    """Run bench.py in tiny (rehearsal) mode on the CPU the test
+    session pins, returning (rc, stdout, stderr, seconds)."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("PBST_BENCH_")}
     env.update({"PBST_BENCH_TINY": "1", **env_extra})
     t0 = time.perf_counter()
     proc = subprocess.run(
-        [sys.executable, BENCH, "--worker"], capture_output=True,
+        [sys.executable, BENCH], capture_output=True,
         text=True, timeout=timeout, env=env, cwd=REPO)
     return proc.returncode, proc.stdout, proc.stderr, time.perf_counter() - t0
 
@@ -60,8 +59,8 @@ def test_bad_knob_fails_fast_without_backend(env, msg):
 @pytest.mark.parametrize("bad", ["0", "-4", "8,0", "4,-2,8"])
 def test_sweep_rejects_non_positive_batches(bad):
     """PBST_SWEEP_BATCHES with a value < 1 must fail fast with the
-    error JSON (ADVICE r3) — not surface as per-point error rows after
-    burning chip time."""
+    error JSON — not surface as per-point error rows after burning
+    chip time."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("PBST_SWEEP_")}
     env.update({"PBST_SWEEP_TINY": "1", "PBST_SWEEP_BATCHES": bad})
@@ -85,8 +84,28 @@ def test_good_knobs_reach_result_with_extras():
     line = [ln for ln in out.splitlines() if ln.startswith("{")][-1]
     result = json.loads(line)
     assert result["value"] > 0
+    # A CPU run is a rehearsal: marked, named for its platform, and
+    # never under the device metric's name or with a utilization.
+    assert result["metric"] == "rehearsal_flagship_train_throughput"
+    assert result["rehearsal"] is True and result["platform"] == "cpu"
+    assert "mfu" not in result and "vs_baseline" not in result
     # The result JSON must name every non-default knob so an artifact
     # can never be mistaken for the default-config headline.
     assert result["batch"] == 2
     assert result["loss_chunks"] == 4
     assert result["remat"] == "none"
+
+
+def test_bench_exits_nonzero_without_a_tpu():
+    """No TPU and no rehearsal asked for: bench.py names the platform
+    it found and exits non-zero before building a model — a
+    measurement path does not fall back to the CPU."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PBST_BENCH_")}
+    proc = subprocess.run(
+        [sys.executable, BENCH], capture_output=True, text=True,
+        timeout=60, env=env, cwd=REPO)
+    assert proc.returncode != 0
+    assert "platform=cpu" in proc.stderr and "not a TPU" in proc.stderr
+    assert proc.stdout.strip() == ""
+    assert "params initialized" not in proc.stderr

@@ -1,12 +1,14 @@
-"""Tiny-mode CI smokes for every chip-queue bench script.
+"""Tiny-mode rehearsals for every root bench script.
 
-Stage scripts fail on the CHIP if they regress — and chip minutes are
-the scarcest resource in this environment (docs/OPS.md). Each script
-has a CPU tiny mode for exactly this reason; this module pins that
-every queue stage's script still runs end to end and emits its
-artifact shape, so a refactor cannot silently spend tonight's claim
-window on a crash. (bench.py itself is covered by test_bench_knobs /
-test_bench_probe.)
+A script that regresses fails on the CHIP, and chip minutes are
+budgeted. Each script has a tiny rehearsal mode for exactly this
+reason; this module pins that every script still runs end to end on
+the CPU and emits its row shape — marked as a rehearsal, never under a
+device metric's name. (bench.py itself is covered by test_bench_knobs.)
+
+The scripts run as children of a pytest parent that has touched JAX:
+fine on the CPU, and the reason tpu_tests/ and chip_smoke.py are never
+run from this tree — a chip belongs to one process.
 """
 
 from __future__ import annotations
@@ -42,8 +44,9 @@ def test_bench_serving_tiny_covers_the_matrix():
     proc, rows = _run("bench_serving.py", {"PBST_BENCH_TINY": "1"})
     assert proc.returncode == 0, proc.stderr[-800:]
     metrics = {r["metric"] for r in rows}
-    assert "serving_prefill_ms" in metrics
-    assert "serving_decode_throughput" in metrics
+    assert "rehearsal_serving_prefill_ms" in metrics
+    assert "rehearsal_serving_decode_throughput" in metrics
+    assert all(r["rehearsal"] and r["platform"] == "cpu" for r in rows)
     # the full {dense, MoE} x {plain, spec} x {bf16, int8} engine
     # matrix minus interpreter-hostile cells (none: all engines are
     # XLA) — 8 rows, none allowed to be an error row on CPU
@@ -52,12 +55,10 @@ def test_bench_serving_tiny_covers_the_matrix():
     errs = [r for r in engine_rows if "error" in r]
     assert not errs, errs
     # Self-draft spec rows (bf16 dense, dropless MoE) are exact on the
-    # CPU's deterministic f32 path: acceptance must be ~1.0.  This is
-    # the guard the r5 chip run showed was missing — the MoE spec rows
-    # silently drafted with unrelated dense weights and measured the
-    # acceptance FLOOR (0.0 over the real vocab).
-    for m in ("serving_spec_continuous_bf16_throughput",
-              "serving_spec_continuous_moe_dropless_throughput"):
+    # CPU's deterministic f32 path: acceptance must be ~1.0 (a draft
+    # with unrelated weights measures the acceptance FLOOR instead).
+    for m in ("rehearsal_serving_spec_continuous_bf16_throughput",
+              "rehearsal_serving_spec_continuous_moe_dropless_throughput"):
         row = next(r for r in engine_rows if r["metric"] == m)
         assert row["acceptance"] >= 0.9, row
 
@@ -74,65 +75,4 @@ def test_bench_longctx_tiny_emits_points():
 def test_bench_decompose_tiny_emits_sections():
     proc, rows = _run("bench_decompose.py", {"PBST_DECOMP_TINY": "1"})
     assert proc.returncode == 0, proc.stderr[-800:]
-    sections = {r.get("section") for r in rows}
     assert len(rows) >= 3, rows
-
-
-def _queue_agenda(tmp_path):
-    """Every (env, argv) pair chip_queue.sh would run, parsed from its
-    own dry-run echo — the rehearsal below can never drift from the
-    real agenda."""
-    qdir = tmp_path / "q"
-    qdir.mkdir()
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("PBST_")}
-    env.update({"PBST_QUEUE_DRYRUN": "1",
-                "PBST_QUEUE_DRYRUN_DIR": str(qdir)})
-    proc = subprocess.run(
-        ["bash", os.path.join(REPO, "chip_queue.sh")],
-        capture_output=True, text=True, timeout=60, env=env,
-        cwd=str(qdir))
-    assert proc.returncode == 0, proc.stderr
-    agenda = []
-    for log in sorted((qdir / "chip_logs").glob("queue_*.log")):
-        for ln in log.read_text().splitlines():
-            if "DRYRUN: " not in ln:
-                continue
-            toks = ln.split("DRYRUN: ", 1)[1].split()
-            stage_env = {}
-            while toks and "=" in toks[0] and not toks[0].startswith(
-                    "python"):
-                k, v = toks.pop(0).split("=", 1)
-                stage_env[k] = v
-            agenda.append((stage_env, toks))
-    return agenda
-
-
-@pytest.mark.slow  # ~90 s full-agenda rehearsal; tier-1 runs at the 870 s kill (docs/PERF.md)
-def test_queue_stage_rehearsal_tiny(tmp_path):
-    """Execute every sweep/candidate stage command from the REAL queue
-    agenda in tiny mode on CPU (r5: stage 4's pallas-only grid was
-    silently empty in tiny mode for three rounds — only echoed, never
-    executed; a stage-level bug like that on the chip burns the one
-    claim window).  Plain-bench and serving/longctx/decompose stages
-    are covered by the dedicated smokes above."""
-    agenda = _queue_agenda(tmp_path)
-    assert len(agenda) >= 14, agenda
-    rehearsed = 0
-    for stage_env, argv in agenda:
-        script = argv[-1] if argv[-1].endswith(".py") else None
-        if script == "bench_sweep.py":
-            tiny_knob = "PBST_SWEEP_TINY"
-        elif script == "bench.py" and any(
-                k.startswith("PBST_BENCH_") for k in stage_env):
-            tiny_knob = "PBST_BENCH_TINY"  # candidate stages 5c-5e
-        else:
-            continue  # chip-only (tpu_tests) or covered by other smokes
-        proc, rows = _run(script, {**stage_env, tiny_knob: "1"})
-        label = f"{stage_env} {argv}"
-        assert proc.returncode == 0, f"{label}: {proc.stderr[-800:]}"
-        ok = [r for r in rows if "error" not in r]
-        assert ok, f"{label}: no green rows ({rows})"
-        rehearsed += 1
-    # stages 4, 4c, 4d, 4e, 4f, 5c, 5d, 5e
-    assert rehearsed == 8, rehearsed

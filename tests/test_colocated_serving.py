@@ -10,12 +10,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from pbs_tpu.models import flagship_config
 from pbs_tpu.runtime import Job, Partition, SchedParams
 from pbs_tpu.sched import FeedbackPolicy
 from pbs_tpu.telemetry import Counter
 from pbs_tpu.telemetry.source import TpuBackend
 from pbs_tpu.utils.clock import MonotonicClock
-from __graft_entry__ import _flagship_cfg
 
 
 @pytest.fixture(scope="module")
@@ -26,7 +26,7 @@ def tiny_world():
         make_train_step,
     )
 
-    cfg = _flagship_cfg(tiny=True)
+    cfg = flagship_config(tiny=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
     init_opt, train_step = make_train_step(cfg, learning_rate=1e-3)
     serve_step = make_serve_step(cfg, max_new_tokens=4)

@@ -116,10 +116,10 @@ def test_prefetcher_feeds_training(corpus):
     import jax
 
     from pbs_tpu.models import init_params, make_train_step
-    from __graft_entry__ import _flagship_cfg
+    from pbs_tpu.models import flagship_config
 
     ds, _ = corpus
-    cfg = _flagship_cfg(tiny=True)
+    cfg = flagship_config(tiny=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
     init_opt, train_step = make_train_step(cfg, learning_rate=1e-3)
     state = (params, jax.jit(init_opt)(params), 0)

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from pbs_tpu.models import (
+    flagship_config,
     forward,
     forward_with_cache,
     init_cache,
@@ -16,12 +17,11 @@ from pbs_tpu.models import (
     make_serve_step,
     prefill,
 )
-from __graft_entry__ import _flagship_cfg
 
 
 @pytest.fixture(scope="module")
 def tiny():
-    cfg = _flagship_cfg(tiny=True)
+    cfg = flagship_config(tiny=True)
     params = init_params(cfg, jax.random.PRNGKey(0))
     return cfg, params
 
