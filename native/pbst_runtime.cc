@@ -200,14 +200,26 @@ int pbst_hist_record_many(uint64_t* buf, int64_t total_slots,
 //
 // Header (u64): [0] head (total records written)  [1] tail (consumed)
 //               [2] capacity (records)            [3] lost
+//               [4] consumer attached (0/1)       [5..7] reserved
 // Records: 8 u64 each: [timestamp_ns, event_id, a0..a5].
 // Producer: the executor thread. Consumer: any monitor process mapping
 // the same buffer (xentrace analog). head/tail are monotonic; index =
 // value % capacity.
+//
+// Full-ring contract (the flight recorder, docs/TRACING.md): with no
+// consumer attached ([4] == 0) the producer owns the tail too and a
+// full ring OVERWRITES ITS OLDEST record (tail advances, lost counts
+// the records overwritten) -- a ring nobody drains keeps the newest
+// `capacity` records of a run of any length. With a consumer attached
+// the tail is the consumer's and a full ring DROPS THE NEW record
+// (lost counts the drops), as before: a drained stream is never torn.
+// Attaching is one store to [4] (the first consume does it); attach
+// before the ring laps if no record may be torn at the switch.
 // ---------------------------------------------------------------------------
 
-static const int kTraceHeaderWords = 4;
+static const int kTraceHeaderWords = 8;
 static const int kTraceRecWords = 8;
+static const int kTraceConsumerWord = 4;
 
 int pbst_trace_rec_words() { return kTraceRecWords; }
 int pbst_trace_header_words() { return kTraceHeaderWords; }
@@ -216,11 +228,13 @@ void pbst_trace_init(uint64_t* buf, uint64_t capacity) {
   buf[0] = 0;
   buf[1] = 0;
   buf[2] = capacity;
-  buf[3] = 0;
+  for (int i = 3; i < kTraceHeaderWords; i++) buf[i] = 0;
 }
 
-// Returns 1 if recorded, 0 if dropped (ring full -> lost++, matching
-// trace.c's "lost records" accounting rather than blocking).
+// Returns 1 if recorded, 0 if dropped (ring full with a consumer
+// attached -> lost++, matching trace.c's "lost records" accounting
+// rather than blocking). Full with no consumer: the oldest record is
+// overwritten (lost++) and the new one recorded.
 int pbst_trace_emit(uint64_t* buf, uint64_t ts_ns, uint64_t event,
                     uint64_t a0, uint64_t a1, uint64_t a2, uint64_t a3,
                     uint64_t a4, uint64_t a5) {
@@ -229,7 +243,9 @@ int pbst_trace_emit(uint64_t* buf, uint64_t ts_ns, uint64_t event,
   uint64_t tail = __atomic_load_n(&buf[1], __ATOMIC_ACQUIRE);
   if (head - tail >= cap) {
     __atomic_fetch_add(&buf[3], 1, __ATOMIC_RELAXED);
-    return 0;
+    if (__atomic_load_n(&buf[kTraceConsumerWord], __ATOMIC_ACQUIRE))
+      return 0;
+    __atomic_store_n(&buf[1], head - cap + 1, __ATOMIC_RELEASE);
   }
   uint64_t* rec = buf + kTraceHeaderWords + (head % cap) * kTraceRecWords;
   rec[0] = ts_ns;
@@ -242,10 +258,12 @@ int pbst_trace_emit(uint64_t* buf, uint64_t ts_ns, uint64_t event,
 
 // Batched emit of n records (flat n*8 u64, caller-staged) in at most
 // two wrap-aware memcpy spans — the EmitBatch flush becomes one C
-// call. Returns records written; records that don't fit are dropped
-// tail-first with the lost counter charged, exactly the drop
-// semantics of n scalar pbst_trace_emit calls (and byte-identical to
-// the Python emit_many fallback).
+// call. Returns records written. With a consumer attached, records
+// that don't fit are dropped tail-first with the lost counter charged;
+// with none, the oldest records make room (lost counts them) and all
+// n are accepted, only the last `capacity` of an oversized batch
+// landing -- exactly the semantics of n scalar pbst_trace_emit calls
+// (and byte-identical to the Python emit_many fallback).
 int pbst_trace_emit_many(uint64_t* buf, const uint64_t* recs, int n) {
   if (n <= 0) return 0;
   uint64_t cap = buf[2];
@@ -253,25 +271,37 @@ int pbst_trace_emit_many(uint64_t* buf, const uint64_t* recs, int n) {
   uint64_t tail = __atomic_load_n(&buf[1], __ATOMIC_ACQUIRE);
   uint64_t space = cap - (head - tail);
   uint64_t k = (uint64_t)n <= space ? (uint64_t)n : space;
+  uint64_t skip = 0;  // leading records of the batch never written
   if (k < (uint64_t)n) {
     __atomic_fetch_add(&buf[3], (uint64_t)n - k, __ATOMIC_RELAXED);
+    if (!__atomic_load_n(&buf[kTraceConsumerWord], __ATOMIC_ACQUIRE)) {
+      k = (uint64_t)n <= cap ? (uint64_t)n : cap;
+      skip = (uint64_t)n - k;
+      __atomic_store_n(&buf[1], head + (uint64_t)n - cap,
+                       __ATOMIC_RELEASE);
+    }
   }
   if (k == 0) return 0;
-  uint64_t start = head % cap;
+  uint64_t start = (head + skip) % cap;
   uint64_t k1 = k <= cap - start ? k : cap - start;
-  std::memcpy(buf + kTraceHeaderWords + start * kTraceRecWords, recs,
+  const uint64_t* src = recs + skip * kTraceRecWords;
+  std::memcpy(buf + kTraceHeaderWords + start * kTraceRecWords, src,
               k1 * kTraceRecWords * sizeof(uint64_t));
   if (k > k1) {
-    std::memcpy(buf + kTraceHeaderWords, recs + k1 * kTraceRecWords,
+    std::memcpy(buf + kTraceHeaderWords, src + k1 * kTraceRecWords,
                 (k - k1) * kTraceRecWords * sizeof(uint64_t));
   }
-  __atomic_store_n(&buf[0], head + k, __ATOMIC_RELEASE);
-  return (int)k;
+  __atomic_store_n(&buf[0], head + skip + k, __ATOMIC_RELEASE);
+  return (int)(skip + k);
 }
 
 // Consume up to max_records into out (flat u64 array). Returns count.
+// Draining IS attaching: the first consume marks the ring as having a
+// consumer, so from then on the producer leaves the tail alone.
 int pbst_trace_consume(uint64_t* buf, uint64_t* out, int max_records) {
   uint64_t cap = buf[2];
+  if (!__atomic_load_n(&buf[kTraceConsumerWord], __ATOMIC_RELAXED))
+    __atomic_store_n(&buf[kTraceConsumerWord], 1, __ATOMIC_RELEASE);
   uint64_t tail = __atomic_load_n(&buf[1], __ATOMIC_RELAXED);
   uint64_t head = __atomic_load_n(&buf[0], __ATOMIC_ACQUIRE);
   int n = 0;

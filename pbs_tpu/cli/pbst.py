@@ -175,7 +175,8 @@ def _load_spans(path: str, rids_path: str | None):
         with open(side_path) as f:
             side = json.load(f)
     asm = SpanAssembler(recs, side.get("rids", []),
-                        side.get("members"), side.get("tenant_table"))
+                        side.get("members"), side.get("tenant_table"),
+                        rid_base=side.get("rid_base", 0))
     return asm, side
 
 

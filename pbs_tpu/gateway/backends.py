@@ -183,14 +183,16 @@ class BatcherBackend(Backend):
         def _hook(rid: int, prompt_len: int, max_new: int) -> None:
             if not self._dispatching:
                 self.bypass_submits += 1
-            elif (self.exec_hook is not None
-                    and self._dispatching_req is not None):
+            elif self._dispatching_req is not None:
                 # Span execution attribution rides the same engine
                 # submit_hook seam the bypass counter uses: a gateway
                 # dispatch that reached engine.submit has entered the
                 # execution pipeline (prefill queue), which is this
-                # backend's observable "execution begins".
-                self.exec_hook(*self._dispatching_req)
+                # backend's observable "execution begins". The engine's
+                # id goes with it from here on.
+                self._dispatching_req[0].engine_rid = rid
+                if self.exec_hook is not None:
+                    self.exec_hook(*self._dispatching_req)
             if prev_hook is not None:
                 prev_hook(rid, prompt_len, max_new)
 

@@ -125,13 +125,14 @@ def _span_continuity(recorder: SpanRecorder, admitted_rids: list[str],
             "chains unverifiable (size the ring for the run)")
     if recorder.dropped_spans:
         problems.append(
-            f"span recorder dropped {recorder.dropped_spans} new "
-            "span(s) at the intern bound; chains unverifiable (raise "
+            f"span recorder forgot {recorder.dropped_spans} span(s) "
+            "at the intern bound; chains unverifiable (raise "
             "max_spans for the run)")
     recs = recorder.drain()
     asm = SpanAssembler(recs, recorder.rid_table(),
                         recorder.member_table(),
-                        recorder.tenant_table())
+                        recorder.tenant_table(),
+                        rid_base=recorder.rid_base)
     chain_problems = asm.validate(admitted_rids, aborted=aborted)
     # Cap the spew: one run with a systemic gap would otherwise emit
     # thousands of identical lines.

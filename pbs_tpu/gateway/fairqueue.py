@@ -67,6 +67,11 @@ class Request:
     #: scheduler exactly once no matter how many feedback periods or
     #: requeues it lives through.
     reported_wait_ns: int = 0
+    #: The id the backend's engine knows this request by, once it has
+    #: reached one (``BatcherBackend``); -1 = none. SPAN_EXEC carries
+    #: it, so the engine's own records join the request's chain by
+    #: identifier (docs/TRACING.md).
+    engine_rid: int = -1
 
 
 class DeficitRoundRobin:

@@ -54,7 +54,7 @@ from pbs_tpu.gateway.fairqueue import (
 )
 from pbs_tpu.gateway import journal as _jr
 from pbs_tpu.obs.spans import HistBatch, LatencyHistograms, SpanRecorder
-from pbs_tpu.obs.trace import EmitBatch, Ev, TraceBuffer
+from pbs_tpu.obs.trace import EmitBatch, Ev, TraceBuffer, register_ring
 from pbs_tpu.telemetry.counters import NUM_COUNTERS, Counter
 from pbs_tpu.utils.clock import MS, MonotonicClock
 
@@ -96,7 +96,7 @@ class Gateway:
         max_queued: int = 256,
         default_quota: TenantQuota | None = None,
         controller=None,
-        trace_capacity: int = 0,
+        trace_capacity: int | None = None,
         ledger_path: str | None = None,
         feedback_sink: Callable[[str, int, int], None] | None = None,
         feedback_period_ns: int = DEFAULT_FEEDBACK_PERIOD_NS,
@@ -159,8 +159,13 @@ class Gateway:
         #: Controller whose breaker/liveness view vetoes routing
         #: targets whose names match cluster agents (dist/controller).
         self.controller = controller
+        # The front door's ring follows the partition's rule: on, at
+        # the ``tbuf_size`` default, unless ``trace_capacity=0`` is
+        # passed; a flight recorder until somebody drains it.
         self.trace = (TraceBuffer(trace_capacity)
-                      if trace_capacity else None)
+                      if trace_capacity != 0 else None)
+        if self.trace is not None:
+            register_ring(f"gateway:{self.name}", self.trace)
         # Staged GW_* events: the pump is single-threaded (module
         # docstring), so a tick's worth of admits/dispatches/completes
         # is one vectorized ring write, flushed at tick end and before
@@ -275,7 +280,8 @@ class Gateway:
     def _span_exec(self, req: Request, now_ns: int) -> None:
         if self.spans is not None:
             self.spans.exec(now_ns, req.rid,
-                            self._backend_slot(req.backend), self.name)
+                            self._backend_slot(req.backend), self.name,
+                            req.engine_rid)
 
     def _span_handoff(self, req: Request, now_ns: int,
                       from_member: str, to_member: str) -> None:
@@ -536,8 +542,11 @@ class Gateway:
         bulk — the observability slabs BEFORE ``_feedback`` (its
         quantile reads and the stats surface must see this tick's
         samples), the trace batch at tick end."""
+        done = self._reap(self.clock.now_ns())
+        # The clock again: a backend's poll IS its engine tick (tens of
+        # milliseconds on a real model), and what follows is stamped
+        # with when it happens, not with when the round began.
         now = self.clock.now_ns()
-        done = self._reap(now)
         self._repair(now)
         self._dispatch(now)
         self._hist_batch.flush()
@@ -572,7 +581,13 @@ class Gateway:
         for b in self.backends:
             if not b.alive():
                 continue
-            for req, info in b.poll(now):
+            polled = b.poll(now)
+            if polled:
+                # A completion is stamped when the poll that produced
+                # it returned (constant within a tick under a
+                # VirtualClock: every record stays byte-identical).
+                now = self.clock.now_ns()
+            for req, info in polled:
                 if self._journal is not None:
                     self._journal.complete(now, self.name, req.rid)
                 self.inflight.pop(req.rid, None)

@@ -43,11 +43,22 @@ import numpy as np
 
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
+from pbs_tpu.obs.trace import Ev, TraceBuffer, host_ring, register_ring
 from pbs_tpu.models.transformer import (
     TransformerConfig,
     rms_norm,
     rope_tables,
 )
+
+
+# Ring stamps and span durations are host wall time whatever clock the
+# latency accounting runs on: they measure host work, and they share
+# time.monotonic_ns with every other ring of the process.
+_ns = time.monotonic_ns
+# A span also opens a profiler annotation of its name: free while no
+# profile is being captured, and an operator's xprof capture then shows
+# the engine's spans against the device lanes.
+_span = jax.profiler.TraceAnnotation
 
 
 def _rope_rows(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -303,6 +314,19 @@ class ContinuousBatcher:
         self.requests_completed = 0
         # This tick's admissions (subclass hook; see _admit).
         self._admitted: list = []
+        # Flight recorder (docs/TRACING.md "Engine and executed-step
+        # records"): the tick, its admissions, key splits, prefills,
+        # decode and retirements, one record a span. The engine's own
+        # ring unless its driver hands it one (bind_trace); None = off.
+        self.trace: TraceBuffer | None = TraceBuffer()
+        register_ring("engine", self.trace)
+        host_ring()  # full collections land beside the ticks they stall
+        self._tick_seq = 0  # ``steps`` at this tick's entry
+        # Slot seams for a backend's span wiring, called as
+        # ``hook(rid, slot)`` when a request wins a decode slot and when
+        # it retires (serve/backend.py turns them into SPAN_EXEC).
+        self.admit_hook = None
+        self.retire_hook = None
         # FFN auxiliary telemetry (MoE: drop fraction), averaged over
         # forwards — the capacity-starvation signal the lockstep MoE
         # serving path reports, preserved through the engine.
@@ -408,6 +432,27 @@ class ContinuousBatcher:
                 jnp.zeros((n_slots,), bool), wk)  # results discarded:
         # self.cache is untouched (jit is functional)
 
+    # -- flight recorder --------------------------------------------------
+
+    def bind_trace(self, ring: TraceBuffer | None) -> None:
+        """Write to ``ring`` from now on: the driver's own (so the
+        engine's records interleave with its driver's in one ring), or
+        ``None`` to record nothing."""
+        self.trace = ring
+
+    def _ev(self, ts_ns: int, event: int, *args: int) -> None:
+        tr = self.trace
+        if tr is not None:
+            tr.emit(ts_ns, event, *args)
+
+    def _split_key(self) -> jax.Array:
+        """Advance the sampling key (two tiny device programs a call)."""
+        t = _ns()
+        with _span("pbst.eng.keysplit"):
+            self._key, sub = jax.random.split(self._key)
+        self._ev(t, Ev.ENG_KEYSPLIT, self._tick_seq, _ns() - t)
+        return sub
+
     # -- request intake ---------------------------------------------------
 
     def submit(self, prompt, max_new_tokens: int) -> int:
@@ -440,14 +485,25 @@ class ContinuousBatcher:
         for slot in range(self.n_slots):
             if self.active[slot] or not self.queue:
                 continue
-            rid, prompt, max_new = self.queue.popleft()
-            padded = np.zeros(self.bucket, np.int32)
-            padded[:len(prompt)] = prompt
-            self._admitted.append((slot, padded, len(prompt)))
-            self._key, sub = jax.random.split(self._key)
-            pkey = prompt.tobytes()
-            ent = (self._prefix_cache.get(pkey)
-                   if self.prefix_cache_size else None)
+            with _span("pbst.eng.admit"):
+                self._admit_one(slot)
+
+    def _admit_one(self, slot: int) -> None:
+        t_admit = _ns()
+        tick = self._tick_seq
+        rid, prompt, max_new = self.queue.popleft()
+        t_slot = self._now()
+        if self.admit_hook is not None:
+            self.admit_hook(rid, slot)
+        padded = np.zeros(self.bucket, np.int32)
+        padded[:len(prompt)] = prompt
+        self._admitted.append((slot, padded, len(prompt)))
+        sub = self._split_key()
+        pkey = prompt.tobytes()
+        ent = (self._prefix_cache.get(pkey)
+               if self.prefix_cache_size else None)
+        t_prefill = _ns()
+        with _span("pbst.eng.prefill"):
             if ent is not None:
                 # Hit: install cached KV, sample from cached logits —
                 # the prompt forward is skipped entirely.
@@ -456,6 +512,7 @@ class ContinuousBatcher:
                 self.cache = self._install_fn(
                     self.cache, slot, ent["k"], ent["v"],
                     int(ent["plen"]))
+                t_dispatched = _ns()
                 first = int(_sample(
                     ent["logits"][None, :], sub, self.temperature)[0])
             else:
@@ -463,36 +520,45 @@ class ContinuousBatcher:
                     self._prefill_fn(
                         self.params, self.cache, slot,
                         jnp.asarray(padded), len(prompt), sub)
+                t_dispatched = _ns()
                 first = int(first)
                 self._mlp_extra_sum += float(extra) / self.cfg.n_layers
-                self._mlp_extra_n += 1
-                self.prefill_count += 1
-                if self.prefix_cache_size:
-                    self.prefix_misses += 1
-                    # Device arrays: lazy slices, no host sync here.
-                    self._prefix_cache[pkey] = {
-                        "k": self.cache["k"][:, slot:slot + 1,
-                                             :self.bucket],
-                        "v": self.cache["v"][:, slot:slot + 1,
-                                             :self.bucket],
-                        "logits": last_logits,
-                        "plen": len(prompt),
-                    }
-                    while len(self._prefix_cache) > self.prefix_cache_size:
-                        self._prefix_cache.popitem(last=False)
-            self.slot_req[slot] = rid
-            self.slot_tokens[slot] = [first]
-            self.slot_prompt_len[slot] = len(prompt)
-            self.slot_remaining[slot] = max_new - 1
-            self.slot_waited[slot] = (
-                self.steps - self._submitted_step.pop(rid, self.steps))
-            now = self._now()
-            t_submit = self._submitted_t.pop(rid, now)
-            self.slot_submit_t[slot] = t_submit
-            self.slot_ttft[slot] = now - t_submit  # first token sampled
-            self.active[slot] = True
-            self.last_tok[slot] = first
-            self.tokens_emitted += 1
+        t_synced = _ns()
+        self._ev(t_prefill, Ev.ENG_PREFILL, tick, rid, slot,
+                 t_dispatched - t_prefill, t_synced - t_dispatched,
+                 int(ent is not None))
+        if ent is None:
+            self._mlp_extra_n += 1
+            self.prefill_count += 1
+            if self.prefix_cache_size:
+                self.prefix_misses += 1
+                # Device arrays: lazy slices, no host sync here.
+                self._prefix_cache[pkey] = {
+                    "k": self.cache["k"][:, slot:slot + 1,
+                                         :self.bucket],
+                    "v": self.cache["v"][:, slot:slot + 1,
+                                         :self.bucket],
+                    "logits": last_logits,
+                    "plen": len(prompt),
+                }
+                while len(self._prefix_cache) > self.prefix_cache_size:
+                    self._prefix_cache.popitem(last=False)
+        self.slot_req[slot] = rid
+        self.slot_tokens[slot] = [first]
+        self.slot_prompt_len[slot] = len(prompt)
+        self.slot_remaining[slot] = max_new - 1
+        self.slot_waited[slot] = (
+            self.steps - self._submitted_step.pop(rid, self.steps))
+        now = self._now()
+        t_submit = self._submitted_t.pop(rid, now)
+        self.slot_submit_t[slot] = t_submit
+        self.slot_ttft[slot] = now - t_submit  # first token sampled
+        self.active[slot] = True
+        self.last_tok[slot] = first
+        self.tokens_emitted += 1
+        self._ev(t_admit, Ev.ENG_ADMIT, tick, rid, slot, len(prompt),
+                 max(0, round((t_slot - t_submit) * 1e9)),
+                 _ns() - t_admit)
 
     def _retire(self, slot: int) -> Completion:
         lat = self._now() - float(self.slot_submit_t[slot])
@@ -508,6 +574,12 @@ class ContinuousBatcher:
         self._ttfts.append(ttft)
         self._latencies.append(lat)
         self.requests_completed += 1
+        # The exact values engine.stats() keeps a rounded window of.
+        self._ev(_ns(), Ev.ENG_RETIRE, self._tick_seq, comp.request_id,
+                 slot, len(comp.tokens), round(ttft * 1e9),
+                 round(lat * 1e9))
+        if self.retire_hook is not None:
+            self.retire_hook(comp.request_id, slot)
         self.slot_req[slot] = None
         self.slot_tokens[slot] = []
         self.active[slot] = False
@@ -545,23 +617,54 @@ class ContinuousBatcher:
 
     def step(self) -> list[Completion]:
         """Admit waiting requests, decode one token for every active
-        slot, retire finished requests. Returns completions."""
+        slot, retire finished requests. Returns completions.
+
+        The tick every engine shares: ``_step`` is the engine's own
+        (plain decode here, speculation in the subclass); the
+        ``ENG_TICK`` record and its annotation wrap whichever runs."""
+        t0 = _ns()
+        self._tick_seq = self.steps
+        with _span("pbst.eng.tick"):
+            done = self._step()
+        if self.trace is not None:
+            self.trace.emit(
+                t0, Ev.ENG_TICK, _ns() - t0, self._tick_seq,
+                int(self.active.sum()) + len(done), len(self._admitted),
+                len(done), len(self.queue))
+        return done
+
+    def _decoded(self, t_pre: int, t_enqueued: int, t_host: int) -> None:
+        """Close the tick's decode span (both engines): ``pre`` runs
+        from ``t_pre`` (admission and the key split are over) until the
+        program is enqueued, host-to-device copies included; ``sync``
+        until its tokens are on the host; ``post`` until now, the emit
+        and retire loops."""
+        self._ev(t_pre, Ev.ENG_DECODE, self._tick_seq,
+                 t_enqueued - t_pre, t_host - t_enqueued, _ns() - t_host)
+
+    def _step(self) -> list[Completion]:
         done, any_active = self._pre_decode()
         if not any_active:
             return done
-        self._key, sub = jax.random.split(self._key)
-        nxt, self.cache, extra = self._decode_fn(
-            self.params, self.cache, jnp.asarray(self.last_tok),
-            jnp.asarray(self.active), sub)
-        self._mlp_extra_sum += float(extra) / self.cfg.n_layers
-        self._mlp_extra_n += 1
-        nxt = np.asarray(nxt)
+        sub = self._split_key()
+        t_pre = _ns()
+        with _span("pbst.eng.decode"):
+            nxt, self.cache, extra = self._decode_fn(
+                self.params, self.cache, jnp.asarray(self.last_tok),
+                jnp.asarray(self.active), sub)
+        t_enqueued = _ns()
+        with _span("pbst.eng.sync"):
+            self._mlp_extra_sum += float(extra) / self.cfg.n_layers
+            self._mlp_extra_n += 1
+            nxt = np.asarray(nxt)
+        t_host = _ns()
         for slot in range(self.n_slots):
             if not self.active[slot]:
                 continue
             if self._emit(slot, int(nxt[slot])):
                 done.append(self._retire(slot))
         self.steps += 1
+        self._decoded(t_pre, t_enqueued, t_host)
         return done
 
     def has_work(self) -> bool:
@@ -733,8 +836,9 @@ class SpeculativeBatcher(ContinuousBatcher):
                 "(speculation needs overshoot room)")
         return super().submit(prompt, max_new_tokens)
 
-    def step(self) -> list[Completion]:
+    def _step(self) -> list[Completion]:
         done, any_active = self._pre_decode()
+        t_pre = _ns()
         for slot, padded, plen in self._admitted:
             self.dcache, d_extra = self._draft_prefill_fn(
                 self.draft_params, self.dcache, slot,
@@ -744,22 +848,27 @@ class SpeculativeBatcher(ContinuousBatcher):
             self._draft_extra_n += 1
         if not any_active:
             return done
-        (toks, counts, self.cache, self.dcache, prop, acc, extra,
-         d_extra) = (
-            self._spec_decode_fn(
-                self.params, self.draft_params, self.cache, self.dcache,
-                jnp.asarray(self.last_tok), jnp.asarray(self.active)))
-        self._mlp_extra_sum += float(extra) / self.cfg.n_layers
-        self._mlp_extra_n += 1
-        # kk+1 draft forwards per tick, each a per-layer sum.
-        self._draft_extra_sum += (float(d_extra)
-                                  / (self.draft_cfg.n_layers
-                                     * (self.k + 1)))
-        self._draft_extra_n += 1
-        toks = np.asarray(toks)
-        counts = np.asarray(counts)
-        self.spec_proposed += int(prop)
-        self.spec_accepted += int(acc)
+        with _span("pbst.eng.decode"):
+            (toks, counts, self.cache, self.dcache, prop, acc, extra,
+             d_extra) = (
+                self._spec_decode_fn(
+                    self.params, self.draft_params, self.cache,
+                    self.dcache, jnp.asarray(self.last_tok),
+                    jnp.asarray(self.active)))
+        t_enqueued = _ns()
+        with _span("pbst.eng.sync"):
+            self._mlp_extra_sum += float(extra) / self.cfg.n_layers
+            self._mlp_extra_n += 1
+            # kk+1 draft forwards per tick, each a per-layer sum.
+            self._draft_extra_sum += (float(d_extra)
+                                      / (self.draft_cfg.n_layers
+                                         * (self.k + 1)))
+            self._draft_extra_n += 1
+            toks = np.asarray(toks)
+            counts = np.asarray(counts)
+            self.spec_proposed += int(prop)
+            self.spec_accepted += int(acc)
+        t_host = _ns()
         for slot in range(self.n_slots):
             if not self.active[slot]:
                 continue
@@ -771,6 +880,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                     done.append(self._retire(slot))
                     break
         self.steps += 1
+        self._decoded(t_pre, t_enqueued, t_host)
         return done
 
     def stats(self) -> dict:

@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import json
 import os
+from collections import deque
 
 import numpy as np
 
@@ -424,17 +425,28 @@ class SpanRecorder:
         self.ring = ring if ring is not None else TraceBuffer(capacity)
         self.batch = (batch if batch is not None
                       else EmitBatch(self.ring, capacity=batch_capacity))
-        #: Intern-table bound: the rid table must stay reconstructable
-        #: by the assembler, so ids are never recycled — instead, once
-        #: ``max_spans`` rids have been seen, NEW spans are dropped
-        #: (counted in ``dropped_spans``; existing chains keep
-        #: emitting), the same graceful degradation as a full trace
-        #: ring. A long-lived gateway therefore has bounded memory;
-        #: size the bound to the run like the ring capacity.
+        #: Intern-table bound (the ring's own rule, docs/TRACING.md "The
+        #: flight recorder"): ids are never recycled, and the table
+        #: keeps the NEWEST requests. An id is forgotten, oldest first,
+        #: once every record of its chain has left the ring (its last
+        #: record is more than ``ring.capacity`` records behind the
+        #: head: overwritten, or long since drained). ``max_spans`` is
+        #: the backstop for chains that never end: at the bound the
+        #: oldest id goes even if a record of it may still be in the
+        #: ring (counted in ``dropped_spans``; its later records open a
+        #: fresh id). A long-lived gateway therefore has bounded memory
+        #: and its ring's records always resolve. A consumer reads the
+        #: table with the drain (``drain()`` then ``rid_table()`` /
+        #: ``rid_base``).
         self.max_spans = int(max_spans)
         self.dropped_spans = 0
+        self.forgotten_spans = 0
         self._span_ids: dict[str, int] = {}
-        self._rids: list[str] = []
+        self._rids: deque[str] = deque()
+        #: Parallel to ``_rids``: the batch sequence number of each
+        #: id's latest record.
+        self._last_seq: deque[int] = deque()
+        self.rid_base = 0  # span id of ``_rids[0]``
         self._member_ids: dict[str, int] = {}
         self._members: list[str] = []
         self._tenant_ids: dict[str, int] = {}
@@ -442,16 +454,29 @@ class SpanRecorder:
         self.spans_started = 0
         self.sheds = 0
 
-    def span_id(self, rid: str) -> int | None:
-        """Interned id for ``rid``; None once the table is full and
-        the rid is new (the caller drops that span's events)."""
+    def _forget_oldest(self) -> None:
+        del self._span_ids[self._rids.popleft()]
+        self._last_seq.popleft()
+        self.rid_base += 1
+        self.forgotten_spans += 1
+
+    def span_id(self, rid: str) -> int:
+        """Interned id for ``rid``, stamped as having a record at the
+        batch's current sequence number (every caller emits one)."""
+        seq = self.batch.emitted + self.batch.pending()
         sid = self._span_ids.get(rid)
-        if sid is None:
-            if len(self._rids) >= self.max_spans:
-                self.dropped_spans += 1
-                return None
-            sid = self._span_ids[rid] = len(self._rids)
-            self._rids.append(rid)
+        if sid is not None:
+            self._last_seq[sid - self.rid_base] = seq
+            return sid
+        last, left_ring = self._last_seq, seq - self.ring.capacity
+        while last and last[0] < left_ring:
+            self._forget_oldest()
+        if len(self._rids) >= self.max_spans:
+            self._forget_oldest()
+            self.dropped_spans += 1
+        sid = self._span_ids[rid] = self.rid_base + len(self._rids)
+        self._rids.append(rid)
+        last.append(seq)
         return sid
 
     def member_id(self, name: str) -> int:
@@ -472,6 +497,8 @@ class SpanRecorder:
         return tid
 
     def rid_table(self) -> list[str]:
+        """Rids of the ids still held; entry ``i`` is span id
+        ``rid_base + i``."""
         return list(self._rids)
 
     def member_table(self) -> list[str]:
@@ -485,8 +512,6 @@ class SpanRecorder:
     def admit(self, now: int, rid: str, tenant: str, cls: int,
               cost: int, member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.spans_started += 1
         self.batch.emit(now, Ev.SPAN_ADMIT, sid,
                         self.tenant_id(tenant), cls, cost,
@@ -501,8 +526,6 @@ class SpanRecorder:
     def enqueue(self, now: int, rid: str, tenant: str, cls: int,
                 member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_ENQUEUE, sid,
                         self.tenant_id(tenant), cls,
                         self.member_id(member))
@@ -511,25 +534,24 @@ class SpanRecorder:
                  qdelay_ns: int, deficit_x1000: int,
                  member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_DISPATCH, sid,
                         backend_slot, qdelay_ns, deficit_x1000,
                         self.member_id(member))
 
     def exec(self, now: int, rid: str, backend_slot: int,
-             member: str) -> None:
+             member: str, engine_rid: int = -1) -> None:
+        """``engine_rid`` is the id the backend's engine knows the
+        request by (``BatcherBackend``; -1 = the backend has none): it
+        lands +1 in the record, so a request's ``ENG_*`` records join
+        its chain by identifier (docs/TRACING.md)."""
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_EXEC, sid,
-                        backend_slot, self.member_id(member))
+                        backend_slot, self.member_id(member),
+                        engine_rid + 1)
 
     def complete(self, now: int, rid: str, backend_slot: int,
                  service_ns: int, latency_ns: int, member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_COMPLETE, sid,
                         backend_slot, service_ns, latency_ns,
                         self.member_id(member))
@@ -537,16 +559,12 @@ class SpanRecorder:
     def requeue(self, now: int, rid: str, backend_slot: int,
                 member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_REQUEUE, sid,
                         backend_slot, self.member_id(member))
 
     def handoff(self, now: int, rid: str, from_member: str,
                 to_member: str) -> None:
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_HANDOFF, sid,
                         self.member_id(from_member),
                         self.member_id(to_member))
@@ -560,8 +578,6 @@ class SpanRecorder:
         first, when the pre-crash span records died staged in the
         dead process's batch."""
         sid = self.span_id(rid)
-        if sid is None:
-            return
         self.batch.emit(now, Ev.SPAN_RECOVER, sid,
                         self.member_id(member), int(generation))
 
@@ -605,6 +621,7 @@ class SpanRecorder:
         sidecar = {
             "version": 1,
             "rids": self.rid_table(),
+            "rid_base": self.rid_base,
             "members": self.member_table(),
             "tenant_table": self.tenant_table(),
             "tenants": tenants or {},
@@ -629,7 +646,7 @@ SPAN_ARGS: dict[int, tuple[int, int | None]] = {
     int(Ev.SPAN_ADMIT): (4, 3),     # tenant, cls, cost, member
     int(Ev.SPAN_ENQUEUE): (3, 2),   # tenant, cls, member
     int(Ev.SPAN_DISPATCH): (4, 3),  # backend, qdelay, deficit, member
-    int(Ev.SPAN_EXEC): (2, 1),      # backend, member
+    int(Ev.SPAN_EXEC): (3, 1),      # backend, member, engine_rid+1
     int(Ev.SPAN_COMPLETE): (4, 3),  # backend, service, latency, member
     int(Ev.SPAN_REQUEUE): (2, 1),   # backend, member
     int(Ev.SPAN_HANDOFF): (2, None),  # from_member, to_member
@@ -665,8 +682,12 @@ class SpanAssembler:
 
     def __init__(self, recs: np.ndarray, rid_table: list[str],
                  member_table: list[str] | None = None,
-                 tenant_table: list[str] | None = None):
+                 tenant_table: list[str] | None = None,
+                 rid_base: int = 0):
         self.rids = list(rid_table)
+        #: Span id of ``rid_table[0]`` (a recorder that has forgotten
+        #: its oldest ids hands over the rest, ``SpanRecorder.rid_base``).
+        self.rid_base = int(rid_base)
         self.members = list(member_table or [])
         self.tenant_table = list(tenant_table or [])
         #: rid -> [(ts, ev, args...)] in emission order.
@@ -680,11 +701,11 @@ class SpanAssembler:
             if ev == Ev.SPAN_SHED:
                 self.shed_events += 1
                 continue
-            sid = a[0]
-            if not 0 <= sid < len(self.rids):
+            idx = a[0] - self.rid_base
+            if not 0 <= idx < len(self.rids):
                 self.unknown_spans += 1
                 continue
-            self.chains.setdefault(self.rids[sid], []).append(
+            self.chains.setdefault(self.rids[idx], []).append(
                 (ts, ev, *a[1:]))
 
     # -- the gap-free chain invariant ------------------------------------
@@ -825,7 +846,8 @@ class SpanAssembler:
         span id so one request is one track, labelled
         ``tenant/rid`` via the sidecar tenant table."""
         events: list[dict] = []
-        sid_of = {rid: i for i, rid in enumerate(self.rids)}
+        sid_of = {rid: self.rid_base + i
+                  for i, rid in enumerate(self.rids)}
         for rid, chain in sorted(self.chains.items()):
             sid = sid_of.get(rid, 0)
             tslot = chain[0][2]  # admit args: tenant slot

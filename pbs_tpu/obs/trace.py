@@ -12,6 +12,16 @@ Here: one ring per executor over a flat u64 buffer (native SPSC ring in
 records of (timestamp, event, 6 args), a lost-record counter instead of
 blocking, and host-side formatting/digestion in ``pbs_tpu.cli``.
 
+**Full-ring contract** (the flight recorder, docs/TRACING.md): a ring
+with NO consumer attached overwrites its oldest record, so a process
+that runs for an hour with nobody draining it still holds its newest
+``capacity`` records (``lost`` counts the records overwritten). A ring
+WITH a consumer (``file_backed(attach=True)``, ``attach_consumer()``,
+or simply the first ``consume()``: draining is attaching) keeps the
+drop-new contract: the tail is the consumer's, a drained stream is
+never torn, ``lost`` counts the drops. In-process readers that must not
+become the consumer use ``peek`` and :func:`live_rings`.
+
 **Hot-path contract** (``pbst perf`` pins it in both modes,
 docs/PERF.md): ``emit`` writes the whole record with ONE
 ``struct.pack_into`` (no per-word store loop, nothing allocated per
@@ -39,15 +49,23 @@ atomic release).
 from __future__ import annotations
 
 import enum
+import functools
+import gc
 import struct
+import time
+import weakref
+import zlib
 
 import numpy as np
 
 from pbs_tpu import knobs
 from pbs_tpu.utils.params import integer_param
 
-TRACE_HEADER_WORDS = 4
+TRACE_HEADER_WORDS = 8
 TRACE_REC_WORDS = 8
+#: Header word that says a consumer owns the tail (drop-new when full);
+#: 0 = flight recorder (overwrite-oldest). Words 5..7 are reserved.
+_W_CONSUMER = 4
 
 # EmitBatch staging watermarks, declared in the knob registry
 # (obs.trace.emit_batch_*): how many records one producer stages, and
@@ -66,8 +84,15 @@ _PACK_FMTS = tuple("<" + "Q" * (2 + k) for k in range(7))
 _ZERO_TAIL = tuple(bytes((6 - k) * 8) for k in range(7))
 
 # ``tbuf_size=`` boot param analog (xen/common/trace.c): default ring
-# capacity in records for rings whose creator doesn't size them.
-_tbuf_size = integer_param("tbuf_size", 4096)
+# capacity in records for rings whose creator doesn't size them. Sized
+# so that a whole benchmark run fits with room to spare: the busiest
+# cell (colo-train-serve) writes about 200 records a second across its
+# rings (32 quanta/s x PICK + EXEC_STEP + DESCHED, 16 engine ticks/s x
+# TICK + KEYSPLIT + DECODE, a dozen records a request at 3 requests/s)
+# over a 51 s window plus ~25 s of set-up = ~15,000 records if they all
+# shared one ring; 32768 is twice that, 2 MiB a ring. A longer run
+# keeps its newest 32768 (the full-ring contract above).
+_tbuf_size = integer_param("tbuf_size", 32768)
 
 
 class Ev(enum.IntEnum):
@@ -121,7 +146,8 @@ class Ev(enum.IntEnum):
     SPAN_ENQUEUE = 0x0803  # args: span, tenant_slot, cls, member
     SPAN_DISPATCH = 0x0804  # args: span, backend_slot, qdelay_ns,
     #                               deficit_x1000, member
-    SPAN_EXEC = 0x0805  # args: span, backend_slot, member
+    SPAN_EXEC = 0x0805  # args: span, backend_slot, member,
+    #                           engine_rid + 1 (0: the backend has none)
     SPAN_COMPLETE = 0x0806  # args: span, backend_slot, service_ns,
     #                               latency_ns, member
     SPAN_REQUEUE = 0x0807  # args: span, backend_slot, member
@@ -144,6 +170,43 @@ class Ev(enum.IntEnum):
     AP_CANARY = 0x0902  # args: n_members, guard_window_ns
     AP_PROMOTE = 0x0903  # args: n_members, reserved
     AP_ROLLBACK = 0x0904  # args: reason_code, max_burn_x1000
+    # serving engine (0x0Axx) — the inside of ContinuousBatcher.step
+    # (models/serving.py; docs/TRACING.md "Engine and executed-step
+    # records"). One record per span, written when the span ENDS:
+    # ``ts_ns`` is the span's START on the ring clock
+    # (time.monotonic_ns), durations are arguments. ``tick`` is the
+    # engine's tick sequence number (its ``steps`` at entry): every
+    # child of a tick carries it; ``rid`` is the engine request id,
+    # which SPAN_EXEC's fourth argument carries +1 on the gateway side.
+    ENG_TICK = 0x0A01  # args: dur_ns, tick, active_slots, admitted,
+    #                          retired, queue_len
+    ENG_ADMIT = 0x0A02  # args: tick, rid, slot, prompt_len, wait_ns
+    #                           (submit -> slot, engine latency clock),
+    #                           dur_ns
+    ENG_PREFILL = 0x0A03  # args: tick, rid, slot, dispatch_ns (call
+    #                             returns), sync_ns (first token on the
+    #                             host), prefix_hit (1: cached KV was
+    #                             installed, no prompt forward ran)
+    ENG_KEYSPLIT = 0x0A04  # args: tick, dur_ns
+    ENG_DECODE = 0x0A05  # args: tick, pre_ns (-> program enqueued),
+    #                            sync_ns (-> tokens on the host),
+    #                            post_ns (-> step returns)
+    ENG_RETIRE = 0x0A06  # args: tick, rid, slot, tokens, ttft_ns,
+    #                            latency_ns (engine latency clock)
+    # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
+    # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
+    EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),
+    #                           wait_ns (block_until_ready), compile_ns,
+    #                           job_tag (crc32 of the job name)
+    # host (0x0Cxx) — what the process itself did to its threads.
+    HOST_GC = 0x0C01  # args: dur_ns, generation, collected
+
+
+@functools.lru_cache(maxsize=None)
+def job_tag(name: str) -> int:
+    """The stable u32 that EXEC_STEP carries for a job name (crc32: str
+    hashing is salted per process)."""
+    return zlib.crc32(name.encode())
 
 
 class TraceBuffer:
@@ -196,10 +259,8 @@ class TraceBuffer:
         if self._nat is not None:
             self._nat.pbst_trace_init(self._ptr, capacity)
         else:
-            self._arr[0] = 0
-            self._arr[1] = 0
+            self._arr[:TRACE_HEADER_WORDS] = 0
             self._arr[2] = capacity
-            self._arr[3] = 0
 
     @classmethod
     def file_backed(cls, path: str, capacity: int | None = None,
@@ -222,6 +283,7 @@ class TraceBuffer:
                 os.close(fd)
             cap = int(np.frombuffer(mm, dtype="<u8", count=3)[2])
             tb = cls(cap, buf=mm, native=native, _attach=True)
+            tb.attach_consumer()
         else:
             capacity = capacity if capacity is not None else _tbuf_size.value
             nbytes = (TRACE_HEADER_WORDS + capacity * TRACE_REC_WORDS) * 8
@@ -235,6 +297,22 @@ class TraceBuffer:
             tb = cls(capacity, buf=mm, native=native)
         tb._mmap = mm
         return tb
+
+    # -- full-ring contract ----------------------------------------------
+
+    def attach_consumer(self) -> None:
+        """From now on the tail is a consumer's: a full ring drops the
+        new record instead of overwriting the oldest."""
+        self._hdr[_W_CONSUMER] = 1
+
+    def detach_consumer(self) -> None:
+        """Back to the flight recorder: nobody drains this ring any
+        more, so a full ring keeps its newest records."""
+        self._hdr[_W_CONSUMER] = 0
+
+    @property
+    def has_consumer(self) -> bool:
+        return bool(self._hdr[_W_CONSUMER])
 
     # -- producer --------------------------------------------------------
 
@@ -255,7 +333,9 @@ class TraceBuffer:
         cap = self.capacity
         if head - hdr[1] >= cap:
             hdr[3] += 1
-            return False
+            if hdr[_W_CONSUMER]:
+                return False
+            hdr[1] = head - cap + 1  # overwrite the oldest
         off = (TRACE_HEADER_WORDS + (head % cap) * TRACE_REC_WORDS) * 8
         n = len(args)
         if n > 6:
@@ -283,10 +363,12 @@ class TraceBuffer:
     def emit_many(self, recs: np.ndarray) -> int:
         """Batched emit of an ``(n, 8)`` u64 record array in at most two
         contiguous slice copies (wrap-aware). Returns the number of
-        records written; records that don't fit are dropped tail-first
-        with the lost counter charged — exactly the per-record drop
-        semantics of ``n`` scalar :meth:`emit` calls. See the module
-        docstring for the batched-writer concurrency contract."""
+        records accepted. With a consumer attached, records that don't
+        fit are dropped tail-first with the lost counter charged; with
+        none, the oldest records make room (``lost`` counts them) and
+        all ``n`` are accepted — exactly the semantics of ``n`` scalar
+        :meth:`emit` calls. See the module docstring for the
+        batched-writer concurrency contract."""
         recs = np.ascontiguousarray(recs, dtype="<u8")
         if recs.ndim != 2 or recs.shape[1] != TRACE_REC_WORDS:
             raise ValueError(
@@ -306,22 +388,27 @@ class TraceBuffer:
         head, tail, cap = hdr[0], hdr[1], self.capacity
         space = cap - (head - tail)
         k = n if n <= space else space
+        skip = 0  # leading records of the batch that never land
         if k < n:
             hdr[3] += n - k
+            if not hdr[_W_CONSUMER]:
+                k = n if n <= cap else cap
+                skip = n - k
+                hdr[1] = head + n - cap
         if k == 0:
             return 0
-        flat = recs.reshape(-1)
+        w = TRACE_REC_WORDS
+        flat = recs.reshape(-1)[skip * w:]
         arr = self._arr
-        start = head % cap
+        start = (head + skip) % cap
         k1 = min(k, cap - start)
         off = TRACE_HEADER_WORDS + start * TRACE_REC_WORDS
-        w = TRACE_REC_WORDS
         arr[off:off + k1 * w] = flat[:k1 * w]
         if k > k1:
             arr[TRACE_HEADER_WORDS:TRACE_HEADER_WORDS + (k - k1) * w] = (
                 flat[k1 * w:k * w])
-        hdr[0] = head + k
-        return k
+        hdr[0] = head + skip + k
+        return skip + k
 
     # -- consumer --------------------------------------------------------
 
@@ -344,7 +431,9 @@ class TraceBuffer:
         return out
 
     def consume(self, max_records: int = 1024) -> np.ndarray:
-        """(n, 8) u64 array of drained records."""
+        """(n, 8) u64 array of drained records. Draining is attaching:
+        the first call marks the ring as having a consumer (the
+        full-ring contract in the module docstring)."""
         if self._fc is not None:
             out = np.empty(max_records * TRACE_REC_WORDS, dtype="<u8")
             n = self._fc.trace_consume(self._addr, out, max_records)
@@ -357,6 +446,8 @@ class TraceBuffer:
                 self._ptr, native_mod.as_u64p(out), max_records)
             return out[: n * TRACE_REC_WORDS].reshape(n, TRACE_REC_WORDS)
         hdr = self._hdr
+        if not hdr[_W_CONSUMER]:
+            hdr[_W_CONSUMER] = 1
         tail = hdr[1]
         n = min(hdr[0] - tail, max_records)
         recs = self._copy_out(tail, n)
@@ -490,6 +581,69 @@ class EmitBatch:
             written = self.ring.emit_many(self._buf[:n])
         self.emitted += written
         return written
+
+
+# -- the process's live rings --------------------------------------------
+
+#: owner name -> ring, weakly held: a ring lives exactly as long as its
+#: owner does, and a reader in the same process (a benchmark's per-layer
+#: reader, a postmortem) reaches every live one without a handle to the
+#: tenants that own them.
+_live: "weakref.WeakValueDictionary[str, TraceBuffer]" = (
+    weakref.WeakValueDictionary())
+
+
+def register_ring(owner: str, ring: TraceBuffer) -> str:
+    """Publish ``ring`` under ``owner`` (``partition:<name>#<lane>``,
+    ``gateway:<name>``, ``engine:<name>``, ``exec:<n>``, ``host``). A
+    second live ring of the same owner name gets ``~2``, ``~3``...
+    appended; the name it was published under is returned."""
+    name, n = owner, 1
+    while _live.get(name) not in (None, ring):
+        n += 1
+        name = f"{owner}~{n}"
+    _live[name] = ring
+    return name
+
+
+def live_rings() -> list[tuple[str, TraceBuffer]]:
+    """The process's rings by owner name, sorted by name. For readers
+    that look and do not drain: ``peek``, never ``consume`` (a consume
+    would make the reader the ring's consumer and end its flight-
+    recorder contract)."""
+    return sorted(_live.items())
+
+
+_host: TraceBuffer | None = None
+_gc_t0 = 0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # Generation 2 only: the young generations run hundreds of times a
+    # second and take microseconds; a full collection over a serving
+    # process's heap is what can stall a tick.
+    if info["generation"] < 2:
+        return
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.monotonic_ns()
+    elif _host is not None and _gc_t0:
+        _host.emit(_gc_t0, Ev.HOST_GC, time.monotonic_ns() - _gc_t0,
+                   info["generation"], info.get("collected", 0))
+        _gc_t0 = 0
+
+
+def host_ring() -> TraceBuffer:
+    """The process-wide ``host`` ring, made on first use: full Python
+    collections land there as ``HOST_GC`` (start, dur_ns), so that a
+    long gap between a tenant's records names its cause or rules one
+    out (docs/TRACING.md "Finding a stall")."""
+    global _host
+    if _host is None:
+        _host = TraceBuffer(1024)
+        register_ring("host", _host)
+        gc.callbacks.append(_on_gc)
+    return _host
 
 
 def merge_records(chunks: list[np.ndarray]) -> np.ndarray:

@@ -21,7 +21,13 @@ import numpy as np
 
 import os
 
-from pbs_tpu.obs.trace import Ev, EmitBatch, TraceBuffer, merge_records
+from pbs_tpu.obs.trace import (
+    Ev,
+    EmitBatch,
+    TraceBuffer,
+    merge_records,
+    register_ring,
+)
 from pbs_tpu.runtime import xsm
 from pbs_tpu.runtime.events import EventBus, Virq
 from pbs_tpu.runtime.executor import Executor
@@ -136,6 +142,13 @@ class Partition:
         if self.traces:
             self.sampler.bind_trace(
                 EmitBatch(self.traces[0], capacity=64), self.clock)
+        for i, ring in enumerate(self.traces):
+            register_ring(f"partition:{name}#{i}", ring)
+        # A source that records its executed steps (TpuBackend's
+        # EXEC_STEP) writes them into the lane's ring, between the
+        # quantum's SCHED_PICK and SCHED_DESCHED.
+        if hasattr(source, "bind_trace"):
+            source.bind_trace(self._source_emit)
 
     # -- admission (domain_create analog, xen/common/domain.c) -----------
 
@@ -479,13 +492,28 @@ class Partition:
             for b in self._trace_batches:
                 b.flush()
 
-    def trace_emit(self, exi: int, event: int, *args: int) -> None:
+    def trace_emit(self, exi: int, event: int, *args: int,
+                   ts_ns: int | None = None) -> None:
+        """``ts_ns`` stamps a record that is written when its span ends
+        with the span's start; the default is now."""
         if self.trace_enabled and 0 <= exi < len(self.traces):
+            if ts_ns is None:
+                ts_ns = self.clock.now_ns()
             if self._trace_batches is not None:
-                self._trace_batches[exi].emit(
-                    self.clock.now_ns(), event, *args)
+                self._trace_batches[exi].emit(ts_ns, event, *args)
             else:
-                self.traces[exi].emit(self.clock.now_ns(), event, *args)
+                self.traces[exi].emit(ts_ns, event, *args)
+
+    def _source_emit(self, ctx, ts_ns: int, event: int,
+                     *args: int) -> None:
+        """The source's records go to the ring of the lane that is
+        running ``ctx`` (lane 0 when it cannot be told)."""
+        lane = 0
+        for ex in self.executors:
+            if ex.current is ctx:
+                lane = ex.index
+                break
+        self.trace_emit(lane, event, *args, ts_ns=ts_ns)
 
     def peek_traces(self, max_records: int = 4096):
         """Non-destructive tail of all rings, merged and time-sorted —

@@ -10,9 +10,13 @@ exposes it as just another backend, journal- and SLO-visible like the
 sims. Per-stage span coverage rides the ``exec_hook`` seam: one EXEC
 record when the prompt enters the prefill pipeline (the inherited
 ``BatcherBackend`` wiring), one when the request wins a decode slot,
-one at retirement — repeated EXECs while inflight are legal span
-transitions (obs/spans._NEXT_STATE), so a request's timeline shows
-where inside the backend its time went.
+one at retirement — fired from the engine's own admission and
+retirement (its ``admit_hook``/``retire_hook``), at the moment they
+happen. Repeated EXECs while inflight are legal span transitions
+(obs/spans._NEXT_STATE), so a request's timeline shows where inside
+the backend its time went; each EXEC carries the engine's request id,
+which the engine's own ``ENG_ADMIT``/``ENG_PREFILL``/``ENG_RETIRE``
+records carry too (docs/TRACING.md).
 
 Two clock modes: ``clock="wall"`` (default) for real benchmarks;
 ``clock="virtual"`` slaves the engine's latency accounting to the
@@ -34,6 +38,7 @@ import zlib
 from pbs_tpu.gateway.backends import BatcherBackend
 from pbs_tpu.gateway.fairqueue import Request
 from pbs_tpu import knobs
+from pbs_tpu.utils.clock import MonotonicClock
 
 #: Default decode-slot count (declared knob; the autopilot can canary
 #: it like any scheduler knob).
@@ -96,6 +101,7 @@ class ShardedServeBackend(BatcherBackend):
         params = shard_fn(params)
         self._virtual = clock == "virtual"
         self._now_ns = 0
+        self._wall = MonotonicClock()  # clock="wall": the gateway's own
         engine_cls = engine_cls or ContinuousBatcher
         engine = engine_cls(
             cfg, params,
@@ -105,6 +111,7 @@ class ShardedServeBackend(BatcherBackend):
             clock=(lambda: self._now_ns * 1e-9) if self._virtual
             else None)
         super().__init__(name, engine)
+        engine.admit_hook = engine.retire_hook = self._slot_event
         self.synth_dispatches = 0
         self.disagg_stages = ("prefill", "decode", "retire")
 
@@ -127,22 +134,18 @@ class ShardedServeBackend(BatcherBackend):
 
     def poll(self, now_ns: int):
         self._observe(now_ns)
-        inflight_before = {
-            rid for rid in self.engine.slot_req if rid is not None}
-        out = super().poll(now_ns)
-        if self.exec_hook is not None:
-            # Decode-slot entry: requests newly holding a slot this
-            # tick. (A request that is admitted and retired within one
-            # tick shows only its retire EXEC — still a legal chain.)
-            for erid in sorted(
-                    rid for rid in self.engine.slot_req
-                    if rid is not None and rid not in inflight_before):
-                req = self._by_engine_rid.get(erid)
-                if req is not None:
-                    self.exec_hook(req, now_ns)
-            for req, _info in out:  # retirement
-                self.exec_hook(req, now_ns)
-        return out
+        return super().poll(now_ns)
+
+    def _slot_event(self, engine_rid: int, _slot: int) -> None:
+        """The engine gave a request a decode slot, or retired it: one
+        more EXEC on the request's chain, stamped when it happened
+        (virtual clock: the tick's ``now_ns``)."""
+        if self.exec_hook is None:
+            return
+        req = self._by_engine_rid.get(engine_rid)
+        if req is not None:
+            self.exec_hook(req, self._now_ns if self._virtual
+                           else self._wall.now_ns())
 
     # -- observability ----------------------------------------------------
 

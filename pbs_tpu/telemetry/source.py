@@ -419,6 +419,29 @@ class TpuBackend:
         from pbs_tpu.telemetry.compile import CompileMeter
 
         self.compile_meter = CompileMeter.install()
+        # Flight recorder (docs/TRACING.md): one EXEC_STEP per invoked
+        # unit. ``_emit_step(ctx, ts_ns, event, *args)`` is the driver's
+        # (a Partition binds its own rings, so the record lands between
+        # the quantum's SCHED_PICK and SCHED_DESCHED); unbound, the
+        # backend's own ring, made on the first step.
+        self._emit_step: Callable[..., None] | None = None
+        self.trace = None
+        # Imported here: obs/__init__ reaches back into telemetry.
+        from pbs_tpu.obs import trace as obs_trace
+
+        self._obs = obs_trace
+        obs_trace.host_ring()  # full collections, beside the steps
+
+    def bind_trace(self, emit: Callable[..., None]) -> None:
+        """Hand the backend its driver's ring: ``emit(ctx, ts_ns,
+        event, *args)``."""
+        self._emit_step = emit
+
+    def _own_ring(self) -> Callable[..., None]:
+        self.trace = ring = self._obs.TraceBuffer()
+        self._obs.register_ring("exec", ring)
+        self._emit_step = lambda _ctx, ts, ev, *a: ring.emit(ts, ev, *a)
+        return self._emit_step
 
     def _job_cost(self, job) -> tuple[int, int]:
         c = self._costs.get(job.name)
@@ -478,18 +501,27 @@ class TpuBackend:
         self._since_profile[job.name] = 1 if due else k + 1
         return due
 
-    def _invoke(self, job, fn) -> tuple[int, dict, int, int]:
+    def _invoke(self, job, fn, ctx=None) -> tuple[int, dict, int, int]:
         """Run one host-callable unit; returns (run_ns, metrics,
         n_compiles, compile_ns). Compilation time is split OUT of the
         runtime charge: a tenant's first-dispatch jit cost (seconds)
         billed as device time would sink it into deep credit debt and
         starve it for the equivalent share — compile spend is tracked
         in its own counters and governed by the admission budget
-        (runtime/compile_gate.py), not by the runtime scheduler."""
+        (runtime/compile_gate.py), not by the runtime scheduler.
+
+        Leaves one ``EXEC_STEP`` record: how long ``fn`` took to return
+        (the dispatch: for a jitted step, until the program is
+        enqueued; compile time taken out, as in the charge) and how
+        long ``block_until_ready`` then waited."""
         import jax
 
+        t_returned = 0
+
         def run():
+            nonlocal t_returned
             out = fn(job.state)
+            t_returned = time.monotonic_ns()
             metrics: dict[str, float] = {}
             if (isinstance(out, tuple) and len(out) == 2
                     and isinstance(out[1], dict)):
@@ -503,16 +535,22 @@ class TpuBackend:
             return st, metrics
 
         t0 = time.monotonic_ns()
-        with self.compile_meter.attribute(job.name):
+        with self.compile_meter.attribute(job.name), \
+                jax.profiler.TraceAnnotation("pbst.exec.step"):
             if self._profile_due(job):
                 (job.state, metrics), stats = self.profiler.profile(run)
                 if stats is not None and stats.n_ops:
                     self._measured[job.name] = stats
             else:
                 job.state, metrics = run()
-        dt = time.monotonic_ns() - t0
+        t1 = time.monotonic_ns()
         n_c, c_ns = self.compile_meter.take(job.name)
-        return max(0, dt - c_ns), metrics, n_c, c_ns
+        (self._emit_step or self._own_ring())(
+            ctx, t0, self._obs.Ev.EXEC_STEP,
+            ctx.ledger_slot if ctx is not None else -1,
+            max(0, t_returned - t0 - c_ns), t1 - t_returned, c_ns,
+            self._obs.job_tag(job.name))
+        return max(0, t1 - t0 - c_ns), metrics, n_c, c_ns
 
     def _charge(self, deltas: np.ndarray, dt: int, flops: int,
                 nbytes: int, metrics: dict, measured=None) -> None:
@@ -550,7 +588,7 @@ class TpuBackend:
         deltas = np.zeros(NUM_COUNTERS, dtype=np.uint64)
         flops, nbytes = self._job_cost(job)
         for _ in range(n_steps):
-            dt, metrics, n_c, c_ns = self._invoke(job, job.step_fn)
+            dt, metrics, n_c, c_ns = self._invoke(job, job.step_fn, ctx)
             self._charge(deltas, dt, flops, nbytes, metrics,
                          measured=self._measured.get(job.name))
             deltas[Counter.COMPILES] += n_c
@@ -580,7 +618,7 @@ class TpuBackend:
         deltas = np.zeros(NUM_COUNTERS, dtype=np.uint64)
         flops, nbytes = self._job_cost(job)
         for _ in range(n_micro):
-            dt, metrics, n_c, c_ns = self._invoke(job, fn)
+            dt, metrics, n_c, c_ns = self._invoke(job, fn, ctx)
             self._charge(deltas, dt, flops // K, nbytes // K, metrics,
                          measured=self._measured.get(job.name))
             deltas[Counter.COMPILES] += n_c

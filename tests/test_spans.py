@@ -116,18 +116,43 @@ def test_latency_histograms_overflow_never_corrupts_allocated_rows():
     assert h.quantile("be:b0", "*", "service", 0.5) >= 512 * MS
 
 
-def test_span_recorder_intern_bound_drops_new_spans_only():
+def test_span_recorder_intern_bound_forgets_oldest_keeps_newest():
+    """The intern table follows the ring's rule: at the bound the
+    OLDEST id goes (counted) and the new span is kept; ids are never
+    recycled, and the assembler resolves the rest through rid_base."""
     rec = SpanRecorder(capacity=256, max_spans=2)
     _happy_chain(rec, "a")
     rec.admit(0, "b", "t", 0, 1, "gw")  # second rid: still fits
-    rec.admit(0, "c", "t", 0, 1, "gw")  # third: dropped, counted
+    rec.admit(0, "c", "t", 0, 1, "gw")  # third: "a" is forgotten
     rec.dispatch(1, "c", 0, 1, 0, "gw")
-    rec.complete(2, "b", 0, 1, 2, "gw")  # existing rid keeps emitting
-    assert rec.dropped_spans == 2
+    rec.complete(2, "c", 0, 1, 2, "gw")
+    assert rec.dropped_spans == 1 and rec.forgotten_spans == 1
+    assert rec.rid_base == 1 and rec.rid_table() == ["b", "c"]
     asm = _asm(rec)
-    assert set(asm.chains) == {"a", "b"}
-    # a's chain is untouched by the drops (b's gap is its own).
-    assert all(p.startswith("span b") for p in asm.validate(["a", "b"]))
+    assert set(asm.chains) == {"b", "c"}
+    assert asm.unknown_spans == 5  # a's records: still in the ring
+    # c's chain is whole; b's gap (never completed) is its own, and
+    # the forgotten id's records are reported, not mis-attributed.
+    assert all(p.startswith("span b") or "outside the rid table" in p
+               for p in asm.validate(["b", "c"]))
+
+
+def test_span_recorder_forgets_ids_whose_records_left_the_ring():
+    """Unbounded run, bounded memory: once every record of a chain is
+    more than the ring's capacity behind the head, its id is forgotten
+    (not counted as dropped), so the table tracks the ring."""
+    rec = SpanRecorder(capacity=64, batch_capacity=8)
+    for i in range(200):
+        _happy_chain(rec, f"r{i}")  # 5 records a chain
+    rec.flush()
+    assert rec.dropped_spans == 0
+    assert rec.forgotten_spans >= 200 - 64 // 5 - 2
+    assert len(rec.rid_table()) <= 64 // 5 + 2
+    assert rec.ring.lost == 200 * 5 - 64  # newest 64 records kept
+    asm = _asm(rec)
+    assert asm.unknown_spans == 0  # every record in the ring resolves
+    assert "r199" in asm.chains
+    assert not [p for p in asm.validate() if p.startswith("span r199")]
 
 
 def test_latency_histograms_file_backed_attach(tmp_path):
@@ -145,7 +170,8 @@ def test_latency_histograms_file_backed_attach(tmp_path):
 
 def _asm(rec: SpanRecorder) -> SpanAssembler:
     return SpanAssembler(rec.drain(), rec.rid_table(),
-                         rec.member_table(), rec.tenant_table())
+                         rec.member_table(), rec.tenant_table(),
+                         rid_base=rec.rid_base)
 
 
 def _happy_chain(rec: SpanRecorder, rid: str, t0: int = 0) -> None:

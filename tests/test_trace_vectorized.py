@@ -85,6 +85,10 @@ def test_batched_paths_match_scalar_reference(use_native):
     if use_native:
         require_native()
     tb = TraceBuffer(capacity=16, native=use_native)
+    # The drop-new contract is the contract of a ring WITH a consumer
+    # (unattached, a full ring overwrites its oldest record:
+    # tests/test_flight_recorder.py).
+    tb.attach_consumer()
     _interleaved_equivalence(tb, tb, seed=7)
 
 
