@@ -32,6 +32,7 @@ TPU-first expression of the idea:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import OrderedDict, deque
@@ -85,7 +86,9 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     """Forward (B, S) tokens where row b sits at absolute position
     ``row_pos[b]`` (S static; per-row cursors). Writes K/V at
     ``row_pos[b] + s``; row b's query s attends cols <= row_pos[b]+s.
-    Returns (logits (B, S, vocab) fp32, updated cache slabs).
+    Returns (logits (B, S, vocab) fp32, updated cache slabs, extra).
+    Only the B x S new positions of each layer are written: under a
+    jit that donates ``cache`` the update is in place.
 
     ``mlp_fn(lp, h) -> (y, extra)`` swaps the FFN block — the SAME
     contract as ``generate._forward_with_cache_impl``, so the MoE
@@ -110,24 +113,39 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     cos = cos_full[abs_pos]  # (B, S, half)
     sin = sin_full[abs_pos]
 
+    def write(rows, new, i):
+        """Row b's S new entries go to ``rows[i, b, row_pos[b]:]``: one
+        dynamic_update_slice a row into the WHOLE cache, so a layer
+        moves its new positions and nothing else. A DUS, not a scatter:
+        GSPMD partitions it on an unsharded axis natively, where the
+        equivalent scatter made tp>2 compiles blow up. Not vmapped over
+        the slot axis either (a batched DUS is a scatter, and XLA then
+        re-lays the carried cache slot-major: whole-cache copies in and
+        out of every call), nor unrolled (the same re-layout)."""
+
+        def one(b, rows):
+            return jax.lax.dynamic_update_slice(
+                rows, jax.lax.dynamic_slice_in_dim(new, b, 1)[None],
+                (i, b, row_pos[b], 0, 0))
+
+        return jax.lax.fori_loop(0, B, one, rows)
+
     def body(carry, layer):
-        x, extra = carry
-        lp, ck, cv = layer  # ck/cv: (B, T, nkv, hd)
+        # The K/V slabs (L, B, T, nkv, hd) ride in the CARRY, not as
+        # xs/ys: a scan's ys is a fresh array written slab by slab,
+        # whatever the body changed; a carry is updated in place.
+        x, extra, ks, vs = carry
+        lp, i = layer
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = (h @ wload(lp["wq"], dt)).reshape(B, S, nh, hd)
         k = (h @ wload(lp["wk"], dt)).reshape(B, S, nkv, hd)
         v = (h @ wload(lp["wv"], dt)).reshape(B, S, nkv, hd)
         q = _rope_rows(q, cos, sin)
         k = _rope_rows(k, cos, sin)
-        # Each row writes S CONTIGUOUS entries at its own cursor: a
-        # vmapped dynamic_update_slice, not a scatter — GSPMD
-        # partitions DUS on an unsharded axis natively, where the
-        # equivalent scatter made tp>2 compiles blow up.
-        write = jax.vmap(
-            lambda slab, new, p: jax.lax.dynamic_update_slice(
-                slab, new, (p, 0, 0)))
-        ck = write(ck, k, row_pos)
-        cv = write(cv, v, row_pos)
+        ks = write(ks, k, i)
+        vs = write(vs, v, i)
+        ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
+        cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
         # attention with per-row causal horizon
         qg = q.reshape(B, S, nkv, group, hd).transpose(0, 2, 3, 1, 4)
         kt = ck.transpose(0, 2, 1, 3)  # (B, nkv, T, hd)
@@ -152,11 +170,12 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         else:
             y, e = mlp_fn(lp, h)
         x = x + y
-        return (x, extra + e), (ck, cv)
+        return (x, extra + e, ks, vs), None
 
     zero = jnp.zeros((), jnp.float32)
-    (x, extra), (new_k, new_v) = jax.lax.scan(
-        body, (x, zero), (params["layers"], cache["k"], cache["v"]))
+    (x, extra, new_k, new_v), _ = jax.lax.scan(
+        body, (x, zero, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ wload(params["head"], dt)).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v, "pos": cache["pos"]}, extra
@@ -166,9 +185,11 @@ def ingest_slot_prompt(cfg: TransformerConfig, params: dict, cache: dict,
                        slot, prompt: jax.Array, plen, mlp_fn=None):
     """The ONE copy of slot-prompt ingestion (trace-safe): gather the
     slot's slabs as a B=1 view, forward the padded prompt from
-    position 0, write the slabs back (vmapped-DUS layout — load-bearing
-    for tp compiles, see _slot_forward), set the slot cursor. Returns
-    ``(last_logits (V,), cache)``; samplers layer on top."""
+    position 0, write the slabs back (a DUS on the unsharded slot axis
+    — load-bearing for tp compiles, see _slot_forward — and in place
+    where the caller's jit donates ``cache``), set the slot cursor.
+    Returns ``(last_logits (V,), cache, extra)``; samplers layer on
+    top."""
     sub = {
         "k": jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1),
         "v": jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1),
@@ -246,6 +267,12 @@ class ContinuousBatcher:
     one decode token for every active slot, and returns finished
     :class:`Completion`s. All shapes static: ``n_slots`` lanes,
     prompts padded to ``prompt_bucket``, caches sized ``max_len``.
+
+    The engine OWNS its cache: every program that takes it donates it
+    and writes the new positions in place, so after a call the handle
+    that went in is dead and ``self.cache`` is the one that came out.
+    A caller that wants to keep K/V slices it out before the next call
+    (the prefix cache's windows are such slices: arrays of their own).
     """
 
     def __init__(self, cfg: TransformerConfig, params: dict,
@@ -355,7 +382,7 @@ class ContinuousBatcher:
 
         cfg_ = cfg
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _prefill(params, cache, slot, prompt, plen, key):
             """Write one request's prompt into ``slot`` and sample its
             first token. prompt: (bucket,) padded; plen: real length.
@@ -375,7 +402,7 @@ class ContinuousBatcher:
         else:
             _kv_sharding = None
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(0,))
         def _install(cache, slot, kwin, vwin, plen):
             """Prefix-cache hit: write the cached prompt-window KV
             (L, 1, bucket, nkv, hd) into ``slot``; no forward at all.
@@ -396,7 +423,7 @@ class ContinuousBatcher:
             cache["pos"] = cache["pos"].at[slot].set(plen)
             return cache
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, last_tok, active, key):
             """One token for every slot; inactive lanes masked."""
             logits, new_cache, extra = _slot_forward(
@@ -414,23 +441,25 @@ class ContinuousBatcher:
         self._prefill_fn = _prefill
         self._install_fn = _install
         self._decode_fn = _decode
-        # Warm both programs NOW: compilation belongs to engine
+        # Warm the programs NOW: compilation belongs to engine
         # construction, not to the first unlucky request's TTFT — a
         # multi-second jit landing in the SLO percentiles would read
-        # as a false violation for the next ~1024 completions.
+        # as a false violation for the next ~1024 completions. Each
+        # call donates the cache, so each rebinds it; a zero-length
+        # prompt and no active lane leave every cursor at 0, and what
+        # they write (slot 0's bucket, position 0 of each lane) the
+        # first tenant's prefill or decode overwrites before reading.
         wk = jax.random.PRNGKey(0)
-        _prefill(self.params, self.cache, 0,
-                 jnp.zeros((self.bucket,), jnp.int32), 1, wk)
+        self.cache = _prefill(
+            self.params, self.cache, 0,
+            jnp.zeros((self.bucket,), jnp.int32), 0, wk)[2]
         if prefix_cache_size:
-            _install(self.cache, 0, jnp.zeros(
-                (cfg.n_layers, 1, self.bucket, cfg.n_kv_heads,
-                 cfg.head_dim), cfg.dtype), jnp.zeros(
-                (cfg.n_layers, 1, self.bucket, cfg.n_kv_heads,
-                 cfg.head_dim), cfg.dtype), 1)
-        _decode(self.params, self.cache,
-                jnp.zeros((n_slots,), jnp.int32),
-                jnp.zeros((n_slots,), bool), wk)  # results discarded:
-        # self.cache is untouched (jit is functional)
+            win = jnp.zeros((cfg.n_layers, 1, self.bucket,
+                             cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+            self.cache = _install(self.cache, 0, win, win, 0)
+        self.cache = _decode(
+            self.params, self.cache, jnp.zeros((n_slots,), jnp.int32),
+            jnp.zeros((n_slots,), bool), wk)[1]
 
     # -- flight recorder --------------------------------------------------
 
@@ -766,7 +795,7 @@ class SpeculativeBatcher(ContinuousBatcher):
         self._draft_extra_n = 0
         dcfg_, cfg_, n_slots = draft_cfg, cfg, self.n_slots
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _draft_prefill(dparams, dcache, slot, prompt, plen):
             """Mirror of the target prefill for the draft cache: the
             shared ingest, logits discarded (the target picks tokens)."""
@@ -777,7 +806,7 @@ class SpeculativeBatcher(ContinuousBatcher):
 
         kk = self.k
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(2, 3))
         def _spec_decode(params, dparams, tcache, dcache, cur, active):
             """One speculation round across all slots at their own
             cursors. Returns (toks (B, k+1), counts (B,), caches,
@@ -818,13 +847,15 @@ class SpeculativeBatcher(ContinuousBatcher):
 
         self._draft_prefill_fn = _draft_prefill
         self._spec_decode_fn = _spec_decode
-        # Warm both programs at construction (same SLO reasoning as
-        # the parent's warm-up).
-        _draft_prefill(self.draft_params, self.dcache, 0,
-                       jnp.zeros((self.bucket,), jnp.int32), 1)
-        _spec_decode(self.params, self.draft_params, self.cache,
-                     self.dcache, jnp.zeros((n_slots,), jnp.int32),
-                     jnp.zeros((n_slots,), bool))
+        # Warm both programs at construction (same SLO reasoning, same
+        # rebinding and same untouched cursors as the parent's warm-up).
+        self.dcache = _draft_prefill(
+            self.draft_params, self.dcache, 0,
+            jnp.zeros((self.bucket,), jnp.int32), 0)[0]
+        self.cache, self.dcache = _spec_decode(
+            self.params, self.draft_params, self.cache, self.dcache,
+            jnp.zeros((n_slots,), jnp.int32),
+            jnp.zeros((n_slots,), bool))[2:4]
 
     def submit(self, prompt, max_new_tokens: int) -> int:
         # The verify window writes up to k+1 positions past the

@@ -30,6 +30,7 @@ handoffs per tick — all canary-able by the autopilot.
 
 from __future__ import annotations
 
+import functools
 from collections import deque
 
 import numpy as np
@@ -48,7 +49,10 @@ class PrefillPool:
     """The ingest-only pool: ``n_lanes`` slots of a private KV slab,
     one jitted program (the shared ``ingest_slot_prompt``), no decode.
     ``prefill()`` returns the request's prompt-window KV + logits as
-    lazy device slices — the handoff payload."""
+    lazy device slices — the handoff payload. The pool owns its slab
+    as the engines own theirs (``ContinuousBatcher``): the program
+    donates it, and the windows are sliced out of what it returns
+    before the next call."""
 
     def __init__(self, cfg, params, *, n_lanes: int, bucket: int,
                  max_len: int, mesh=None, mlp_fn=None):
@@ -70,7 +74,7 @@ class PrefillPool:
         self.tokens_ingested = 0
         cfg_ = cfg
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=(1,))
         def _ingest(params, cache, lane, prompt, plen):
             last_logits, cache, extra = ingest_slot_prompt(
                 cfg_, params, cache, lane, prompt, plen, mlp_fn=mlp_fn)
@@ -78,9 +82,9 @@ class PrefillPool:
 
         self._ingest_fn = _ingest
         # Compile at construction, not on the first tenant's TTFT
-        # (the engines' warm-up rule).
-        _ingest(params, self.cache, 0,
-                jnp.zeros((self.bucket,), jnp.int32), 1)
+        # (the engines' warm-up rule: rebind, zero-length prompt).
+        self.cache = _ingest(params, self.cache, 0,
+                             jnp.zeros((self.bucket,), jnp.int32), 0)[1]
 
     def prefill(self, params, prompt: np.ndarray
                 ) -> tuple[object, object, object]:

@@ -114,3 +114,25 @@ def require_native(flavor: str | None = None) -> str | None:
     if path is None:
         pytest.skip(f"native {flavor} runtime unavailable: {why}")
     return path
+
+
+@pytest.fixture
+def donating():
+    """``donating(call, *dead)``: run ``call()``, return its result,
+    and hold it to buffer donation — every array in ``dead`` (handles
+    that went into a donating program) is deleted afterwards. Where
+    JAX warns that the platform could not use the donation, only the
+    assertion is skipped: the call and its result still count."""
+    import warnings
+
+    def run(call, *dead):
+        with warnings.catch_warnings(record=True) as seen:
+            warnings.simplefilter("always")
+            out = call()
+        if not any("donated buffers were not usable" in str(w.message)
+                   for w in seen):
+            assert all(a.is_deleted() for a in dead), \
+                "a donating program left its input handle alive"
+        return out
+
+    return run

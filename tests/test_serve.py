@@ -209,6 +209,42 @@ def test_disagg_handoff_span_chain(cfg):
     assert asm.validate(rids) == []
 
 
+def test_disagg_window_outlives_the_pool_cache(cfg, donating):
+    """The hand-off payload is sliced out of the prefill pool's slab
+    before the pool's next (donating) ingest: prefilled at prompt n,
+    it installs token-exact after 40 more prompts have gone through
+    the pool and the slab it came from is long dead."""
+    from pbs_tpu.models import make_generate
+
+    backend = DisaggServeBackend("d0", cfg, tp=1, dp=1, n_slots=4,
+                                 prompt_bucket=8, max_len=32, seed=0,
+                                 clock="virtual")
+    pool, eng = backend.prefill_pool, backend.engine
+    assert not np.asarray(pool.cache["pos"]).any()  # warm-up: cursors 0
+    prompt = np.asarray([5, 9, 2, 31, 7], np.int32)
+    old = pool.cache
+    logits, kwin, vwin = donating(
+        lambda: pool.prefill(eng.params, prompt), old["k"], old["v"])
+    snap = np.asarray(kwin).copy()
+    rng = np.random.default_rng(0)
+    for _ in range(40):
+        pool.prefill(eng.params, rng.integers(1, cfg.vocab, 6,
+                                              dtype=np.int32))
+    assert not kwin.is_deleted() and not vwin.is_deleted()
+    np.testing.assert_array_equal(np.asarray(kwin), snap)
+    # The backend's own publish-then-submit, without the gateway.
+    eng._prefix_cache[prompt.tobytes()] = {
+        "k": kwin, "v": vwin, "logits": logits, "plen": len(prompt)}
+    rid = eng.submit(prompt, 8)
+    done = {}
+    while eng.has_work():
+        done.update((c.request_id, c.tokens) for c in eng.step())
+    gold = jax.jit(make_generate(cfg, 8, temperature=0.0))(
+        eng.params, jnp.asarray(prompt)[None, :], jax.random.PRNGKey(1))
+    assert done[rid] == [int(t) for t in np.asarray(gold)[0]]
+    assert eng.prefill_count == 0  # installed, never prefilled here
+
+
 # -- disarmed goldens --------------------------------------------------------
 
 #: The PR 15 constants (also pinned in test_gateway_chaos.py /

@@ -333,3 +333,76 @@ def test_prefix_cache_off_by_default():
         while eng.has_work():
             eng.step()
     assert eng.prefix_hits == 0 and eng.prefill_count == 2
+
+
+# -- the engine owns its cache: donated, written in place ---------------------
+
+
+@pytest.mark.parametrize("program", ["prefill", "install", "decode"])
+def test_programs_donate_the_cache(model, program, donating):
+    """Every program that takes the cache and returns one donates it:
+    the handle that went in is dead, the one that came out is whole."""
+    cfg, params = model
+    eng = ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=8,
+                            max_len=32, prefix_cache_size=2)
+    old = eng.cache
+    key = jax.random.PRNGKey(0)
+    win = jnp.ones((cfg.n_layers, 1, 8, cfg.n_kv_heads, cfg.head_dim),
+                   cfg.dtype)
+    call = {
+        "prefill": lambda: eng._prefill_fn(
+            eng.params, old, 1, jnp.arange(8, dtype=jnp.int32), 5, key)[2],
+        "install": lambda: eng._install_fn(old, 1, win, win, 5),
+        "decode": lambda: eng._decode_fn(
+            eng.params, old, jnp.zeros((2,), jnp.int32),
+            jnp.array([False, True]), key)[1],
+    }[program]
+    before = np.asarray(old["k"]).copy()
+    out = donating(call, old["k"], old["v"])
+    assert out["k"].shape == old["k"].shape
+    want_pos = {"prefill": [0, 5], "install": [0, 5], "decode": [0, 1]}
+    assert np.asarray(out["pos"]).tolist() == want_pos[program]
+    if program == "install":  # the window landed in slot 1, nowhere else
+        k = np.array(out["k"])  # a copy: a device array's view is read-only
+        assert (k[:, 1, :8] == 1).all()
+        k[:, 1, :8] = before[:, 1, :8]
+        np.testing.assert_array_equal(k, before)
+
+
+@pytest.mark.parametrize("prefix_cache_size", [0, 4])
+def test_warm_up_leaves_cursors_zero_and_first_request_exact(
+        model, prefix_cache_size):
+    """The constructor's warm-up runs the donating programs and rebinds
+    the cache: every cursor is still 0 and the first tenant is exact."""
+    cfg, params = model
+    eng = ContinuousBatcher(cfg, params, n_slots=3, prompt_bucket=16,
+                            prefix_cache_size=prefix_cache_size)
+    assert not eng.cache["k"].is_deleted()
+    assert not np.asarray(eng.cache["pos"]).any()
+    prompt = [5, 9, 2, 31, 7]
+    rid = eng.submit(prompt, max_new_tokens=8)
+    assert _drain(eng)[rid].tokens == _gold(cfg, params, prompt, 8)
+
+
+def test_prefix_window_outlives_the_cache_it_was_sliced_from(model):
+    """A window saved at tick n is an array of its own: it installs
+    token-exact at tick n + 40, after the cache it was sliced from has
+    been donated on every tick in between."""
+    cfg, params = model
+    eng = ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=8,
+                            max_len=64, prefix_cache_size=4)
+    prompt = [5, 7, 11]
+    gold = _gold(cfg, params, prompt, 6)
+    rid = eng.submit(prompt, max_new_tokens=6)
+    assert _drain(eng)[rid].tokens == gold
+    saved_at = eng.steps
+    ent = eng._prefix_cache[np.asarray(prompt, np.int32).tobytes()]
+    snap = np.asarray(ent["k"]).copy()
+    other = eng.submit([3, 1, 4, 1, 5], max_new_tokens=45)
+    assert len(_drain(eng)[other].tokens) == 45
+    assert eng.steps >= saved_at + 40
+    assert not ent["k"].is_deleted() and not ent["v"].is_deleted()
+    np.testing.assert_array_equal(np.asarray(ent["k"]), snap)
+    rid = eng.submit(prompt, max_new_tokens=6)
+    assert _drain(eng)[rid].tokens == gold
+    assert eng.prefix_hits == 1 and eng.prefill_count == 2

@@ -285,3 +285,49 @@ def test_moe_drop_telemetry_surfaces(models):
     st = spec.stats()
     assert st["draft_mlp_extra_mean"] > 0.1, st
     assert st["mlp_extra_mean"] == 0.0  # dense target: no drops
+
+
+@pytest.mark.parametrize("program", ["draft_prefill", "spec_decode"])
+def test_spec_programs_donate_their_caches(models, program, donating):
+    """The speculative engine owns BOTH slot caches the way the plain
+    engine owns one: each program donates every cache it returns."""
+    params, dparams = models
+    eng = SpeculativeBatcher(CFG, params, CFG, dparams, k=3, n_slots=2,
+                             prompt_bucket=8, max_len=64)
+    tc, dc = eng.cache, eng.dcache
+    if program == "draft_prefill":
+        out = donating(
+            lambda: eng._draft_prefill_fn(
+                eng.draft_params, dc, 1, jnp.arange(8, dtype=jnp.int32),
+                5)[0],
+            dc["k"], dc["v"])
+        assert not tc["k"].is_deleted()  # the target's is not its to take
+        assert np.asarray(out["pos"]).tolist() == [0, 5]
+    else:
+        tout, dout = donating(
+            lambda: eng._spec_decode_fn(
+                eng.params, eng.draft_params, tc, dc,
+                jnp.zeros((2,), jnp.int32), jnp.array([False, True]))[2:4],
+            tc["k"], tc["v"], dc["k"], dc["v"])
+        # The active lane advanced by its accepted prefix + 1, the
+        # same in both caches (the pos invariant); the idle one held.
+        assert np.asarray(tout["pos"]).tolist() == \
+            np.asarray(dout["pos"]).tolist()
+        assert int(tout["pos"][0]) == 0 and 1 <= int(tout["pos"][1]) <= 4
+
+
+def test_spec_warm_up_leaves_cursors_zero_and_first_request_exact(models):
+    """Both caches come out of the constructor's (donating, rebinding)
+    warm-up alive with every cursor at 0; the first tenant's tokens
+    are the plain engine's."""
+    params, dparams = models
+    eng = SpeculativeBatcher(CFG, params, CFG, dparams, k=3, n_slots=2,
+                             prompt_bucket=8, max_len=64)
+    for cache in (eng.cache, eng.dcache):
+        assert not cache["k"].is_deleted()
+        assert not np.asarray(cache["pos"]).any()
+    plain = ContinuousBatcher(CFG, params, n_slots=2, prompt_bucket=8,
+                              max_len=64)
+    for e in (eng, plain):
+        e.submit(PROMPTS[1], max_new_tokens=12)
+    assert drain(eng) == drain(plain)

@@ -312,3 +312,86 @@ def test_chunked_ce_train_step_compiled():
     assert np.isfinite(losses["chunk"])
     assert abs(losses["chunk"] - losses["mat"]) < 5e-3 * max(
         1.0, abs(losses["mat"]))
+
+
+def _bf16_params(cfg, seed=0):
+    """``init_params``' distribution made in bf16 on the device, a layer
+    at a time: the fp32 tree of a 7B cut does not fit beside its cast."""
+    from pbs_tpu.models import init_params
+
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+
+    def make(n, name, shape):
+        if name.endswith("norm"):
+            return jnp.ones(shape, jnp.bfloat16)
+        scale = float((np.sqrt(cfg.d_model) if name == "embed" else 1.0)
+                      / np.sqrt(shape[-2]))  # a Python float: stays bf16
+        draw = lambda k: jax.random.normal(  # noqa: E731
+            k, shape[-2:], jnp.bfloat16) * scale
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), n)
+        if len(shape) == 2:
+            return jax.jit(draw)(key)
+        return jax.jit(lambda k: jax.lax.map(  # stacked over layers
+            draw, jax.random.split(k, shape[0])))(key)
+
+    return jax.tree_util.tree_unflatten(treedef, [
+        make(n, path[-1].key, leaf.shape)
+        for n, (path, leaf) in enumerate(leaves)])
+
+
+def test_slot_cache_updated_in_place_at_the_mistral_cell():
+    """The engine's programs at the benchmark's mistral cell (26
+    layers, 16 slots x 1024, bucket 512): the compiler aliases the
+    whole donated cache and what a call needs beside its arguments is
+    under 0.3 GiB (a rebuilt cache is 1.6 GiB more). Then one request
+    for 8 ticks: every served token is the best, to bf16's grain, of a
+    plain full-sequence forward of the same weights that has no cache
+    at all (the training forward, over prompt + served tokens)."""
+    from pbs_tpu.models import ContinuousBatcher, TransformerConfig
+    from pbs_tpu.models.transformer import forward
+
+    cfg = TransformerConfig(
+        vocab=32768, d_model=4096, n_layers=26, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq=1024, rope_theta=1e6, dtype=jnp.bfloat16)
+    slots, bucket = 16, 512
+    params = _bf16_params(cfg)
+    eng = ContinuousBatcher(cfg, params, n_slots=slots,
+                            prompt_bucket=bucket, max_len=cfg.max_seq)
+    cache_bytes = sum(eng.cache[x].nbytes for x in ("k", "v"))
+    assert cache_bytes == 2 * 26 * 16 * 1024 * 8 * 128 * 2
+    key = jax.random.PRNGKey(0)
+    lowered = {
+        "decode": eng._decode_fn.lower(
+            eng.params, eng.cache, jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots,), bool), key),
+        "prefill": eng._prefill_fn.lower(
+            eng.params, eng.cache, 0, jnp.zeros((bucket,), jnp.int32), 1,
+            key),
+    }
+    for name, low in lowered.items():
+        m = low.compile().memory_analysis()
+        transient = (m.temp_size_in_bytes + m.output_size_in_bytes
+                     - m.alias_size_in_bytes)
+        print(f"{name}: alias {m.alias_size_in_bytes / 2**30:.3f} GiB, "
+              f"temp + output - alias {transient / 2**30:.3f} GiB")
+        assert m.alias_size_in_bytes >= cache_bytes, name
+        assert transient < 0.3 * 2**30, name
+
+    prompt = [int(t) for t in np.random.default_rng(0).integers(
+        1, cfg.vocab, 12)]
+    eng.submit(prompt, max_new_tokens=9)  # 1 at prefill + 8 decode ticks
+    served = None
+    while eng.has_work():
+        for c in eng.step():
+            served = [int(t) for t in c.tokens]
+    assert len(served) == 9 and eng.steps >= 8
+    seq = jnp.asarray([prompt + served], jnp.int32)
+    logits = np.asarray(jax.jit(
+        lambda p, t: forward(cfg, p, t))(eng.params, seq))[0]
+    at = np.arange(len(prompt) - 1, len(prompt) + len(served) - 1)
+    gap = logits[at].max(axis=-1) - logits[at, served]
+    print("served-token gap to the full forward's best:", gap.round(4))
+    # A wrong token reads ~4 here (random weights); bf16 rounding of
+    # two unlike summation orders, under 0.1.
+    assert gap.max() < 0.25, gap
