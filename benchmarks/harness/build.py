@@ -3,10 +3,12 @@
 The recipe is the program's (``Job`` -> ``Partition("credit")`` +
 ``FeedbackPolicy`` over a ``TpuBackend``; ``Gateway`` ->
 ``ShardedServeBackend`` -> ``ContinuousBatcher``); the sizes come from
-the configuration file; the weights come from ``--seed`` through the
-reference's weight definition, made on the device in one jitted call in
-the type they are held in (the trainer's float32 masters, the server's
-bfloat16).
+the configuration file; what is particular to a model (its program
+configuration, step factory, serving backend and reference) comes from
+the configuration's family (``benchmarks/families/``); the weights come
+from ``--seed`` through the reference's weight definition, made on the
+device in one jitted call in the type they are held in (the trainer's
+float32 masters, the server's bfloat16).
 """
 
 from __future__ import annotations
@@ -16,30 +18,13 @@ import time
 import jax
 import jax.numpy as jnp
 
-from benchmarks.reference import model as ref
 from pbs_tpu.gateway import Gateway, TenantQuota
-from pbs_tpu.models import make_continuous_serve_step, make_train_step
-from pbs_tpu.models.transformer import TransformerConfig
+from pbs_tpu.models import make_continuous_serve_step
 from pbs_tpu.runtime import Job, Partition, SchedParams
 from pbs_tpu.sched import FeedbackPolicy
-from pbs_tpu.serve import ShardedServeBackend
-from pbs_tpu.serve.partition import make_serve_mesh, rule_shardings
 from pbs_tpu.telemetry.source import TpuBackend
 
 from .engine import Book, StampingBatcher
-
-DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-
-
-def transformer_config(c: dict, n_layers: int, max_seq: int,
-                       **extra) -> TransformerConfig:
-    return TransformerConfig(
-        vocab=c["vocab_size"], d_model=c["hidden_size"], n_layers=n_layers,
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        max_seq=max_seq, rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        dtype=DTYPES[c["compute_dtype"]], **extra)
 
 
 class TsliceLog:
@@ -89,9 +74,9 @@ class Trainer:
     """The train tenant: a donated jitted step over float32 masters and
     AdamW, fed a fresh seeded row each step."""
 
-    def __init__(self, c: dict, seed: int, rows, be: TpuBackend):
-        tr = c["train"]
-        self.cfg = transformer_config(
+    def __init__(self, fam, c: dict, seed: int, rows, be: TpuBackend):
+        tr, ref = c["train"], fam.reference
+        self.cfg = fam.program_config(
             c, tr["num_hidden_layers"], tr["seq"], remat=tr["remat"],
             remat_policy=tr.get("remat_policy", "full"))
         self.lr = float(tr["learning_rate"])
@@ -100,8 +85,7 @@ class Trainer:
         self.step_times: list[float] = []  # when each step was dispatched
         self.tokens_per_step = rows.shape[1] * (rows.shape[2] - 1)
         self.first_losses: list = []
-        init_opt, train_step = make_train_step(self.cfg,
-                                               learning_rate=self.lr)
+        init_opt, train_step = fam.train_step(self.cfg, self.lr)
         self.step = jax.jit(train_step, donate_argnums=(0,))
         params = jax.jit(lambda s: ref.init_tree(
             c, s, tr["num_hidden_layers"], jnp.float32))(ref.seed_word(seed))
@@ -124,30 +108,16 @@ class Trainer:
         return st, {"tokens": m["tokens"]}
 
 
-def serve_weights(c: dict, seed: int):
-    """bfloat16 weights of the serving depth, made where the backend's
-    rule table will place them, so placement copies nothing."""
-    sv = c["serve"]
-    make = lambda s: ref.init_tree(  # noqa: E731
-        c, s, sv["num_hidden_layers"], DTYPES[sv["weights_dtype"]])
-    word = ref.seed_word(seed)
-    shardings = rule_shardings(jax.eval_shape(make, word),
-                               make_serve_mesh(tp=1, dp=1))
-    return jax.jit(make, out_shardings=shardings)(word)
-
-
 class Server:
-    """The serve tenant: ``ShardedServeBackend`` over the stamping
+    """The serve tenant: the family's gateway backend over the stamping
     engine, with the benchmark's book attached."""
 
-    def __init__(self, c: dict, seed: int):
+    def __init__(self, fam, c: dict, seed: int):
         sv = c["serve"]
-        self.cfg = transformer_config(c, sv["num_hidden_layers"],
+        self.cfg = fam.program_config(c, sv["num_hidden_layers"],
                                       sv["max_len"])
-        self.backend = ShardedServeBackend(
-            "engine", self.cfg, serve_weights(c, seed), tp=1, dp=1,
-            n_slots=int(sv["slots"]), prompt_bucket=int(sv["prompt_bucket"]),
-            max_len=int(sv["max_len"]), engine_cls=StampingBatcher)
+        self.backend = fam.serve_backend("engine", self.cfg, c, seed,
+                                         engine_cls=StampingBatcher)
         self.engine = self.backend.engine
         self.book = self.engine.book = Book()
 

@@ -1,6 +1,7 @@
 """What decides ``correct``: the timed path's own output against the
 plain reference, each number beside its limit (the limits, and the
 readings they were set from, are in the configuration file and PERF.md).
+The reference is the configuration's family's (``family.reference``).
 
 Serving: a seeded sample of the requests the window finished, the
 longest among them; the reference runs once over each prompt with its
@@ -20,8 +21,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.reference import model as ref
-
 SAMPLE = 8
 PAD_TO = 256
 
@@ -38,10 +37,11 @@ def pick_sample(requests, t0: float, t1: float, seed: int) -> list[dict]:
     return [longest] + [rest[i] for i in idx]
 
 
-def serving_readings(c: dict, seed: int, sample, max_new: int,
+def serving_readings(ref, c: dict, seed: int, sample, max_new: int,
                      control: bool = False) -> dict:
-    """Gaps below the reference's best logit: of the served tokens, and
-    with ``control`` of the tokens the int8 reference puts first."""
+    """Gaps below the best logit of ``ref`` (the family's reference): of
+    the served tokens, and with ``control`` of the tokens the int8
+    reference puts first."""
     import jax.numpy as jnp
 
     sv = c["serve"]
@@ -96,7 +96,7 @@ def _sketch_gap(prog: dict, want: dict, norms: dict) -> float:
                / max(norms[k], median) for k in want)
 
 
-def training_readings(c: dict, seed: int, first_steps: dict,
+def training_readings(ref, c: dict, seed: int, first_steps: dict,
                       control: bool = False) -> dict:
     tr = c["train"]
 
@@ -123,28 +123,30 @@ def training_readings(c: dict, seed: int, first_steps: dict,
     return out
 
 
-def run(config: dict, traffic: dict, seed: int, first_steps: dict | None,
-        sample: list, control: bool) -> bool:
-    """Read every number the cell's tenants give, print each beside its
-    limit, and say whether all of them hold."""
+def run(ref, config: dict, traffic: dict, seed: int,
+        first_steps: dict | None, sample: list, control: bool
+        ) -> tuple[bool, dict, list[str]]:
+    """Read every number the cell's tenants give and say whether all of
+    them hold: ``(correct, {name: {"value", "limit"}}, lines)``, each
+    line a number beside its limit."""
     limits, readings = {}, {}
     if first_steps is not None:
         limits.update(config["check"]["training"])
-        readings.update(training_readings(config, seed, first_steps,
+        readings.update(training_readings(ref, config, seed, first_steps,
                                           control))
     if "serve" in traffic:
         limits.update(config["check"]["serving"])
         if sample:
             readings.update(serving_readings(
-                config, seed, sample,
+                ref, config, seed, sample,
                 int(traffic["serve"]["output_len"]["max"]), control))
     ok, lines = judge(readings, limits)
-    for line in lines:
-        print(line)
     for k, v in readings.items():
         if k not in limits:
             print(f"check-reading {k}: {v}")
-    return ok
+    compared = {k: {"value": readings.get(k), "limit": limit}
+                for k, limit in limits.items()}
+    return ok, compared, lines
 
 
 def judge(readings: dict, limits: dict) -> tuple[bool, list[str]]:

@@ -18,6 +18,7 @@ from . import reduce, trace
 class Context:
     """What a per-layer reader may look at."""
 
+    family: object          # the configuration's family module
     config: dict
     traffic: dict
     device_kind: str

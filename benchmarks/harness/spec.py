@@ -20,6 +20,7 @@ class Spec:
         self.root = root
         self.bench = _load(os.path.join(root, "BENCHMARK.json"))
         self.dir = os.path.join(root, self.bench["paths"][0])
+        self._modules: dict = {}
 
     def cell(self, name: str) -> dict:
         for w in self.bench["workloads"]:
@@ -46,10 +47,21 @@ class Spec:
     def metric_file(self, name: str) -> dict:
         return _load(os.path.join(self.dir, "metrics", name + ".json"))
 
+    def _module(self, kind: str, name: str):
+        """``benchmarks/<kind>/<name>.py``, loaded once by its path."""
+        key = f"benchmarks.{kind}.{name}"
+        if key not in self._modules:
+            mod_spec = importlib.util.spec_from_file_location(
+                key, os.path.join(self.dir, kind, name + ".py"))
+            mod = importlib.util.module_from_spec(mod_spec)
+            mod_spec.loader.exec_module(mod)
+            self._modules[key] = mod
+        return self._modules[key]
+
     def reader(self, name: str):
-        path = os.path.join(self.dir, "readers", name + ".py")
-        mod_spec = importlib.util.spec_from_file_location(
-            f"benchmarks.readers.{name}", path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return self._module("readers", name).read
+
+    def family(self, name: str):
+        """What a configuration's ``"family"`` names: the module that
+        holds everything about a model the harness does not know."""
+        return self._module("families", name)

@@ -24,8 +24,9 @@ LEDGER = ("DEVICE_TIME_NS", "TOKENS", "STEPS_RETIRED")
 class Tenancy:
     """What every tenancy leaves behind for the metrics."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int,
+    def __init__(self, family, config: dict, traffic: dict, seed: int,
                  seconds: float):
+        self.fam = family
         self.c, self.t, self.seed = config, traffic, seed
         self.seconds = seconds
         self.gw = None
@@ -90,8 +91,7 @@ class Tenancy:
         import jax
         import jax.numpy as jnp
 
-        from benchmarks.reference import model as ref
-
+        ref = self.fam.reference
         tr, job = self.trainer, self.trainer.job
         c, seed = self.c, self.seed
         n_layers = c["train"]["num_hidden_layers"]
@@ -129,7 +129,8 @@ class Tenancy:
         rows = train_rows(self.c["train"], self.c["vocab_size"], self.seed)
         self.be, self.part, self.fb = build.make_partition("bench")
         self.mark("rows")
-        self.trainer = build.Trainer(self.c, self.seed, rows, self.be)
+        self.trainer = build.Trainer(self.fam, self.c, self.seed, rows,
+                                     self.be)
         self.mark("trainer")
         self.part.add_job(self.trainer.job)
         self._first_steps()
@@ -172,14 +173,27 @@ class Train(Tenancy):
 
 class ClosedLoop:
     """``clients`` callers, each sending its next request when the last
-    one completed, with no think time."""
+    one completed, with no think time. They start one after another,
+    evenly over the first ``ramp_s`` seconds (0: all at once): admitted
+    in one tick, the callers' prefills put the serving
+    tenant some hundreds of milliseconds into credit debt, the scheduler
+    takes seconds to collect it, and the requests alive meanwhile are a
+    slow mode of their own in the window's tail (PERF.md section 6,
+    PR 27)."""
 
     def __init__(self, serve: dict, book, requests):
         self.book, self.requests = book, requests
-        self.owed = int(serve["clients"])
-        self.seen = 0
+        n, ramp = int(serve["clients"]), float(serve["ramp_s"])
+        self.starts = [k * ramp / n for k in range(n)][::-1]
+        self.t_start = None
+        self.owed = self.seen = 0
 
     def feed(self, _tick: int):
+        if self.t_start is None:
+            self.t_start = now()
+        while self.starts and self.starts[-1] <= now() - self.t_start:
+            self.starts.pop()
+            self.owed += 1
         self.owed += self.book.completions - self.seen
         self.seen = self.book.completions
         out = []
@@ -194,7 +208,7 @@ class ClosedLoop:
 class Colo(Tenancy):
     def setup(self) -> None:
         self._make_trainer()
-        self.server = build.Server(self.c, self.seed)
+        self.server = build.Server(self.fam, self.c, self.seed)
         self.mark("server")
         loop = ClosedLoop(self.t["serve"], self.server.book, Requests(
             self.t["serve"], self.c["vocab_size"], self.seed))
@@ -207,7 +221,7 @@ class Serve(Tenancy):
     """Open loop through the gateway; this process is the pump."""
 
     def setup(self) -> None:
-        self.server = build.Server(self.c, self.seed)
+        self.server = build.Server(self.fam, self.c, self.seed)
         self.mark("server")
         self.gw = self.server.gateway()
         self.requests = Requests(self.t["serve"], self.c["vocab_size"],

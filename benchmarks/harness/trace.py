@@ -7,7 +7,9 @@ trace in ``benchmarks/tests``.
 
 An event: ``{"plane", "line", "name", "start", "dur"}`` in nanoseconds
 on the trace's clock, plus ``"module"`` / ``"run"`` where the profiler
-attached the HLO module and run id.
+attached the HLO module and run id, and on a device op ``"scope"``: the
+op's ``tf_op`` metadata (``jit(_decode)/while/body/attn/dot_general:``),
+which is where a ``jax.named_scope`` or a Pallas kernel's name ends up.
 
 - **device ops**: events of a device plane's ``XLA Ops`` line. Where the
   trace has no device plane (a CPU rehearsal) events that carry an
@@ -47,15 +49,93 @@ def short_op(name: str) -> str:
     return f"{head.lstrip('%')} {result}"[:96]
 
 
-def _profile(logdir: str):
-    import jax.profiler
-
+def _xplane_path(logdir: str) -> str:
     paths = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {logdir}")
-    return jax.profiler.ProfileData.from_file(
-        max(paths, key=os.path.getmtime))
+    return max(paths, key=os.path.getmtime)
+
+
+def _profile(logdir: str):
+    import jax.profiler
+
+    return jax.profiler.ProfileData.from_file(_xplane_path(logdir))
+
+
+def _fields(buf):
+    """``(field number, value)`` of one protobuf message: an int for a
+    varint, bytes for a length-delimited field. ``ProfileData`` shows an
+    event's own stats but not its metadata's, so the few fields
+    :func:`op_scopes` needs are read from the wire (``xplane.proto``;
+    fixed-width fields are skipped)."""
+    i, n = 0, len(buf)
+
+    def varint() -> int:
+        nonlocal i
+        val = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            val |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return val
+
+    while i < n:
+        key = varint()
+        num, wire = key >> 3, key & 7
+        if wire == 0:
+            yield num, varint()
+        elif wire == 2:
+            size = varint()
+            yield num, buf[i:i + size]
+            i += size
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"unexpected protobuf wire type {wire}")
+
+
+def op_scopes(path: str, stat: str = "tf_op") -> dict[str, dict[str, str]]:
+    """Per device plane of an ``.xplane.pb``, event name -> the string
+    stat ``stat`` of that event's metadata. Field numbers: ``XSpace``
+    planes 1; ``XPlane`` name 2, event_metadata 4, stat_metadata 5 (map
+    entries: value 2); ``XEventMetadata`` name 2, stats 5;
+    ``XStatMetadata`` id 1, name 2; ``XStat`` metadata_id 1, str_value 5,
+    ref_value 7."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())  # slices of it copy nothing
+    out: dict[str, dict[str, str]] = {}
+    for num, plane in _fields(space):
+        if num != 1:
+            continue
+        name, metas, stat_names = "", [], {}
+        for k, v in _fields(plane):
+            if k == 2:
+                name = str(v, "utf-8")
+            elif k == 4:
+                metas.append(dict(_fields(v)).get(2, b""))
+            elif k == 5:
+                sm = dict(_fields(dict(_fields(v)).get(2, b"")))
+                stat_names[sm.get(1, 0)] = str(sm.get(2, b""), "utf-8")
+        if not name.startswith("/device:"):
+            continue
+        scopes = out.setdefault(name, {})
+        for meta in metas:
+            ev_name, found = "", None
+            for k, v in _fields(meta):
+                if k == 2:
+                    ev_name = str(v, "utf-8")
+                elif k == 5:
+                    st = dict(_fields(v))
+                    if stat_names.get(st.get(1)) != stat:
+                        continue
+                    found = str(st[5], "utf-8") if 5 in st \
+                        else stat_names.get(st.get(7), "")
+            if found:
+                scopes[ev_name] = found
+    return out
 
 
 def describe(logdir: str) -> list[str]:
@@ -75,9 +155,11 @@ def describe(logdir: str) -> list[str]:
 
 def load_xplane(logdir: str) -> list[dict]:
     data = _profile(logdir)
+    scopes = op_scopes(_xplane_path(logdir))
     out = []
     for plane in data.planes:
         device = plane.name.startswith("/device:")
+        scope_of = scopes.get(plane.name, {})
         for line in plane.lines:
             on_device = device and line.name in ("XLA Ops", "XLA Modules")
             for ev in line.events:
@@ -91,10 +173,14 @@ def load_xplane(logdir: str) -> list[dict]:
                     stats = dict(ev.stats)
                     if "hlo_op" not in stats:
                         continue
+                scope = None
                 if on_device and line.name == "XLA Ops":
+                    scope = scope_of.get(name)
                     name = short_op(name)
                 rec = {"plane": plane.name, "line": line.name, "name": name,
                        "start": int(ev.start_ns), "dur": int(ev.duration_ns)}
+                if scope:
+                    rec["scope"] = scope
                 if "hlo_module" in stats:
                     rec["module"] = str(stats["hlo_module"])
                     rec["run"] = int(stats.get("run_id", 0))

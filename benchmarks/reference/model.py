@@ -132,12 +132,12 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def block(c: dict, x, w: dict, quant: bool = False):
-    """One decoder layer on x (B, S, d), float32, causal."""
+def attention(c: dict, x, w: dict, quant: bool = False):
+    """The attention half of a decoder layer on x (B, S, d), float32,
+    causal, with its residual; ``w`` already float32."""
     B, S, d = x.shape
     nh, nkv = c["num_attention_heads"], c["num_key_value_heads"]
     hd = d // nh
-    w = {k: v.astype(jnp.float32) for k, v in w.items()}
     h = rms_norm(x, w["attn_norm"], c["rms_norm_eps"])
     q = rope(matmul(h, w["wq"], quant).reshape(B, S, nh, hd),
              c["rope_theta"])
@@ -151,7 +151,13 @@ def block(c: dict, x, w: dict, quant: bool = False):
     p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
     a = jnp.einsum("bhqk,bkhd->bqhd", p, v,
                    precision=jax.lax.Precision.HIGHEST).reshape(B, S, d)
-    x = x + matmul(a, w["wo"], quant)
+    return x + matmul(a, w["wo"], quant)
+
+
+def block(c: dict, x, w: dict, quant: bool = False):
+    """One decoder layer on x (B, S, d), float32, causal."""
+    w = {k: v.astype(jnp.float32) for k, v in w.items()}
+    x = attention(c, x, w, quant)
     h = rms_norm(x, w["mlp_norm"], c["rms_norm_eps"])
     gate = jax.nn.silu(matmul(h, w["w1"], quant))
     return x + matmul(gate * matmul(h, w["w3"], quant), w["w2"], quant)

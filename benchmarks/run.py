@@ -2,12 +2,14 @@
 
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Finds the cell in ``BENCHMARK.json`` and its configuration, traffic mix
-and per-layer metrics by name under ``benchmarks/``; builds the tenants
-from ``--seed``; warms up (all of that is ``setup_s``); measures for
-``--seconds``; then frees the program and checks what the timed path
-produced against the float32 reference. The last line of standard
-output is the result object. Without a TPU (or with fewer chips than
+Finds the cell in ``BENCHMARK.json`` and its configuration (with the
+family it names), traffic mix and per-layer metrics by name under
+``benchmarks/``; builds the tenants from ``--seed``; warms up (all of
+that is ``setup_s``); measures for ``--seconds``; then frees the program
+and checks what the timed path produced against the float32 reference.
+The last line of standard output is the result object; each number the
+check compared stands beside its limit in its last key, ``check``, and
+in the last lines of standard error. Without a TPU (or with fewer chips than
 the cell asks for) it exits non-zero and prints no result; ``--rehearsal``
 runs the cell's tiny preset on whatever JAX has and prefixes every
 metric with ``rehearsal_``.
@@ -91,7 +93,9 @@ def main(argv=None) -> int:
     traffic = apply_sets(traffic, args.set)
     prefix = "rehearsal_" if args.rehearsal else ""
 
-    ten = KINDS[traffic["tenancy"]](config, traffic, args.seed, args.seconds)
+    family = spec.family(config["family"])
+    ten = KINDS[traffic["tenancy"]](family, config, traffic, args.seed,
+                                    args.seconds)
     ten.setup()
     setup_s = time.monotonic() - T_START
     print(f"setup {setup_s:.2f}s phases={ten.phases} settle={ten.settle}",
@@ -103,7 +107,7 @@ def main(argv=None) -> int:
 
     book = ten.server.book if ten.server is not None else None
     ctx = measure.Context(
-        config=config, traffic=traffic, device_kind=dev.device_kind,
+        family=family, config=config, traffic=traffic, device_kind=dev.device_kind,
         t0=ten.t0, t1=ten.t1,
         requests=book.requests if book else [],
         ticks=book.ticks if book else [],
@@ -149,8 +153,9 @@ def main(argv=None) -> int:
     ten.free()
     del ctx, book, tracer
     t_check = time.monotonic()
-    correct = check.run(config, traffic, args.seed, first_steps, sample,
-                        bool(args.control))
+    correct, compared, lines = check.run(
+        family.reference, config, traffic, args.seed, first_steps, sample,
+        bool(args.control))
     print(f"check took {time.monotonic() - t_check:.2f}s", flush=True)
 
     result = {
@@ -161,6 +166,11 @@ def main(argv=None) -> int:
         "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # Each number compared beside its limit: the last key of the result
+    # line and the last lines of standard error.
+    result["check"] = compared
+    sys.stdout.flush()
+    print("\n".join(lines), file=sys.stderr, flush=True)
     print(json.dumps(result))
     return 0
 

@@ -37,7 +37,7 @@ def test_sound_run_is_correct(capsys):
     out = rehearse("colo-train-serve", capsys)
     assert out["correct"] is True and out["failed"] == 0
     assert set(out["metrics"]) == {"rehearsal_train_tokens_per_s",
-                                   "rehearsal_tpot_p95_ms",
+                                   "rehearsal_output_tokens_per_s",
                                    "rehearsal_setup_s"}
 
 
@@ -80,7 +80,7 @@ def test_int8_control_fails_the_training_limits():
     low = ref.train_readings(c, SEED, tr["num_hidden_layers"], rows,
                              tr["learning_rate"], quant=True)
     low["rows"] = rows
-    readings = check.training_readings(c, SEED, low)
+    readings = check.training_readings(ref, c, SEED, low)
     ok, _ = check.judge(readings, c["check"]["training"])
     assert not ok
     assert readings["grad_sketch_gap"] > 3 * c["check"]["training"][
@@ -98,7 +98,7 @@ def test_int8_control_fails_the_serving_limits(name):
                                       dtype=np.int32),
                "tokens": [int(t) for t in rng.integers(
                    1, c["vocab_size"], 24)]} for _ in range(check.SAMPLE)]
-    got = check.serving_readings(c, SEED, sample, 24, control=True)
+    got = check.serving_readings(ref, c, SEED, sample, 24, control=True)
     as_program = {"served_gap_max": got["control_gap_max"],
                   "served_gap_mean": got["control_gap_mean"]}
     ok, _ = check.judge(as_program, c["check"]["serving"])

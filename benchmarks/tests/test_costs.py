@@ -2,12 +2,14 @@
 against numbers written out by hand."""
 import pytest
 
-from benchmarks.harness import costs, peaks
+from benchmarks.families import dense_gqa_costs as costs
+from benchmarks.harness import peaks
 from benchmarks.harness.spec import Spec
 
 SPEC = Spec()
 INTERN = SPEC.config("internlm2-1.8b")
 MISTRAL = SPEC.config("mistral-7b-v0.3")
+FAMILY = SPEC.family("dense-gqa")
 
 
 def test_layer_matmul_params_by_hand():
@@ -46,6 +48,18 @@ def test_decode_tick_bytes_by_hand():
         n * 2 + kv * (4000 + 16)
     # internlm2, 24 layers: 98304 bytes of KV a position
     assert costs.kv_bytes_per_position(INTERN, 24) == 98304
+
+
+def test_family_cost_table_gives_the_same_numbers():
+    """What ``roofline_pct`` reads through the family's ``COSTS`` is the
+    hand-computed value above; a decode tick with nothing live in the
+    traced part has no cost to read."""
+    assert FAMILY.COSTS["train_step"](INTERN, {}) == {
+        "flops": costs.train_step_flops(INTERN, 2, 1, 1024)}
+    assert FAMILY.COSTS["decode_tick"](MISTRAL, {"live_positions": 4000}) \
+        == {"bytes": costs.decode_tick_bytes(MISTRAL, 26, 16, 4000)}
+    assert FAMILY.COSTS["decode_tick"](MISTRAL,
+                                       {"live_positions": None}) is None
 
 
 def test_unknown_device_is_an_error():

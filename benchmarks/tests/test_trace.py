@@ -2,6 +2,7 @@
 a small trace recorded on the chip (``benchmarks/data``)."""
 import json
 import os
+import shutil
 
 import pytest
 
@@ -73,6 +74,38 @@ def test_programs_without_a_modules_line():
     assert [(p["name"], p["start"], p["dur"], p["busy"])
             for p in trace.programs(cpu)] == \
         [("jit_f", 10, 15, 10), ("jit_f", 40, 5, 5)]
+
+
+DATA = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "data")
+
+
+def test_device_ops_keep_their_scope(tmp_path):
+    """A profile recorded on the v5e of one jitted ``_decode`` whose
+    layer scan holds ``jax.named_scope("attn")`` and whose tail holds
+    ``"head"``: each device op comes back with the scope path from its
+    event metadata, and the names ``breakdown.device_ops`` prints stay
+    as they were."""
+    log = tmp_path / "plugins" / "profile" / "once"
+    log.mkdir(parents=True)
+    shutil.copy(os.path.join(DATA, "named-scopes.xplane.pb"), log)
+    events = trace.load_xplane(str(tmp_path))
+    ops = [e for e in events if e["line"] == "XLA Ops"]
+    by_scope = {}
+    for e in ops:
+        by_scope.setdefault(e.get("scope"), set()).add(e["name"])
+    assert by_scope["jit(_decode)/head/dot_general:"] == {
+        "fusion.18 bf16[]"}
+    assert by_scope["jit(_decode)/while/body/closed_call/attn/"
+                    "dot_general:"] == {
+        "fusion.33 (bf16[256], bf16[256,1024])"}
+    # 3 runs x 4 layers of the scanned matmul; copies and the loop
+    # itself carry no scope
+    assert sum(1 for e in ops if "/attn/dot_general" in e.get("scope", "")
+               ) == 12
+    assert None in by_scope
+    assert trace.top_ops(events, trace.programs(events), 1)[0][0] == \
+        "jit__decode/fusion.33 (bf16[256], bf16[256,1024])"
 
 
 RECORDED = os.path.join(os.path.dirname(os.path.dirname(

@@ -75,3 +75,31 @@ def test_train_rows_all_differ():
     assert len({r.tobytes() for r in rows}) == 32
     assert (rows == train_rows({"pool_rows": 32, "batch": 1, "seq": 64},
                                512, 5)).all()
+
+
+def test_closed_loop_ramps_its_clients(monkeypatch):
+    """Four clients over two seconds: one at once, one more every half
+    second; a completion is answered with a new request at any time;
+    with a ramp of 0 s all start together."""
+    from benchmarks.harness import tenancy
+
+    class FakeBook:
+        completions = 0
+
+        def expect(self, *_a, **_kw):
+            pass
+
+    clock = [100.0]
+    monkeypatch.setattr(tenancy, "now", lambda: clock[0])
+    serve = dict(Spec().traffic("colo-chat")["serve"], clients=4, ramp_s=2.0)
+    book = FakeBook()
+    loop = tenancy.ClosedLoop(serve, book, Requests(serve, 512, 1))
+    sent = []
+    for t, completions in ((100.0, 0), (100.2, 0), (100.5, 0), (100.9, 1),
+                           (101.6, 1), (107.0, 3)):
+        clock[0], book.completions = t, completions
+        sent.append(len(loop.feed(0)))
+    assert sent == [1, 0, 1, 1, 2, 2]
+    serve["ramp_s"] = 0
+    assert len(tenancy.ClosedLoop(serve, FakeBook(), Requests(
+        serve, 512, 1)).feed(0)) == 4

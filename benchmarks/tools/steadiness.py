@@ -53,6 +53,46 @@ def stats_of(run: dict, seconds: float) -> dict:
     if steps:
         out["train_tokens_per_s"] = (len(steps)
                                      * run["train_tokens_per_step"] / seconds)
+    if tpot:
+        out.update(interleave_of(run, steps, tpot, gaps, t0, t1))
+    return out
+
+
+def interleave_of(run: dict, steps, tpot, gaps, t0: float, t1: float) -> dict:
+    """Where a co-located cell's spread could come from: how the
+    scheduler interleaved engine ticks with train steps (over the whole
+    window and over its 5 s pieces), what a tick and a step cost on the
+    wall, how many requests the window admitted and completed, and the
+    serving statistics that are averages beside those that are tails."""
+    ticks = [t for t in run["ticks"] if t0 <= t[0] < t1]
+    out = {"admitted": sum(1 for r in run["requests"]
+                           if r["admit"] is not None
+                           and t0 <= r["admit"] < t1),
+           "ticks": len(ticks),
+           "tick_wall_ms_p50": reduce.percentile(
+               [(t[1] - t[0]) * 1e3 for t in ticks], 50),
+           "tpot_p90_ms": reduce.percentile(tpot, 90),
+           "tpot_p99_ms": reduce.percentile(tpot, 99),
+           "tpot_mean_ms": statistics.fmean(tpot),
+           "gap_mean_ms": statistics.fmean(gaps)}
+    if steps:
+        out["train_steps"] = len(steps)
+        out["ticks_per_step"] = len(ticks) / len(steps)
+        # between two ticks with exactly one train step dispatched
+        one = [(b[0] - a[1]) * 1e3 for a, b in zip(ticks, ticks[1:])
+               if sum(1 for s in steps if a[1] <= s < b[0]) == 1]
+        if one:
+            out["step_wall_ms_p50"] = reduce.percentile(one, 50)
+        pieces = []
+        for k in range(int((t1 - t0) // 5)):
+            lo, hi = t0 + 5 * k, t0 + 5 * k + 5
+            n_steps = sum(1 for s in steps if lo <= s < hi)
+            if n_steps:
+                pieces.append(sum(1 for t in ticks if lo <= t[0] < hi)
+                              / n_steps)
+        if pieces:
+            out["ticks_per_step_5s_min"] = min(pieces)
+            out["ticks_per_step_5s_max"] = max(pieces)
     return out
 
 
@@ -88,10 +128,11 @@ def main(argv) -> None:
                   f"{100 * sb:.2f}% | {100 * abs(mb - ma) / ma:.2f}% | "
                   f"{500 * max(sa, sb):.1f}% |")
         for i, s in enumerate(rows, 1):
-            for key in ("tpot_p95_ms", "gap_p95_ms", "gap_p99_ms"):
+            for key in ("tpot_p50_ms", "tpot_p95_ms", "gap_p95_ms",
+                        "gap_p99_ms", "ticks_per_step", "completed"):
                 if key in s[0]:
                     print(f"set {i} {key}: "
-                          + " ".join(f"{r[key]:.2f}" for r in s))
+                          + " ".join(f"{r[key]:.4g}" for r in s))
     h = histogram(sets[0][0])
     print(f"\ngap histogram of the first run ({h['n']} gaps, 5 ms bins): "
           + ", ".join(f"{int(b)}:{n}" for b, n in h["bins"].items()))
