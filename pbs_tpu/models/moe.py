@@ -269,6 +269,75 @@ def moe_mlp(cfg: MoEConfig, x: jax.Array, lp: dict, constrain_ec):
     return y.reshape(B, S, d), jnp.mean(aux), jnp.mean(drop)
 
 
+# -- grouped experts: the share of a layer that this program holds -----------
+
+
+def route_top_k(h: jax.Array, router: jax.Array, kind):
+    """Token-choice routing over ALL of a layer's experts: h (T, d) ->
+    (weights (T, k) fp32, expert ids (T, k)). Scores are a float32
+    softmax over the router's logits; the k largest are kept,
+    renormalised to sum to one and scaled by ``kind.routed_scale``. No capacity: every token keeps every choice.
+    ``kind`` is a ``models.plan.MlpKind``."""
+    logits = h.astype(jnp.float32) @ router.astype(jnp.float32)
+    topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), kind.top_k)
+    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    return topv * kind.routed_scale, topi
+
+
+def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
+    """The routed part of an expert layer, as one holder of ``kind.held``
+    = (first, count) of its experts computes it: route over all
+    ``kind.n_experts``, keep the assignments that land on a held expert,
+    sort them by expert and run one grouped matrix product a weight
+    (``jax.lax.ragged_dot``: rows of expert e against ``we*[e]``). No
+    (T, E, C) tensor exists and no token is dropped, however the router
+    concentrates: the sorted buffer has a row for every assignment.
+    What an absent expert would add is left out; with every share's
+    result summed (``parallel/expert.py``) the layer is whole.
+
+    h (T, d), ``valid`` (T,) marks rows that are tokens (idle decode
+    lanes and bucket padding route nowhere and touch no expert).
+    Returns ``(y (T, d), counts)`` with ``counts`` int32
+    [assignments to held experts, to absent ones, held experts touched,
+    largest load of one held expert]."""
+    T, d = h.shape
+    k = kind.top_k
+    first, n = kind.held
+    with jax.named_scope("moe.route"):
+        w, idx = route_top_k(h, lp["router"], kind)
+        local = idx - first
+        ours = (local >= 0) & (local < n)
+        here = ours & valid[:, None]
+        # Held assignments sort to their expert's run; the rest behind.
+        key = jnp.where(here, local, n).reshape(-1)
+        order = jnp.argsort(key)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=order.dtype))
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+        xs = h[order // k]
+    with jax.named_scope("moe.experts"):
+        gate = jax.nn.silu(jax.lax.ragged_dot(xs, wload(lp["we1"], dt),
+                                              sizes))
+        up = jax.lax.ragged_dot(xs, wload(lp["we3"], dt), sizes)
+        out = jax.lax.ragged_dot(gate * up, wload(lp["we2"], dt), sizes)
+        # Back to (token, choice) order; rows behind the last run hold
+        # whatever the grouped product left there and are never read.
+        out = out[back].reshape(T, k, d)
+        y = jnp.sum(jnp.where(here[:, :, None],
+                              out * w[:, :, None].astype(dt), 0), axis=1)
+    counts = jnp.stack([
+        jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
+        jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
+    return y.astype(dt), counts
+
+
+def shared_expert_ffn(h: jax.Array, lp: dict, dt) -> jax.Array:
+    """The always-on expert every holder computes alike (added ungated)."""
+    with jax.named_scope("moe.shared"):
+        gate = jax.nn.silu(h @ wload(lp["ws1"], dt))
+        return (gate * (h @ wload(lp["ws3"], dt))) @ wload(lp["ws2"], dt)
+
+
 def moe_layer_body(cfg: MoEConfig, x: jax.Array, lp: dict, cos, sin,
                    constrain, constrain_ec, mesh=None, mlp=None,
                    attn=None):

@@ -1,0 +1,197 @@
+"""Layer plan: one description of a decoder's layer stack.
+
+A dense decoder is ``n_layers`` copies of one layer, and
+``TransformerConfig`` says so with a handful of widths. A model whose
+layers differ (full beside sliding-window attention with their own head
+counts and rotary settings; a leading dense MLP followed by routed
+experts) is described here instead: a few layer *kinds*, and per layer
+which attention kind and which MLP kind it is. The serving engine reads
+the plan (``models/serving.py``: the cache, the forward over slot rows,
+the ingestion of a prompt); nothing branches on a model's name.
+
+A planned model's parameters are held a layer at a time, every weight
+a buffer of its own (stacked by kind, XLA:TPU copies a layer's slice out
+of the stack before each use: 768 MB a routed-expert weight, every
+tick)::
+
+    embed, final_norm, head
+    blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]}
+    blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
+    blocks/<NN>/mlp/{mlp_norm, router, we1, we3, we2[, ws1, ws3, ws2]}
+
+A configuration without a plan is the uniform one its widths describe
+(:func:`uniform_plan`), held in the stacked ``layers/...`` tree and run
+by one ``lax.scan`` as always.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """Rotary embedding of one attention kind. ``rotary_dim`` leading
+    dims of each head rotate (half-split convention), the rest pass
+    through; ``None`` is the whole head. ``factor`` > 1 is YaRN as
+    Hugging Face's ``_compute_yarn_parameters`` writes it."""
+
+    theta: float = 10_000.0
+    rotary_dim: int | None = None
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    name: str
+    n_heads: int
+    window: int | None = None  # None: every earlier position is seen
+    rope: Rope = Rope()
+    head_gate: bool = False    # sigmoid gate, one scalar a query head
+
+
+@dataclasses.dataclass(frozen=True)
+class MlpKind:
+    """``n_experts`` 0 is a dense SwiGLU of width ``d_ff``. Otherwise
+    ``d_ff`` is one routed expert's width, the router scores all
+    ``n_experts``, and this program holds ``held`` = (first, count) of
+    them: what the others would add is some other chip's to compute
+    (``parallel/expert.py``)."""
+
+    name: str
+    d_ff: int
+    n_experts: int = 0
+    top_k: int = 0
+    held: tuple[int, int] = (0, 0)
+    shared_d_ff: int = 0       # one always-on expert beside the routed
+    routed_scale: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerPlan:
+    attn: tuple[AttnKind, ...]
+    mlp: tuple[MlpKind, ...]
+    layers: tuple[tuple[int, int], ...]  # per layer: (attn i, mlp i)
+
+    @property
+    def routed(self) -> bool:
+        return any(self.mlp[m].n_experts for _, m in self.layers)
+
+    def kinds(self, layer: int) -> tuple[AttnKind, MlpKind]:
+        a, m = self.layers[layer]
+        return self.attn[a], self.mlp[m]
+
+
+def block_name(layer: int) -> str:
+    return f"{layer:02d}"
+
+
+def uniform_plan(cfg) -> LayerPlan:
+    """What a configuration without a plan describes."""
+    return LayerPlan(
+        attn=(AttnKind("full", cfg.n_heads, None,
+                       Rope(theta=cfg.rope_theta)),),
+        mlp=(MlpKind("dense", cfg.d_ff),),
+        layers=((0, 0),) * cfg.n_layers)
+
+
+def plan_of(cfg) -> LayerPlan:
+    return cfg.layer_plan if cfg.layer_plan is not None \
+        else uniform_plan(cfg)
+
+
+# -- rotary tables ------------------------------------------------------------
+
+
+def inv_freq(rope: Rope, head_dim: int) -> np.ndarray:
+    """Inverse frequencies of the rotating pairs, float64 on the host
+    (a table's constants, not a traced value)."""
+    dim = rope.rotary_dim or head_dim
+    pos_freqs = rope.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope.factor <= 1.0:
+        return 1.0 / pos_freqs
+
+    def correction_dim(n_rot: float) -> float:
+        return (dim * math.log(rope.original_max / (n_rot * 2 * math.pi))
+                / (2 * math.log(rope.theta)))
+
+    low = max(math.floor(correction_dim(rope.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    extrapolation = 1.0 - ramp  # share of the unscaled frequency
+    return ((1.0 / (rope.factor * pos_freqs)) * (1.0 - extrapolation)
+            + (1.0 / pos_freqs) * extrapolation)
+
+
+def rope_table(rope: Rope, head_dim: int,
+               seq: int) -> tuple[jax.Array, jax.Array]:
+    """cos, sin of shape (seq, rotary_dim / 2), float32, already times
+    the kind's ``attention_factor``."""
+    freqs = jnp.asarray(inv_freq(rope, head_dim), jnp.float32)
+    ang = jnp.outer(jnp.arange(seq, dtype=jnp.float32), freqs)
+    scale = jnp.float32(rope.attention_factor)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def plan_shapes(cfg) -> dict:
+    """Shapes of a planned model's parameter tree (see the module
+    docstring), as nested dicts of tuples."""
+    plan = plan_of(cfg)
+    d, hd, nkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
+    out: dict = {"embed": (cfg.vocab, d), "final_norm": (d,),
+                 "head": (d, cfg.vocab), "blocks": {}}
+    for layer in range(len(plan.layers)):
+        a, m = plan.kinds(layer)
+        attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
+                "wk": (d, nkv * hd), "wv": (d, nkv * hd),
+                "wo": (a.n_heads * hd, d)}
+        if a.head_gate:
+            attn["wg"] = (d, a.n_heads)
+        f = m.d_ff
+        if not m.n_experts:
+            mlp = {"mlp_norm": (d,), "w1": (d, f), "w3": (d, f),
+                   "w2": (f, d)}
+        else:
+            n = m.held[1]
+            mlp = {"mlp_norm": (d,), "router": (d, m.n_experts),
+                   "we1": (n, d, f), "we3": (n, d, f), "we2": (n, f, d)}
+            if m.shared_d_ff:
+                s = m.shared_d_ff
+                mlp.update({"ws1": (d, s), "ws3": (d, s), "ws2": (s, d)})
+        out["blocks"][block_name(layer)] = {"attn": attn, "mlp": mlp}
+    return out
+
+
+def init_plan_params(cfg, key: jax.Array) -> dict:
+    """fp32 parameters of a planned model: normal / sqrt(fan_in), norms
+    at one, the embedding scaled by sqrt(d) as ``init_params`` has it."""
+    shapes = plan_shapes(cfg)
+    flat, treedef = jax.tree.flatten(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    paths = [p for p, _ in jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))[0]]
+    keys = jax.random.split(key, len(flat))
+    leaves = []
+    for path, shape, k in zip(paths, flat, keys):
+        name = str(path[-1].key)
+        if name.endswith("norm"):
+            leaves.append(jnp.ones(shape, jnp.float32))
+            continue
+        w = jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[-2])
+        leaves.append(w * np.sqrt(cfg.d_model) if name == "embed" else w)
+    return jax.tree.unflatten(treedef, leaves)

@@ -45,8 +45,11 @@ import numpy as np
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
 from pbs_tpu.obs.trace import Ev, TraceBuffer, host_ring, register_ring
+from pbs_tpu.models.plan import (
+    block_name, init_plan_params, plan_of, rope_table, uniform_plan)
 from pbs_tpu.models.transformer import (
     TransformerConfig,
+    init_params,
     rms_norm,
     rope_tables,
 )
@@ -80,6 +83,54 @@ def init_slot_cache(cfg: TransformerConfig, n_slots: int,
     }
 
 
+def _write_rows(rows, new, at, layer=None):
+    """Slot b's S new entries (``new``: (B, S, nkv, hd)) go to
+    ``rows[b, at[b]:]``, or with ``layer`` to ``rows[layer, b,
+    at[b]:]`` of a cache stacked by layer: one dynamic_update_slice a
+    slot into the WHOLE cache, so a layer moves its new positions and
+    nothing else. A DUS, not a scatter: GSPMD partitions it on an
+    unsharded axis natively, where the equivalent scatter made tp>2
+    compiles blow up. Not vmapped over the slot axis either (a batched
+    DUS is a scatter, and XLA then re-lays the carried cache
+    slot-major: whole-cache copies in and out of every call), nor
+    unrolled (the same re-layout)."""
+    B = new.shape[0]
+    if layer is None:
+        # ``new`` has the rank of ``rows`` here, and sliced at that rank
+        # an XLA:TPU pass takes it for ``rows`` (RET_CHECK, jax 0.9.0):
+        # slice it flat. (Sliced flat under ``layer`` too, the dense
+        # decode compiles to other fusions than it always has.)
+        flat = new.reshape(B, -1)
+
+    def one(b, rows):
+        if layer is None:
+            return jax.lax.dynamic_update_slice(
+                rows, jax.lax.dynamic_slice_in_dim(flat, b, 1).reshape(
+                    (1,) + new.shape[1:]), (b, at[b], 0, 0))
+        return jax.lax.dynamic_update_slice(
+            rows, jax.lax.dynamic_slice_in_dim(new, b, 1)[None],
+            (layer, b, at[b], 0, 0))
+
+    return jax.lax.fori_loop(0, B, one, rows)
+
+
+def _grouped_attention(q, k, v, mask, dt):
+    """q (B, S, H, hd) against k, v (B, K, nkv, hd); query head g reads
+    kv head g // (H / nkv); ``mask`` (B or 1, S, K) says what a query
+    sees. Softmax in float32. Returns (B, S, H, hd)."""
+    B, S, H, hd = q.shape
+    nkv = k.shape[2]
+    qg = q.reshape(B, S, nkv, H // nkv, hd).transpose(0, 2, 3, 1, 4)
+    kt = k.transpose(0, 2, 1, 3)  # (B, nkv, K, hd)
+    vt = v.transpose(0, 2, 1, 3)
+    scores = jnp.einsum("bngqh,bnkh->bngqk", qg, kt) / np.sqrt(hd)
+    mask = jnp.broadcast_to(mask[:, None, None, :, :], scores.shape)
+    scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
+    attn = jnp.einsum("bngqk,bnkh->bngqh", probs, vt)
+    return attn.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
+
+
 def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                   cache: dict, row_pos: jax.Array,
                   mlp_fn=None) -> tuple[jax.Array, dict]:
@@ -103,7 +154,6 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     T = cache["k"].shape[2]
     dt = cfg.dtype
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    group = nh // nkv
 
     x = embed_rows(params["embed"], tokens, dt)
     cos_full, sin_full = rope_tables(cfg, T)
@@ -112,23 +162,6 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     abs_pos = jnp.minimum(abs_pos, T - 1)  # clamp: masked rows only
     cos = cos_full[abs_pos]  # (B, S, half)
     sin = sin_full[abs_pos]
-
-    def write(rows, new, i):
-        """Row b's S new entries go to ``rows[i, b, row_pos[b]:]``: one
-        dynamic_update_slice a row into the WHOLE cache, so a layer
-        moves its new positions and nothing else. A DUS, not a scatter:
-        GSPMD partitions it on an unsharded axis natively, where the
-        equivalent scatter made tp>2 compiles blow up. Not vmapped over
-        the slot axis either (a batched DUS is a scatter, and XLA then
-        re-lays the carried cache slot-major: whole-cache copies in and
-        out of every call), nor unrolled (the same re-layout)."""
-
-        def one(b, rows):
-            return jax.lax.dynamic_update_slice(
-                rows, jax.lax.dynamic_slice_in_dim(new, b, 1)[None],
-                (i, b, row_pos[b], 0, 0))
-
-        return jax.lax.fori_loop(0, B, one, rows)
 
     def body(carry, layer):
         # The K/V slabs (L, B, T, nkv, hd) ride in the CARRY, not as
@@ -142,25 +175,15 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         v = (h @ wload(lp["wv"], dt)).reshape(B, S, nkv, hd)
         q = _rope_rows(q, cos, sin)
         k = _rope_rows(k, cos, sin)
-        ks = write(ks, k, i)
-        vs = write(vs, v, i)
+        ks = _write_rows(ks, k, row_pos, layer=i)
+        vs = _write_rows(vs, v, row_pos, layer=i)
         ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
-        # attention with per-row causal horizon
-        qg = q.reshape(B, S, nkv, group, hd).transpose(0, 2, 3, 1, 4)
-        kt = ck.transpose(0, 2, 1, 3)  # (B, nkv, T, hd)
-        vt = cv.transpose(0, 2, 1, 3)
-        scores = jnp.einsum("bngqh,bnkh->bngqk", qg, kt) / np.sqrt(hd)
         # per-row causal horizon: row b's query s sees cols <= abs_pos
         reach = (jnp.arange(T)[None, None, :]
                  <= abs_pos[:, :, None])  # (B, S, T)
-        mask = jnp.broadcast_to(reach[:, None, None, :, :], scores.shape)
-        scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-        probs = jax.nn.softmax(
-            scores.astype(jnp.float32), axis=-1).astype(dt)
-        attn = jnp.einsum("bngqk,bnkh->bngqh", probs, vt)
-        attn = attn.transpose(0, 3, 1, 2, 4).reshape(B, S, nh * hd)
-        x = x + attn @ wload(lp["wo"], dt)
+        attn = _grouped_attention(q, ck, cv, reach, dt)
+        x = x + attn.reshape(B, S, nh * hd) @ wload(lp["wo"], dt)
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         if mlp_fn is None:
             gate = jax.nn.silu(h @ wload(lp["w1"], dt))
@@ -205,6 +228,254 @@ def ingest_slot_prompt(cfg: TransformerConfig, params: dict, cache: dict,
         cache["v"], sub["v"], slot, axis=1)
     cache["pos"] = cache["pos"].at[slot].set(plen)
     return logits[0, plen - 1], cache, extra
+
+
+# -- a planned stack: layers that differ ------------------------------------
+
+
+def _rope_leading(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """Per-row rope on the leading ``2 * cos.shape[-1]`` dims of each
+    head; the rest pass through (partial rotary)."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return _rope_rows(x, cos, sin)
+    return jnp.concatenate(
+        [_rope_rows(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
+
+
+def init_plan_cache(cfg: TransformerConfig, n_slots: int,
+                    max_len: int) -> dict:
+    """Two kinds of cache in one, a layer at a time (a layer's keys are
+    a buffer of their own: read out of a stack they would be copied
+    first): a full layer keeps every position, ``(slots, max_len, nkv,
+    hd)``; a window layer keeps a ring of its window, ``(slots, W, nkv,
+    hd)``, position p at ``p mod W``. One cursor a slot serves both:
+    which ring entries are live follows from it alone."""
+    plan = plan_of(cfg)
+
+    def slabs():
+        out = {}
+        for layer in range(len(plan.layers)):
+            a, _ = plan.kinds(layer)
+            out[block_name(layer)] = jnp.zeros(
+                (n_slots, min(a.window, max_len) if a.window else max_len,
+                 cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+        return out
+
+    return {"k": slabs(), "v": slabs(),
+            "pos": jnp.zeros((n_slots,), jnp.int32)}
+
+
+def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
+                  cache: dict, row_pos: jax.Array, valid: jax.Array,
+                  slot=None):
+    """The planned stack over (B, S) tokens, layer by layer (a layer's
+    kinds are static, so each layer is its own code over its own
+    parameters and its own cache).
+
+    ``slot`` None is the decode tick: S == 1, row b at position
+    ``row_pos[b]``; each layer writes its one new position (full: at
+    the cursor; window: at cursor mod W, rotary already applied) and
+    attends over its cache. With a ``slot`` it is the ingestion of one
+    prompt from position 0 (B == 1): attention stays inside the prompt
+    (banded in a window layer) and the layer leaves the prompt's keys
+    and values in that slot (a window layer its last W positions, each
+    where the ring keeps it).
+
+    ``valid`` (B, S) marks real tokens: the expert layers route nothing
+    else. Returns (logits fp32: (B, 1, V), or (V,) at the prompt's last
+    position; the cache's new k and v; ``route``: int32 [tokens routed,
+    assignments to held experts, to absent experts, held experts
+    touched (both summed over expert layers), largest load of one
+    expert], None for a stack without experts). Donated, the cache is
+    updated in place."""
+    from pbs_tpu.models.moe import held_expert_ffn, shared_expert_ffn
+
+    plan = plan_of(cfg)
+    B, S = tokens.shape
+    dt, hd, nkv = cfg.dtype, cfg.head_dim, cfg.n_kv_heads
+    decode = slot is None
+    if decode and S != 1:
+        raise NotImplementedError(
+            "a planned stack decodes one position a tick: a window "
+            "layer's ring cannot take a multi-token verify window")
+    ks, vs = dict(cache["k"]), dict(cache["v"])
+    T = max(cfg.max_seq, *(c.shape[1] for c in ks.values()))
+    abs_pos = jnp.minimum(
+        row_pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :], T - 1)
+    tables = {a: rope_table(a.rope, hd, T) for a in plan.attn}
+    x = embed_rows(params["embed"], tokens, dt)
+    flat_valid = valid.reshape(-1)
+    counts = jnp.zeros((4,), jnp.int32)
+
+    for layer in range(len(plan.layers)):
+        a, m = plan.kinds(layer)
+        name = block_name(layer)
+        ap, mp = params["blocks"][name]["attn"], params["blocks"][name]["mlp"]
+        H = a.n_heads
+        h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
+        q = (h @ wload(ap["wq"], dt)).reshape(B, S, H, hd)
+        k = (h @ wload(ap["wk"], dt)).reshape(B, S, nkv, hd)
+        v = (h @ wload(ap["wv"], dt)).reshape(B, S, nkv, hd)
+        cos, sin = (t[abs_pos] for t in tables[a])
+        q, k = _rope_leading(q, cos, sin), _rope_leading(k, cos, sin)
+        K = ks[name].shape[1]
+        with jax.named_scope("attn.window" if a.window else "attn.full"):
+            if decode:
+                at = row_pos % K if a.window else row_pos
+                ks[name] = _write_rows(ks[name], k, at)
+                vs[name] = _write_rows(vs[name], v, at)
+                col = jnp.arange(K)[None, :]
+                # Ring entry j holds the largest p <= cursor with
+                # p = j mod W: live once written, always after a lap.
+                seen = (col <= row_pos[:, None]) | (
+                    (row_pos[:, None] >= K) if a.window else False)
+                attn = _grouped_attention(q, ks[name], vs[name],
+                                          seen[:, None, :], dt)
+            else:
+                i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+                seen = (j <= i) & ((i - j < a.window) if a.window else True)
+                attn = _grouped_attention(q, k, v, seen[None], dt)
+                if a.window:
+                    # Entry j of the ring: the prompt's last position
+                    # that is j mod W (an entry with none is not live).
+                    last = row_pos[0] + valid.sum() - 1
+                    src = last - (last - jnp.arange(K)) % K
+                    k, v = (t[:, jnp.clip(src, 0, S - 1)] for t in (k, v))
+                else:
+                    k, v = k[:, :K], v[:, :K]
+                ks[name] = jax.lax.dynamic_update_slice(
+                    ks[name], k, (slot, 0, 0, 0))
+                vs[name] = jax.lax.dynamic_update_slice(
+                    vs[name], v, (slot, 0, 0, 0))
+            if a.head_gate:
+                gate = jax.nn.sigmoid(h @ wload(ap["wg"], dt))
+                attn = attn * gate[..., None]
+        x = x + attn.reshape(B, S, H * hd) @ wload(ap["wo"], dt)
+
+        h = rms_norm(x, mp["mlp_norm"], cfg.norm_eps)
+        if not m.n_experts:
+            with jax.named_scope("mlp.dense"):
+                gate = jax.nn.silu(h @ wload(mp["w1"], dt))
+                y = (gate * (h @ wload(mp["w3"], dt))) @ wload(mp["w2"], dt)
+        else:
+            hf = h.reshape(B * S, -1)
+            y, c = held_expert_ffn(hf, mp, m, flat_valid, dt)
+            if m.shared_d_ff:
+                y = y + shared_expert_ffn(hf, mp, dt)
+            y = y.reshape(B, S, -1)
+            counts = jnp.concatenate(
+                [counts[:3] + c[:3], jnp.maximum(counts[3:], c[3:])])
+        x = x + y
+
+    if not decode:
+        x = jax.lax.dynamic_index_in_dim(
+            x[0], jnp.maximum(valid.sum() - 1, 0), 0, keepdims=False)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = (x @ wload(params["head"], dt)).astype(jnp.float32)
+    route = jnp.concatenate(
+        [valid.sum().astype(jnp.int32)[None], counts]) \
+        if plan.routed else None
+    return logits, ks, vs, route
+
+
+class _ScanProgram:
+    """Every layer alike (any dense configuration, and the ``mlp_fn``
+    fixture): the layer ``lax.scan`` of ``_slot_forward`` over one
+    ``(L, slots, max_len, nkv, hd)`` cache. Routes nothing."""
+
+    #: a window of positions can be cut from, installed into and
+    #: verified over every layer's cache (prefix cache, speculation)
+    windows = True
+
+    def __init__(self, cfg: TransformerConfig, mlp_fn=None):
+        self.cfg, self.mlp_fn = cfg, mlp_fn
+
+    def init_params(self, key: jax.Array) -> dict:
+        return init_params(self.cfg, key)
+
+    def init_cache(self, n_slots: int, max_len: int) -> dict:
+        return init_slot_cache(self.cfg, n_slots, max_len)
+
+    def place(self, params: dict, cache: dict, mesh):
+        return (_shard_serving_params(self.cfg, params, mesh),
+                _shard_slot_cache(cache, mesh))
+
+    def decode(self, params, cache, last_tok, active):
+        logits, new, extra = _slot_forward(
+            self.cfg, params, last_tok[:, None], cache, cache["pos"],
+            mlp_fn=self.mlp_fn)
+        return logits, new, extra, None
+
+    def ingest(self, params, cache, slot, prompt, plen):
+        return ingest_slot_prompt(self.cfg, params, cache, slot, prompt,
+                                  plen, mlp_fn=self.mlp_fn) + (None,)
+
+
+class _PlannedProgram:
+    """Layers that differ (``cfg.layer_plan``): two kinds of cache in
+    one manager, the grouped expert layer, ``route`` counters."""
+
+    #: a window layer's ring takes one position a tick
+    windows = False
+
+    def __init__(self, cfg: TransformerConfig):
+        self.cfg = cfg
+
+    def init_params(self, key: jax.Array) -> dict:
+        return init_plan_params(self.cfg, key)
+
+    def init_cache(self, n_slots: int, max_len: int) -> dict:
+        return init_plan_cache(self.cfg, n_slots, max_len)
+
+    def place(self, params: dict, cache: dict, mesh):
+        """The caller placed the parameters (the serve rule table names
+        every leaf of a planned tree); the cache goes beside them. One
+        device: how a ring and a share of experts divide over a tensor
+        axis is not written."""
+        if mesh.devices.size != 1:
+            raise NotImplementedError(
+                f"a planned layer stack serves on one device, not on a "
+                f"mesh of {dict(mesh.shape)}: neither the window ring's "
+                f"nor the held experts' division over a tensor axis is "
+                f"written (ROADMAP R4)")
+        import jax.sharding as jsh
+
+        return params, jax.device_put(
+            cache, jsh.NamedSharding(mesh, jsh.PartitionSpec()))
+
+    def decode(self, params, cache, last_tok, active):
+        logits, ks, vs, route = _plan_forward(
+            self.cfg, params, last_tok[:, None], cache, cache["pos"],
+            active[:, None])
+        return (logits, {"k": ks, "v": vs, "pos": cache["pos"]},
+                jnp.zeros((), jnp.float32), route)
+
+    def ingest(self, params, cache, slot, prompt, plen):
+        valid = (jnp.arange(prompt.shape[0]) < plen)[None, :]
+        last_logits, ks, vs, route = _plan_forward(
+            self.cfg, params, prompt[None, :], cache,
+            jnp.zeros((1,), jnp.int32), valid, slot=slot)
+        cache = {"k": ks, "v": vs, "pos": cache["pos"].at[slot].set(plen)}
+        return last_logits, cache, jnp.zeros((), jnp.float32), route
+
+
+def slot_program(cfg: TransformerConfig, mlp_fn=None):
+    """What a configuration's layer stack gives the engine and the
+    serve backend, and the one place that chooses between the two
+    forms: its parameter tree (``init_params``), its cache
+    (``init_cache``), one decode position for every slot (``decode``)
+    and the ingestion of one prompt (``ingest``), both returning
+    ``(logits, cache, mlp extra, route)``, and whether its caches take
+    windows of positions (``windows``). A configuration whose layers
+    are all alike, said by its widths or by a plan, gets the stacked
+    tree and the layer scan it always had."""
+    if plan_of(cfg) == uniform_plan(cfg):
+        return _ScanProgram(cfg, mlp_fn)
+    if mlp_fn is not None:
+        raise ValueError("a planned layer stack names its own MLP kinds; "
+                         "mlp_fn swaps the FFN of a uniform stack only")
+    return _PlannedProgram(cfg)
 
 
 def _shard_serving_params(cfg, params: dict, mesh) -> dict:
@@ -296,6 +567,9 @@ class ContinuousBatcher:
         # FFN swap (same seam as generate._forward_with_cache_impl):
         # the MoE family serves through this engine via moe_slot_mlp.
         self.mlp_fn = mlp_fn
+        # What the configuration's layer stack gives the engine: its
+        # cache, a decode position for every slot, a prompt's ingestion.
+        self.program = slot_program(cfg, mlp_fn)
         self.n_slots = n_slots
         self.bucket = prompt_bucket
         self.max_len = max_len or cfg.max_seq
@@ -304,7 +578,12 @@ class ContinuousBatcher:
         self.temperature = temperature
         self.eos_id = eos_id
         self.mesh = mesh
-        cache = init_slot_cache(cfg, n_slots, self.max_len)
+        if prefix_cache_size and not self.program.windows:
+            raise ValueError(
+                "prefix_cache_size > 0 needs a prompt window that can be "
+                "cut from and installed into every layer's cache; over a "
+                "window layer's ring that is not written (ROADMAP R4)")
+        cache = self.program.init_cache(n_slots, self.max_len)
         if mesh is not None:
             # Tensor-parallel serving by PLACEMENT (the GSPMD recipe):
             # shard params Megatron-style and the KV slabs over the kv
@@ -314,8 +593,7 @@ class ContinuousBatcher:
                 raise ValueError(
                     f"serving mesh needs a 'tp' axis; got "
                     f"{mesh.axis_names}")
-            params = _shard_serving_params(cfg, params, mesh)
-            cache = _shard_slot_cache(cache, mesh)
+            params, cache = self.program.place(params, cache, mesh)
         self.params = params
         self.cache = cache
         self._key = jax.random.PRNGKey(seed)
@@ -388,11 +666,12 @@ class ContinuousBatcher:
             first token. prompt: (bucket,) padded; plen: real length.
             Also returns the last-position logits (for the prefix
             cache)."""
-            last_logits, cache, extra = ingest_slot_prompt(
-                cfg_, params, cache, slot, prompt, plen,
-                mlp_fn=self.mlp_fn)
+            last_logits, cache, extra, route = self.program.ingest(
+                params, cache, slot, prompt, plen)
             first = _sample(last_logits[None, :], key,
                             self.temperature)[0]
+            if route is not None:  # rides to the host with the token
+                first = jnp.concatenate([first[None], route])
             return first, last_logits, cache, extra
 
         if mesh is not None:
@@ -426,9 +705,8 @@ class ContinuousBatcher:
         @functools.partial(jax.jit, donate_argnums=(1,))
         def _decode(params, cache, last_tok, active, key):
             """One token for every slot; inactive lanes masked."""
-            logits, new_cache, extra = _slot_forward(
-                cfg_, params, last_tok[:, None], cache, cache["pos"],
-                mlp_fn=self.mlp_fn)
+            logits, new_cache, extra, route = self.program.decode(
+                params, cache, last_tok, active)
             keys = jax.random.split(key, self.n_slots)
             nxt = jax.vmap(
                 lambda lg, k: _sample(lg[None, :], k,
@@ -436,6 +714,8 @@ class ContinuousBatcher:
             )(logits[:, 0, :], keys)
             nxt = jnp.where(active, nxt, 0)
             new_cache["pos"] = cache["pos"] + active.astype(jnp.int32)
+            if route is not None:  # rides to the host with the tokens
+                nxt = jnp.concatenate([nxt, route])
             return nxt, new_cache, extra
 
         self._prefill_fn = _prefill
@@ -473,6 +753,16 @@ class ContinuousBatcher:
         tr = self.trace
         if tr is not None:
             tr.emit(ts_ns, event, *args)
+
+    def _routed(self, ts_ns: int, out: np.ndarray, n: int) -> np.ndarray:
+        """The ``n`` tokens of a program's integer output; what an
+        expert-routing program sent behind them (``_plan_forward``'s
+        ``route``) becomes the ``ENG_ROUTE`` record of that prefill or
+        decode, stamped like its ``ENG_PREFILL`` / ``ENG_DECODE``."""
+        if len(out) > n:
+            self._ev(ts_ns, Ev.ENG_ROUTE, self._tick_seq,
+                     *(int(c) for c in out[n:]))
+        return out[:n]
 
     def _split_key(self) -> jax.Array:
         """Advance the sampling key (two tiny device programs a call)."""
@@ -550,7 +840,8 @@ class ContinuousBatcher:
                         self.params, self.cache, slot,
                         jnp.asarray(padded), len(prompt), sub)
                 t_dispatched = _ns()
-                first = int(first)
+                first = int(self._routed(
+                    t_prefill, np.asarray(first).ravel(), 1)[0])
                 self._mlp_extra_sum += float(extra) / self.cfg.n_layers
         t_synced = _ns()
         self._ev(t_prefill, Ev.ENG_PREFILL, tick, rid, slot,
@@ -685,7 +976,7 @@ class ContinuousBatcher:
         with _span("pbst.eng.sync"):
             self._mlp_extra_sum += float(extra) / self.cfg.n_layers
             self._mlp_extra_n += 1
-            nxt = np.asarray(nxt)
+            nxt = self._routed(t_pre, np.asarray(nxt), self.n_slots)
         t_host = _ns()
         for slot in range(self.n_slots):
             if not self.active[slot]:
@@ -770,6 +1061,12 @@ class SpeculativeBatcher(ContinuousBatcher):
             raise ValueError(f"k must be >= 1, got {k}")
         if cfg.vocab != draft_cfg.vocab:
             raise ValueError("draft vocab != target vocab")
+        for c in (cfg, draft_cfg):
+            if not slot_program(c).windows:
+                raise NotImplementedError(
+                    "speculation verifies k + 1 positions a tick, over "
+                    "uniform layer stacks only: a window layer's ring "
+                    "takes one position a tick")
         super().__init__(cfg, params, **kw)
         self.draft_cfg = draft_cfg
         self.draft_params = draft_params

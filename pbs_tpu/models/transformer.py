@@ -68,9 +68,21 @@ class TransformerConfig:
     # for ~one extra head-matmul pass of recompute in the backward.
     # 0/1 = off (materialized logits, the original path).
     loss_chunks: int = 0
+    # Width of one attention head where the model publishes it apart
+    # from its other widths; None is d_model // n_heads (every dense
+    # configuration). Read through ``head_dim``.
+    head_size: int | None = None
+    # Layers that differ (attention kinds with their own head counts,
+    # windows and rotary settings; dense beside routed-expert MLPs):
+    # a ``models.plan.LayerPlan``. None is n_layers copies of the one
+    # layer the widths above describe. Read by the serving engine; the
+    # training step runs uniform configurations only.
+    layer_plan: Any = None
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         return self.d_model // self.n_heads
 
     def bytes_per_token_step(self) -> int:

@@ -193,6 +193,13 @@ class Ev(enum.IntEnum):
     #                            post_ns (-> step returns)
     ENG_RETIRE = 0x0A06  # args: tick, rid, slot, tokens, ttft_ns,
     #                            latency_ns (engine latency clock)
+    ENG_ROUTE = 0x0A07  # one a prefill and one a decode tick of a
+    #                     program that routes tokens to experts, stamped
+    #                     like that ENG_PREFILL / ENG_DECODE. args: tick,
+    #                     tokens routed, assignments to held experts, to
+    #                     absent ones, held experts touched (the last
+    #                     three summed over expert layers), largest load
+    #                     of one expert
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

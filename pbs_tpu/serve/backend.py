@@ -87,9 +87,9 @@ class ShardedServeBackend(BatcherBackend):
             raise ValueError(f"clock must be 'wall' or 'virtual', "
                              f"got {clock!r}")
         if params is None:
-            from pbs_tpu.models import init_params
+            from pbs_tpu.models.serving import slot_program
 
-            params = init_params(cfg, jax.random.PRNGKey(seed))
+            params = slot_program(cfg).init_params(jax.random.PRNGKey(seed))
         self.cfg = cfg
         self.mesh = make_serve_mesh(tp=tp, dp=dp)
         # Rule-table placement first (hard error on an uncovered

@@ -52,9 +52,22 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"/w[13]$", (None, None, -1)),
     (r"/w2$", (None, -1, None)),
     (r"^head$", (None, -1)),
+    # A planned stack (models/plan.py) holds a layer at a time, under
+    # ``blocks/<NN>/attn/`` and ``blocks/<NN>/mlp/``: there a spec
+    # above, written for a stack of layers, loses its leading (layer)
+    # entry (match_partition_rules). The planned tree's own leaves, at
+    # their own rank: the per-head output gate and the router
+    # replicated (a few columns; every holder routes over all experts),
+    # the routed experts along the axis they are divided on (the expert
+    # axis), the shared expert like one layer's dense MLP.
+    (r"/(wg|router)$", ()),
+    (r"/we[123]$", (-1, None, None)),
+    (r"/ws[13]$", (None, -1)),
+    (r"/ws2$", (-1, None)),
 )
 
-#: The canonical flagship param paths the table must cover — the
+#: The canonical param paths the table must cover (the flagship's
+#: ``layers/...`` tree and a planned stack's per-kind one) — the
 #: static ``serve-unmatched-rule`` check audits PARTITION_RULES
 #: against this literal (dead/shadowed/uncovered detection without
 #: importing jax), and tests/test_serve.py pins it against the real
@@ -72,6 +85,25 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "layers/w2",
     "final_norm",
     "head",
+    # one block of a planned stack's tree (models/plan.plan_shapes);
+    # N stands for the layer's number
+    "blocks/N/attn/attn_norm",
+    "blocks/N/attn/wq",
+    "blocks/N/attn/wk",
+    "blocks/N/attn/wv",
+    "blocks/N/attn/wo",
+    "blocks/N/attn/wg",
+    "blocks/N/mlp/mlp_norm",
+    "blocks/N/mlp/w1",
+    "blocks/N/mlp/w3",
+    "blocks/N/mlp/w2",
+    "blocks/N/mlp/router",
+    "blocks/N/mlp/we1",
+    "blocks/N/mlp/we3",
+    "blocks/N/mlp/we2",
+    "blocks/N/mlp/ws1",
+    "blocks/N/mlp/ws3",
+    "blocks/N/mlp/ws2",
 )
 
 
@@ -103,7 +135,11 @@ def match_partition_rules(rules: Iterable[tuple[str, tuple]],
                           params: dict) -> dict:
     """Positional-spec tree for ``params``: scalars (ndim 0 or one
     element) are unpartitioned, the first rule whose regex ``search``es
-    the "/"-joined path wins, an unmatched non-scalar leaf raises."""
+    the "/"-joined path wins, an unmatched non-scalar leaf raises. A
+    planned tree keeps one layer a leaf under ``blocks/``: there a
+    spec one entry longer than the leaf was written for a stack of
+    layers, and its leading entry falls away. Any other mismatch of
+    rank stands, and fails where the spec is resolved."""
     rules = tuple(rules)
 
     def walk(tree: dict, prefix: str) -> dict:
@@ -120,7 +156,11 @@ def match_partition_rules(rules: Iterable[tuple[str, tuple]],
                 continue
             for pattern, spec in rules:
                 if re.search(pattern, path) is not None:
-                    out[key] = tuple(spec)
+                    spec = tuple(spec)
+                    if path.startswith("blocks/") \
+                            and len(spec) == len(shape) + 1:
+                        spec = spec[1:]
+                    out[key] = spec
                     break
             else:
                 raise ValueError(

@@ -43,6 +43,31 @@ def params(cfg):
     return init_params(cfg, jax.random.PRNGKey(0))
 
 
+@pytest.fixture(scope="module")
+def planned_params():
+    """A planned stack (models/plan.py) with every leaf kind: a gated
+    full layer with a dense MLP, a gated window layer with routed
+    experts and a shared one."""
+    from pbs_tpu.models import plan as P
+
+    plan = P.LayerPlan(
+        attn=(P.AttnKind("full", 2, None, P.Rope(), True),
+              P.AttnKind("window", 4, 4, P.Rope(), True)),
+        mlp=(P.MlpKind("dense", 32),
+             P.MlpKind("experts", 8, n_experts=4, top_k=2, held=(0, 2),
+                       shared_d_ff=8)),
+        layers=((0, 0), (1, 1)))
+    cfg = TransformerConfig(**dict(TINY, n_layers=2, head_size=8,
+                                   layer_plan=plan))
+    return P.init_plan_params(cfg, jax.random.PRNGKey(0))
+
+
+def tree_paths(*trees):
+    """Leaf paths of the trees, a planned tree's layer number as N."""
+    return [re.sub(r"^blocks/\d+/", "blocks/N/", p)
+            for tree in trees for p, _ in iter_leaf_paths(tree)]
+
+
 def _tiny_kw(seed):
     return dict(tp=1, dp=1, n_slots=2, prompt_bucket=8, max_len=32,
                 seed=seed, clock="virtual")
@@ -75,11 +100,35 @@ def test_every_leaf_matches_exactly_one_rule(params):
         assert len(hits) == 1, f"{path}: matched {hits}"
 
 
-def test_template_paths_pin_the_param_tree(params):
+def test_template_paths_pin_the_param_tree(params, planned_params):
     """TEMPLATE_PATHS is the audit's coverage universe; it must BE the
-    init_params leaf set or the audit goes blind to drift."""
-    actual = tuple(p for p, _ in iter_leaf_paths(params))
+    leaf set of the two trees the engine serves (init_params' stacked
+    one, a planned stack's per-layer one) or the audit goes blind to
+    drift."""
+    actual = set(tree_paths(params, planned_params))
     assert sorted(actual) == sorted(TEMPLATE_PATHS)
+
+
+def test_a_stacked_spec_places_one_layer_of_it(planned_params):
+    """Written for (layer, ...) stacks, a spec loses its layer entry on
+    a planned tree's per-layer leaf under ``blocks/``, and nowhere
+    else; every leaf still meets one rule."""
+    for path, _leaf in iter_leaf_paths(planned_params):
+        hits = [pat for pat, _ in PARTITION_RULES if re.search(pat, path)]
+        assert len(hits) == 1, f"{path}: matched {hits}"
+    specs = match_partition_rules(PARTITION_RULES, planned_params)
+    block = specs["blocks"]["01"]
+    assert block["attn"]["wq"] == (None, -1)
+    assert block["attn"]["wo"] == (-1, None)
+    assert block["attn"]["attn_norm"] == () and block["attn"]["wg"] == ()
+    assert block["mlp"]["we1"] == (-1, None, None)
+    assert block["mlp"]["ws2"] == (-1, None)
+    assert block["mlp"]["router"] == ()
+    # Outside blocks/ a spec stands as written: a leaf of the wrong
+    # rank keeps its three entries and fails where they are resolved.
+    stray = match_partition_rules(
+        PARTITION_RULES, {"layers": {"wq": jnp.ones((8, 4))}})
+    assert stray["layers"]["wq"] == (None, None, -1)
 
 
 def test_audit_is_clean():
@@ -87,11 +136,11 @@ def test_audit_is_clean():
     assert audit == {"dead": [], "shadowed": [], "uncovered": []}
 
 
-def test_every_rule_claims_a_leaf(params):
-    paths = [p for p, _ in iter_leaf_paths(params)]
+def test_every_rule_claims_a_leaf(params, planned_params):
+    paths = tree_paths(params, planned_params)
     for pat, _spec in PARTITION_RULES:
         assert any(re.search(pat, p) for p in paths), \
-            f"rule {pat!r} claims no leaf of the flagship tree"
+            f"rule {pat!r} claims no leaf of either served tree"
 
 
 def test_unmatched_leaf_is_a_hard_error(params):
