@@ -11,10 +11,11 @@ step boundaries and finished ones retired immediately.
 TPU-first expression of the idea:
 
 - **Static everything**: ``n_slots`` decode lanes, one shared KV slab
-  ``(L, n_slots, T, nkv, hd)``, prompts padded to a static bucket.
-  Admission/retirement changes DATA (per-slot cursors and masks),
-  never shapes — so exactly two XLA programs exist (slot-prefill,
-  slot-decode) regardless of traffic.
+  ``(L, n_slots, T, nkv, hd)``, prompts padded to one of two
+  static lengths (``prefill_rungs``). Admission/retirement
+  changes DATA (per-slot cursors and masks), never shapes — so two
+  XLA programs exist (slot-prefill, once a rung, and slot-decode)
+  regardless of traffic, all compiled at construction.
 - **Per-slot cursors**: unlike ``forward_with_cache`` (one scalar
   position for the whole batch), every slot carries its own ``pos``;
   rope tables are gathered per row, cache writes scatter per row, and
@@ -34,6 +35,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import math
 import time
 from collections import OrderedDict, deque
 from typing import Any
@@ -521,6 +523,28 @@ def _shard_slot_cache(cache: dict, mesh) -> dict:
     }
 
 
+def prefill_rungs(bucket: int) -> tuple[int, ...]:
+    """The padded lengths a prompt forward is compiled at, ascending:
+    ``bucket`` and its half, if a prefill of that half is still bound
+    by arithmetic on this chip. The chip's balance (peak FLOP/s over
+    peak bytes/s: 240 rows of bf16 on a v5e), rounded up to a power of
+    two, is where that stops; below it a prefill reads every weight
+    once, as a decode tick does, and a shorter rung would buy a
+    compile and no time. A bucket that small (every test's) is its own
+    only rung. One halving, not a ladder down to that floor: every
+    rung is one more program to trace, lower and load at construction
+    (half a second of set-up each, measured), and a quarter-bucket
+    rung bought a third of a percent where it was tried (PERF.md 6,
+    PR 29)."""
+    from pbs_tpu.telemetry.peaks import device_peaks
+
+    peaks = device_peaks()
+    floor = 1 << math.ceil(math.log2(peaks.flops / peaks.hbm_bw))
+    half = bucket // 2
+    return (half, bucket) if bucket % 2 == 0 and half >= floor \
+        else (bucket,)
+
+
 @dataclasses.dataclass
 class Completion:
     request_id: int
@@ -536,8 +560,11 @@ class ContinuousBatcher:
 
     ``submit`` enqueues; ``step()`` admits into free slots, advances
     one decode token for every active slot, and returns finished
-    :class:`Completion`s. All shapes static: ``n_slots`` lanes,
-    prompts padded to ``prompt_bucket``, caches sized ``max_len``.
+    :class:`Completion`s. All shapes static: ``n_slots`` lanes, caches
+    sized ``max_len``, a prompt padded to the smallest of ``rungs``
+    that holds it (``prefill_rungs``: ``prompt_bucket``, the longest
+    prompt the engine takes, and its half where a shorter forward is
+    a faster one), each rung one compiled instance of the prefill.
 
     The engine OWNS its cache: every program that takes it donates it
     and writes the new positions in place, so after a call the handle
@@ -572,6 +599,7 @@ class ContinuousBatcher:
         self.program = slot_program(cfg, mlp_fn)
         self.n_slots = n_slots
         self.bucket = prompt_bucket
+        self.rungs = prefill_rungs(prompt_bucket)
         self.max_len = max_len or cfg.max_seq
         if self.bucket >= self.max_len:
             raise ValueError("prompt_bucket must be < max_len")
@@ -657,13 +685,15 @@ class ContinuousBatcher:
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefill_count = 0  # real prefill dispatches (cache misses)
+        self.prefill_rows = 0  # rows they ran at (each one's rung)
+        self.prefill_prompt_tokens = 0  # rows of those that were prompt
 
         cfg_ = cfg
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def _prefill(params, cache, slot, prompt, plen, key):
             """Write one request's prompt into ``slot`` and sample its
-            first token. prompt: (bucket,) padded; plen: real length.
+            first token. prompt: (rung,) padded; plen: real length.
             Also returns the last-position logits (for the prefix
             cache)."""
             last_logits, cache, extra, route = self.program.ingest(
@@ -729,10 +759,13 @@ class ContinuousBatcher:
         # prompt and no active lane leave every cursor at 0, and what
         # they write (slot 0's bucket, position 0 of each lane) the
         # first tenant's prefill or decode overwrites before reading.
+        # Every rung is its own instance of the prefill: all of them
+        # now, so that none compiles under a request.
         wk = jax.random.PRNGKey(0)
-        self.cache = _prefill(
-            self.params, self.cache, 0,
-            jnp.zeros((self.bucket,), jnp.int32), 0, wk)[2]
+        for rung in self.rungs:
+            self.cache = _prefill(
+                self.params, self.cache, 0,
+                jnp.zeros((rung,), jnp.int32), 0, wk)[2]
         if prefix_cache_size:
             win = jnp.zeros((cfg.n_layers, 1, self.bucket,
                              cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
@@ -796,9 +829,10 @@ class ContinuousBatcher:
     # -- the engine tick --------------------------------------------------
 
     def _admit(self) -> None:
-        # (slot, padded_prompt, plen) of this tick's admissions — the
-        # hook subclasses use to mirror work per new tenant (the
-        # speculative engine draft-prefills the same prompt).
+        # (slot, padded_prompt, plen) of this tick's admissions, each
+        # padded to its rung — the hook subclasses use to mirror work
+        # per new tenant (the speculative engine draft-prefills the
+        # same prompt, at the same rung by its shape).
         # Initialized in __init__ too, so it is safe to read pre-tick.
         self._admitted = []
         for slot in range(self.n_slots):
@@ -807,6 +841,10 @@ class ContinuousBatcher:
             with _span("pbst.eng.admit"):
                 self._admit_one(slot)
 
+    def _rung(self, plen: int) -> int:
+        """The padded length a prompt of ``plen`` tokens runs at."""
+        return next(r for r in self.rungs if r >= plen)
+
     def _admit_one(self, slot: int) -> None:
         t_admit = _ns()
         tick = self._tick_seq
@@ -814,7 +852,8 @@ class ContinuousBatcher:
         t_slot = self._now()
         if self.admit_hook is not None:
             self.admit_hook(rid, slot)
-        padded = np.zeros(self.bucket, np.int32)
+        rows = self._rung(len(prompt))
+        padded = np.zeros(rows, np.int32)
         padded[:len(prompt)] = prompt
         self._admitted.append((slot, padded, len(prompt)))
         sub = self._split_key()
@@ -846,13 +885,18 @@ class ContinuousBatcher:
         t_synced = _ns()
         self._ev(t_prefill, Ev.ENG_PREFILL, tick, rid, slot,
                  t_dispatched - t_prefill, t_synced - t_dispatched,
-                 int(ent is not None))
+                 rows if ent is None else 0)
         if ent is None:
             self._mlp_extra_n += 1
             self.prefill_count += 1
+            self.prefill_rows += rows
+            self.prefill_prompt_tokens += len(prompt)
             if self.prefix_cache_size:
                 self.prefix_misses += 1
-                # Device arrays: lazy slices, no host sync here.
+                # Device arrays: lazy slices, no host sync here. A
+                # window is ``bucket`` positions whatever the rung was
+                # (one shape for _install); past the rung it holds an
+                # earlier tenant's, which no cursor reaches.
                 self._prefix_cache[pkey] = {
                     "k": self.cache["k"][:, slot:slot + 1,
                                          :self.bucket],
@@ -1017,6 +1061,11 @@ class ContinuousBatcher:
             "latency_p99_s": round(self._pct(self._latencies, 0.99), 6),
             "prefix_hits": self.prefix_hits,
             "prefix_misses": self.prefix_misses,
+            # Real prefills, the rows they ran at and how many of those
+            # were prompt: 1 - tokens / rows is the padding paid for.
+            "prefill_count": self.prefill_count,
+            "prefill_rows": self.prefill_rows,
+            "prefill_prompt_tokens": self.prefill_prompt_tokens,
             # FFN auxiliary mean (MoE: drop fraction; 0 for dense) —
             # nonzero under capacity starvation means engine routing
             # has diverged from the dropless/lockstep contract.
@@ -1146,9 +1195,10 @@ class SpeculativeBatcher(ContinuousBatcher):
         self._spec_decode_fn = _spec_decode
         # Warm both programs at construction (same SLO reasoning, same
         # rebinding and same untouched cursors as the parent's warm-up).
-        self.dcache = _draft_prefill(
-            self.draft_params, self.dcache, 0,
-            jnp.zeros((self.bucket,), jnp.int32), 0)[0]
+        for rung in self.rungs:
+            self.dcache = _draft_prefill(
+                self.draft_params, self.dcache, 0,
+                jnp.zeros((rung,), jnp.int32), 0)[0]
         self.cache, self.dcache = _spec_decode(
             self.params, self.draft_params, self.cache, self.dcache,
             jnp.zeros((n_slots,), jnp.int32),

@@ -185,8 +185,10 @@ class Ev(enum.IntEnum):
     #                           dur_ns
     ENG_PREFILL = 0x0A03  # args: tick, rid, slot, dispatch_ns (call
     #                             returns), sync_ns (first token on the
-    #                             host), prefix_hit (1: cached KV was
-    #                             installed, no prompt forward ran)
+    #                             host), rows (the padded length the
+    #                             prompt forward ran at, its rung; 0:
+    #                             a prefix hit, cached KV was installed
+    #                             and no prompt forward ran)
     ENG_KEYSPLIT = 0x0A04  # args: tick, dur_ns
     ENG_DECODE = 0x0A05  # args: tick, pre_ns (-> program enqueued),
     #                            sync_ns (-> tokens on the host),

@@ -205,7 +205,7 @@ def test_one_tick_two_admissions_one_retirement(cfg, params):
     admits = [r for r in recs if r[1] == Ev.ENG_ADMIT]
     assert [(r[3], r[4], r[5]) for r in admits] == [(a, 0, 3), (b, 1, 4)]
     prefills = [r for r in recs if r[1] == Ev.ENG_PREFILL]
-    assert [r[7] for r in prefills] == [0, 0]    # no prefix cache: no hits
+    assert [r[7] for r in prefills] == [8, 8]    # both ran, at the bucket
     # A prefill and its key split lie inside their admission.
     for adm, pre in zip(admits, prefills):
         assert adm[0] <= pre[0] and pre[0] + pre[5] + pre[6] <= adm[0] + adm[7]
@@ -223,8 +223,8 @@ def test_prefix_hit_is_on_the_prefill_record(cfg, params):
     eng.step()
     eng.submit([1, 2, 3], 2)
     eng.step()
-    hits = [r[7] for r in engine_records(eng) if r[1] == Ev.ENG_PREFILL]
-    assert hits == [0, 1]
+    rows = [r[7] for r in engine_records(eng) if r[1] == Ev.ENG_PREFILL]
+    assert rows == [8, 0]   # a forward at its rung; a hit ran none: 0 rows
 
 
 def test_engine_trace_can_be_bound_or_off(cfg, params):
