@@ -74,7 +74,7 @@ GIB = float(1 << 30)
 class Sizes:
     cfg: TransformerConfig
     seq: int
-    train_batch: int  # the train leg: bench.py's batch
+    train_batch: int  # the train leg
     colo_batch: int  # the co-resident leg: sized so both tenants fit
     attn_shape: tuple  # (B, S, H, Hkv, hd) of the kernels leg
     matmul_shape: tuple  # (M, K, N)

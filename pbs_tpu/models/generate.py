@@ -14,7 +14,7 @@ TPU-first choices:
 - **``lax.scan`` everywhere**: over stacked layer params + cache slabs
   inside one forward (compile time O(1) in depth), and over decode
   steps inside :func:`make_generate` (one dispatch per generation, not
-  per token — the same reason bench.py scans its train loop).
+  per token).
 - **GQA cache**: cached K/V at ``n_kv_heads`` (memory ∝ kv heads, not
   query heads); queries group over them at attention time.
 - **bfloat16 cache** (compute dtype): HBM-resident cache is the serving

@@ -43,6 +43,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
@@ -55,6 +56,7 @@ from pbs_tpu.models.transformer import (
     rms_norm,
     rope_tables,
 )
+from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
 
 
 # Ring stamps and span durations are host wall time whatever clock the
@@ -399,9 +401,21 @@ class _ScanProgram:
     def init_cache(self, n_slots: int, max_len: int) -> dict:
         return init_slot_cache(self.cfg, n_slots, max_len)
 
-    def place(self, params: dict, cache: dict, mesh):
-        return (_shard_serving_params(self.cfg, params, mesh),
-                _shard_slot_cache(cache, mesh))
+    def place_cache(self, cache: dict, mesh) -> dict:
+        """KV slabs cut over the kv heads on the mesh's tensor axis,
+        cursors replicated."""
+        kv = slot_cache_kv_sharding(mesh)
+        heads, ways = cache["k"].shape[-2], mesh.shape[kv.spec[-2]]
+        if heads % ways:
+            raise ValueError(
+                f"n_kv_heads={heads} not divisible by the {ways} devices "
+                f"of the mesh's tensor axis")
+        return {
+            "k": jax.device_put(cache["k"], kv),
+            "v": jax.device_put(cache["v"], kv),
+            "pos": jax.device_put(
+                cache["pos"], NamedSharding(mesh, PartitionSpec(None))),
+        }
 
     def decode(self, params, cache, last_tok, active):
         logits, new, extra = _slot_forward(
@@ -430,21 +444,16 @@ class _PlannedProgram:
     def init_cache(self, n_slots: int, max_len: int) -> dict:
         return init_plan_cache(self.cfg, n_slots, max_len)
 
-    def place(self, params: dict, cache: dict, mesh):
-        """The caller placed the parameters (the serve rule table names
-        every leaf of a planned tree); the cache goes beside them. One
-        device: how a ring and a share of experts divide over a tensor
-        axis is not written."""
+    def place_cache(self, cache: dict, mesh) -> dict:
+        """One device: how a ring and a share of experts divide over a
+        tensor axis is not written."""
         if mesh.devices.size != 1:
             raise NotImplementedError(
                 f"a planned layer stack serves on one device, not on a "
                 f"mesh of {dict(mesh.shape)}: neither the window ring's "
                 f"nor the held experts' division over a tensor axis is "
                 f"written (ROADMAP R4)")
-        import jax.sharding as jsh
-
-        return params, jax.device_put(
-            cache, jsh.NamedSharding(mesh, jsh.PartitionSpec()))
+        return jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
 
     def decode(self, params, cache, last_tok, active):
         logits, ks, vs, route = _plan_forward(
@@ -466,7 +475,9 @@ def slot_program(cfg: TransformerConfig, mlp_fn=None):
     """What a configuration's layer stack gives the engine and the
     serve backend, and the one place that chooses between the two
     forms: its parameter tree (``init_params``), its cache
-    (``init_cache``), one decode position for every slot (``decode``)
+    (``init_cache``) and where that lies on a mesh (``place_cache``;
+    the weights are the caller's to place, ``serve.partition.place``),
+    one decode position for every slot (``decode``)
     and the ingestion of one prompt (``ingest``), both returning
     ``(logits, cache, mlp extra, route)``, and whether its caches take
     windows of positions (``windows``). A configuration whose layers
@@ -478,49 +489,6 @@ def slot_program(cfg: TransformerConfig, mlp_fn=None):
         raise ValueError("a planned layer stack names its own MLP kinds; "
                          "mlp_fn swaps the FFN of a uniform stack only")
     return _PlannedProgram(cfg)
-
-
-def _shard_serving_params(cfg, params: dict, mesh) -> dict:
-    """Place a serving param tree on a tp mesh. One quant-aware
-    sharding walk covers all four weight forms (r5 — the former MoE
-    and int8 mesh rejections are lifted): dense fp, dense int8, MoE
-    fp, MoE int8. MoE trees take the Megatron-attention +
-    expert-d_ff serving table; {"q","s"} leaves shard q like the fp
-    weight and s with its size-1 reduced axis unsharded."""
-    from pbs_tpu.parallel.sharding import (
-        param_specs,
-        quant_aware_shardings,
-    )
-
-    if cfg.n_kv_heads % mesh.shape["tp"]:
-        raise ValueError(
-            f"n_kv_heads={cfg.n_kv_heads} not divisible by "
-            f"tp={mesh.shape['tp']}")
-    if isinstance(params.get("layers"), dict) and \
-            "router" in params["layers"]:
-        from pbs_tpu.parallel.expert import moe_serving_param_specs
-
-        specs = moe_serving_param_specs(cfg)
-    else:
-        specs = param_specs(cfg)
-    return jax.tree.map(
-        jax.device_put, params,
-        quant_aware_shardings(specs, params, mesh))
-
-
-def _shard_slot_cache(cache: dict, mesh) -> dict:
-    """KV slabs sharded over the kv heads on tp; cursors replicated."""
-    import jax.sharding as jsh
-
-    from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
-
-    kv = slot_cache_kv_sharding(mesh)
-    rep = jsh.NamedSharding(mesh, jsh.PartitionSpec(None))
-    return {
-        "k": jax.device_put(cache["k"], kv),
-        "v": jax.device_put(cache["v"], kv),
-        "pos": jax.device_put(cache["pos"], rep),
-    }
 
 
 def prefill_rungs(bucket: int) -> tuple[int, ...]:
@@ -614,14 +582,12 @@ class ContinuousBatcher:
         cache = self.program.init_cache(n_slots, self.max_len)
         if mesh is not None:
             # Tensor-parallel serving by PLACEMENT (the GSPMD recipe):
-            # shard params Megatron-style and the KV slabs over the kv
-            # heads; the two jitted programs below are unchanged — XLA
-            # propagates the shardings and inserts the collectives.
-            if "tp" not in mesh.axis_names:
-                raise ValueError(
-                    f"serving mesh needs a 'tp' axis; got "
-                    f"{mesh.axis_names}")
-            params, cache = self.program.place(params, cache, mesh)
+            # the caller handed ``params`` already laid out on ``mesh``
+            # (serve.partition.place) and the engine lays the KV slabs
+            # over the kv heads; the two jitted programs below are
+            # unchanged — XLA propagates the shardings and inserts the
+            # collectives.
+            cache = self.program.place_cache(cache, mesh)
         self.params = params
         self.cache = cache
         self._key = jax.random.PRNGKey(seed)
@@ -704,12 +670,8 @@ class ContinuousBatcher:
                 first = jnp.concatenate([first[None], route])
             return first, last_logits, cache, extra
 
-        if mesh is not None:
-            from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
-
-            _kv_sharding = slot_cache_kv_sharding(mesh)
-        else:
-            _kv_sharding = None
+        _kv_sharding = slot_cache_kv_sharding(mesh) \
+            if mesh is not None else None
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _install(cache, slot, kwin, vwin, plen):
@@ -1125,14 +1087,13 @@ class SpeculativeBatcher(ContinuousBatcher):
                                       self.max_len)
         if self.mesh is not None:
             # r5: speculative serving composes with the tp mesh — the
-            # parent sharded the target; the draft tree and its slot
-            # cache take the same placement. (The prefix cache also
-            # composes: a hit installs the TARGET window, and the
-            # _admitted hook below draft-prefills hits and misses
-            # alike, preserving the pos invariant.)
-            self.draft_params = _shard_serving_params(
-                draft_cfg, self.draft_params, self.mesh)
-            self.dcache = _shard_slot_cache(self.dcache, self.mesh)
+            # caller placed both trees; the draft's slot cache lies
+            # like the target's. (The prefix cache also composes: a hit
+            # installs the TARGET window, and the _admitted hook below
+            # draft-prefills hits and misses alike, preserving the pos
+            # invariant.)
+            self.dcache = slot_program(draft_cfg).place_cache(
+                self.dcache, self.mesh)
         self.spec_proposed = 0
         self.spec_accepted = 0
         # Draft-side FFN telemetry (a starved MoE draft collapses

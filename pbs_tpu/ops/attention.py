@@ -41,9 +41,9 @@ from jax.experimental import pallas as pl
 from pbs_tpu.ops.interpret import resolve_interpret
 from pbs_tpu.utils.params import integer_param
 
-# Block-shape defaults, env-tunable so the on-chip sweep can explore
-# the VMEM/occupancy trade at long S without code edits (e.g.
-# PBST_FLASH_BLOCK_Q=256 PBST_FLASH_BLOCK_K=512 python bench_longctx.py).
+# Block-shape defaults, env-tunable so an on-chip run can explore the
+# VMEM/occupancy trade at long S without code edits
+# (PBST_FLASH_BLOCK_Q=256 PBST_FLASH_BLOCK_K=512).
 # Registered through the boot-param registry: a malformed value warns
 # and falls back instead of making the package unimportable.
 _block_q_param = integer_param("flash_block_q", 128)

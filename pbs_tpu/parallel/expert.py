@@ -40,33 +40,6 @@ def moe_param_specs(cfg: MoEConfig) -> dict:
     }
 
 
-def moe_serving_param_specs(cfg: MoEConfig) -> dict:
-    """MoE tree on a tp SERVING mesh: attention Megatron-sharded like
-    the dense serving path, expert FFNs column/row-sharded over the
-    SAME tp axis on their d_ff dimension (we1/we3 column, we2 row —
-    XLA inserts the psum at the we2 product), router + norms
-    replicated. Experts stay replicated over E here: a serving mesh
-    is one chip group and tp is its axis; ep-style expert placement
-    is the training layout (moe_param_specs)."""
-    return {
-        "embed": P("tp", None),
-        "layers": {
-            "attn_norm": P(None, None),
-            "wq": P(None, None, "tp"),
-            "wk": P(None, None, "tp"),
-            "wv": P(None, None, "tp"),
-            "wo": P(None, "tp", None),
-            "mlp_norm": P(None, None),
-            "router": P(None, None, None),
-            "we1": P(None, None, None, "tp"),
-            "we3": P(None, None, None, "tp"),
-            "we2": P(None, None, "tp", None),
-        },
-        "final_norm": P(None),
-        "head": P(None, "tp"),
-    }
-
-
 def _named(mesh: Mesh, tree):
     return jax.tree.map(
         lambda spec: NamedSharding(mesh, spec), tree,

@@ -115,6 +115,9 @@ def slot_cache_kv_sharding(mesh: Mesh) -> NamedSharding:
     serving twin of the Megatron attention layout above. The single
     home for this spec: mesh-axis names stay inside ``parallel/`` (the
     ``serve-raw-mesh-axis`` rule, docs/ANALYSIS.md)."""
+    if "tp" not in mesh.axis_names:
+        raise ValueError(
+            f"serving mesh needs a 'tp' axis; got {mesh.axis_names}")
     return NamedSharding(mesh, P(None, None, None, "tp", None))
 
 

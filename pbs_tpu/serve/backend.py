@@ -63,12 +63,13 @@ def synth_payload(req: Request, bucket: int, max_len: int,
 class ShardedServeBackend(BatcherBackend):
     """Rule-partitioned serving engine as a gateway backend.
 
-    Construction: partition ``params`` by the serve rule table onto a
-    ``(dp, tp)`` mesh (1x1 on this CPU box — the placement code path
-    is identical, the collectives are no-ops), then stand up the slot
-    engine over the sharded tree. The engine re-pins the canonical
-    layout itself (``mesh=``), so the rule table and the engine's
-    placement contract are held to each other on every boot.
+    Who places what: the weights go onto a ``(dp, tp)`` mesh by the
+    serve rule table, once, through ``serve.partition.place`` (a tree
+    its maker laid out under ``rule_shardings`` is already there, and
+    the call hands each leaf back as it came); the slot engine takes
+    them as handed and places its own cache on the same mesh
+    (``mesh=``). 1x1 on a one-chip box: the same code path, the
+    collectives no-ops.
     """
 
     def __init__(self, name: str, cfg, params=None, *, tp: int = 1,
@@ -80,7 +81,7 @@ class ShardedServeBackend(BatcherBackend):
 
         from pbs_tpu.models.serving import ContinuousBatcher
         from pbs_tpu.serve.partition import (
-            make_serve_mesh, make_shard_and_gather_fns, rule_shardings,
+            make_serve_mesh, make_shard_and_gather_fns,
         )
 
         if clock not in ("wall", "virtual"):
@@ -95,9 +96,7 @@ class ShardedServeBackend(BatcherBackend):
         # Rule-table placement first (hard error on an uncovered
         # leaf), THEN the engine: a tree the table cannot place never
         # reaches a compile.
-        self._shardings = rule_shardings(params, self.mesh)
-        shard_fn, self._gather_fn = make_shard_and_gather_fns(
-            params, self.mesh)
+        shard_fn, self._gather_fn = make_shard_and_gather_fns(self.mesh)
         params = shard_fn(params)
         self._virtual = clock == "virtual"
         self._now_ns = 0

@@ -59,16 +59,15 @@ class PrefillPool:
         import jax
         import jax.numpy as jnp
 
-        from pbs_tpu.models.serving import (
-            _shard_slot_cache, ingest_slot_prompt, init_slot_cache,
-        )
+        from pbs_tpu.models.serving import ingest_slot_prompt, slot_program
 
         self.cfg = cfg
         self.n_lanes = int(n_lanes)
         self.bucket = int(bucket)
-        self.cache = init_slot_cache(cfg, self.n_lanes, int(max_len))
+        program = slot_program(cfg, mlp_fn)
+        self.cache = program.init_cache(self.n_lanes, int(max_len))
         if mesh is not None:
-            self.cache = _shard_slot_cache(self.cache, mesh)
+            self.cache = program.place_cache(self.cache, mesh)
         self._next_lane = 0
         self.prompts_ingested = 0
         self.tokens_ingested = 0
@@ -120,9 +119,7 @@ class DisaggServeBackend(Backend):
         import jax
 
         from pbs_tpu.models.serving import ContinuousBatcher
-        from pbs_tpu.serve.partition import (
-            make_serve_mesh, make_shard_and_gather_fns,
-        )
+        from pbs_tpu.serve.partition import make_serve_mesh, place
 
         if clock not in ("wall", "virtual"):
             raise ValueError(f"clock must be 'wall' or 'virtual', "
@@ -134,9 +131,7 @@ class DisaggServeBackend(Backend):
         self.name = name
         self.cfg = cfg
         self.mesh = make_serve_mesh(tp=tp, dp=dp)
-        shard_fn, self._gather_fn = make_shard_and_gather_fns(
-            params, self.mesh)
-        params = shard_fn(params)
+        params = place(params, self.mesh)
         self._virtual = clock == "virtual"
         self._now_ns = 0
 

@@ -20,10 +20,16 @@ rule of ``pbst check`` (docs/ANALYSIS.md) holds every other module to
 that. Resolution against a concrete mesh reuses
 ``parallel/sharding.quant_aware_shardings``, so int8 ``{"q","s"}``
 checkpoint leaves place exactly like their fp twins.
+
+This table is the one place that says where a serving weight lives,
+and :func:`place` the one call that puts it there: the engine
+(``models/serving.py``) takes its parameters as handed and places
+only its own cache.
 """
 
 from __future__ import annotations
 
+import functools
 import re
 from typing import Any, Callable, Iterable
 
@@ -38,12 +44,13 @@ from pbs_tpu.parallel.sharding import quant_aware_shardings
 #: ``int`` mesh-axis index, or a tuple of indices (multi-axis dim).
 SpecEntry = Any
 
-#: The flagship transformer's rule table. Paths are "/"-joined from
-#: the ``init_params`` tree; order matters (first match wins). The
-#: layout is the Megatron one ``parallel/sharding.param_specs``
-#: derives — vocab-sharded embed/head, column-parallel wq/wk/wv/w1/w3,
-#: row-parallel wo/w2, replicated norms — restated positionally:
-#: ``-1`` = the innermost (tensor) mesh axis.
+#: The rule table of every tree the serving path takes. Paths are
+#: "/"-joined from the ``init_params`` tree; order matters (first match
+#: wins). The layout is the Megatron one ``parallel/sharding.param_specs``
+#: derives for training (tests/test_serve.py holds the two to each
+#: other leaf by leaf) — vocab-sharded embed/head, column-parallel
+#: wq/wk/wv/w1/w3, row-parallel wo/w2, replicated norms — restated
+#: positionally: ``-1`` = the innermost (tensor) mesh axis.
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
     (r"(^|/)(attn_norm|mlp_norm|final_norm)$", ()),
@@ -52,6 +59,13 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"/w[13]$", (None, None, -1)),
     (r"/w2$", (None, -1, None)),
     (r"^head$", (None, -1)),
+    # The stacked MoE tree (models/moe.init_moe_params): experts
+    # ``(layer, expert, d, F)`` cut on their d_ff over the same tensor
+    # axis, we1/we3 by column and we2 by row (XLA puts the psum at the
+    # we2 product); every expert on every chip. Ahead of the planned
+    # tree's ``we`` rule below, which is written for ``(expert, d, F)``.
+    (r"^layers/we[13]$", (None, None, None, -1)),
+    (r"^layers/we2$", (None, None, -1, None)),
     # A planned stack (models/plan.py) holds a layer at a time, under
     # ``blocks/<NN>/attn/`` and ``blocks/<NN>/mlp/``: there a spec
     # above, written for a stack of layers, loses its leading (layer)
@@ -66,12 +80,13 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"/ws2$", (-1, None)),
 )
 
-#: The canonical param paths the table must cover (the flagship's
-#: ``layers/...`` tree and a planned stack's per-kind one) — the
-#: static ``serve-unmatched-rule`` check audits PARTITION_RULES
-#: against this literal (dead/shadowed/uncovered detection without
-#: importing jax), and tests/test_serve.py pins it against the real
-#: ``init_params`` tree so it cannot drift from the model.
+#: The canonical param paths the table must cover (the dense
+#: ``layers/...`` tree, the stacked MoE one and a planned stack's
+#: per-kind one) — the static ``serve-unmatched-rule`` check audits
+#: PARTITION_RULES against this literal (dead/shadowed/uncovered
+#: detection without importing jax), and tests/test_serve.py pins it
+#: against the real ``init_params`` trees so it cannot drift from the
+#: models.
 TEMPLATE_PATHS: tuple[str, ...] = (
     "embed",
     "layers/attn_norm",
@@ -85,6 +100,11 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "layers/w2",
     "final_norm",
     "head",
+    # the stacked MoE tree's own leaves (models/moe.init_moe_params)
+    "layers/router",
+    "layers/we1",
+    "layers/we3",
+    "layers/we2",
     # one block of a planned stack's tree (models/plan.plan_shapes);
     # N stands for the layer's number
     "blocks/N/attn/attn_norm",
@@ -138,8 +158,10 @@ def match_partition_rules(rules: Iterable[tuple[str, tuple]],
     the "/"-joined path wins, an unmatched non-scalar leaf raises. A
     planned tree keeps one layer a leaf under ``blocks/``: there a
     spec one entry longer than the leaf was written for a stack of
-    layers, and its leading entry falls away. Any other mismatch of
-    rank stands, and fails where the spec is resolved."""
+    layers, and its leading entry falls away. Any other spec that
+    names dimensions names as many as its leaf has, or raises here
+    with the path: a rule written for a tree of another rank would
+    cut the wrong axis. ``()`` is replicated at any rank."""
     rules = tuple(rules)
 
     def walk(tree: dict, prefix: str) -> dict:
@@ -160,6 +182,12 @@ def match_partition_rules(rules: Iterable[tuple[str, tuple]],
                     if path.startswith("blocks/") \
                             and len(spec) == len(shape) + 1:
                         spec = spec[1:]
+                    if spec and len(spec) != len(shape):
+                        raise ValueError(
+                            f"partition rule {pattern!r} gives param "
+                            f"{path!r} (shape {shape}) the "
+                            f"{len(spec)}-entry spec {spec}: written "
+                            f"for a leaf of another rank")
                     out[key] = spec
                     break
             else:
@@ -220,13 +248,11 @@ def resolve_spec(mesh: Mesh, raw: tuple) -> P:
     return P(*(one(e) for e in raw))
 
 
-def rule_shardings(params: dict, mesh: Mesh,
-                   rules: Iterable[tuple[str, tuple]] = PARTITION_RULES
-                   ) -> dict:
+def rule_shardings(params: dict, mesh: Mesh) -> dict:
     """NamedSharding tree for ``params``: match rules, resolve the
     positional specs against ``mesh``, and hand placement to the
     quant-aware walk ``parallel/sharding`` already owns."""
-    raw = match_partition_rules(rules, params)
+    raw = match_partition_rules(PARTITION_RULES, params)
 
     def named(tree):
         if isinstance(tree, dict):
@@ -236,29 +262,23 @@ def rule_shardings(params: dict, mesh: Mesh,
     return quant_aware_shardings(named(raw), params, mesh)
 
 
-def make_shard_and_gather_fns(params: dict, mesh: Mesh,
-                              rules: Iterable[tuple[str, tuple]]
-                              = PARTITION_RULES
-                              ) -> tuple[Callable, Callable]:
-    """(shard, gather) tree functions for trees shaped like ``params``.
-    ``shard`` places leaves by the rule table; ``gather`` jit-reshards
-    everything to fully-replicated (host-readable) form — the
-    checkpoint save path, and the roundtrip the byte-identity test
-    pins."""
-    shardings = rule_shardings(params, mesh)
-    replicated = jax.tree.map(
-        lambda _: NamedSharding(mesh, P()), shardings,
-        is_leaf=lambda x: isinstance(x, NamedSharding))
+def place(params: dict, mesh: Mesh) -> dict:
+    """Serving weights on ``mesh``, each leaf where the rule table
+    says: the only code that puts a serving weight on a mesh. A leaf
+    that already lies there (a tree made under :func:`rule_shardings`)
+    comes back as the array it was."""
+    return jax.tree.map(jax.device_put, params,
+                        rule_shardings(params, mesh))
 
-    def shard(tree: dict) -> dict:
-        return jax.tree.map(jax.device_put, tree, shardings)
 
-    gather_jit = jax.jit(lambda tree: tree, out_shardings=replicated)
-
-    def gather(tree: dict) -> dict:
-        return gather_jit(tree)
-
-    return shard, gather
+def make_shard_and_gather_fns(mesh: Mesh) -> tuple[Callable, Callable]:
+    """(shard, gather) tree functions on ``mesh``. ``shard`` is
+    :func:`place`; ``gather`` jit-reshards everything to
+    fully-replicated (host-readable) form — the checkpoint save path,
+    and the roundtrip the byte-identity test pins."""
+    gather = jax.jit(lambda tree: tree,
+                     out_shardings=NamedSharding(mesh, P()))
+    return functools.partial(place, mesh=mesh), gather
 
 
 def make_serve_mesh(tp: int = 1, dp: int = 1,

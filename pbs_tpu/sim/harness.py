@@ -4,8 +4,8 @@ The offline regression gate for scheduling PRs: run the identical
 (workload, seed) through each policy and put the numbers that matter
 side by side — Jain fairness over per-tenant device time, p50/p99
 runqueue wait, context switches, adapted-quantum range, and the trace
-digest (the determinism witness). ``bench_sim.py`` and ``pbst sim
---policy all`` are thin wrappers over :func:`compare`.
+digest (the determinism witness). ``pbst sim --policy all`` is a thin
+wrapper over :func:`compare`.
 """
 
 from __future__ import annotations

@@ -6,21 +6,26 @@ their own kernels, but every distinct program a tenant brings costs
 seconds of XLA compile time and a compile-cache slot, and a partition
 multiplexing many tenants can spend more time compiling than running.
 
-This module taps JAX's public monitoring stream
-(``jax.monitoring.register_event_duration_secs_listener``; the
-``/jax/core/compile/backend_compile_duration`` event fires once per
+This module taps JAX's public monitoring stream (``jax.monitoring``:
+the ``/jax/core/compile/backend_compile_duration`` event fires once per
 actual XLA compilation) and attributes each event to the job whose
 dispatch triggered it — the scope is set by ``TpuBackend`` around every
-host-callable invocation. The drained per-job sums land in the
-``COMPILES`` / ``COMPILE_TIME_NS`` ledger slots, making compilation a
-first-class scheduled-resource like device time, and feed the
-admission gate in ``pbs_tpu.runtime.compile_gate``.
+host-callable invocation. The time it reports is a wall time: JAX's
+events nest (a jit traced inside a jit reports its trace under its own
+event and again inside the outer one; an eager op inside a trace
+compiles inside it), so their durations summed can pass the wall of the
+call they happened in. The meter stamps the outermost event's start and
+end on the thread itself and counts that span once. The drained
+per-job sums land in the ``COMPILES`` / ``COMPILE_TIME_NS`` ledger
+slots, making compilation a first-class scheduled-resource like device
+time, and feed the admission gate in ``pbs_tpu.runtime.compile_gate``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import threading
+import time
 from typing import Iterator
 
 #: The monitoring event that corresponds to one real XLA compilation.
@@ -31,6 +36,7 @@ FRONTEND_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
 )
+_EVENTS = frozenset((BACKEND_COMPILE_EVENT,) + FRONTEND_EVENTS)
 
 
 class CompileMeter:
@@ -47,7 +53,7 @@ class CompileMeter:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._tls = threading.local()
-        # name -> [compiles, compile_ns, frontend_ns] (pending drain)
+        # name -> [compiles, wall ns of compiling] (pending drain)
         self._pending: dict[str, list[int]] = {}
         # lifetime totals (admission projections read these)
         self.total_compiles = 0
@@ -69,27 +75,45 @@ class CompileMeter:
             return
         import jax
 
+        # JAX reports an event's start as a scalar and its end as a
+        # duration (dispatch.log_elapsed_time), both on the thread that
+        # does the work.
+        jax.monitoring.register_scalar_listener(self._on_start)
         jax.monitoring.register_event_duration_secs_listener(
             self._on_event)
         self._installed = True
 
-    # -- listener ---------------------------------------------------------
+    # -- listeners --------------------------------------------------------
+
+    def _on_start(self, event: str, _value: float, **kw) -> None:
+        if event not in _EVENTS:
+            return
+        depth = getattr(self._tls, "depth", 0)
+        if depth == 0:
+            self._tls.t_open = time.monotonic_ns()
+        self._tls.depth = depth + 1
 
     def _on_event(self, event: str, duration_s: float, **kw) -> None:
+        if event not in _EVENTS:
+            return
+        # The outermost event's span on this thread's own clock, once;
+        # an event nested in it is inside that span already.
+        depth = getattr(self._tls, "depth", 0) - 1
+        if depth < 0:  # began before the meter was installed
+            return
+        self._tls.depth = depth
+        wall = time.monotonic_ns() - self._tls.t_open if depth == 0 else 0
         is_backend = event == BACKEND_COMPILE_EVENT
-        if not is_backend and event not in FRONTEND_EVENTS:
+        if not (wall or is_backend):
             return
         scope = getattr(self._tls, "scope", None) or "<ambient>"
-        ns = int(duration_s * 1e9)
         with self._lock:
-            ent = self._pending.setdefault(scope, [0, 0, 0])
+            ent = self._pending.setdefault(scope, [0, 0])
+            ent[1] += wall
             if is_backend:
                 ent[0] += 1
-                ent[1] += ns
                 self.total_compiles += 1
-                self.total_compile_ns += ns
-            else:
-                ent[2] += ns
+                self.total_compile_ns += int(duration_s * 1e9)
 
     # -- attribution scope ------------------------------------------------
 
@@ -104,18 +128,17 @@ class CompileMeter:
 
     def take(self, name: str) -> tuple[int, int]:
         """Drain (compiles, compile_ns) attributed to ``name`` since the
-        last take. Frontend time is folded into compile_ns — from the
-        tenant's perspective it is all time-to-first-step."""
+        last take. Frontend time is part of compile_ns — from the
+        tenant's perspective it is all time-to-first-step — and
+        compile_ns is wall time: never more than the wall of the scopes
+        it was attributed in."""
         with self._lock:
             ent = self._pending.pop(name, None)
-        if ent is None:
-            return 0, 0
-        return ent[0], ent[1] + ent[2]
+        return tuple(ent) if ent else (0, 0)
 
     def peek_all(self) -> dict[str, tuple[int, int]]:
         with self._lock:
-            return {k: (v[0], v[1] + v[2])
-                    for k, v in self._pending.items()}
+            return {k: tuple(v) for k, v in self._pending.items()}
 
     @property
     def mean_compile_ns(self) -> int:

@@ -513,7 +513,10 @@ class TpuBackend:
         Leaves one ``EXEC_STEP`` record: how long ``fn`` took to return
         (the dispatch: for a jitted step, until the program is
         enqueued; compile time taken out, as in the charge) and how
-        long ``block_until_ready`` then waited."""
+        long ``block_until_ready`` then waited. The compile time of the
+        record and of the charge is what the meter saw inside this call:
+        a wall time of work done before ``fn`` returned, so neither
+        difference below can go negative."""
         import jax
 
         t_returned = 0
@@ -534,6 +537,10 @@ class TpuBackend:
             jax.block_until_ready(st)
             return st, metrics
 
+        # Compiled for this job outside a step (a foreign tenant's
+        # executable, harvested by _job_cost): the job's to pay for,
+        # not part of this call's wall.
+        n_before, ns_before = self.compile_meter.take(job.name)
         t0 = time.monotonic_ns()
         with self.compile_meter.attribute(job.name), \
                 jax.profiler.TraceAnnotation("pbst.exec.step"):
@@ -548,9 +555,10 @@ class TpuBackend:
         (self._emit_step or self._own_ring())(
             ctx, t0, self._obs.Ev.EXEC_STEP,
             ctx.ledger_slot if ctx is not None else -1,
-            max(0, t_returned - t0 - c_ns), t1 - t_returned, c_ns,
+            t_returned - t0 - c_ns, t1 - t_returned, c_ns,
             self._obs.job_tag(job.name))
-        return max(0, t1 - t0 - c_ns), metrics, n_c, c_ns
+        return (t1 - t0 - c_ns, metrics, n_c + n_before,
+                c_ns + ns_before)
 
     def _charge(self, deltas: np.ndarray, dt: int, flops: int,
                 nbytes: int, metrics: dict, measured=None) -> None:

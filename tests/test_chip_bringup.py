@@ -28,9 +28,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: Root scripts that need the chip: each is its only client, so none
 #: may start a child.
-CHIP_SCRIPTS = ("chip_smoke.py", "bench.py", "bench_sweep.py",
-                "bench_serving.py", "bench_longctx.py",
-                "bench_decompose.py")
+CHIP_SCRIPTS = ("chip_smoke.py",)
 
 
 def test_chip_smoke_refuses_the_cpu():

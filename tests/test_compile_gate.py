@@ -7,6 +7,8 @@ projected compile-cache pressure. The scarce resource is TPU-new
 copies the reference's fail-fast memory claims (XENMEM_claim_pages).
 """
 
+import time
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -51,6 +53,44 @@ def test_compile_attribution_per_job():
     # attribution is per-job, not pooled on the first job.
     total = sum(int(j.contexts[0].counters[Counter.COMPILES]) for j in jobs)
     assert total >= 3
+
+
+def test_compile_ns_of_nested_jits_is_inside_its_call():
+    """``take()`` never exceeds the wall of the call it was attributed
+    in (PERF.md section 7 row 0d). JAX reports a jit traced inside a
+    jit under its own event and again inside the outer one: the
+    durations summed passed the call's wall, and
+    ``EXEC_STEP.dispatch_ns`` was clipped to 0."""
+    def body(x):
+        for _ in range(30):  # a new jit each: none finds a cached trace
+            x = jax.jit(lambda y: jnp.sin(y) * 1.0173)(x)
+        return x
+
+    meter = CompileMeter.install()
+    t0 = time.monotonic_ns()
+    with meter.attribute("meter-nested"):
+        jax.block_until_ready(jax.jit(body)(jnp.ones((16, 16))))
+    wall = time.monotonic_ns() - t0
+    n, ns = meter.take("meter-nested")
+    assert n >= 1 and 0 < ns <= wall, (n, ns, wall)
+
+
+def test_a_harvest_compile_is_not_in_the_first_steps_wall():
+    """A foreign tenant's executable is compiled by the backend before
+    its first step (``_job_cost``): the job pays for that compile, the
+    step's record and charge do not hold it."""
+    job = Job.foreign("meter-foreign", jax.jit(lambda x: jnp.tanh(x * 1.0391)),
+                      jnp.ones((32, 32)), max_steps=1)
+    be = TpuBackend(profile_every=0)
+    be._job_cost(job)
+    t0 = time.monotonic_ns()
+    dt, _metrics, n, ns = be._invoke(job, job.step_fn)
+    wall = time.monotonic_ns() - t0
+    _ts, _ev, _slot, dispatch, wait, compile_ns, _tag, _ = \
+        be.trace.peek().astype("int64").tolist()[-1]
+    assert n >= 1 and ns > compile_ns >= 0
+    assert min(dispatch, wait, dt) >= 0
+    assert dispatch + compile_ns + wait <= wall
 
 
 def test_compile_time_excluded_from_runtime_charge():

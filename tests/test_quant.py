@@ -116,14 +116,16 @@ def test_quant_tp_mesh_token_exact():
 
     from pbs_tpu.models.serving import ContinuousBatcher
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 devices")
     qp = quantize_weights(_params())
 
     def run(mesh):
-        eng = ContinuousBatcher(CFG, qp, n_slots=2, prompt_bucket=8,
-                                max_len=32, mesh=mesh)
+        eng = ContinuousBatcher(
+            CFG, qp if mesh is None else place(qp, mesh), n_slots=2,
+            prompt_bucket=8, max_len=32, mesh=mesh)
         rid = eng.submit([1, 2, 3], max_new_tokens=6)
         done = []
         for _ in range(30):
@@ -148,6 +150,7 @@ def test_quant_moe_tp_mesh_token_exact():
     from pbs_tpu.models.moe import moe_slot_mlp
     from pbs_tpu.models.serving import ContinuousBatcher
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 devices")
@@ -159,7 +162,8 @@ def test_quant_moe_tp_mesh_token_exact():
 
     def run(mesh):
         eng = ContinuousBatcher(
-            mcfg, qp, n_slots=2, prompt_bucket=8, max_len=32,
+            mcfg, qp if mesh is None else place(qp, mesh), n_slots=2,
+            prompt_bucket=8, max_len=32,
             mlp_fn=moe_slot_mlp(mcfg), mesh=mesh)
         rid = eng.submit([1, 2, 3], max_new_tokens=5)
         done = []

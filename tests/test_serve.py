@@ -25,7 +25,9 @@ from pbs_tpu.serve.partition import (
     make_serve_mesh,
     make_shard_and_gather_fns,
     match_partition_rules,
+    place,
     resolve_spec,
+    rule_shardings,
 )
 from pbs_tpu.utils.clock import MS, VirtualClock
 
@@ -60,6 +62,21 @@ def planned_params():
     cfg = TransformerConfig(**dict(TINY, n_layers=2, head_size=8,
                                    layer_plan=plan))
     return P.init_plan_params(cfg, jax.random.PRNGKey(0))
+
+
+MOE = dict(TINY, n_layers=2, n_kv_heads=2, n_experts=4, top_k=2,
+           dropless=True, router_group_size=8)
+
+
+@pytest.fixture(scope="module")
+def moe_model():
+    """The stacked MoE tree (models/moe.py) the engine serves through
+    ``mlp_fn=moe_slot_mlp(cfg)``."""
+    from pbs_tpu.models import MoEConfig
+    from pbs_tpu.models.moe import init_moe_params
+
+    mcfg = MoEConfig(**MOE)
+    return mcfg, init_moe_params(mcfg, jax.random.PRNGKey(0))
 
 
 def tree_paths(*trees):
@@ -100,12 +117,13 @@ def test_every_leaf_matches_exactly_one_rule(params):
         assert len(hits) == 1, f"{path}: matched {hits}"
 
 
-def test_template_paths_pin_the_param_tree(params, planned_params):
+def test_template_paths_pin_the_param_tree(params, planned_params,
+                                           moe_model):
     """TEMPLATE_PATHS is the audit's coverage universe; it must BE the
-    leaf set of the two trees the engine serves (init_params' stacked
-    one, a planned stack's per-layer one) or the audit goes blind to
-    drift."""
-    actual = set(tree_paths(params, planned_params))
+    leaf set of the trees the engine serves (init_params' stacked one,
+    the stacked MoE one, a planned stack's per-layer one) or the audit
+    goes blind to drift."""
+    actual = set(tree_paths(params, moe_model[1], planned_params))
     assert sorted(actual) == sorted(TEMPLATE_PATHS)
 
 
@@ -124,11 +142,27 @@ def test_a_stacked_spec_places_one_layer_of_it(planned_params):
     assert block["mlp"]["we1"] == (-1, None, None)
     assert block["mlp"]["ws2"] == (-1, None)
     assert block["mlp"]["router"] == ()
-    # Outside blocks/ a spec stands as written: a leaf of the wrong
-    # rank keeps its three entries and fails where they are resolved.
-    stray = match_partition_rules(
-        PARTITION_RULES, {"layers": {"wq": jnp.ones((8, 4))}})
-    assert stray["layers"]["wq"] == (None, None, -1)
+
+
+@pytest.mark.parametrize("tree, path", [
+    ({"layers": {"wq": jnp.ones((8, 4))}}, "layers/wq"),
+    # one entry too many lifts nothing outside blocks/
+    ({"stack": {"w2": jnp.ones((8, 4))}}, "stack/w2"),
+    # under blocks/ only the layer entry falls away
+    ({"blocks": {"00": {"mlp": {"we1": jnp.ones((2, 2, 8, 4))}}}},
+     "blocks/00/mlp/we1"),
+])
+def test_a_spec_of_another_rank_raises_with_the_path(tree, path):
+    """A rule written for a tree of another rank would cut the wrong
+    axis (the planned tree's ``we`` rule on the stacked MoE tree cut
+    its layers over tp): it raises where it is matched, not where the
+    spec is resolved, and ``()`` stays replicated at any rank."""
+    with pytest.raises(ValueError, match=re.escape(repr(path))):
+        match_partition_rules(PARTITION_RULES, tree)
+    assert match_partition_rules(
+        PARTITION_RULES, {"layers": {"router": jnp.ones((2, 8, 4))},
+                          "x": {"wg": jnp.ones((8, 4))}}) == {
+        "layers": {"router": ()}, "x": {"wg": ()}}
 
 
 def test_audit_is_clean():
@@ -136,8 +170,8 @@ def test_audit_is_clean():
     assert audit == {"dead": [], "shadowed": [], "uncovered": []}
 
 
-def test_every_rule_claims_a_leaf(params, planned_params):
-    paths = tree_paths(params, planned_params)
+def test_every_rule_claims_a_leaf(params, planned_params, moe_model):
+    paths = tree_paths(params, moe_model[1], planned_params)
     for pat, _spec in PARTITION_RULES:
         assert any(re.search(pat, p) for p in paths), \
             f"rule {pat!r} claims no leaf of either served tree"
@@ -168,12 +202,159 @@ def test_resolve_spec_positional_semantics():
         resolve_spec(mesh, (7,))
 
 
+# -- one table: the training specs and the retired MoE serving specs --------
+
+DENSE_LEAVES = ("embed", "final_norm", "head") + tuple(
+    f"layers/{k}" for k in ("attn_norm", "wq", "wk", "wv", "wo",
+                            "mlp_norm", "w1", "w3", "w2"))
+
+#: The stacked MoE tree on a serving mesh, the layout
+#: tests/test_serving.py's tp test proves token-exact (and a dict table
+#: of ``parallel/expert.py`` held until the rule table took the tree
+#: over): per leaf, the dimension cut over the tensor axis, None =
+#: replicated.
+MOE_TP_DIM = {
+    "embed": 0, "final_norm": None, "head": 1,
+    "layers/attn_norm": None, "layers/mlp_norm": None,
+    "layers/wq": 2, "layers/wk": 2, "layers/wv": 2, "layers/wo": 1,
+    "layers/router": None,
+    "layers/we1": 3, "layers/we3": 3, "layers/we2": 2,
+}
+
+
+def _leaf(tree, path):
+    for key in path.split("/"):
+        tree = tree[key]
+    return tree
+
+
+@pytest.mark.parametrize("path", DENSE_LEAVES)
+def test_training_specs_agree_with_the_rule_table(params, cfg, path):
+    """``parallel/sharding.param_specs`` is training's table (meshes
+    with sp/pp/ep axes a positional table does not describe); on a
+    serving mesh it must say what the rule table says, leaf by leaf."""
+    from jax.sharding import NamedSharding
+
+    from pbs_tpu.parallel.sharding import param_specs
+
+    mesh = make_serve_mesh(tp=2, dp=2)
+    ruled = _leaf(rule_shardings(params, mesh), path)
+    trained = NamedSharding(mesh, _leaf(param_specs(cfg), path))
+    assert ruled.is_equivalent_to(trained, _leaf(params, path).ndim), \
+        (path, ruled.spec, trained.spec)
+
+
+@pytest.mark.parametrize("path", sorted(MOE_TP_DIM))
+def test_rule_table_places_the_stacked_moe_tree(moe_model, path):
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    _mcfg, mparams = moe_model
+    assert sorted(MOE_TP_DIM) == sorted(
+        p for p, _ in iter_leaf_paths(mparams))
+    mesh = make_serve_mesh(tp=2, dp=2)
+    ndim = _leaf(mparams, path).ndim
+    want = [None] * ndim
+    if MOE_TP_DIM[path] is not None:
+        want[MOE_TP_DIM[path]] = mesh.axis_names[-1]
+    ruled = _leaf(rule_shardings(mparams, mesh), path)
+    assert ruled.is_equivalent_to(
+        NamedSharding(mesh, PartitionSpec(*want)), ndim), (path, ruled.spec)
+
+
+# -- placed once -------------------------------------------------------------
+
+
+def _engine_of(kind, form, through):
+    """(the tree as placed, the engine it was handed to). A tp=2 mesh
+    for the stacked trees; a planned stack serves on one device."""
+    from pbs_tpu.models import MoEConfig
+    from pbs_tpu.models.moe import init_moe_params, moe_slot_mlp
+    from pbs_tpu.models.serving import ContinuousBatcher, slot_program
+
+    kw = dict(n_slots=2, prompt_bucket=8, max_len=32)
+    if kind == "planned":
+        from pbs_tpu.models import plan as P
+
+        plan = P.LayerPlan(
+            attn=(P.AttnKind("full", 2, None, P.Rope(), True),),
+            mlp=(P.MlpKind("experts", 8, n_experts=4, top_k=2,
+                           held=(0, 2), shared_d_ff=8),),
+            layers=((0, 0),))
+        cfg, tp, extra = TransformerConfig(**dict(
+            TINY, head_size=8, layer_plan=plan)), 1, {}
+        tree = slot_program(cfg).init_params(jax.random.PRNGKey(0))
+    elif kind == "moe":
+        cfg, tp = MoEConfig(**MOE), 2
+        tree = init_moe_params(cfg, jax.random.PRNGKey(0))
+        extra = {"mlp_fn": moe_slot_mlp(cfg)}
+    else:
+        cfg, tp, extra = TransformerConfig(**dict(TINY, n_kv_heads=2)), 2, {}
+        tree = init_params(cfg, jax.random.PRNGKey(0))
+    if form == "int8":
+        from pbs_tpu.models.quant import quantize_weights
+
+        tree = quantize_weights(tree)
+    mesh = make_serve_mesh(tp=tp)
+    placed = place(tree, mesh)
+    if through == "backend":
+        return placed, ShardedServeBackend(
+            "b", cfg, placed, tp=tp, **kw).engine
+    return placed, ContinuousBatcher(cfg, placed, mesh=mesh, **kw, **extra)
+
+
+@pytest.mark.parametrize("kind, form, through", [
+    ("dense", "fp", "backend"), ("dense", "fp", "engine"),
+    ("dense", "int8", "backend"), ("dense", "int8", "engine"),
+    ("moe", "fp", "engine"),  # ShardedServeBackend takes no mlp_fn
+    ("planned", "fp", "backend"), ("planned", "fp", "engine"),
+])
+def test_a_placed_tree_reaches_the_engine_untouched(kind, form, through):
+    """A serving weight is placed once, by the rule table: a tree laid
+    out by ``place`` and handed to the backend or to the engine comes
+    out as ``engine.params`` with every leaf the array that went in
+    (``jax.device_put`` onto the sharding an array already has returns
+    that array; a second table, or a second opinion, would not)."""
+    placed, engine = _engine_of(kind, form, through)
+    went_in = jax.tree_util.tree_leaves_with_path(placed)
+    came_out = jax.tree_util.tree_leaves(engine.params)
+    assert len(went_in) == len(came_out) > 0
+    for (path, a), b in zip(went_in, came_out):
+        assert a is b, jax.tree_util.keystr(path)
+    if kind != "planned":
+        assert any(not leaf.sharding.is_fully_replicated
+                   for _, leaf in went_in)
+
+
+def test_the_engine_names_no_parameter_table():
+    """models/serving.py places its cache and nothing else: it imports
+    nothing of ``pbs_tpu.serve`` and, of ``pbs_tpu.parallel``, only
+    the cache's own sharding."""
+    import ast
+
+    import pbs_tpu.models.serving as serving
+
+    with open(serving.__file__) as f:
+        tree = ast.parse(f.read())
+    seen = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            seen += [(a.name, None) for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            seen += [(node.module, a.name) for a in node.names]
+    layered = [(m, n) for m, n in seen
+               if m.startswith(("pbs_tpu.serve", "pbs_tpu.parallel"))]
+    assert layered == [("pbs_tpu.parallel.sharding",
+                        "slot_cache_kv_sharding")]
+    for form in (serving._ScanProgram, serving._PlannedProgram):
+        assert not hasattr(form, "place") and callable(form.place_cache)
+
+
 # -- shard / gather ----------------------------------------------------------
 
 
 def test_shard_gather_roundtrip_byte_identical(params):
     mesh = make_serve_mesh(tp=1, dp=1)
-    shard, gather = make_shard_and_gather_fns(params, mesh)
+    shard, gather = make_shard_and_gather_fns(mesh)
     back = gather(shard(params))
     flat_a = jax.tree_util.tree_leaves(params)
     flat_b = jax.tree_util.tree_leaves(back)

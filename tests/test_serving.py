@@ -143,13 +143,14 @@ def test_tensor_parallel_serving_token_parity(model):
     cache slabs on a tp mesh — outputs must be token-exact against the
     single-device engine."""
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     cfg, params = model
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
     prompts = {0: [3, 1, 4], 1: [15, 9, 2, 6]}
 
-    eng_tp = ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=16,
-                               mesh=mesh)
+    eng_tp = ContinuousBatcher(cfg, place(params, mesh), n_slots=2,
+                               prompt_bucket=16, mesh=mesh)
     rids = {i: eng_tp.submit(p, max_new_tokens=6)
             for i, p in prompts.items()}
     done = _drain(eng_tp)
@@ -246,12 +247,14 @@ def test_prefix_cache_token_exact_and_skips_prefill():
 
 def test_moe_serving_on_tp_mesh_token_exact():
     """r5: the mlp_fn x mesh rejection is lifted — an MoE engine on a
-    tp mesh (Megatron attention + expert d_ff column/row shards,
-    moe_serving_param_specs) must produce token-exact greedy output vs
-    the single-device MoE engine, with zero drops (dropless)."""
+    tp mesh (Megatron attention + expert d_ff column/row shards, the
+    serve rule table's ``layers/we*`` rules) must produce token-exact
+    greedy output vs the single-device MoE engine, with zero drops
+    (dropless)."""
     from pbs_tpu.models import MoEConfig
     from pbs_tpu.models.moe import init_moe_params, moe_slot_mlp
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     mcfg = MoEConfig(
         vocab=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2,
@@ -263,7 +266,8 @@ def test_moe_serving_on_tp_mesh_token_exact():
 
     def run(mesh):
         eng = ContinuousBatcher(
-            mcfg, params, n_slots=2, prompt_bucket=16,
+            mcfg, params if mesh is None else place(params, mesh),
+            n_slots=2, prompt_bucket=16,
             mlp_fn=moe_slot_mlp(mcfg), mesh=mesh)
         rid = eng.submit(prompt, max_new_tokens=8)
         done = _drain(eng)
@@ -280,11 +284,13 @@ def test_prefix_cache_on_tp_mesh_token_exact(model):
     installs with zero prefill dispatches and the greedy completion is
     token-exact against the single-device gold."""
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     cfg, params = model
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
-    eng = ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=16,
-                            mesh=mesh, prefix_cache_size=4)
+    eng = ContinuousBatcher(cfg, place(params, mesh), n_slots=2,
+                            prompt_bucket=16, mesh=mesh,
+                            prefix_cache_size=4)
     prompt = [3, 1, 4]
     gold = _gold(cfg, params, prompt, 6)
 

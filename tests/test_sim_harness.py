@@ -80,16 +80,6 @@ def test_cli_sim_compare_json(tmp_path, capsys):
         assert (tmp_path / f"cmp.{p}.jsonl").exists(), p
 
 
-def test_bench_sim_entry(capsys):
-    import bench_sim
-
-    assert bench_sim.main(["--seconds", "0.1", "--tenants", "3",
-                           "--workloads", "contended"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["headline"]["metric"] == "contended_p99_wait_us"
-    assert doc["workloads"]["contended"]["feedback"]["trace_digest"]
-
-
 @pytest.mark.slow
 def test_full_sweep_all_policies_all_workloads():
     """The long regression sweep: every policy × every workload at the

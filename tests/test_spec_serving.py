@@ -47,13 +47,15 @@ def test_spec_serving_on_tp_mesh_token_exact(models):
     draft trees Megatron-sharded, both slot caches kv-head-sharded.
     Outputs stay bit-identical to the single-device spec engine."""
     from pbs_tpu.parallel import make_mesh
+    from pbs_tpu.serve.partition import place
 
     params, dparams = models
     gold_eng = SpeculativeBatcher(CFG, params, CFG, dparams, k=3,
                                   n_slots=2, prompt_bucket=8,
                                   max_len=64)
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])
-    mesh_eng = SpeculativeBatcher(CFG, params, CFG, dparams, k=3,
+    mesh_eng = SpeculativeBatcher(CFG, place(params, mesh), CFG,
+                                  place(dparams, mesh), k=3,
                                   n_slots=2, prompt_bucket=8,
                                   max_len=64, mesh=mesh)
     for eng in (gold_eng, mesh_eng):

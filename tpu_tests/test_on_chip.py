@@ -127,8 +127,8 @@ def test_instrumented_matmul_compiled():
 
 
 def test_flash_long_context_numerics():
-    """Flash at S=2048 (towards the regime bench_longctx measures)
-    against the dense reference, on the chip — online-softmax
+    """Flash at S=2048 (towards long context) against the dense
+    reference, on the chip — online-softmax
     accumulation error must stay bounded as the number of folded
     k-blocks grows."""
     from pbs_tpu.ops.attention import flash_attention
