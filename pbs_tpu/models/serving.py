@@ -174,9 +174,21 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         x, extra, ks, vs = carry
         lp, i = layer
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ wload(lp["wq"], dt)).reshape(B, S, nh, hd)
-        k = (h @ wload(lp["wk"], dt)).reshape(B, S, nkv, hd)
-        v = (h @ wload(lp["wv"], dt)).reshape(B, S, nkv, hd)
+        # The three products come out flat, behind a barrier, and the
+        # split into heads reads that small result. Without the barrier
+        # XLA:TPU moves the reshape through the product onto the weight
+        # ((d, H * hd) -> (H, hd, d)), which no tiled layout of the
+        # stacked leaf gives without moving it: it then slices wq, wk
+        # and wv out of the stack and copies them transposed in every
+        # layer of every call, and dequantises an int8 leaf whole
+        # besides (tests/test_tpu_compile.py). wo and the MLP's three
+        # have no such reshape behind their products.
+        q, k, v = jax.lax.optimization_barrier(
+            (h @ wload(lp["wq"], dt), h @ wload(lp["wk"], dt),
+             h @ wload(lp["wv"], dt)))
+        q = q.reshape(B, S, nh, hd)
+        k = k.reshape(B, S, nkv, hd)
+        v = v.reshape(B, S, nkv, hd)
         q = _rope_rows(q, cos, sin)
         k = _rope_rows(k, cos, sin)
         ks = _write_rows(ks, k, row_pos, layer=i)

@@ -412,3 +412,140 @@ def test_prefix_window_outlives_the_cache_it_was_sliced_from(model):
     rid = eng.submit(prompt, max_new_tokens=6)
     assert _drain(eng)[rid].tokens == gold
     assert eng.prefix_hits == 1 and eng.prefill_count == 2
+
+
+# -- the layer scan against a plain loop over unstacked weights ---------------
+
+
+GQA = dict(vocab=61, d_model=64, n_layers=3, n_heads=8, n_kv_heads=2,
+           d_ff=96, max_seq=24, dtype=jnp.float32)
+
+
+def _tanh_mlp(lp, h):
+    """A stand-in for the FFN seam: its own product and a constant
+    auxiliary scalar, so the sum over layers can be told."""
+    from pbs_tpu.models.quant import wload
+
+    y = jnp.tanh(h @ wload(lp["w1"], h.dtype)) @ wload(lp["w2"], h.dtype)
+    return y, jnp.float32(0.25)
+
+
+def _layer_loop(cfg, params, tokens, cache, row_pos, mlp_fn=None):
+    """``_slot_forward``'s contract the plain way: a Python loop over
+    layers, each layer's leaves taken out of the stack first (an int8
+    leaf dequantised whole), one row and one position at a time."""
+    from pbs_tpu.models.quant import embed_rows, wload
+    from pbs_tpu.models.transformer import rms_norm, rope_tables
+
+    dt = cfg.dtype
+    B, S = tokens.shape
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    T = cache["k"].shape[2]
+    cos, sin = (np.asarray(t) for t in rope_tables(cfg, T))
+    ks, vs = np.array(cache["k"]), np.array(cache["v"])
+    row_pos = np.asarray(row_pos)
+
+    def rope(x, p):  # x (H, hd) at position p
+        x1, x2 = x[:, :hd // 2], x[:, hd // 2:]
+        return np.concatenate([x1 * cos[p] - x2 * sin[p],
+                               x2 * cos[p] + x1 * sin[p]], axis=-1)
+
+    x = embed_rows(params["embed"], tokens, dt)
+    extra = 0.0
+    for layer in range(cfg.n_layers):
+        lp = jax.tree.map(lambda w: w[layer], params["layers"])
+        h = np.asarray(rms_norm(x, lp["attn_norm"], cfg.norm_eps))
+        wq, wk, wv, wo = (np.asarray(wload(lp[n], dt))
+                          for n in ("wq", "wk", "wv", "wo"))
+        attn = np.zeros((B, S, nh * hd), np.float32)
+        for b in range(B):
+            for s in range(S):
+                p = int(row_pos[b]) + s
+                ks[layer, b, p] = rope((h[b, s] @ wk).reshape(nkv, hd), p)
+                vs[layer, b, p] = (h[b, s] @ wv).reshape(nkv, hd)
+            for s in range(S):
+                p = int(row_pos[b]) + s
+                q = rope((h[b, s] @ wq).reshape(nh, hd), p)
+                for head in range(nh):
+                    g = head // (nh // nkv)
+                    sc = ks[layer, b, :p + 1, g] @ q[head] / np.sqrt(hd)
+                    pr = np.exp(sc - sc.max())
+                    attn[b, s, head * hd:(head + 1) * hd] = \
+                        (pr / pr.sum()) @ vs[layer, b, :p + 1, g]
+        x = x + jnp.asarray(attn @ wo)
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        if mlp_fn is None:
+            y = (jax.nn.silu(h @ wload(lp["w1"], dt))
+                 * (h @ wload(lp["w3"], dt))) @ wload(lp["w2"], dt)
+        else:
+            y, e = mlp_fn(lp, h)
+            extra += float(e)
+        x = x + y
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return np.asarray(x @ wload(params["head"], dt)), ks, vs, extra
+
+
+@pytest.mark.parametrize("seam", ["dense", "mlp_fn"])
+@pytest.mark.parametrize("leaf", ["plain", "int8"])
+@pytest.mark.parametrize("S", [1, 5], ids=["decode", "window"])
+def test_layer_scan_equals_a_plain_loop_over_unstacked_weights(S, leaf, seam):
+    """The scan body's products read their operands in the stack; what
+    comes out is what a loop over each layer's own weights gives: the
+    logits, and a cache that differs from the one that went in at the
+    B x S written positions of each layer and nowhere else. Eight query
+    heads on two kv heads, every row at its own cursor, the cache full
+    of another tenant's rows beforehand."""
+    from pbs_tpu.models.quant import quantize_weights
+    from pbs_tpu.models.serving import _slot_forward
+
+    cfg = TransformerConfig(**GQA)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    if leaf == "int8":
+        params = quantize_weights(params)
+        assert set(params["layers"]["wq"]) == {"q", "s"}
+    mlp_fn = _tanh_mlp if seam == "mlp_fn" else None
+    B, T = 3, cfg.max_seq
+    kk, kv, kt = jax.random.split(jax.random.PRNGKey(S), 3)
+    shape = (cfg.n_layers, B, T, cfg.n_kv_heads, cfg.head_dim)
+    cache = {"k": jax.random.normal(kk, shape, cfg.dtype),
+             "v": jax.random.normal(kv, shape, cfg.dtype),
+             "pos": jnp.zeros((B,), jnp.int32)}
+    row_pos = jnp.asarray([0, 7, 13], jnp.int32)
+    tokens = jax.random.randint(kt, (B, S), 0, cfg.vocab)
+
+    logits, new, extra = jax.jit(
+        lambda p, t, c, r: _slot_forward(cfg, p, t, c, r, mlp_fn=mlp_fn))(
+            params, tokens, cache, row_pos)
+    want, ks, vs, want_extra = _layer_loop(cfg, params, tokens, cache,
+                                           row_pos, mlp_fn)
+
+    assert logits.shape == (B, S, cfg.vocab) and logits.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(logits), want, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(new["k"]), ks, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(new["v"]), vs, atol=2e-5)
+    written = np.zeros(shape[:3], bool)
+    for b, p in enumerate(np.asarray(row_pos)):
+        written[:, b, p:p + S] = True
+    for name in ("k", "v"):  # untouched rows are the very bits that went in
+        np.testing.assert_array_equal(np.asarray(new[name])[~written],
+                                      np.asarray(cache[name])[~written])
+    assert float(extra) == pytest.approx(want_extra)
+    assert want_extra == (0.25 * cfg.n_layers if mlp_fn else 0.0)
+
+
+@pytest.mark.parametrize("leaf", ["plain", "int8"])
+def test_grouped_heads_served_tokens_equal_lockstep_generate(leaf):
+    """Eight query heads on two kv heads, through the engine (prefill,
+    then ticks beside another tenant): the lockstep path's tokens."""
+    from pbs_tpu.models.quant import quantize_weights
+
+    cfg = TransformerConfig(**GQA)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    if leaf == "int8":
+        params = quantize_weights(params)
+    eng = ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=8)
+    prompts = [[5, 9, 2, 31, 7], [11, 3, 40]]
+    rids = [eng.submit(p, max_new_tokens=9) for p in prompts]
+    done = _drain(eng)
+    for rid, prompt in zip(rids, prompts):
+        assert done[rid].tokens == _gold(cfg, params, prompt, 9)

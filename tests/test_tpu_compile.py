@@ -1,0 +1,155 @@
+"""What XLA:TPU makes of the dense slot forward, compiled for a v5e
+that is described and not attached (libtpu is installed; nothing runs,
+no chip is needed): the copies a CPU compile cannot show.
+
+The topology is described inside a fixture, never at import: one
+process at a time may load libtpu, and every pytest-xdist worker
+imports every test file. All such compiles live in this one file, so
+one worker loads the library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from pbs_tpu.models import TransformerConfig, init_params
+from pbs_tpu.models.quant import quantize_weights
+from pbs_tpu.models.serving import (
+    _slot_forward, ingest_slot_prompt, init_slot_cache)
+from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
+from pbs_tpu.serve.partition import make_serve_mesh, rule_shardings
+from pbs_tpu.telemetry.hlo import dims, materialised, written
+
+# mistral-7b's widths, three layers of them: the copies were of a
+# layer's projections, whatever the depth.
+CFG = TransformerConfig(
+    vocab=32768, d_model=4096, n_layers=3, n_heads=32, n_kv_heads=8,
+    d_ff=14336, max_seq=256, rope_theta=1e6, dtype=jnp.bfloat16)
+SLOTS = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to JAX's persistent
+    # cache and cannot be read back without the chip (a warning every
+    # time after the first): the cache is off around these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(topo, tp: int, int8: bool):
+    """The serving tree and the slot cache as shapes, laid on one
+    described chip or on a tensor axis of four by the rule table."""
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(CFG.dtype),
+        init_params(CFG, jax.random.PRNGKey(0))))
+    if int8:
+        params = jax.eval_shape(quantize_weights, params)
+    cache = jax.eval_shape(
+        lambda: init_slot_cache(CFG, SLOTS, CFG.max_seq))
+    if tp == 1:
+        one = SingleDeviceSharding(topo.devices[0])
+        where, kv, rest = jax.tree.map(lambda _: one, params), one, one
+    else:
+        mesh = make_serve_mesh(tp=tp, dp=1, devices=topo.devices[:tp])
+        where = rule_shardings(params, mesh)
+        kv = slot_cache_kv_sharding(mesh)
+        rest = NamedSharding(mesh, PartitionSpec())
+    lay = lambda x, s: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=s)
+    return (jax.tree.map(lay, params, where),
+            {"k": lay(cache["k"], kv), "v": lay(cache["v"], kv),
+             "pos": lay(cache["pos"], rest)},
+            lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                sharding=rest))
+
+
+def _weightlike(tp: int) -> set:
+    """One layer's wq, wk or wv (wo is wq's size) on one device: (d,
+    H * hd) or split into heads (d, H, hd), its axes in any order."""
+    d, hd = CFG.d_model, CFG.head_dim
+    out = set()
+    for heads in (CFG.n_heads // tp, CFG.n_kv_heads // tp):
+        out |= {tuple(sorted((d, heads * hd))), tuple(sorted((d, heads, hd)))}
+    return out
+
+
+@pytest.mark.parametrize("case,rows,tp,int8", [
+    ("decode", 1, 1, False),
+    ("verify-window", 5, 1, False),
+    ("prefill", 256, 1, False),
+    ("decode-int8", 1, 1, True),
+    ("decode-tp4", 1, 4, False),
+    ("decode-int8-tp4", 1, 4, True),
+])
+def test_attention_projections_are_read_where_they_lie(topo, case, rows,
+                                                       tp, int8):
+    """No instruction of the compiled layer scan writes a tensor that
+    is one layer's attention projection, sliced out of the stack,
+    transposed or dequantised whole: the q, k and v products read the
+    stacked leaf as the MLP's always did. (XLA:TPU moves a reshape to
+    heads through the product onto the weight; PERF.md section 6, PR
+    32.) On a tensor axis of four the collectives are the layer's two
+    reductions and nothing gathers a weight."""
+    params, cache, i32 = _shapes(topo, tp, int8)
+    if case == "prefill":
+        fn = lambda p, c, prompt: ingest_slot_prompt(  # noqa: E731
+            CFG, p, c, 0, prompt, 7)[:2]
+        args = (params, cache, i32(rows))
+    else:
+        fn = lambda p, c, tok: _slot_forward(  # noqa: E731
+            CFG, p, tok, c, c["pos"])[:2]
+        args = (params, cache, i32(SLOTS, rows))
+    ops = materialised(jax.jit(fn, donate_argnums=(1,)).lower(
+        *args).compile().as_text())
+    # The reading sees into the scan: an MLP product's result is there.
+    width = CFG.d_ff // tp
+    assert any(dims(shape)[-1:] == (width,) and op == "fusion"
+               for _, op, shape in ops), case
+    moved = written(ops, _weightlike(tp))
+    assert not moved, moved
+    gathers = [op for op in written(ops, _weightlike(1) | _weightlike(tp))
+               if op[1].startswith("all-gather")]
+    assert not gathers, gathers
+
+
+def test_materialised_leaves_out_fused_computations():
+    hlo = """HloModule m
+
+%fused_computation (p0: bf16[3,8,4], p1: s32[]) -> bf16[2,4] {
+  %p0 = bf16[3,8,4]{2,1,0} parameter(0)
+  %slice.1 = bf16[1,8,4]{2,1,0:T(8,128)(2,1)} dynamic-slice(%p0, %p1)
+  ROOT %dot.1 = bf16[2,4]{1,0} convolution(%x, %slice.1)
+}
+
+%body (arg: (s32[], bf16[3,8,4])) -> (s32[], bf16[3,8,4]) {
+  %w = bf16[3,8,4]{2,1,0} get-tuple-element(%arg), index=1
+  %copy.7 = bf16[1,8,4]{1,2,0:T(8,128)(2,1)S(1)} copy(%slice.9)
+  ROOT %fusion.3 = bf16[2,4]{1,0} fusion(%w, %i), kind=kOutput, calls=%fused_computation
+}
+
+ENTRY %main (a: bf16[3,8,4]) -> bf16[2,4] {
+  %a = bf16[3,8,4]{2,1,0} parameter(0)
+  ROOT %while.1 = (s32[], bf16[3,8,4]{2,1,0}) while(%t), body=%body
+}
+"""
+    assert materialised(hlo) == [
+        ("w", "get-tuple-element", "bf16[3,8,4]"),
+        ("copy.7", "copy", "bf16[1,8,4]"),
+        ("fusion.3", "fusion", "bf16[2,4]"),
+        ("a", "parameter", "bf16[3,8,4]")]
+    assert dims("bf16[1,8,4]") == dims("bf16[4,8]") == (4, 8)
+    assert written(materialised(hlo), {(4, 8)}) == [
+        ("copy.7", "copy", "bf16[1,8,4]")]

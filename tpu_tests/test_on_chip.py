@@ -395,3 +395,38 @@ def test_slot_cache_updated_in_place_at_the_mistral_cell():
     # A wrong token reads ~4 here (random weights); bf16 rounding of
     # two unlike summation orders, under 0.1.
     assert gap.max() < 0.25, gap
+
+
+def test_decode_reads_attention_projections_where_they_lie_in_the_stack():
+    """The engine's decode at mistral's widths (4 layers of them): no
+    instruction of the compiled program writes a tensor the size of one
+    layer's wq, wk, wv or wo, in any arrangement of its axes. Given a
+    reshape to heads straight behind the q, k and v products, XLA:TPU
+    moves it onto the weight, slices that out of the (L, ...) stack and
+    copies it transposed in every layer of every tick: 3.2 ms of
+    mistral's 22.2 (PERF.md section 6, PR 32; the same compile without
+    the chip is tests/test_tpu_compile.py). A CPU compile has no such
+    copy to show."""
+    from pbs_tpu.models import ContinuousBatcher, TransformerConfig
+    from pbs_tpu.telemetry.hlo import materialised, written
+
+    cfg = TransformerConfig(
+        vocab=32768, d_model=4096, n_layers=4, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq=256, rope_theta=1e6, dtype=jnp.bfloat16)
+    slots = 16
+    eng = ContinuousBatcher(cfg, _bf16_params(cfg), n_slots=slots,
+                            prompt_bucket=64, max_len=cfg.max_seq)
+    ops = materialised(eng._decode_fn.lower(
+        eng.params, eng.cache, jnp.zeros((slots,), jnp.int32),
+        jnp.zeros((slots,), bool),
+        jax.random.PRNGKey(0)).compile().as_text())
+    # The scan is there and the reading sees into it: the MLP's
+    # products write (slots, d_ff) from inside the loop.
+    assert any(shape == f"bf16[{slots},{cfg.d_ff}]" for _, _, shape in ops)
+    d, hd = cfg.d_model, cfg.head_dim
+    weightlike = set()  # (d, H * hd) and (d, H, hd), axes in any order
+    for heads in (cfg.n_heads, cfg.n_kv_heads):
+        weightlike |= {tuple(sorted((d, heads * hd))),
+                       tuple(sorted((d, heads, hd)))}
+    moved = written(ops, weightlike)
+    assert not moved, moved
