@@ -272,14 +272,26 @@ def moe_mlp(cfg: MoEConfig, x: jax.Array, lp: dict, constrain_ec):
 # -- grouped experts: the share of a layer that this program holds -----------
 
 
-def route_top_k(h: jax.Array, router: jax.Array, kind):
+def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
     """Token-choice routing over ALL of a layer's experts: h (T, d) ->
-    (weights (T, k) fp32, expert ids (T, k)). Scores are a float32
-    softmax over the router's logits; the k largest are kept,
-    renormalised to sum to one and scaled by ``kind.routed_scale``. No capacity: every token keeps every choice.
+    (weights (T, k) fp32, expert ids (T, k)). Scores are float32, by
+    ``kind.scoring``: a ``softmax`` over the router's logits, or the
+    ``sigmoid`` of each, where ``bias`` (one an expert) is added for
+    the choice of the k largest and left out of their weights. The
+    chosen scores are renormalised to sum to one and scaled by
+    ``kind.routed_scale``. No capacity: every token keeps every choice.
     ``kind`` is a ``models.plan.MlpKind``."""
     logits = h.astype(jnp.float32) @ router.astype(jnp.float32)
-    topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), kind.top_k)
+    if kind.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                kind.top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    elif kind.scoring == "softmax":
+        topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                   kind.top_k)
+    else:
+        raise ValueError(f"unknown router scoring {kind.scoring!r}")
     topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
     return topv * kind.routed_scale, topi
 
@@ -304,7 +316,7 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     k = kind.top_k
     first, n = kind.held
     with jax.named_scope("moe.route"):
-        w, idx = route_top_k(h, lp["router"], kind)
+        w, idx = route_top_k(h, lp["router"], kind, lp.get("router_bias"))
         local = idx - first
         ours = (local >= 0) & (local < n)
         here = ours & valid[:, None]

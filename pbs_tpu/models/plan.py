@@ -15,9 +15,12 @@ of the stack before each use: 768 MB a routed-expert weight, every
 tick)::
 
     embed, final_norm, head
-    blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]}
+    blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]}        softmax
+    blocks/<NN>/attn/{attn_norm, wq, wk, wv, cq, ck, cv, wa1, wa2,
+                      a_log, dt_bias, wb, wg1, wg2, o_norm, wo}  delta rule
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
-    blocks/<NN>/mlp/{mlp_norm, router, we1, we3, we2[, ws1, ws3, ws2]}
+    blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
+                     [, ws1, ws3, ws2]}
 
 A configuration without a plan is the uniform one its widths describe
 (:func:`uniform_plan`), held in the stacked ``layers/...`` tree and run
@@ -52,11 +55,46 @@ class Rope:
 
 @dataclasses.dataclass(frozen=True)
 class AttnKind:
+    """Softmax attention over cached keys and values."""
+
     name: str
     n_heads: int
     window: int | None = None  # None: every earlier position is seen
-    rope: Rope = Rope()
-    head_gate: bool = False    # sigmoid gate, one scalar a query head
+    rope: Rope | None = Rope()  # None: no rotary at all (NoPE)
+    # The sigmoid output gate, at most one of two widths (each a field
+    # callers construct with; ``gate`` reads them as one word).
+    head_gate: bool = False    # one scalar a query head
+    wide_gate: bool = False    # one scalar a channel of every head
+
+    def __post_init__(self):
+        if self.head_gate and self.wide_gate:
+            raise ValueError(f"attention kind {self.name!r} names two "
+                             f"output gates")
+
+    @property
+    def gate(self) -> str | None:
+        """``per_head`` | ``elementwise`` | None: what the sigmoid
+        output gate, read off the layer's normed input, has one of."""
+        return "per_head" if self.head_gate else \
+            "elementwise" if self.wide_gate else None
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaKind:
+    """Gated delta-rule linear attention (Kimi Delta Attention): no
+    keys and values are kept; a request's state is one float32
+    ``(head_dim, head_dim)`` matrix a head, decayed per key channel and
+    corrected by the delta rule each token, and the last ``conv - 1``
+    inputs of the short causal convolution q, k and v pass through
+    (``models/kda.py``).
+    ``rank`` is the width of the two low-rank pairs (decay, output
+    gate)."""
+
+    name: str
+    n_heads: int
+    head_dim: int
+    conv: int = 4
+    rank: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,17 +112,28 @@ class MlpKind:
     held: tuple[int, int] = (0, 0)
     shared_d_ff: int = 0       # one always-on expert beside the routed
     routed_scale: float = 1.0
+    #: ``softmax`` over the router's logits, or ``sigmoid`` of each with
+    #: a learned bias (``router_bias``) that takes part in choosing the
+    #: experts and not in weighting them
+    scoring: str = "softmax"
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    attn: tuple[AttnKind, ...]
+    attn: tuple[AttnKind | KdaKind, ...]
     mlp: tuple[MlpKind, ...]
     layers: tuple[tuple[int, int], ...]  # per layer: (attn i, mlp i)
 
     @property
     def routed(self) -> bool:
         return any(self.mlp[m].n_experts for _, m in self.layers)
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps a state that every token is folded into,
+        not keys and values a cursor can mask."""
+        return any(isinstance(self.attn[a], KdaKind)
+                   for a, _ in self.layers)
 
     def kinds(self, layer: int) -> tuple[AttnKind, MlpKind]:
         a, m = self.layers[layer]
@@ -157,11 +206,21 @@ def plan_shapes(cfg) -> dict:
                  "head": (d, cfg.vocab), "blocks": {}}
     for layer in range(len(plan.layers)):
         a, m = plan.kinds(layer)
-        attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
-                "wk": (d, nkv * hd), "wv": (d, nkv * hd),
-                "wo": (a.n_heads * hd, d)}
-        if a.head_gate:
-            attn["wg"] = (d, a.n_heads)
+        if isinstance(a, KdaKind):
+            w, r = a.n_heads * a.head_dim, a.rank
+            attn = {"attn_norm": (d,), "wq": (d, w), "wk": (d, w),
+                    "wv": (d, w), "cq": (a.conv, w), "ck": (a.conv, w),
+                    "cv": (a.conv, w), "wa1": (d, r), "wa2": (r, w),
+                    "a_log": (a.n_heads,), "dt_bias": (w,),
+                    "wb": (d, a.n_heads), "wg1": (d, r), "wg2": (r, w),
+                    "o_norm": (a.head_dim,), "wo": (w, d)}
+        else:
+            attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
+                    "wk": (d, nkv * hd), "wv": (d, nkv * hd),
+                    "wo": (a.n_heads * hd, d)}
+            if a.gate:
+                attn["wg"] = (d, a.n_heads * (
+                    hd if a.gate == "elementwise" else 1))
         f = m.d_ff
         if not m.n_experts:
             mlp = {"mlp_norm": (d,), "w1": (d, f), "w3": (d, f),
@@ -170,6 +229,8 @@ def plan_shapes(cfg) -> dict:
             n = m.held[1]
             mlp = {"mlp_norm": (d,), "router": (d, m.n_experts),
                    "we1": (n, d, f), "we3": (n, d, f), "we2": (n, f, d)}
+            if m.scoring == "sigmoid":
+                mlp["router_bias"] = (m.n_experts,)
             if m.shared_d_ff:
                 s = m.shared_d_ff
                 mlp.update({"ws1": (d, s), "ws3": (d, s), "ws2": (s, d)})
@@ -179,7 +240,10 @@ def plan_shapes(cfg) -> dict:
 
 def init_plan_params(cfg, key: jax.Array) -> dict:
     """fp32 parameters of a planned model: normal / sqrt(fan_in), norms
-    at one, the embedding scaled by sqrt(d) as ``init_params`` has it."""
+    at one, the embedding scaled by sqrt(d) as ``init_params`` has it.
+    A delta-rule layer's ``a_log`` is the log of uniform(1, 16) and its
+    ``dt_bias`` the inverse softplus of a step in [0.001, 0.1] (the
+    published layer's own start), the router's bias a small normal."""
     shapes = plan_shapes(cfg)
     flat, treedef = jax.tree.flatten(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
@@ -191,6 +255,18 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
         name = str(path[-1].key)
         if name.endswith("norm"):
             leaves.append(jnp.ones(shape, jnp.float32))
+            continue
+        if name == "a_log":
+            leaves.append(jnp.log(jax.random.uniform(
+                k, shape, jnp.float32, 1.0, 16.0)))
+            continue
+        if name == "dt_bias":
+            dt = jnp.exp(jax.random.uniform(
+                k, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+            leaves.append(dt + jnp.log(-jnp.expm1(-dt)))
+            continue
+        if name == "router_bias":
+            leaves.append(0.005 * jax.random.normal(k, shape, jnp.float32))
             continue
         w = jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[-2])
         leaves.append(w * np.sqrt(cfg.d_model) if name == "embed" else w)

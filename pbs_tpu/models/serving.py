@@ -45,11 +45,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
 from pbs_tpu.obs.trace import Ev, TraceBuffer, host_ring, register_ring
 from pbs_tpu.models.plan import (
-    block_name, init_plan_params, plan_of, rope_table, uniform_plan)
+    KdaKind, block_name, init_plan_params, plan_of, rope_table,
+    uniform_plan)
 from pbs_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -261,25 +263,37 @@ def _rope_leading(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 def init_plan_cache(cfg: TransformerConfig, n_slots: int,
                     max_len: int) -> dict:
-    """Two kinds of cache in one, a layer at a time (a layer's keys are
-    a buffer of their own: read out of a stack they would be copied
-    first): a full layer keeps every position, ``(slots, max_len, nkv,
-    hd)``; a window layer keeps a ring of its window, ``(slots, W, nkv,
-    hd)``, position p at ``p mod W``. One cursor a slot serves both:
-    which ring entries are live follows from it alone."""
+    """Every kind of per-slot state in one cache, a layer at a time (a
+    layer's keys are a buffer of their own: read out of a stack they
+    would be copied first): a full layer keeps every position,
+    ``(slots, max_len, nkv, hd)``; a window layer keeps a ring of its
+    window, ``(slots, W, nkv, hd)``, position p at ``p mod W``; a
+    delta-rule layer keeps no positions at all but ``state``, one
+    float32 ``(hd, hd)`` matrix a head, ``(slots, H, hd, hd)``, and
+    ``conv``, the last ``kernel - 1`` inputs of its short convolution
+    (q, k and v side by side), ``(slots, kernel - 1, 3 * H * hd)``. One
+    cursor a slot serves all: which ring entries are live follows from
+    it alone, and a state needs none. ``state`` and ``conv`` are there
+    only where some layer has them."""
     plan = plan_of(cfg)
-
-    def slabs():
-        out = {}
-        for layer in range(len(plan.layers)):
-            a, _ = plan.kinds(layer)
-            out[block_name(layer)] = jnp.zeros(
+    out: dict = {"k": {}, "v": {},
+                 "pos": jnp.zeros((n_slots,), jnp.int32)}
+    if plan.recurrent:
+        out["state"], out["conv"] = {}, {}
+    for layer in range(len(plan.layers)):
+        a, _ = plan.kinds(layer)
+        name = block_name(layer)
+        if isinstance(a, KdaKind):
+            out["state"][name] = jnp.zeros(
+                (n_slots, a.n_heads, a.head_dim, a.head_dim), jnp.float32)
+            out["conv"][name] = jnp.zeros(
+                (n_slots, a.conv - 1, 3 * a.n_heads * a.head_dim), cfg.dtype)
+            continue
+        for kv in ("k", "v"):
+            out[kv][name] = jnp.zeros(
                 (n_slots, min(a.window, max_len) if a.window else max_len,
                  cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
-        return out
-
-    return {"k": slabs(), "v": slabs(),
-            "pos": jnp.zeros((n_slots,), jnp.int32)}
+    return out
 
 
 def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
@@ -292,19 +306,23 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     ``slot`` None is the decode tick: S == 1, row b at position
     ``row_pos[b]``; each layer writes its one new position (full: at
     the cursor; window: at cursor mod W, rotary already applied) and
-    attends over its cache. With a ``slot`` it is the ingestion of one
-    prompt from position 0 (B == 1): attention stays inside the prompt
-    (banded in a window layer) and the layer leaves the prompt's keys
-    and values in that slot (a window layer its last W positions, each
-    where the ring keeps it).
+    attends over its cache; a delta-rule layer takes one recurrent step
+    in every lane that ``valid`` marks and leaves the others' state as
+    it was. With a ``slot`` it is the ingestion of one prompt from
+    position 0 (B == 1): attention stays inside the prompt (banded in a
+    window layer) and the layer leaves the prompt's keys and values in
+    that slot (a window layer its last W positions, each where the ring
+    keeps it); a delta-rule layer leaves the prompt's state, built from
+    zero, over whatever the slot held.
 
     ``valid`` (B, S) marks real tokens: the expert layers route nothing
-    else. Returns (logits fp32: (B, 1, V), or (V,) at the prompt's last
-    position; the cache's new k and v; ``route``: int32 [tokens routed,
-    assignments to held experts, to absent experts, held experts
-    touched (both summed over expert layers), largest load of one
-    expert], None for a stack without experts). Donated, the cache is
-    updated in place."""
+    else, and no state folds anything else in. Returns (logits fp32:
+    (B, 1, V), or (V,) at the prompt's last position; the cache's new
+    entries, every key of it but ``pos``; ``route``: int32 [tokens
+    routed, assignments to held experts, to absent experts, held
+    experts touched (both summed over expert layers), largest load of
+    one expert], None for a stack without experts). Donated, the cache
+    is updated in place."""
     from pbs_tpu.models.moe import held_expert_ffn, shared_expert_ffn
 
     plan = plan_of(cfg)
@@ -315,11 +333,14 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         raise NotImplementedError(
             "a planned stack decodes one position a tick: a window "
             "layer's ring cannot take a multi-token verify window")
-    ks, vs = dict(cache["k"]), dict(cache["v"])
-    T = max(cfg.max_seq, *(c.shape[1] for c in ks.values()))
+    new = {key: dict(entries) for key, entries in cache.items()
+           if key != "pos"}
+    ks, vs = new["k"], new["v"]
+    T = max([cfg.max_seq] + [c.shape[1] for c in ks.values()])
     abs_pos = jnp.minimum(
         row_pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :], T - 1)
-    tables = {a: rope_table(a.rope, hd, T) for a in plan.attn}
+    tables = {a.rope: rope_table(a.rope, hd, T) for a in plan.attn
+              if getattr(a, "rope", None) is not None}
     x = embed_rows(params["embed"], tokens, dt)
     flat_valid = valid.reshape(-1)
     counts = jnp.zeros((4,), jnp.int32)
@@ -328,46 +349,25 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         a, m = plan.kinds(layer)
         name = block_name(layer)
         ap, mp = params["blocks"][name]["attn"], params["blocks"][name]["mlp"]
-        H = a.n_heads
         h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
-        q = (h @ wload(ap["wq"], dt)).reshape(B, S, H, hd)
-        k = (h @ wload(ap["wk"], dt)).reshape(B, S, nkv, hd)
-        v = (h @ wload(ap["wv"], dt)).reshape(B, S, nkv, hd)
-        cos, sin = (t[abs_pos] for t in tables[a])
-        q, k = _rope_leading(q, cos, sin), _rope_leading(k, cos, sin)
-        K = ks[name].shape[1]
-        with jax.named_scope("attn.window" if a.window else "attn.full"):
-            if decode:
-                at = row_pos % K if a.window else row_pos
-                ks[name] = _write_rows(ks[name], k, at)
-                vs[name] = _write_rows(vs[name], v, at)
-                col = jnp.arange(K)[None, :]
-                # Ring entry j holds the largest p <= cursor with
-                # p = j mod W: live once written, always after a lap.
-                seen = (col <= row_pos[:, None]) | (
-                    (row_pos[:, None] >= K) if a.window else False)
-                attn = _grouped_attention(q, ks[name], vs[name],
-                                          seen[:, None, :], dt)
-            else:
-                i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
-                seen = (j <= i) & ((i - j < a.window) if a.window else True)
-                attn = _grouped_attention(q, k, v, seen[None], dt)
-                if a.window:
-                    # Entry j of the ring: the prompt's last position
-                    # that is j mod W (an entry with none is not live).
-                    last = row_pos[0] + valid.sum() - 1
-                    src = last - (last - jnp.arange(K)) % K
-                    k, v = (t[:, jnp.clip(src, 0, S - 1)] for t in (k, v))
+        if isinstance(a, KdaKind):
+            with jax.named_scope("attn.kda"):
+                if decode:
+                    y, state, tail = kda_decode(
+                        a, ap, h, new["state"][name], new["conv"][name],
+                        valid[:, 0], cfg.norm_eps, dt)
+                    new["state"][name], new["conv"][name] = state, tail
                 else:
-                    k, v = k[:, :K], v[:, :K]
-                ks[name] = jax.lax.dynamic_update_slice(
-                    ks[name], k, (slot, 0, 0, 0))
-                vs[name] = jax.lax.dynamic_update_slice(
-                    vs[name], v, (slot, 0, 0, 0))
-            if a.head_gate:
-                gate = jax.nn.sigmoid(h @ wload(ap["wg"], dt))
-                attn = attn * gate[..., None]
-        x = x + attn.reshape(B, S, H * hd) @ wload(ap["wo"], dt)
+                    y, state, tail = kda_ingest(a, ap, h, valid,
+                                                 cfg.norm_eps, dt)
+                    new["state"][name] = jax.lax.dynamic_update_slice(
+                        new["state"][name], state, (slot, 0, 0, 0))
+                    new["conv"][name] = jax.lax.dynamic_update_slice(
+                        new["conv"][name], tail, (slot, 0, 0))
+            x = x + y
+        else:
+            x = x + _softmax_layer(a, ap, h, ks, vs, name, row_pos, valid,
+                                   abs_pos, tables, slot, nkv, hd, dt)
 
         h = rms_norm(x, mp["mlp_norm"], cfg.norm_eps)
         if not m.n_experts:
@@ -392,7 +392,59 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     route = jnp.concatenate(
         [valid.sum().astype(jnp.int32)[None], counts]) \
         if plan.routed else None
-    return logits, ks, vs, route
+    return logits, new, route
+
+
+def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
+                   row_pos, valid, abs_pos, tables: dict, slot, nkv: int,
+                   hd: int, dt) -> jax.Array:
+    """A full or window attention layer of the planned stack on its
+    normed input h (B, S, d): writes the layer's new keys and values
+    into ``ks[name]`` / ``vs[name]`` (replaced in the dicts) and
+    returns what the layer adds to the stream."""
+    B, S, _ = h.shape
+    H, decode = a.n_heads, slot is None
+    q = (h @ wload(ap["wq"], dt)).reshape(B, S, H, hd)
+    k = (h @ wload(ap["wk"], dt)).reshape(B, S, nkv, hd)
+    v = (h @ wload(ap["wv"], dt)).reshape(B, S, nkv, hd)
+    if a.rope is not None:
+        cos, sin = (t[abs_pos] for t in tables[a.rope])
+        q, k = _rope_leading(q, cos, sin), _rope_leading(k, cos, sin)
+    K = ks[name].shape[1]
+    with jax.named_scope("attn.window" if a.window else "attn.full"):
+        if decode:
+            at = row_pos % K if a.window else row_pos
+            ks[name] = _write_rows(ks[name], k, at)
+            vs[name] = _write_rows(vs[name], v, at)
+            col = jnp.arange(K)[None, :]
+            # Ring entry j holds the largest p <= cursor with
+            # p = j mod W: live once written, always after a lap.
+            seen = (col <= row_pos[:, None]) | (
+                (row_pos[:, None] >= K) if a.window else False)
+            attn = _grouped_attention(q, ks[name], vs[name],
+                                      seen[:, None, :], dt)
+        else:
+            i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+            seen = (j <= i) & ((i - j < a.window) if a.window else True)
+            attn = _grouped_attention(q, k, v, seen[None], dt)
+            if a.window:
+                # Entry j of the ring: the prompt's last position
+                # that is j mod W (an entry with none is not live).
+                last = row_pos[0] + valid.sum() - 1
+                src = last - (last - jnp.arange(K)) % K
+                k, v = (t[:, jnp.clip(src, 0, S - 1)] for t in (k, v))
+            else:
+                k, v = k[:, :K], v[:, :K]
+            ks[name] = jax.lax.dynamic_update_slice(
+                ks[name], k, (slot, 0, 0, 0))
+            vs[name] = jax.lax.dynamic_update_slice(
+                vs[name], v, (slot, 0, 0, 0))
+        if a.gate == "per_head":
+            attn = attn * jax.nn.sigmoid(h @ wload(ap["wg"], dt))[..., None]
+        elif a.gate == "elementwise":
+            attn = attn * jax.nn.sigmoid(
+                h @ wload(ap["wg"], dt)).reshape(B, S, H, hd)
+    return attn.reshape(B, S, H * hd) @ wload(ap["wo"], dt)
 
 
 class _ScanProgram:
@@ -441,14 +493,24 @@ class _ScanProgram:
 
 
 class _PlannedProgram:
-    """Layers that differ (``cfg.layer_plan``): two kinds of cache in
-    one manager, the grouped expert layer, ``route`` counters."""
+    """Layers that differ (``cfg.layer_plan``): every kind of per-slot
+    state in one manager (positions, a ring of them, a recurrent
+    state), the grouped expert layer, ``route`` counters."""
 
-    #: a window layer's ring takes one position a tick
+    #: neither a ring nor a state hands out or takes in a window of
+    #: positions (``no_windows`` says which, for the error)
     windows = False
 
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
+        self.no_windows = (
+            "a delta-rule layer keeps one recurrent state a slot, not "
+            "positions: a prefix hit or a verify window would need a "
+            "snapshot of that state at the window's end (ROADMAP R6)"
+            if plan_of(cfg).recurrent else
+            "a window layer's ring takes one position a tick, and "
+            "cutting a window from it or installing one is not written "
+            "(ROADMAP R4)")
 
     def init_params(self, key: jax.Array) -> dict:
         return init_plan_params(self.cfg, key)
@@ -462,24 +524,24 @@ class _PlannedProgram:
         if mesh.devices.size != 1:
             raise NotImplementedError(
                 f"a planned layer stack serves on one device, not on a "
-                f"mesh of {dict(mesh.shape)}: neither the window ring's "
-                f"nor the held experts' division over a tensor axis is "
-                f"written (ROADMAP R4)")
+                f"mesh of {dict(mesh.shape)}: neither the window ring's, "
+                f"the recurrent state's nor the held experts' division "
+                f"over a tensor axis is written (ROADMAP R4, R6)")
         return jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
 
     def decode(self, params, cache, last_tok, active):
-        logits, ks, vs, route = _plan_forward(
+        logits, new, route = _plan_forward(
             self.cfg, params, last_tok[:, None], cache, cache["pos"],
             active[:, None])
-        return (logits, {"k": ks, "v": vs, "pos": cache["pos"]},
+        return (logits, dict(new, pos=cache["pos"]),
                 jnp.zeros((), jnp.float32), route)
 
     def ingest(self, params, cache, slot, prompt, plen):
         valid = (jnp.arange(prompt.shape[0]) < plen)[None, :]
-        last_logits, ks, vs, route = _plan_forward(
+        last_logits, new, route = _plan_forward(
             self.cfg, params, prompt[None, :], cache,
             jnp.zeros((1,), jnp.int32), valid, slot=slot)
-        cache = {"k": ks, "v": vs, "pos": cache["pos"].at[slot].set(plen)}
+        cache = dict(new, pos=cache["pos"].at[slot].set(plen))
         return last_logits, cache, jnp.zeros((), jnp.float32), route
 
 
@@ -589,8 +651,8 @@ class ContinuousBatcher:
         if prefix_cache_size and not self.program.windows:
             raise ValueError(
                 "prefix_cache_size > 0 needs a prompt window that can be "
-                "cut from and installed into every layer's cache; over a "
-                "window layer's ring that is not written (ROADMAP R4)")
+                "cut from and installed into every layer's cache: "
+                + self.program.no_windows)
         cache = self.program.init_cache(n_slots, self.max_len)
         if mesh is not None:
             # Tensor-parallel serving by PLACEMENT (the GSPMD recipe):
@@ -733,6 +795,9 @@ class ContinuousBatcher:
         # prompt and no active lane leave every cursor at 0, and what
         # they write (slot 0's bucket, position 0 of each lane) the
         # first tenant's prefill or decode overwrites before reading.
+        # A recurrent state has no cursor to hide behind: a prompt is
+        # ingested from a zero state over whatever the slot held, and a
+        # lane that is not active keeps its state bit for bit.
         # Every rung is its own instance of the prefill: all of them
         # now, so that none compiles under a request.
         wk = jax.random.PRNGKey(0)
@@ -1088,8 +1153,8 @@ class SpeculativeBatcher(ContinuousBatcher):
             if not slot_program(c).windows:
                 raise NotImplementedError(
                     "speculation verifies k + 1 positions a tick, over "
-                    "uniform layer stacks only: a window layer's ring "
-                    "takes one position a tick")
+                    "uniform layer stacks only: "
+                    + slot_program(c).no_windows)
         super().__init__(cfg, params, **kw)
         self.draft_cfg = draft_cfg
         self.draft_params = draft_params

@@ -53,7 +53,7 @@ SpecEntry = Any
 #: positionally: ``-1`` = the innermost (tensor) mesh axis.
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
-    (r"(^|/)(attn_norm|mlp_norm|final_norm)$", ()),
+    (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm)$", ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -70,14 +70,23 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     # ``blocks/<NN>/attn/`` and ``blocks/<NN>/mlp/``: there a spec
     # above, written for a stack of layers, loses its leading (layer)
     # entry (match_partition_rules). The planned tree's own leaves, at
-    # their own rank: the per-head output gate and the router
-    # replicated (a few columns; every holder routes over all experts),
-    # the routed experts along the axis they are divided on (the expert
-    # axis), the shared expert like one layer's dense MLP.
-    (r"/(wg|router)$", ()),
+    # their own rank: the output gate (one column a head, or one a
+    # channel) and the router with its selection bias replicated (every
+    # holder routes over all experts), the routed experts along the
+    # axis they are divided on (the expert axis), the shared expert
+    # like one layer's dense MLP.
+    (r"/(wg|router|router_bias)$", ()),
     (r"/we[123]$", (-1, None, None)),
     (r"/ws[13]$", (None, -1)),
     (r"/ws2$", (-1, None)),
+    # A delta-rule layer (models/plan.KdaKind): what feeds a head's
+    # state lies along the head axis the state would be divided on (wq,
+    # wk, wv by the rule above, like any column-parallel projection;
+    # the convolution's filters and beta, one column a head, here); the
+    # decay's and the gate's low-rank pairs, ``a_log`` and ``dt_bias``
+    # are a few hundred columns and replicated.
+    (r"/(c[qkv]|wb)$", (None, -1)),
+    (r"/(wa[12]|wg[12]|a_log|dt_bias)$", ()),
 )
 
 #: The canonical param paths the table must cover (the dense
@@ -118,12 +127,26 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/mlp/w3",
     "blocks/N/mlp/w2",
     "blocks/N/mlp/router",
+    "blocks/N/mlp/router_bias",
     "blocks/N/mlp/we1",
     "blocks/N/mlp/we3",
     "blocks/N/mlp/we2",
     "blocks/N/mlp/ws1",
     "blocks/N/mlp/ws3",
     "blocks/N/mlp/ws2",
+    # a delta-rule layer's own leaves (its attn_norm, wq, wk, wv and wo
+    # are the paths above)
+    "blocks/N/attn/cq",
+    "blocks/N/attn/ck",
+    "blocks/N/attn/cv",
+    "blocks/N/attn/wa1",
+    "blocks/N/attn/wa2",
+    "blocks/N/attn/a_log",
+    "blocks/N/attn/dt_bias",
+    "blocks/N/attn/wb",
+    "blocks/N/attn/wg1",
+    "blocks/N/attn/wg2",
+    "blocks/N/attn/o_norm",
 )
 
 

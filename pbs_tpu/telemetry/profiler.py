@@ -166,7 +166,11 @@ def parse_trace_events(events: Iterable[dict]) -> TraceStats:
     for e in events:
         if e.get("ph") == "M" and e.get("name") == "process_name":
             pid_names[e.get("pid")] = (e.get("args") or {}).get("name", "")
-    device_pids = {p for p, n in pid_names.items() if "/device:" in n}
+    # A device lane that ran nothing is no device: a process that loaded
+    # libtpu only to compile for a described chip gets an empty
+    # ``/device:CUSTOM:Megascale Trace`` lane beside its CPU thunks.
+    device_pids = {p for p, n in pid_names.items() if "/device:" in n} \
+        & {e.get("pid") for e in events if e.get("ph") == "X"}
 
     stats = TraceStats(source="device" if device_pids else "host")
     intervals: list[tuple[int, int]] = []

@@ -39,11 +39,15 @@ def test_flagship_story(tmp_path):
 
     @jax.jit
     def serve_fn(x):  # MXU-heavy and short: latency tenant
+        # Products only (every entry of x0 is 1/n, so x @ x is x): with
+        # a scaling pass after each product its measured stall read
+        # 0.07-0.12, on both sides of FeedbackPolicy's 0.10, and above
+        # it in a process that had compiled for a described chip.
         for _ in range(4):
-            x = x @ x / n
+            x = x @ x
         return x
 
-    x0 = jnp.ones((n, n), jnp.float32)
+    x0 = jnp.full((n, n), 1.0 / n, jnp.float32)
     train_fn(x0).block_until_ready()
     serve_fn(x0).block_until_ready()
 

@@ -110,6 +110,23 @@ def test_parse_trace_events_prefers_device_lanes():
     assert st.n_ops == 1 and st.memory_ns == 10_000
 
 
+def test_parse_trace_events_takes_an_empty_device_lane_for_no_device():
+    """A process that loaded libtpu only to compile for a described chip
+    (``tests/test_tpu_compile.py``, in whichever xdist worker) traces an
+    empty ``/device:CUSTOM:Megascale Trace`` lane beside its CPU thunks:
+    the thunks are what ran."""
+    events = [
+        {"ph": "M", "name": "process_name", "pid": 1,
+         "args": {"name": "/device:CUSTOM:Megascale Trace"}},
+        {"ph": "M", "name": "process_name", "pid": 701,
+         "args": {"name": "/host:CPU"}},
+        _ev("dot_general.1", ts=0, dur=100, pid=701),
+    ]
+    st = parse_trace_events(events)
+    assert st.source == "host"
+    assert st.n_ops == 1 and st.compute_ns == 100_000
+
+
 def test_stall_frac_empty_trace():
     st = TraceStats()
     assert st.stall_frac == 0.0 and st.collective_frac == 0.0

@@ -13,9 +13,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from pbs_tpu.models import TransformerConfig, init_params
+from pbs_tpu.models import plan as P
 from pbs_tpu.models.quant import quantize_weights
 from pbs_tpu.models.serving import (
-    _slot_forward, ingest_slot_prompt, init_slot_cache)
+    _slot_forward, ingest_slot_prompt, init_slot_cache, slot_program)
 from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
 from pbs_tpu.serve.partition import make_serve_mesh, rule_shardings
 from pbs_tpu.telemetry.hlo import dims, materialised, written
@@ -123,6 +124,62 @@ def test_attention_projections_are_read_where_they_lie(topo, case, rows,
     gathers = [op for op in written(ops, _weightlike(1) | _weightlike(tp))
                if op[1].startswith("all-gather")]
     assert not gathers, gathers
+
+
+# One delta-rule layer at Solar-Open2's published widths (64 heads of
+# 128, kernel 4, hidden 4096) over a small dense MLP: what is checked
+# is the layer's recurrent state, 4 MiB a slot.
+KDA_CFG = TransformerConfig(
+    vocab=1024, d_model=4096, n_layers=1, n_heads=64, n_kv_heads=8,
+    d_ff=256, max_seq=1024, dtype=jnp.bfloat16, head_size=128,
+    layer_plan=P.LayerPlan((P.KdaKind("kda", 64, 128, conv=4, rank=128),),
+                           (P.MlpKind("dense", 256),), ((0, 0),)))
+KDA_SLOTS = 32
+
+
+@pytest.mark.parametrize("case", ["decode", "ingest"])
+def test_a_recurrent_state_is_updated_where_it_lies(topo, case):
+    """The decode step over every lane and the chunked ingestion of a
+    512-row prompt compile for the chip (the triangular solve of a
+    chunk included), donate the cache and write the state in place:
+    beyond its arguments the decode step needs less than half of the
+    layer's state (a second copy of it would be the whole) and the
+    ingestion a chunk's pairwise decays (64 heads x 64 x 64 positions x
+    128 channels, 128 MiB) and no more, whatever the slots; no
+    instruction outside a fusion writes a tensor of the state's
+    size."""
+    one = SingleDeviceSharding(topo.devices[0])
+    lay = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    prog = slot_program(KDA_CFG)
+    params = lay(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(KDA_CFG.dtype),
+        prog.init_params(jax.random.PRNGKey(0)))))
+    cache = lay(jax.eval_shape(
+        lambda: prog.init_cache(KDA_SLOTS, KDA_CFG.max_seq)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one)
+    if case == "decode":
+        fn = lambda p, c, tok, active: prog.decode(  # noqa: E731
+            p, c, tok, active)[:2]
+        args = (params, cache, i32(KDA_SLOTS), jax.ShapeDtypeStruct(
+            (KDA_SLOTS,), bool, sharding=one))
+    else:
+        fn = lambda p, c, slot, prompt, plen: prog.ingest(  # noqa: E731
+            p, c, slot, prompt, plen)[:2]
+        args = (params, cache, i32(), i32(512), i32())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    state = KDA_SLOTS * 64 * 128 * 128 * 4
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= state
+    assert m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes < (state // 2 if case == "decode"
+                                   else 160 << 20)
+    moved = written(materialised(compiled.as_text()),
+                    {(KDA_SLOTS, 64, 128, 128)})
+    # the one writer is the update itself, fused with its arithmetic
+    assert all(op[1] == "fusion" for op in moved), moved
+    assert len(moved) <= 1, moved
 
 
 def test_materialised_leaves_out_fused_computations():
