@@ -27,8 +27,10 @@ import jax.numpy as jnp
 from pbs_tpu.models.plan import KdaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
+from pbs_tpu.ops.kda_step import kda_state_step, kda_step_tiles
 
-__all__ = ["KDA_CHUNK", "kda_chunked", "kda_decode", "kda_ingest"]
+__all__ = ["KDA_CHUNK", "kda_chunked", "kda_decode", "kda_ingest",
+           "state_step"]
 
 #: Positions a chunk of the prompt's delta rule holds (``kda_chunked``).
 KDA_CHUNK = 64
@@ -86,6 +88,39 @@ def _conv_filters(ap: dict) -> jax.Array:
         [ap[n].astype(jnp.float32) for n in ("cq", "ck", "cv")], axis=-1)
 
 
+def state_step(state, alpha, k, q, v, beta, active):
+    """The recurrent step in ``jax.numpy``: what a CPU runs and what
+    :func:`pbs_tpu.ops.kda_step.kda_state_step` is held to. ``state``
+    (B, H, hd, hd) float32, key channel on the rows; ``alpha`` (the
+    decay ``exp g``), k, q, v (B, H, hd); ``beta`` (B, H); ``active``
+    (B,). Returns (o (B, H, hd), state).
+
+    Products with the state are multiply-and-sum, not dots: a float32
+    dot runs in bfloat16 passes on the chip by default. S^T k and S^T q
+    of the decayed state S = Diag(alpha) state in one reduction over
+    the state as it lies, the decay folded into the two vectors.
+    o = S'^T q with S' = S + k u^T is S^T q + (k . q) u. ``S^T k`` has
+    to be whole before the correction is written, so XLA:TPU makes of
+    this a read pass and a read-and-write pass over the state."""
+    kq = jnp.stack([k, q], axis=2) * alpha[:, :, None, :]
+    seen = jnp.sum(state[:, :, None] * kq[..., None], axis=-2)
+    u = beta[..., None] * (v - seen[:, :, 0])
+    o = seen[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
+    new = state * alpha[..., None] + k[..., None] * u[..., None, :]
+    return o, jnp.where(active[:, None, None, None], new, state)
+
+
+def _state_step(state, *rest):
+    """The step by the platform the program is lowered for: on a TPU
+    the one-pass kernel, where its tiling takes the state's shape (a
+    head of a multiple of 128 channels, heads by the eight); anywhere
+    else, and for any other shape, :func:`state_step`."""
+    if not kda_step_tiles(state.shape):
+        return state_step(state, *rest)
+    return jax.lax.platform_dependent(
+        state, *rest, tpu=kda_state_step, default=state_step)
+
+
 def kda_decode(a: KdaKind, ap: dict, h: jax.Array, state: jax.Array,
                 tail: jax.Array, active: jax.Array, eps: float, dt):
     """One recurrent step for every lane: h (B, 1, d), ``state`` (B, H,
@@ -101,21 +136,8 @@ def kda_decode(a: KdaKind, ap: dict, h: jax.Array, state: jax.Array,
         new_tail = jnp.where(active[:, None, None], window[:, 1:], tail)
         q, k, v = _kda_qkv(a, conved)           # (B, H, hd)
     with jax.named_scope("kda.state"):
-        # Products with the state are multiply-and-sum, not dots: a
-        # float32 dot runs in bfloat16 passes on the chip by default.
-        # S^T k and S^T q of the decayed state S = Diag(alpha) state in
-        # one reduction over the state as it lies, the decay folded
-        # into the two vectors: one pass gives both. o = S'^T q with
-        # S' = S + k u^T is S^T q + (k . q) u. (Reduced off a decayed
-        # copy, XLA:TPU writes that copy out whole or reads the state
-        # once a product: a GiB a layer either way.)
-        alpha = jnp.exp(g[:, 0])
-        kq = jnp.stack([k, q], axis=2) * alpha[:, :, None, :]
-        seen = jnp.sum(state[:, :, None] * kq[..., None], axis=-2)
-        u = beta[:, 0, :, None] * (v - seen[:, :, 0])
-        o = seen[:, :, 1] + jnp.sum(k * q, axis=-1, keepdims=True) * u
-        new = state * alpha[..., None] + k[..., None] * u[..., None, :]
-        new = jnp.where(active[:, None, None, None], new, state)
+        o, new = _state_step(state, jnp.exp(g[:, 0]), k, q, v, beta[:, 0],
+                             active)
     return _kda_out(a, ap, o[:, None], gate, eps, dt), new, new_tail
 
 

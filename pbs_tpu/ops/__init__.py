@@ -1,4 +1,5 @@
 from pbs_tpu.ops.attention import flash_attention
+from pbs_tpu.ops.kda_step import kda_state_step
 from pbs_tpu.ops.matmul import (
     MatmulStats,
     instrumented_matmul,
@@ -9,5 +10,6 @@ __all__ = [
     "MatmulStats",
     "flash_attention",
     "instrumented_matmul",
+    "kda_state_step",
     "scale_stats",
 ]

@@ -157,6 +157,37 @@ def test_bfloat16_in_place_of_float32_fails_the_tolerance():
     assert worst_gap(got, want) > 50 * TOL
 
 
+@pytest.mark.parametrize("dtype,holds", [("float32", True),
+                                         ("bfloat16", False)])
+def test_the_kernel_in_the_decode_step_holds_the_same_tolerance(
+        monkeypatch, dtype, holds):
+    """The one-pass kernel (``ops/kda_step.py``, interpreted: these
+    heads of 16 channels are under its tiling on a chip) put where a
+    TPU lowering has it: the float32 engine agrees with the full
+    forward as it does through the ``jax.numpy`` step, beside idle
+    lanes too, and bfloat16 weights still miss by two orders."""
+    from pbs_tpu.models import kda
+    from pbs_tpu.ops.kda_step import kda_state_step
+
+    stepped = []
+
+    def step(state, *rest):
+        stepped.append(state.shape)
+        return kda_state_step(state, *rest, interpret=True)
+
+    monkeypatch.setattr(kda, "_state_step", step)
+    monkeypatch.setitem(globals(), "program", functools.lru_cache(
+        maxsize=None)(program.__wrapped__))
+    tokens, want = tokens_and_reference()
+    if holds:
+        got = served_logits(dtype, tokens, (3, 17, BUCKET), (0, 3, 7), ROW)
+        assert worst_gap(got, want) < TOL
+    else:
+        got = served_logits(dtype, tokens, [3, 7, 11], (0, 0, 0), 18)
+        assert worst_gap(got, want) > 50 * TOL
+    assert stepped == [(SLOTS, 4, 16, 16)] * 3      # a trace a layer
+
+
 # -- the chunked scan against the recurrence ----------------------------------
 
 

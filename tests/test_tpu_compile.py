@@ -7,6 +7,8 @@ process at a time may load libtpu, and every pytest-xdist worker
 imports every test file. All such compiles live in this one file, so
 one worker loads the library."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -145,9 +147,14 @@ def test_a_recurrent_state_is_updated_where_it_lies(topo, case):
     beyond its arguments the decode step needs less than half of the
     layer's state (a second copy of it would be the whole) and the
     ingestion a chunk's pairwise decays (64 heads x 64 x 64 positions x
-    128 channels, 128 MiB) and no more, whatever the slots; no
-    instruction outside a fusion writes a tensor of the state's
-    size."""
+    128 channels, 128 MiB) and no more, whatever the slots. **The
+    decode step passes over the state once**: one instruction of the
+    program takes or gives a tensor of the state's size, it is the
+    Mosaic kernel ``kda_state_step`` under ``attn.kda/kda.state`` (the
+    program is lowered for a TPU, whatever the process's own backend),
+    its result aliased to the state it was given, and no fusion or copy
+    of that size stands before or behind it. The ingestion's one writer
+    is a fusion, as it was."""
     one = SingleDeviceSharding(topo.devices[0])
     lay = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
@@ -175,11 +182,26 @@ def test_a_recurrent_state_is_updated_where_it_lies(topo, case):
     assert m.temp_size_in_bytes + m.output_size_in_bytes \
         - m.alias_size_in_bytes < (state // 2 if case == "decode"
                                    else 160 << 20)
-    moved = written(materialised(compiled.as_text()),
-                    {(KDA_SLOTS, 64, 128, 128)})
-    # the one writer is the update itself, fused with its arithmetic
-    assert all(op[1] == "fusion" for op in moved), moved
-    assert len(moved) <= 1, moved
+    hlo = compiled.as_text()
+    moved = written(materialised(hlo), {(KDA_SLOTS, 64, 128, 128)})
+    if case == "ingest":
+        # the one writer is the update itself, fused with its arithmetic
+        assert all(op[1] == "fusion" for op in moved), moved
+        assert len(moved) <= 1, moved
+        return
+    assert not moved, moved         # array-valued: a fusion, a copy
+    size = f"f32[{KDA_SLOTS},64,128,128]"
+    entry = hlo[hlo.index("ENTRY "):]
+    touching = [ln for ln in entry.splitlines()[1:]
+                if size in ln and not re.search(
+                    r" (parameter|get-tuple-element|tuple|bitcast)\(", ln)]
+    assert len(touching) == 1, [ln[:160] for ln in touching]
+    kernel, = touching
+    assert 'custom_call_target="tpu_custom_call"' in kernel
+    assert re.search(r'op_name="[^"]*/attn\.kda/kda\.state/[^"]*'
+                     r'kda_state_step', kernel), kernel[:300]
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(\d+, \{\}\)\}",
+                     kernel)
 
 
 def test_materialised_leaves_out_fused_computations():

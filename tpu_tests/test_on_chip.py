@@ -430,3 +430,80 @@ def test_decode_reads_attention_projections_where_they_lie_in_the_stack():
                        tuple(sorted((d, heads, hd)))}
     moved = written(ops, weightlike)
     assert not moved, moved
+
+
+def test_kda_state_step_compiled_at_the_cell():
+    """The one-pass recurrent step (``ops/kda_step.py``) compiled
+    through Mosaic at the KDA cell's size, a layer's (256, 64, 128,
+    128) float32 state: eight sampled tiles against the delta rule in
+    float64 on the host (1e-5 of the tile's largest entry: float32
+    products, whatever unit makes them), the idle lanes' state bit for
+    bit; then three layers of it, donated, 250 of 256 lanes active,
+    timed beside the ``jax.numpy`` step XLA makes two passes of (PERF.md
+    section 6, PR 36)."""
+    import time
+
+    from pbs_tpu.models.kda import state_step
+    from pbs_tpu.ops.kda_step import kda_state_step
+
+    B, H, hd, layers = 256, 64, 128, 3
+    f32 = jnp.float32
+
+    def inputs(seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        q, k, v = (jax.random.normal(kk, (B, H, hd), f32) for kk in ks[:3])
+        unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa
+        g = -jnp.exp(jax.random.uniform(ks[3], (B, H, hd), f32,
+                                        np.log(1e-3), np.log(30.0)))
+        return (jnp.exp(g), unit(k), unit(q) * hd ** -0.5, v,
+                jax.random.uniform(ks[4], (B, H), f32, 0.0, 2.0))
+
+    def fresh(seed):
+        return 0.5 * jax.random.normal(jax.random.PRNGKey(seed),
+                                       (B, H, hd, hd), f32)
+
+    active = jnp.arange(B) % 43 != 7                # 250 of 256
+    idle = np.flatnonzero(~np.asarray(active))
+    state, ins = fresh(1), inputs(2)
+    o, new = jax.jit(kda_state_step)(state, *ins, active)
+    assert bool(jnp.array_equal(
+        jax.lax.bitcast_convert_type(new[idle], jnp.uint32),
+        jax.lax.bitcast_convert_type(state[idle], jnp.uint32)))
+    rng = np.random.default_rng(0)
+    live = np.flatnonzero(np.asarray(active))
+    worst = 0.0
+    for b, h in zip(rng.choice(live, 8), rng.integers(0, H, 8)):
+        alpha, k, q, v = (np.asarray(t[b, h], np.float64) for t in ins[:4])
+        s = np.asarray(state[b, h], np.float64) * alpha[:, None]
+        s = s + float(ins[4][b, h]) * np.outer(k, v - s.T @ k)
+        for got, want in ((new[b, h], s), (o[b, h], s.T @ q)):
+            gap = np.abs(np.asarray(got, np.float64) - want).max() \
+                / np.abs(want).max()
+            worst = max(worst, float(gap))
+    print(f"kda_state_step against float64, eight tiles: {worst:.2e}")
+    assert worst < 1e-5, worst
+    del state, new, o
+
+    def three(step):
+        def run(states, ins, active):
+            outs = [step(s, *i, active) for s, i in zip(states, ins)]
+            return [o for o, _ in outs], [s for _, s in outs]
+        return jax.jit(run, donate_argnums=(0,))
+
+    ins = [inputs(10 + layer) for layer in range(layers)]
+    ms = {}
+    for name, step in (("kda_state_step", kda_state_step),
+                       ("jax.numpy step", state_step)):
+        fn = three(step)
+        states = [fresh(20 + layer) for layer in range(layers)]
+        _, states = fn(states, ins, active)          # compile, warm
+        jax.block_until_ready(states)
+        t0 = time.perf_counter()
+        for _ in range(20):
+            _, states = fn(states, ins, active)
+        jax.block_until_ready(states)
+        ms[name] = (time.perf_counter() - t0) / 20 * 1e3
+        del states
+    print("three layers' recurrent step at (256, 64, 128, 128), ms a call: "
+          + ", ".join(f"{n} {t:.2f}" for n, t in ms.items()))
+    assert ms["kda_state_step"] < ms["jax.numpy step"]
