@@ -14,10 +14,13 @@ a buffer of its own (stacked by kind, XLA:TPU copies a layer's slice out
 of the stack before each use: 768 MB a routed-expert weight, every
 tick)::
 
-    embed, final_norm, head
+    embed, final_norm[, head]           (no head: the embedding is tied)
     blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]}        softmax
     blocks/<NN>/attn/{attn_norm, wq, wk, wv, cq, ck, cv, wa1, wa2,
                       a_log, dt_bias, wb, wg1, wg2, o_norm, wo}  delta rule
+    blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, w_x, dt_norm,
+                      b_norm, c_norm, w_dt, dt_bias, a_log, d_skip,
+                      w_out}                                  state space
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
     blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
                      [, ws1, ws3, ws2]}
@@ -98,6 +101,25 @@ class KdaKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class MambaKind:
+    """A selective state-space layer (Mamba-1, as ``JambaMambaMixer``
+    writes it: RMSNorm on the step, ``B`` and ``C``): no keys and
+    values are kept; a request's state is one float32 ``(d_state,
+    d_inner)`` matrix, every entry decayed by a factor of its own each
+    token (``exp(dt[c] A[c, n])``: no matrix product moves it), and the
+    last ``conv - 1`` inputs of the short causal convolution
+    (``models/mamba.py``). The state and ``a_log`` lie ``d_state``
+    first, so that the 5120 channels and not the 16 states fill a
+    vector register's 128 lanes and a tile of HBM."""
+
+    name: str
+    d_inner: int
+    d_state: int
+    dt_rank: int
+    conv: int = 4
+
+
+@dataclasses.dataclass(frozen=True)
 class MlpKind:
     """``n_experts`` 0 is a dense SwiGLU of width ``d_ff``. Otherwise
     ``d_ff`` is one routed expert's width, the router scores all
@@ -120,7 +142,7 @@ class MlpKind:
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    attn: tuple[AttnKind | KdaKind, ...]
+    attn: tuple[AttnKind | KdaKind | MambaKind, ...]
     mlp: tuple[MlpKind, ...]
     layers: tuple[tuple[int, int], ...]  # per layer: (attn i, mlp i)
 
@@ -130,9 +152,10 @@ class LayerPlan:
 
     @property
     def recurrent(self) -> bool:
-        """Some layer keeps a state that every token is folded into,
-        not keys and values a cursor can mask."""
-        return any(isinstance(self.attn[a], KdaKind)
+        """Some layer (delta-rule or state-space) keeps a state that
+        every token is folded into, not keys and values a cursor can
+        mask."""
+        return any(isinstance(self.attn[a], (KdaKind, MambaKind))
                    for a, _ in self.layers)
 
     def kinds(self, layer: int) -> tuple[AttnKind, MlpKind]:
@@ -202,8 +225,9 @@ def plan_shapes(cfg) -> dict:
     docstring), as nested dicts of tuples."""
     plan = plan_of(cfg)
     d, hd, nkv = cfg.d_model, cfg.head_dim, cfg.n_kv_heads
-    out: dict = {"embed": (cfg.vocab, d), "final_norm": (d,),
-                 "head": (d, cfg.vocab), "blocks": {}}
+    out: dict = {"embed": (cfg.vocab, d), "final_norm": (d,), "blocks": {}}
+    if not cfg.tie_embeddings:
+        out["head"] = (d, cfg.vocab)
     for layer in range(len(plan.layers)):
         a, m = plan.kinds(layer)
         if isinstance(a, KdaKind):
@@ -214,6 +238,14 @@ def plan_shapes(cfg) -> dict:
                     "a_log": (a.n_heads,), "dt_bias": (w,),
                     "wb": (d, a.n_heads), "wg1": (d, r), "wg2": (r, w),
                     "o_norm": (a.head_dim,), "wo": (w, d)}
+        elif isinstance(a, MambaKind):
+            c, n, r = a.d_inner, a.d_state, a.dt_rank
+            attn = {"attn_norm": (d,), "w_in": (d, 2 * c),
+                    "conv_w": (a.conv, c), "conv_b": (c,),
+                    "w_x": (c, r + 2 * n), "dt_norm": (r,),
+                    "b_norm": (n,), "c_norm": (n,), "w_dt": (r, c),
+                    "dt_bias": (c,), "a_log": (n, c), "d_skip": (c,),
+                    "w_out": (c, d)}
         else:
             attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
                     "wk": (d, nkv * hd), "wv": (d, nkv * hd),
@@ -243,7 +275,10 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
     at one, the embedding scaled by sqrt(d) as ``init_params`` has it.
     A delta-rule layer's ``a_log`` is the log of uniform(1, 16) and its
     ``dt_bias`` the inverse softplus of a step in [0.001, 0.1] (the
-    published layer's own start), the router's bias a small normal."""
+    published layer's own start), the router's bias a small normal. A
+    state-space layer starts as Mamba does: ``a_log`` the log of 1 ..
+    ``d_state`` a channel, the same ``dt_bias``, ``d_skip`` one, the
+    convolution's filter and bias uniform in +-1/2."""
     shapes = plan_shapes(cfg)
     flat, treedef = jax.tree.flatten(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
@@ -256,9 +291,20 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
         if name.endswith("norm"):
             leaves.append(jnp.ones(shape, jnp.float32))
             continue
+        if name == "a_log" and len(shape) == 2:
+            leaves.append(jnp.log(jnp.broadcast_to(jnp.arange(
+                1, shape[0] + 1, dtype=jnp.float32)[:, None], shape)))
+            continue
         if name == "a_log":
             leaves.append(jnp.log(jax.random.uniform(
                 k, shape, jnp.float32, 1.0, 16.0)))
+            continue
+        if name == "d_skip":
+            leaves.append(jnp.ones(shape, jnp.float32))
+            continue
+        if name in ("conv_w", "conv_b"):
+            leaves.append(jax.random.uniform(k, shape, jnp.float32,
+                                             -0.5, 0.5))
             continue
         if name == "dt_bias":
             dt = jnp.exp(jax.random.uniform(

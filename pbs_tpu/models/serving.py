@@ -46,11 +46,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from pbs_tpu.models.kda import kda_decode, kda_ingest
+from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
 from pbs_tpu.obs.trace import Ev, TraceBuffer, host_ring, register_ring
 from pbs_tpu.models.plan import (
-    KdaKind, block_name, init_plan_params, plan_of, rope_table,
+    KdaKind, MambaKind, block_name, init_plan_params, plan_of, rope_table,
     uniform_plan)
 from pbs_tpu.models.transformer import (
     TransformerConfig,
@@ -271,29 +272,44 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     delta-rule layer keeps no positions at all but ``state``, one
     float32 ``(hd, hd)`` matrix a head, ``(slots, H, hd, hd)``, and
     ``conv``, the last ``kernel - 1`` inputs of its short convolution
-    (q, k and v side by side), ``(slots, kernel - 1, 3 * H * hd)``. One
-    cursor a slot serves all: which ring entries are live follows from
-    it alone, and a state needs none. ``state`` and ``conv`` are there
-    only where some layer has them."""
+    (q, k and v side by side), ``(slots, kernel - 1, 3 * H * hd)``; a
+    state-space layer keeps ``ssm``, one float32 ``(slots, d_state,
+    d_inner)`` matrix (the channels last, where they fill the lanes),
+    and its own ``conv``, ``(slots, kernel - 1, d_inner)``. One cursor
+    a slot serves all: which ring entries are live follows from it
+    alone, and a state needs none. ``state``, ``ssm`` and ``conv`` are
+    there only where some layer has them."""
     plan = plan_of(cfg)
     out: dict = {"k": {}, "v": {},
                  "pos": jnp.zeros((n_slots,), jnp.int32)}
-    if plan.recurrent:
-        out["state"], out["conv"] = {}, {}
     for layer in range(len(plan.layers)):
         a, _ = plan.kinds(layer)
         name = block_name(layer)
         if isinstance(a, KdaKind):
-            out["state"][name] = jnp.zeros(
+            out.setdefault("state", {})[name] = jnp.zeros(
                 (n_slots, a.n_heads, a.head_dim, a.head_dim), jnp.float32)
-            out["conv"][name] = jnp.zeros(
+            out.setdefault("conv", {})[name] = jnp.zeros(
                 (n_slots, a.conv - 1, 3 * a.n_heads * a.head_dim), cfg.dtype)
+            continue
+        if isinstance(a, MambaKind):
+            out.setdefault("ssm", {})[name] = jnp.zeros(
+                (n_slots, a.d_state, a.d_inner), jnp.float32)
+            out.setdefault("conv", {})[name] = jnp.zeros(
+                (n_slots, a.conv - 1, a.d_inner), cfg.dtype)
             continue
         for kv in ("k", "v"):
             out[kv][name] = jnp.zeros(
                 (n_slots, min(a.window, max_len) if a.window else max_len,
                  cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
     return out
+
+
+#: A layer kind that keeps a recurrent state, not positions: the scope
+#: its ops carry, the cache entry that holds the state (its tail is
+#: ``conv``), its decode step and its prompt ingestion.
+_RECURRENT = {
+    KdaKind: ("attn.kda", "state", kda_decode, kda_ingest),
+    MambaKind: ("attn.mamba", "ssm", mamba_decode, mamba_ingest)}
 
 
 def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
@@ -306,14 +322,14 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     ``slot`` None is the decode tick: S == 1, row b at position
     ``row_pos[b]``; each layer writes its one new position (full: at
     the cursor; window: at cursor mod W, rotary already applied) and
-    attends over its cache; a delta-rule layer takes one recurrent step
-    in every lane that ``valid`` marks and leaves the others' state as
-    it was. With a ``slot`` it is the ingestion of one prompt from
-    position 0 (B == 1): attention stays inside the prompt (banded in a
-    window layer) and the layer leaves the prompt's keys and values in
-    that slot (a window layer its last W positions, each where the ring
-    keeps it); a delta-rule layer leaves the prompt's state, built from
-    zero, over whatever the slot held.
+    attends over its cache; a delta-rule or state-space layer takes one
+    recurrent step in every lane that ``valid`` marks and leaves the
+    others' state as it was. With a ``slot`` it is the ingestion of one
+    prompt from position 0 (B == 1): attention stays inside the prompt
+    (banded in a window layer) and the layer leaves the prompt's keys
+    and values in that slot (a window layer its last W positions, each
+    where the ring keeps it); a delta-rule or state-space layer leaves
+    the prompt's state, built from zero, over whatever the slot held.
 
     ``valid`` (B, S) marks real tokens: the expert layers route nothing
     else, and no state folds anything else in. Returns (logits fp32:
@@ -350,18 +366,19 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         name = block_name(layer)
         ap, mp = params["blocks"][name]["attn"], params["blocks"][name]["mlp"]
         h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
-        if isinstance(a, KdaKind):
-            with jax.named_scope("attn.kda"):
+        if type(a) in _RECURRENT:
+            scope, key, step, ingest = _RECURRENT[type(a)]
+            with jax.named_scope(scope):
                 if decode:
-                    y, state, tail = kda_decode(
-                        a, ap, h, new["state"][name], new["conv"][name],
+                    y, new[key][name], new["conv"][name] = step(
+                        a, ap, h, new[key][name], new["conv"][name],
                         valid[:, 0], cfg.norm_eps, dt)
-                    new["state"][name], new["conv"][name] = state, tail
                 else:
-                    y, state, tail = kda_ingest(a, ap, h, valid,
-                                                 cfg.norm_eps, dt)
-                    new["state"][name] = jax.lax.dynamic_update_slice(
-                        new["state"][name], state, (slot, 0, 0, 0))
+                    y, state, tail = ingest(a, ap, h, valid, cfg.norm_eps,
+                                            dt)
+                    new[key][name] = jax.lax.dynamic_update_slice(
+                        new[key][name], state,
+                        (slot,) + (0,) * (state.ndim - 1))
                     new["conv"][name] = jax.lax.dynamic_update_slice(
                         new["conv"][name], tail, (slot, 0, 0))
             x = x + y
@@ -388,7 +405,13 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         x = jax.lax.dynamic_index_in_dim(
             x[0], jnp.maximum(valid.sum() - 1, 0), 0, keepdims=False)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ wload(params["head"], dt)).astype(jnp.float32)
+    if cfg.tie_embeddings:
+        # the embedding read where it lies, rows against rows: no
+        # (d, vocab) copy of it is made
+        logits = jnp.einsum("...d,vd->...v", x, wload(params["embed"], dt))
+    else:
+        logits = x @ wload(params["head"], dt)
+    logits = logits.astype(jnp.float32)
     route = jnp.concatenate(
         [valid.sum().astype(jnp.int32)[None], counts]) \
         if plan.routed else None
@@ -504,9 +527,10 @@ class _PlannedProgram:
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
         self.no_windows = (
-            "a delta-rule layer keeps one recurrent state a slot, not "
-            "positions: a prefix hit or a verify window would need a "
-            "snapshot of that state at the window's end (ROADMAP R6)"
+            "a delta-rule or state-space layer keeps one recurrent state "
+            "a slot, not positions: a prefix hit or a verify window would "
+            "need a snapshot of that state at the window's end (ROADMAP "
+            "R6, R23)"
             if plan_of(cfg).recurrent else
             "a window layer's ring takes one position a tick, and "
             "cutting a window from it or installing one is not written "
@@ -526,7 +550,7 @@ class _PlannedProgram:
                 f"a planned layer stack serves on one device, not on a "
                 f"mesh of {dict(mesh.shape)}: neither the window ring's, "
                 f"the recurrent state's nor the held experts' division "
-                f"over a tensor axis is written (ROADMAP R4, R6)")
+                f"over a tensor axis is written (ROADMAP R4, R6, R23)")
         return jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
 
     def decode(self, params, cache, last_tok, active):
@@ -556,8 +580,9 @@ def slot_program(cfg: TransformerConfig, mlp_fn=None):
     ``(logits, cache, mlp extra, route)``, and whether its caches take
     windows of positions (``windows``). A configuration whose layers
     are all alike, said by its widths or by a plan, gets the stacked
-    tree and the layer scan it always had."""
-    if plan_of(cfg) == uniform_plan(cfg):
+    tree and the layer scan it always had (with an untied head: a tied
+    one is the planned program's to read)."""
+    if plan_of(cfg) == uniform_plan(cfg) and not cfg.tie_embeddings:
         return _ScanProgram(cfg, mlp_fn)
     if mlp_fn is not None:
         raise ValueError("a planned layer stack names its own MLP kinds; "

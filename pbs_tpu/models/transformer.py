@@ -78,6 +78,11 @@ class TransformerConfig:
     # layer the widths above describe. Read by the serving engine; the
     # training step runs uniform configurations only.
     layer_plan: Any = None
+    # The logits are ``h @ embed.T`` and the tree has no ``head``. Read
+    # by the serving engine, which then serves through the planned
+    # stack's program (``models.serving.slot_program``); the training
+    # step keeps its untied head.
+    tie_embeddings: bool = False
 
     @property
     def head_dim(self) -> int:

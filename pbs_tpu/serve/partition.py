@@ -53,7 +53,8 @@ SpecEntry = Any
 #: positionally: ``-1`` = the innermost (tensor) mesh axis.
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
-    (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm)$", ()),
+    (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm)$",
+     ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -87,6 +88,18 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     # are a few hundred columns and replicated.
     (r"/(c[qkv]|wb)$", (None, -1)),
     (r"/(wa[12]|wg[12]|a_log|dt_bias)$", ()),
+    # A state-space layer (models/plan.MambaKind): what it holds lies
+    # along ``d_inner``, the axis its state would be divided on: the
+    # in-projection, the filter and the step's projection by column,
+    # the two products that contract over it (``w_x``, ``w_out``) by
+    # row, the bias and the skip by their one axis. Its ``a_log``
+    # (d_state, d_inner) and ``dt_bias`` go by the delta-rule layer's
+    # rule above (a rule reads a path, not a rank, and there they are
+    # one entry a head or a channel): replicated, 340 KB a layer; the
+    # step's, B's and C's small norms by the norms' rule.
+    (r"/(w_in|conv_w|w_dt)$", (None, -1)),
+    (r"/(w_x|w_out)$", (-1, None)),
+    (r"/(conv_b|d_skip)$", (-1,)),
 )
 
 #: The canonical param paths the table must cover (the dense
@@ -147,6 +160,18 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/attn/wg1",
     "blocks/N/attn/wg2",
     "blocks/N/attn/o_norm",
+    # a state-space layer's own leaves (its attn_norm, a_log and
+    # dt_bias are the paths above)
+    "blocks/N/attn/w_in",
+    "blocks/N/attn/conv_w",
+    "blocks/N/attn/conv_b",
+    "blocks/N/attn/w_x",
+    "blocks/N/attn/dt_norm",
+    "blocks/N/attn/b_norm",
+    "blocks/N/attn/c_norm",
+    "blocks/N/attn/w_dt",
+    "blocks/N/attn/d_skip",
+    "blocks/N/attn/w_out",
 )
 
 

@@ -113,13 +113,17 @@ def test_lockprof_counts_acquires_and_contention():
         pass
     assert lk.stats.acquires == 1 and lk.stats.contended == 0
 
+    held = threading.Event()
+
     def _holder():
         with lk:
+            held.set()
             time.sleep(0.02)
 
     t = threading.Thread(target=_holder)
     t.start()
-    time.sleep(0.005)
+    # not a sleep: on a loaded host the holder may not have run yet
+    assert held.wait(timeout=10)
     with lk:  # must block on the holder
         pass
     t.join()

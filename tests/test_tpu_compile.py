@@ -204,6 +204,78 @@ def test_a_recurrent_state_is_updated_where_it_lies(topo, case):
                      kernel)
 
 
+# One state-space layer and one multi-query attention layer at
+# AI21-Jamba2-3B's published widths (hidden 2560, d_inner 5120, d_state
+# 16, dt_rank 160, kernel 4; 20 query heads on one KV head of 128) over
+# a small dense MLP, under the published vocabulary's tied embedding:
+# what is checked is the layer's state and the head.
+SSM_CFG = TransformerConfig(
+    vocab=65536, d_model=2560, n_layers=2, n_heads=20, n_kv_heads=1,
+    d_ff=256, max_seq=2560, norm_eps=1e-6, dtype=jnp.bfloat16,
+    head_size=128, tie_embeddings=True,
+    layer_plan=P.LayerPlan(
+        (P.MambaKind("mamba", 5120, 16, 160, conv=4),
+         P.AttnKind("full", 20, None, None)),
+        (P.MlpKind("dense", 256),), ((0, 0), (1, 0))))
+SSM_SLOTS = 64
+
+
+@pytest.mark.parametrize("case", ["decode", "ingest"])
+def test_a_state_space_layer_at_published_widths(topo, case):
+    """The decode step over every lane and the ingestion of a 2048-row
+    prompt (the scan's two loops, the 2048 x 2048 scores of 20 heads)
+    compile for the chip, donate the cache and write the state in
+    place: beyond its arguments and the logits it returns the decode
+    step needs less than a quarter of the layer's state, and no
+    instruction but a fusion
+    writes a tensor of the state's size (a copy would be a second pass
+    over it). **The tied head reads the embedding where it lies**: no
+    instruction writes a tensor of the embedding's size, transposed or
+    not. The ingestion holds a step's ``(chunks, d_state, d_inner)`` and
+    a chunk's ``(chunk, d_state, d_inner)``, never the prompt's 671 MB
+    of decays."""
+    one = SingleDeviceSharding(topo.devices[0])
+    lay = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    prog = slot_program(SSM_CFG)
+    params = lay(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.float32 if x.ndim < 2 or x.shape[0] == 16
+                           else SSM_CFG.dtype),
+        prog.init_params(jax.random.PRNGKey(0)))))
+    assert "head" not in params
+    cache = lay(jax.eval_shape(
+        lambda: prog.init_cache(SSM_SLOTS, SSM_CFG.max_seq)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one)
+    if case == "decode":
+        fn = lambda p, c, tok, active: prog.decode(  # noqa: E731
+            p, c, tok, active)[:2]
+        args = (params, cache, i32(SSM_SLOTS), jax.ShapeDtypeStruct(
+            (SSM_SLOTS,), bool, sharding=one))
+    else:
+        fn = lambda p, c, slot, prompt, plen: prog.ingest(  # noqa: E731
+            p, c, slot, prompt, plen)[:2]
+        args = (params, cache, i32(), i32(2048), i32())
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    state = SSM_SLOTS * 16 * 5120 * 4
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= state
+    beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes
+    # ingestion: 320 MiB of float32 scores, the in-projection's output,
+    # a handful of (2048, 5120) float32 rows; not 671 MB of decays twice
+    logits = SSM_SLOTS * SSM_CFG.vocab * 4
+    assert beyond < (logits + state // 4 if case == "decode"
+                     else 900 << 20)
+    ops = materialised(compiled.as_text())
+    assert not written(ops, {(2560, 65536)})
+    moved = written(ops, {(16, SSM_SLOTS, 5120)})
+    assert all(op[1] in ("fusion", "dynamic-update-slice")
+               for op in moved), moved
+    assert len(moved) <= 2, moved
+    assert not written(ops, {(16, 2048, 5120)})     # (T, d_state, d_inner)
+
+
 def test_materialised_leaves_out_fused_computations():
     hlo = """HloModule m
 
