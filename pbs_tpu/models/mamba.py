@@ -21,7 +21,8 @@ bytes).
 Three programs of the engine (``models/serving.py``) in the shape of
 ``models/kda.py``: :func:`mamba_decode`, one recurrent step for every
 lane of a decode tick; :func:`mamba_ingest`, a whole prompt from a zero
-state by :func:`mamba_scan`. A state has no cursor to mask what was
+state by :func:`mamba_scan` or, lowered for a TPU, by the one-pass
+kernel of ``ops/mamba_scan.py``. A state has no cursor to mask what was
 folded into it, so they keep two invariants: **padding and idle lanes
 are no-ops** (a padded position enters with ``dt`` 0: decay 1, input 0;
 an inactive lane's state and tail come out bit for bit) and **ingestion
@@ -36,6 +37,7 @@ import jax.numpy as jnp
 from pbs_tpu.models.plan import MambaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
+from pbs_tpu.ops.mamba_scan import mamba_prompt_scan, mamba_scan_tiles
 
 __all__ = ["MAMBA_CHUNK", "mamba_decode", "mamba_ingest", "mamba_scan"]
 
@@ -43,9 +45,10 @@ __all__ = ["MAMBA_CHUNK", "mamba_decode", "mamba_ingest", "mamba_scan"]
 #: token loop is this long whatever the prompt's rung.
 MAMBA_CHUNK = 64
 #: Steps of that loop XLA sees as one body: each writes its rows of the
-#: scan's output, and XLA:TPU writes sixteen steps' rows in one piece
-#: faster than one step's sixteen times (8 and 32 read no better than 1:
-#: PERF.md section 6, PR 37).
+#: scan's output, and sixteen steps' rows go in one piece. It shapes what
+#: a CPU runs and nothing on a chip, where the prompt's scan is
+#: ``ops/mamba_scan.py`` (when PR 37 ran this loop there, sixteen read
+#: 3.2 ms a layer where one read 4.7: PERF.md section 6).
 MAMBA_UNROLL = 16
 _F32 = jnp.float32
 
@@ -168,6 +171,25 @@ def mamba_scan(x: jax.Array, step: jax.Array, bm: jax.Array, cm: jax.Array,
     return y.reshape(K * L, C)[:S], h_end
 
 
+# One trace and one lowered function a rung, whatever the layers: a
+# program's call sites are unrolled in Python, and a Pallas kernel is
+# lowered to its Mosaic module in Python at every start.
+_kernel_scan = jax.jit(mamba_prompt_scan)
+
+
+def _scan(x, step, bm, cm, a_log, plen):
+    """The prompt's scan by the platform the program is lowered for: on
+    a TPU the one-pass kernel, where its tiling takes the shape
+    (channels by the 128, positions by the block); anywhere else, and
+    for any other shape, :func:`mamba_scan`. ``plen``, the prompt's
+    real length, lets the kernel pass over blocks of padding."""
+    if not mamba_scan_tiles(x.shape + a_log.shape[:1]):
+        return mamba_scan(x, step, bm, cm, a_log)
+    return jax.lax.platform_dependent(
+        x, step, bm, cm, a_log, plen, tpu=_kernel_scan,
+        default=lambda *args: mamba_scan(*args[:-1]))
+
+
 def mamba_ingest(a: MambaKind, ap: dict, h: jax.Array, valid: jax.Array,
                  eps: float, dt):
     """One prompt's pass through a state-space layer, **from a zero
@@ -189,6 +211,6 @@ def mamba_ingest(a: MambaKind, ap: dict, h: jax.Array, valid: jax.Array,
         tail = jax.lax.dynamic_slice_in_dim(padded, plen, taps - 1)[None]
     step, bm, cm = _selective(a, ap, x, eps, dt)
     with jax.named_scope("mamba.scan"):
-        y, state = mamba_scan(x, jnp.where(valid[0][:, None], step, 0.0),
-                              bm, cm, ap["a_log"].astype(_F32))
+        y, state = _scan(x, jnp.where(valid[0][:, None], step, 0.0),
+                         bm, cm, ap["a_log"].astype(_F32), plen)
     return _out_proj(ap, y, x, z[0], dt)[None], state[None], tail

@@ -1,5 +1,6 @@
 from pbs_tpu.ops.attention import flash_attention
 from pbs_tpu.ops.kda_step import kda_state_step
+from pbs_tpu.ops.mamba_scan import mamba_prompt_scan
 from pbs_tpu.ops.matmul import (
     MatmulStats,
     instrumented_matmul,
@@ -11,5 +12,6 @@ __all__ = [
     "flash_attention",
     "instrumented_matmul",
     "kda_state_step",
+    "mamba_prompt_scan",
     "scale_stats",
 ]

@@ -165,6 +165,37 @@ def test_a_bfloat16_state_fails_the_tolerance():
     assert worst_gap(got, rounded) > 30 * TOL
 
 
+@pytest.mark.parametrize("against,holds", [("float32", True),
+                                           ("bfloat16-state", False)])
+def test_the_kernel_in_the_prompt_scan_holds_the_same_tolerance(
+        monkeypatch, against, holds):
+    """The one-pass kernel (``ops/mamba_scan.py``, interpreted: these 64
+    channels are under its tiling on a chip) put where a TPU lowering
+    has it: prefill-then-decode agrees with the full forward as it does
+    through the ``jax.numpy`` scan, lanes admitted one after another,
+    and the reference whose state is rounded to bfloat16 between tokens
+    still lies 30 x the tolerance away."""
+    from pbs_tpu.models import mamba
+    from pbs_tpu.ops.mamba_scan import mamba_prompt_scan
+
+    scanned = []
+
+    def scan(x, *rest):
+        scanned.append(x.shape)
+        return mamba_prompt_scan(x, *rest, interpret=True)
+
+    monkeypatch.setattr(mamba, "_scan", scan)
+    monkeypatch.setitem(globals(), "program", functools.lru_cache(
+        maxsize=None)(program.__wrapped__))
+    tokens, want = tokens_and_reference(quant=False if holds else "state")
+    got = served_logits("float32", tokens, (3, 17, BUCKET), (0, 3, 7), ROW)
+    if holds:
+        assert worst_gap(got, want) < TOL
+    else:
+        assert worst_gap(got, want) > 30 * TOL
+    assert scanned == [(BUCKET, 64)] * 3            # a trace a layer
+
+
 def test_bfloat16_in_place_of_float32_fails_the_tolerance():
     tokens, want = tokens_and_reference()
     got = served_logits("bfloat16", tokens, [3, 7, 11], (0, 0, 0), 18)

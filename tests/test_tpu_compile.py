@@ -7,6 +7,7 @@ process at a time may load libtpu, and every pytest-xdist worker
 imports every test file. All such compiles live in this one file, so
 one worker loads the library."""
 
+import dataclasses
 import re
 
 import jax
@@ -231,9 +232,16 @@ def test_a_state_space_layer_at_published_widths(topo, case):
     writes a tensor of the state's size (a copy would be a second pass
     over it). **The tied head reads the embedding where it lies**: no
     instruction writes a tensor of the embedding's size, transposed or
-    not. The ingestion holds a step's ``(chunks, d_state, d_inner)`` and
-    a chunk's ``(chunk, d_state, d_inner)``, never the prompt's 671 MB
-    of decays."""
+    not. **The ingestion's scan is one kernel**: a Mosaic call under
+    ``attn.mamba/mamba.scan`` (the program is lowered for a TPU,
+    whatever the process's own backend) that holds a tile of channels'
+    state in VMEM, so nothing writes a step's ``(chunks, d_state,
+    d_inner)``, a chunk's ``(chunk, d_state, d_inner)`` or the prompt's
+    671 MB of decays, and the program needs less beyond its arguments
+    than the ``jax.numpy`` scan's two loops did. Two state-space
+    layers' call sites share one lowered kernel function: a Pallas
+    kernel is lowered to its Mosaic module in Python at every start,
+    once a rung and not once a layer."""
     one = SingleDeviceSharding(topo.devices[0])
     lay = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
@@ -263,17 +271,43 @@ def test_a_state_space_layer_at_published_widths(topo, case):
     beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
         - m.alias_size_in_bytes
     # ingestion: 320 MiB of float32 scores, the in-projection's output,
-    # a handful of (2048, 5120) float32 rows; not 671 MB of decays twice
+    # a handful of (2048, 5120) float32 rows; no carry of 32 chunks'
+    # states, no stacked output (323 MiB read, and 345 with the
+    # ``jax.numpy`` scan in this place)
     logits = SSM_SLOTS * SSM_CFG.vocab * 4
     assert beyond < (logits + state // 4 if case == "decode"
-                     else 900 << 20)
-    ops = materialised(compiled.as_text())
+                     else 335 << 20), beyond >> 20
+    hlo = compiled.as_text()
+    ops = materialised(hlo)
     assert not written(ops, {(2560, 65536)})
     moved = written(ops, {(16, SSM_SLOTS, 5120)})
     assert all(op[1] in ("fusion", "dynamic-update-slice")
                for op in moved), moved
     assert len(moved) <= 2, moved
     assert not written(ops, {(16, 2048, 5120)})     # (T, d_state, d_inner)
+    if case == "decode":
+        assert "mamba_prompt_scan" not in hlo
+        return
+    assert not written(ops, {(32, 16, 5120), (64, 16, 5120)})
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(kernels) == 1, len(kernels)
+    assert re.search(r'op_name="[^"]*/attn\.mamba/mamba\.scan/[^"]*'
+                     r'mamba_prompt_scan', kernels[0]), kernels[0][:300]
+    twice = dataclasses.replace(SSM_CFG, n_layers=3, layer_plan=P.LayerPlan(
+        SSM_CFG.layer_plan.attn, SSM_CFG.layer_plan.mlp,
+        ((0, 0), (0, 0), (1, 0))))
+    prog = slot_program(twice)
+    shapes = lambda f: lay(jax.eval_shape(f))  # noqa: E731
+    lowered = jax.jit(lambda p, c, slot, prompt, plen: prog.ingest(
+        p, c, slot, prompt, plen)[:2]).lower(
+        shapes(lambda: prog.init_params(jax.random.PRNGKey(0))),
+        shapes(lambda: prog.init_cache(2, twice.max_seq)),
+        i32(), i32(2048), i32()).as_text()
+    assert len(re.findall(r"func\.func private @\w*mamba_prompt_scan",
+                          lowered)) == 1
+    assert len(re.findall(r"call @\w*mamba_prompt_scan", lowered)) == 2
+    assert lowered.count("tpu_custom_call") == 1
 
 
 def test_materialised_leaves_out_fused_computations():

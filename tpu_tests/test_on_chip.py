@@ -507,3 +507,81 @@ def test_kda_state_step_compiled_at_the_cell():
     print("three layers' recurrent step at (256, 64, 128, 128), ms a call: "
           + ", ".join(f"{n} {t:.2f}" for n, t in ms.items()))
     assert ms["kda_state_step"] < ms["jax.numpy step"]
+
+
+def test_mamba_prompt_scan_compiled_at_the_cell():
+    """The one-pass prompt scan (``ops/mamba_scan.py``) compiled through
+    Mosaic at the state-space cell's size, a layer's 2048 positions of
+    5120 channels and 16 states, the last 500 of them padding (``dt``
+    0): sixteen sampled channels against the recurrence in float64 on
+    the host (1e-4 of the largest entry, and the ``jax.numpy`` scan
+    beside it: a slow channel's state is a product of a thousand of the
+    chip's exponentials, each a few float32 roundings off, and both
+    read 2-3e-5 where one step reads 1e-7), the rows of the blocks
+    behind the prompt zeros, and the state and the real rows the same
+    bits at the 2048 rung as the first 1548 positions leave at a rung
+    of their own; then both rungs timed beside the ``jax.numpy`` scan
+    XLA makes two loops of (PERF.md section 6, PR 38)."""
+    import time
+
+    from pbs_tpu.models.mamba import mamba_scan
+    from pbs_tpu.ops.mamba_scan import BLOCK, mamba_prompt_scan
+
+    S, C, N, plen = 2048, 5120, 16, 1548
+    f32 = jnp.float32
+    ks = jax.random.split(jax.random.PRNGKey(38), 4)
+    x = jax.random.normal(ks[0], (S, C), f32)
+    bm, cm = (jax.random.normal(k, (S, N), f32) for k in ks[1:3])
+    dt = jnp.exp(jax.random.uniform(ks[3], (S, C), f32, np.log(1e-3),
+                                    np.log(1e-1)))
+    dt = jnp.where(jnp.arange(S)[:, None] < plen, dt, 0.0)
+    a_log = jnp.log(jnp.broadcast_to(
+        jnp.arange(1, N + 1, dtype=f32)[:, None], (N, C)))
+    kernel = jax.jit(mamba_prompt_scan)
+    y, h = kernel(x, dt, bm, cm, a_log, jnp.int32(plen))
+    behind = -(-plen // BLOCK) * BLOCK
+    assert not bool(y[behind:].any())
+    short = -(-plen // BLOCK) * BLOCK + BLOCK       # a rung of its own
+    y2, h2 = kernel(x[:short], dt[:short], bm[:short], cm[:short], a_log,
+                    jnp.int32(plen))
+    as_bits = lambda t: jax.lax.bitcast_convert_type(t, jnp.uint32)  # noqa
+    assert bool(jnp.array_equal(as_bits(h), as_bits(h2)))
+    assert bool(jnp.array_equal(as_bits(y[:plen]), as_bits(y2[:plen])))
+    cols = np.random.default_rng(0).choice(C, 16, replace=False)
+    xs, dts = (np.asarray(t, np.float64)[:, cols] for t in (x, dt))
+    A = -np.exp(np.asarray(a_log, np.float64))[:, cols]
+    b64, c64 = np.asarray(bm, np.float64), np.asarray(cm, np.float64)
+    state, want = np.zeros((N, 16)), np.zeros((plen, 16))
+    for t in range(plen):
+        state = np.exp(dts[t][None] * A) * state \
+            + (dts[t] * xs[t])[None] * b64[t][:, None]
+        want[t] = (state * c64[t][:, None]).sum(0)
+    y_np, h_np = jax.jit(mamba_scan)(x, dt, bm, cm, a_log)
+    gaps = {}
+    for name, ys, hs in (("mamba_prompt_scan", y, h),
+                         ("jax.numpy scan", y_np, h_np)):
+        gaps[name] = (
+            float(np.abs(np.asarray(ys, np.float64)[:plen, cols]
+                         - want).max() / np.abs(want).max()),
+            float(np.abs(np.asarray(hs, np.float64)[:, cols]
+                         - state).max() / np.abs(state).max()))
+    print("against float64, sixteen channels, (y, state): " + ", ".join(
+        f"{n} ({a:.2e}, {b:.2e})" for n, (a, b) in gaps.items()))
+    assert max(gaps["mamba_prompt_scan"]) < 1e-4, gaps
+
+    ms = {}
+    for rows in (2048, 1024):
+        real = jnp.int32(min(plen, rows))
+        args = (x[:rows], dt[:rows], bm[:rows], cm[:rows], a_log)
+        for name, fn, extra in (("mamba_prompt_scan", kernel, (real,)),
+                                ("jax.numpy scan", jax.jit(mamba_scan), ())):
+            jax.block_until_ready(fn(*args, *extra))     # compile, warm
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(*args, *extra)
+            jax.block_until_ready(out)
+            ms[name, rows] = (time.perf_counter() - t0) / 20 * 1e3
+    print("a layer's prompt scan at (rows, 5120) x 16 states, ms a call: "
+          + ", ".join(f"{n} at {r} {t:.3f}" for (n, r), t in ms.items()))
+    for rows in (2048, 1024):
+        assert ms["mamba_prompt_scan", rows] < ms["jax.numpy scan", rows]
