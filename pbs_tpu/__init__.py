@@ -31,4 +31,11 @@ idiomatically for TPUs with JAX/XLA/Pallas/pjit:
 - ``pbs_tpu.cli``        — ``pbst`` management CLI (``xl`` analog).
 """
 
+import time as _time
+
+#: ``time.monotonic_ns()`` at the package's first import: what
+#: ``HOST_START`` falls back on where the process's own start cannot be
+#: read (``obs.trace.process_start_ns``).
+T_IMPORT_NS = _time.monotonic_ns()
+
 __version__ = "0.1.0"

@@ -49,7 +49,9 @@ from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
-from pbs_tpu.obs.trace import Ev, TraceBuffer, host_ring, register_ring
+from pbs_tpu.obs.trace import (
+    Ev, TraceBuffer, host_phase, host_ring, register_ring,
+)
 from pbs_tpu.models.plan import (
     KdaKind, MambaKind, block_name, init_plan_params, plan_of, rope_table,
     uniform_plan)
@@ -678,15 +680,22 @@ class ContinuousBatcher:
                 "prefix_cache_size > 0 needs a prompt window that can be "
                 "cut from and installed into every layer's cache: "
                 + self.program.no_windows)
-        cache = self.program.init_cache(n_slots, self.max_len)
-        if mesh is not None:
-            # Tensor-parallel serving by PLACEMENT (the GSPMD recipe):
-            # the caller handed ``params`` already laid out on ``mesh``
-            # (serve.partition.place) and the engine lays the KV slabs
-            # over the kv heads; the two jitted programs below are
-            # unchanged — XLA propagates the shardings and inserts the
-            # collectives.
-            cache = self.program.place_cache(cache, mesh)
+        # Set-up from the inside (docs/TRACING.md "Where a start-up
+        # goes"): the allocation here and every program warmed below is
+        # a HOST_PHASE span of the host ring, each waited for, so that
+        # its wall is its own.
+        with host_phase("eng.cache") as span:
+            cache = self.program.init_cache(n_slots, self.max_len)
+            if mesh is not None:
+                # Tensor-parallel serving by PLACEMENT (the GSPMD
+                # recipe): the caller handed ``params`` already laid
+                # out on ``mesh`` (serve.partition.place) and the engine
+                # lays the KV slabs over the kv heads; the two jitted
+                # programs below are unchanged — XLA propagates the
+                # shardings and inserts the collectives.
+                cache = self.program.place_cache(cache, mesh)
+            span.size = sum(x.nbytes for x in jax.tree.leaves(
+                jax.block_until_ready(cache)))
         self.params = params
         self.cache = cache
         self._key = jax.random.PRNGKey(seed)
@@ -718,7 +727,9 @@ class ContinuousBatcher:
         # ring unless its driver hands it one (bind_trace); None = off.
         self.trace: TraceBuffer | None = TraceBuffer()
         register_ring("engine", self.trace)
-        host_ring()  # full collections land beside the ticks they stall
+        # Full collections, and every program JAX builds, land beside
+        # the ticks they stall.
+        host_ring()
         self._tick_seq = 0  # ``steps`` at this tick's entry
         # Slot seams for a backend's span wiring, called as
         # ``hook(rid, slot)`` when a request wins a decode slot and when
@@ -827,16 +838,32 @@ class ContinuousBatcher:
         # now, so that none compiles under a request.
         wk = jax.random.PRNGKey(0)
         for rung in self.rungs:
-            self.cache = _prefill(
-                self.params, self.cache, 0,
-                jnp.zeros((rung,), jnp.int32), 0, wk)[2]
+            self.cache = self._build(
+                f"eng.prefill@{rung}", rung, lambda: _prefill(
+                    self.params, self.cache, 0,
+                    jnp.zeros((rung,), jnp.int32), 0, wk)[2])
         if prefix_cache_size:
             win = jnp.zeros((cfg.n_layers, 1, self.bucket,
                              cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
-            self.cache = _install(self.cache, 0, win, win, 0)
-        self.cache = _decode(
+            self.cache = self._build(
+                "eng.install", self.bucket,
+                lambda: _install(self.cache, 0, win, win, 0))
+        self.cache = self._build("eng.decode", n_slots, lambda: _decode(
             self.params, self.cache, jnp.zeros((n_slots,), jnp.int32),
-            jnp.zeros((n_slots,), bool), wk)[1]
+            jnp.zeros((n_slots,), bool), wk)[1])
+        # The key split of every tick and admission is two small eager
+        # programs: without this the first request built them.
+        self._build("eng.keysplit", 2, lambda: tuple(jax.random.split(wk)))
+
+    @staticmethod
+    def _build(scope: str, size: int, call):
+        """One program of the warm-up as an ``eng.build`` span of the
+        host ring: the call and the wait for what it returns, so that
+        one build's execution is not billed to the next one's. ``scope``
+        names the build on each ``HOST_COMPILE`` inside it; ``size`` is
+        its rows (a prefill's rung, an install's window) or lanes."""
+        with host_phase("eng.build", size, scope):
+            return jax.block_until_ready(call())
 
     # -- flight recorder --------------------------------------------------
 
@@ -1259,13 +1286,15 @@ class SpeculativeBatcher(ContinuousBatcher):
         # Warm both programs at construction (same SLO reasoning, same
         # rebinding and same untouched cursors as the parent's warm-up).
         for rung in self.rungs:
-            self.dcache = _draft_prefill(
-                self.draft_params, self.dcache, 0,
-                jnp.zeros((rung,), jnp.int32), 0)[0]
-        self.cache, self.dcache = _spec_decode(
-            self.params, self.draft_params, self.cache, self.dcache,
-            jnp.zeros((n_slots,), jnp.int32),
-            jnp.zeros((n_slots,), bool))[2:4]
+            self.dcache = self._build(
+                f"eng.draft_prefill@{rung}", rung, lambda: _draft_prefill(
+                    self.draft_params, self.dcache, 0,
+                    jnp.zeros((rung,), jnp.int32), 0)[0])
+        self.cache, self.dcache = self._build(
+            "eng.spec_decode", n_slots, lambda: _spec_decode(
+                self.params, self.draft_params, self.cache, self.dcache,
+                jnp.zeros((n_slots,), jnp.int32),
+                jnp.zeros((n_slots,), bool))[2:4])
 
     def submit(self, prompt, max_new_tokens: int) -> int:
         # The verify window writes up to k+1 positions past the

@@ -48,13 +48,18 @@ atomic release).
 
 from __future__ import annotations
 
+import contextlib
 import enum
 import functools
 import gc
+import os
 import struct
+import threading
 import time
+import types
 import weakref
 import zlib
+from typing import Iterator
 
 import numpy as np
 
@@ -209,13 +214,61 @@ class Ev(enum.IntEnum):
     #                           job_tag (crc32 of the job name)
     # host (0x0Cxx) — what the process itself did to its threads.
     HOST_GC = 0x0C01  # args: dur_ns, generation, collected
+    # Set-up from the inside (docs/TRACING.md "Where a start-up goes").
+    # ``ts_ns`` of HOST_START is the process's own start on the ring
+    # clock; the three durations count from it and ascend.
+    HOST_START = 0x0C02  # args: pkg_ns (-> pbs_tpu first imported),
+    #                            import_ns (-> the entry point's imports
+    #                            done, the last stamp before the backend
+    #                            is asked for), backend_ns (-> the JAX
+    #                            backend answered), ask_ns (of that,
+    #                            setup_compilation_cache's own question:
+    #                            the backend's start where nothing asked
+    #                            before it, microseconds where it was
+    #                            up), devices, flags (START_* below)
+    HOST_COMPILE = 0x0C03  # one an outermost JAX compile event, ts_ns
+    #                        its start. args: kind (COMPILE_KINDS),
+    #                        wall_ns, fun tag (job_tag of JAX's
+    #                        fun_name), scope tag (the attribute() scope
+    #                        in force; 0: ambient), cache (CACHE_*; a
+    #                        backend event's verdict), retrieval_ns (a
+    #                        hit's read of the persistent cache)
+    HOST_PHASE = 0x0C04  # one a named span of a constructor
+    #                      (host_phase), ts_ns its start. args: name tag,
+    #                      wall_ns, compile_ns (the meter's wall inside
+    #                      it, this thread), size (rows, lanes, bytes:
+    #                      the span's own), scope tag (what its
+    #                      HOST_COMPILEs carry; 0: none)
+
+
+#: HOST_START.flags: the origin is pbs_tpu's first import, because
+#: /proc/self/stat could not be read or CLOCK_BOOTTIME and the ring
+#: clock have parted (a suspended host).
+START_FROM_IMPORT = 1
+#: HOST_COMPILE.kind, in the order JAX fires them for one program.
+COMPILE_KINDS = ("trace", "lower", "backend")
+#: HOST_COMPILE.cache: not asked (below JAX's thresholds, or the cache
+#: is off), served by the persistent cache, compiled and written to it.
+CACHE_NONE, CACHE_HIT, CACHE_MISS = 0, 1, 2
+
+#: tag -> the name it was made from, for whoever prints a record.
+_tag_names: dict[int, str] = {0: "-"}
 
 
 @functools.lru_cache(maxsize=None)
 def job_tag(name: str) -> int:
-    """The stable u32 that EXEC_STEP carries for a job name (crc32: str
-    hashing is salted per process)."""
-    return zlib.crc32(name.encode())
+    """The stable u32 that a record carries for a name (EXEC_STEP's job,
+    HOST_COMPILE's function and scope, HOST_PHASE's span; crc32: str
+    hashing is salted per process). The name is kept for
+    :func:`tag_name`."""
+    tag = zlib.crc32(name.encode())
+    _tag_names[tag] = name
+    return tag
+
+
+def tag_name(tag: int) -> str:
+    """The name ``tag`` was made from in this process, else its hex."""
+    return _tag_names.get(tag) or f"0x{tag:08x}"
 
 
 class TraceBuffer:
@@ -625,6 +678,23 @@ def live_rings() -> list[tuple[str, TraceBuffer]]:
 
 _host: TraceBuffer | None = None
 _gc_t0 = 0
+# The host ring has many producers (a full collection, a compile and a
+# constructor's span may each end on any thread), a ring one: they take
+# turns. Re-entrant, because a collection can start inside an emit.
+_host_lock = threading.RLock()
+# Records of the host ring, counted on the chip (PERF.md section 6,
+# PR 39): a benchmark process writes 19 (the solo trainer) to 73 (the
+# co-located cell) before its window, three HOST_COMPILE a program it
+# builds, eager jax.numpy calls included, some ten HOST_PHASE and the
+# HOST_START, cold as warm, and a handful of HOST_GC in it. 2048 is 28
+# times the busiest cell's count, 128 KiB: a process that builds a dozen
+# engines (chip_smoke.py's four legs, a long-lived server that reloads)
+# still loses none; one that builds hundreds keeps its newest.
+HOST_RING_CAPACITY = 2048
+#: CLOCK_BOOTTIME and the ring clock (CLOCK_MONOTONIC) count from the
+#: same boot; they part by what the host spent suspended. Within the
+#: grain of /proc's start time they have not.
+_CLOCKS_AGREE_NS = 10_000_000
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -637,22 +707,89 @@ def _on_gc(phase: str, info: dict) -> None:
     if phase == "start":
         _gc_t0 = time.monotonic_ns()
     elif _host is not None and _gc_t0:
-        _host.emit(_gc_t0, Ev.HOST_GC, time.monotonic_ns() - _gc_t0,
-                   info["generation"], info.get("collected", 0))
+        host_emit(_gc_t0, Ev.HOST_GC, time.monotonic_ns() - _gc_t0,
+                  info["generation"], info.get("collected", 0))
         _gc_t0 = 0
 
 
 def host_ring() -> TraceBuffer:
-    """The process-wide ``host`` ring, made on first use: full Python
-    collections land there as ``HOST_GC`` (start, dur_ns), so that a
-    long gap between a tenant's records names its cause or rules one
-    out (docs/TRACING.md "Finding a stall")."""
+    """The process-wide ``host`` ring, made on first use, and with it
+    the process's two taps: full Python collections land there as
+    ``HOST_GC`` (start, dur_ns), so that a long gap between a tenant's
+    records names its cause or rules one out (docs/TRACING.md "Finding
+    a stall"), and every program JAX traces, lowers and compiles or
+    loads as ``HOST_COMPILE`` (``telemetry.compile.CompileMeter``)."""
     global _host
     if _host is None:
-        _host = TraceBuffer(1024)
-        register_ring("host", _host)
-        gc.callbacks.append(_on_gc)
+        with _host_lock:
+            if _host is None:
+                ring = TraceBuffer(HOST_RING_CAPACITY)
+                register_ring("host", ring)
+                gc.callbacks.append(_on_gc)
+                _host = ring
+        # Imported here: telemetry's package reaches back into obs.
+        from pbs_tpu.telemetry.compile import CompileMeter
+
+        CompileMeter.install()
     return _host
+
+
+def host_emit(ts_ns: int, event: int, *args: int) -> None:
+    """One record into the ``host`` ring, from whatever thread."""
+    ring = host_ring()
+    with _host_lock:
+        ring.emit(ts_ns, event, *args)
+
+
+def process_start_ns() -> tuple[int, int]:
+    """``(ns, flags)``: when this process began, on the ring clock.
+    ``/proc/self/stat`` field 22 counts clock ticks from boot, which is
+    where ``time.monotonic()`` counts from on a host that was never
+    suspended. Where ``CLOCK_BOOTTIME`` says it was, or ``/proc`` cannot
+    be read, the stamp of ``pbs_tpu``'s first import stands in and
+    ``flags`` says so (``START_FROM_IMPORT``)."""
+    import pbs_tpu
+
+    t_import = pbs_tpu.T_IMPORT_NS
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # The command's name may hold spaces and parentheses:
+            # field 3 follows its last ')'.
+            fields = f.read().rsplit(b")", 1)[1].split()
+        start = int(fields[19]) * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        parted = abs(time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                     - time.monotonic_ns())
+    except (OSError, ValueError, IndexError, AttributeError):
+        return t_import, START_FROM_IMPORT
+    if parted > _CLOCKS_AGREE_NS or start > t_import:
+        return t_import, START_FROM_IMPORT
+    return start, 0
+
+
+@contextlib.contextmanager
+def host_phase(name: str, size: int = 0,
+               scope: str | None = None) -> Iterator[types.SimpleNamespace]:
+    """One ``HOST_PHASE`` record for a named span of a constructor,
+    written when it ends: its wall, the compile wall the meter saw
+    inside it on this thread (wall less compile is what ran), and one
+    size (what is yielded holds it: a span that learns its size inside
+    sets ``.size``). With ``scope`` the span's compiles are attributed
+    to it (``CompileMeter.attribute``), so that each ``HOST_COMPILE``
+    inside names the span it belongs to."""
+    from pbs_tpu.telemetry.compile import CompileMeter
+
+    meter = CompileMeter.install()
+    with meter.attribute(scope) if scope is not None \
+            else contextlib.nullcontext():
+        span = types.SimpleNamespace(size=size)
+        c0, t0 = meter.thread_wall_ns(), time.monotonic_ns()
+        try:
+            yield span
+        finally:
+            host_emit(t0, Ev.HOST_PHASE, job_tag(name),
+                      time.monotonic_ns() - t0,
+                      meter.thread_wall_ns() - c0, int(span.size),
+                      job_tag(scope) if scope is not None else 0)
 
 
 def merge_records(chunks: list[np.ndarray]) -> np.ndarray:
@@ -676,6 +813,11 @@ def format_records(recs: np.ndarray) -> list[str]:
             name = Ev(ev).name
         except ValueError:
             name = f"0x{ev:04x}"
+        if ev == Ev.HOST_COMPILE:  # kind, wall, function, scope, cache
+            args[0] = COMPILE_KINDS[args[0]]
+            args[2], args[3] = tag_name(args[2]), tag_name(args[3])
+        elif ev == Ev.HOST_PHASE:  # span, wall, compile, size, scope
+            args[0], args[4] = tag_name(args[0]), tag_name(args[4])
         out.append(f"[{ts / 1e9:.6f}] {name} {' '.join(map(str, args))}")
     return out
 

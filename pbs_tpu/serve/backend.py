@@ -80,6 +80,7 @@ class ShardedServeBackend(BatcherBackend):
         import jax
 
         from pbs_tpu.models.serving import ContinuousBatcher
+        from pbs_tpu.obs.trace import host_phase
         from pbs_tpu.serve.partition import (
             make_serve_mesh, make_shard_and_gather_fns,
         )
@@ -97,7 +98,9 @@ class ShardedServeBackend(BatcherBackend):
         # leaf), THEN the engine: a tree the table cannot place never
         # reaches a compile.
         shard_fn, self._gather_fn = make_shard_and_gather_fns(self.mesh)
-        params = shard_fn(params)
+        with host_phase("serve.place", sum(
+                x.nbytes for x in jax.tree.leaves(params))):
+            params = jax.block_until_ready(shard_fn(params))
         self._virtual = clock == "virtual"
         self._now_ns = 0
         self._wall = MonotonicClock()  # clock="wall": the gateway's own

@@ -19,6 +19,15 @@ end on the thread itself and counts that span once. The drained
 per-job sums land in the ``COMPILES`` / ``COMPILE_TIME_NS`` ledger
 slots, making compilation a first-class scheduled-resource like device
 time, and feed the admission gate in ``pbs_tpu.runtime.compile_gate``.
+
+Each outermost event is also one ``HOST_COMPILE`` record of the
+process's ``host`` ring (``obs/trace.py``; docs/TRACING.md "Where a
+start-up goes"): when it began on the rings' clock, its kind, its wall,
+the function JAX named, the attribution scope in force and, for a
+backend event, whether the persistent cache served it. The meter is the
+process's one listener on that stream: the persistent cache's hit and
+miss events, which ``utils.compile_cache.cache_counts`` reports, are
+heard here too.
 """
 
 from __future__ import annotations
@@ -37,6 +46,16 @@ FRONTEND_EVENTS = (
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
 )
 _EVENTS = frozenset((BACKEND_COMPILE_EVENT,) + FRONTEND_EVENTS)
+#: HOST_COMPILE.kind (``obs.trace.COMPILE_KINDS``).
+_KIND = {FRONTEND_EVENTS[0]: 0, FRONTEND_EVENTS[1]: 1,
+         BACKEND_COMPILE_EVENT: 2}
+#: The persistent cache's events, fired inside a backend event on the
+#: thread that compiles: a hit is a deserialized executable (with the
+#: time the read took); a miss is counted when the freshly compiled
+#: entry is written.
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class CompileMeter:
@@ -51,6 +70,10 @@ class CompileMeter:
     _instance_lock = threading.Lock()
 
     def __init__(self) -> None:
+        # Imported here: obs' package reaches back into telemetry.
+        from pbs_tpu.obs import trace as obs_trace
+
+        self._obs = obs_trace
         self._lock = threading.Lock()
         self._tls = threading.local()
         # name -> [compiles, wall ns of compiling] (pending drain)
@@ -58,6 +81,9 @@ class CompileMeter:
         # lifetime totals (admission projections read these)
         self.total_compiles = 0
         self.total_compile_ns = 0
+        # persistent-cache verdicts, process-wide
+        self.cache_hits = 0
+        self.cache_misses = 0
         self._installed = False
 
     @classmethod
@@ -81,6 +107,7 @@ class CompileMeter:
         jax.monitoring.register_scalar_listener(self._on_start)
         jax.monitoring.register_event_duration_secs_listener(
             self._on_event)
+        jax.monitoring.register_event_listener(self._on_cache)
         self._installed = True
 
     # -- listeners --------------------------------------------------------
@@ -93,7 +120,20 @@ class CompileMeter:
             self._tls.t_open = time.monotonic_ns()
         self._tls.depth = depth + 1
 
+    def _on_cache(self, event: str, **kw) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._tls.cache = self._obs.CACHE_HIT
+            with self._lock:
+                self.cache_hits += 1
+        elif event == CACHE_MISS_EVENT:
+            self._tls.cache = self._obs.CACHE_MISS
+            with self._lock:
+                self.cache_misses += 1
+
     def _on_event(self, event: str, duration_s: float, **kw) -> None:
+        if event == CACHE_RETRIEVAL_EVENT:
+            self._tls.retrieval_ns = int(duration_s * 1e9)
+            return
         if event not in _EVENTS:
             return
         # The outermost event's span on this thread's own clock, once;
@@ -101,12 +141,27 @@ class CompileMeter:
         depth = getattr(self._tls, "depth", 0) - 1
         if depth < 0:  # began before the meter was installed
             return
-        self._tls.depth = depth
-        wall = time.monotonic_ns() - self._tls.t_open if depth == 0 else 0
+        tls = self._tls
+        tls.depth = depth
+        wall = time.monotonic_ns() - tls.t_open if depth == 0 else 0
         is_backend = event == BACKEND_COMPILE_EVENT
+        scope = getattr(tls, "scope", None)
+        if is_backend:  # the verdict the cache gave inside this event
+            cache = getattr(tls, "cache", 0)
+            retrieval_ns = getattr(tls, "retrieval_ns", 0)
+            tls.cache = tls.retrieval_ns = 0
+        else:
+            cache = retrieval_ns = 0
+        if depth == 0:
+            obs = self._obs
+            tls.wall_ns = getattr(tls, "wall_ns", 0) + wall
+            obs.host_emit(
+                tls.t_open, obs.Ev.HOST_COMPILE, _KIND[event], wall,
+                obs.job_tag(str(kw.get("fun_name", ""))),
+                obs.job_tag(scope) if scope else 0, cache, retrieval_ns)
         if not (wall or is_backend):
             return
-        scope = getattr(self._tls, "scope", None) or "<ambient>"
+        scope = scope or "<ambient>"
         with self._lock:
             ent = self._pending.setdefault(scope, [0, 0])
             ent[1] += wall
@@ -125,6 +180,12 @@ class CompileMeter:
             yield
         finally:
             self._tls.scope = prev
+
+    def thread_wall_ns(self) -> int:
+        """Wall nanoseconds of outermost compile events that ended on
+        the calling thread, ever: the difference over a span is the
+        compile wall inside it (``obs.trace.host_phase``)."""
+        return getattr(self._tls, "wall_ns", 0)
 
     def take(self, name: str) -> tuple[int, int]:
         """Drain (compiles, compile_ns) attributed to ``name`` since the

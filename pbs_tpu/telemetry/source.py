@@ -21,6 +21,7 @@ the same seam as a ``TelemetrySource`` protocol with two backends:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Protocol
@@ -362,6 +363,11 @@ def cost_analysis_of(compiled) -> tuple[int, int]:
     return int(ca.get("flops", 0.0)), int(ca.get("bytes accessed", 0.0))
 
 
+#: What every invocation of a job but its first enters in the place of
+#: an ``exec.first`` span.
+_NOT_FIRST = contextlib.nullcontext()
+
+
 class TpuBackend:
     """Measures real jobs: wall time + XLA cost analysis + in-graph metrics.
 
@@ -430,7 +436,10 @@ class TpuBackend:
         from pbs_tpu.obs import trace as obs_trace
 
         self._obs = obs_trace
-        obs_trace.host_ring()  # full collections, beside the steps
+        # Full collections and compiles, beside the steps; a job's
+        # first invocation is an ``exec.first`` span there.
+        obs_trace.host_ring()
+        self._invoked: set[str] = set()
 
     def bind_trace(self, emit: Callable[..., None]) -> None:
         """Hand the backend its driver's ring: ``emit(ctx, ts_ns,
@@ -541,8 +550,17 @@ class TpuBackend:
         # executable, harvested by _job_cost): the job's to pay for,
         # not part of this call's wall.
         n_before, ns_before = self.compile_meter.take(job.name)
+        # A job's first invocation, whole: what it traces, lowers and
+        # loads is in its HOST_COMPILE records, under the job's name;
+        # the span's wall less compile is the first execution.
+        first = _NOT_FIRST
+        if job.name not in self._invoked:
+            self._invoked.add(job.name)
+            first = self._obs.host_phase(
+                "exec.first", ctx.ledger_slot if ctx is not None else 0,
+                scope=job.name)
         t0 = time.monotonic_ns()
-        with self.compile_meter.attribute(job.name), \
+        with first, self.compile_meter.attribute(job.name), \
                 jax.profiler.TraceAnnotation("pbst.exec.step"):
             if self._profile_due(job):
                 (job.state, metrics), stats = self.profiler.profile(run)

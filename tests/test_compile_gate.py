@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from pbs_tpu.obs import trace as obs_trace
 from pbs_tpu.runtime.compile_gate import (
     CompileAdmission,
     CompileBudget,
@@ -23,6 +24,16 @@ from pbs_tpu.runtime.partition import Partition
 from pbs_tpu.telemetry.compile import CompileMeter
 from pbs_tpu.telemetry.counters import Counter
 from pbs_tpu.telemetry.source import TpuBackend
+
+
+def _host_compiles(since_ns: int, scope: str) -> list[list[int]]:
+    """HOST_COMPILE records of ``scope`` that began since ``since_ns``:
+    [ts, event, kind, wall_ns, function tag, scope tag, cache, ...]."""
+    ring = obs_trace.host_ring()
+    recs = ring.peek(ring.capacity).astype("int64")
+    return [r for r in recs[recs[:, 1] == int(obs_trace.Ev.HOST_COMPILE)]
+            .tolist() if r[0] >= since_ns
+            and r[5] == obs_trace.job_tag(scope)]
 
 
 def _distinct_program_job(name: str, scale: float, size: int = 64) -> Job:
@@ -73,6 +84,15 @@ def test_compile_ns_of_nested_jits_is_inside_its_call():
     wall = time.monotonic_ns() - t0
     n, ns = meter.take("meter-nested")
     assert n >= 1 and 0 < ns <= wall, (n, ns, wall)
+    # On the ring as in the sums: one HOST_COMPILE an outermost event,
+    # the thirty inner jits inside the outer trace's record and not
+    # beside it, and the records' walls are what take() summed.
+    recs = _host_compiles(t0, "meter-nested")
+    names = [obs_trace.tag_name(r[4]) for r in recs]
+    assert "body" in names and "jit(body)" in names
+    assert not [x for x in names if "lambda" in x], names
+    assert sum(r[3] for r in recs) == ns
+    assert sum(1 for r in recs if r[2] == 2) <= n
 
 
 def test_a_harvest_compile_is_not_in_the_first_steps_wall():
@@ -82,6 +102,7 @@ def test_a_harvest_compile_is_not_in_the_first_steps_wall():
     job = Job.foreign("meter-foreign", jax.jit(lambda x: jnp.tanh(x * 1.0391)),
                       jnp.ones((32, 32)), max_steps=1)
     be = TpuBackend(profile_every=0)
+    t_made = time.monotonic_ns()
     be._job_cost(job)
     t0 = time.monotonic_ns()
     dt, _metrics, n, ns = be._invoke(job, job.step_fn)
@@ -91,6 +112,15 @@ def test_a_harvest_compile_is_not_in_the_first_steps_wall():
     assert n >= 1 and ns > compile_ns >= 0
     assert min(dispatch, wait, dt) >= 0
     assert dispatch + compile_ns + wait <= wall
+    # On the ring: the harvest's records carry the job's scope and end
+    # before the step's span begins; the span's compile wall is the
+    # step record's.
+    recs = _host_compiles(t_made, "meter-foreign")
+    harvest = [r for r in recs if r[0] < t0]
+    assert {r[2] for r in harvest} == {0, 1, 2}
+    assert all(r[0] + r[3] <= t0 for r in harvest)
+    assert sum(r[3] for r in recs if r[0] >= t0) == compile_ns
+    assert ns == sum(r[3] for r in recs)
 
 
 def test_compile_time_excluded_from_runtime_charge():
