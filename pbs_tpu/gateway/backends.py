@@ -163,7 +163,11 @@ class BatcherBackend(Backend):
     ``poll`` advances the engine one tick (``engine.step()``), so the
     gateway pump *is* the serving loop: one gateway tick = one decode
     token across slots, the same quantum-sized unit
-    ``make_continuous_serve_step`` exposes to the scheduler.
+    ``make_continuous_serve_step`` exposes to the scheduler. The
+    engine keeps one decode in flight: a poll enqueues a tick and
+    returns the completions of the tick before it, and
+    ``engine.has_work()`` holds until the last one is read, so the pump
+    keeps polling until every token is out.
 
     Request payloads: ``{"prompt": <tokens>, "max_new": <int>}``.
     """

@@ -28,6 +28,13 @@ TPU-first expression of the idea:
   serving Job under the credit scheduler interleaves with training at
   token granularity — the latency story the reference's BOOST class
   exists for.
+- **One tick in flight**: ``step()`` enqueues the decode of tick n+1
+  before it reads tick n's tokens, and books tick n (emit, retire,
+  records) while the device runs tick n+1. The token vector goes from
+  one decode to the next on the device; the host decides the lane mask
+  ahead from the budgets it already knows. ``step_settled()`` is the
+  tick that reads what it dispatched before it returns, for a driver
+  whose quantum has to be its own (``make_continuous_serve_step``).
 """
 
 from __future__ import annotations
@@ -72,6 +79,13 @@ _ns = time.monotonic_ns
 # profile is being captured, and an operator's xprof capture then shows
 # the engine's spans against the device lanes.
 _span = jax.profiler.TraceAnnotation
+
+# What the host says of a lane in a decode dispatch, in the place of its
+# last token: the lane is off, or its last token is the one the previous
+# decode left on the device (not on the host yet). Any value >= 0 is the
+# lane's last token itself, where the host has it.
+_LANE_OFF = -2
+_LANE_CARRY = -1
 
 
 def _rope_rows(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
@@ -624,6 +638,17 @@ class Completion:
     latency_s: float = 0.0  # wall time submit -> completion
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A decode that is enqueued and not yet read: what it will send to
+    the host, and the lanes it ran with the request each one held at
+    dispatch (a lane whose slot has changed hands by the time the
+    tokens arrive books nothing)."""
+    out: jax.Array    # (n_slots,) tokens, the program's route behind them
+    extra: jax.Array  # the FFN's auxiliary sum over layers
+    lanes: list[tuple[int, int]]  # (slot, request id)
+
+
 class ContinuousBatcher:
     """The slot engine. Host-side control, two compiled programs.
 
@@ -640,6 +665,14 @@ class ContinuousBatcher:
     that went in is dead and ``self.cache`` is the one that came out.
     A caller that wants to keep K/V slices it out before the next call
     (the prefix cache's windows are such slices: arrays of their own).
+
+    The decode is pipelined one deep (docs/SERVING.md "The pipelined
+    tick"): a ``step()`` enqueues its decode and then books the decode
+    of the ``step()`` before it, so what it returns, and what
+    ``slot_tokens`` holds when it returns, is one dispatch behind what
+    the device has run. ``has_work()`` stays true until the last tick
+    is booked; ``settle()`` books it now; ``step_settled()`` is a tick
+    that leaves nothing in flight.
     """
 
     def __init__(self, cfg: TransformerConfig, params: dict,
@@ -716,6 +749,12 @@ class ContinuousBatcher:
         self._latencies: deque = deque(maxlen=1024)
         self.active = np.zeros(n_slots, bool)
         self.last_tok = np.zeros(n_slots, np.int32)
+        # The pipeline: the decode not yet read (None: settled), and
+        # how many dispatches found one (overlapped) or none (settled).
+        self._inflight: _InFlight | None = None
+        self._settling = False  # inside step_settled()
+        self.ticks_overlapped = 0
+        self.ticks_settled = 0
         self.steps = 0
         self.tokens_emitted = 0
         self.requests_completed = 0
@@ -805,8 +844,16 @@ class ContinuousBatcher:
             return cache
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def _decode(params, cache, last_tok, active, key):
-            """One token for every slot; inactive lanes masked."""
+        def _decode(params, cache, prev_tok, lanes, key):
+            """One token for every slot; inactive lanes masked.
+            ``lanes`` is the host's word on each lane (``_LANE_OFF``,
+            ``_LANE_CARRY`` or its last token), ``prev_tok`` the tokens
+            the previous decode returned, still on the device. Returns
+            the tokens twice: alone, to be the next call's ``prev_tok``,
+            and as what goes to the host, where a routing program's
+            ``route`` rides behind them."""
+            active = lanes > _LANE_OFF
+            last_tok = jnp.where(lanes > _LANE_CARRY, lanes, prev_tok)
             logits, new_cache, extra, route = self.program.decode(
                 params, cache, last_tok, active)
             keys = jax.random.split(key, self.n_slots)
@@ -816,9 +863,8 @@ class ContinuousBatcher:
             )(logits[:, 0, :], keys)
             nxt = jnp.where(active, nxt, 0)
             new_cache["pos"] = cache["pos"] + active.astype(jnp.int32)
-            if route is not None:  # rides to the host with the tokens
-                nxt = jnp.concatenate([nxt, route])
-            return nxt, new_cache, extra
+            out = nxt if route is None else jnp.concatenate([nxt, route])
+            return nxt, out, new_cache, extra
 
         self._prefill_fn = _prefill
         self._install_fn = _install
@@ -848,9 +894,18 @@ class ContinuousBatcher:
             self.cache = self._build(
                 "eng.install", self.bucket,
                 lambda: _install(self.cache, 0, win, win, 0))
-        self.cache = self._build("eng.decode", n_slots, lambda: _decode(
-            self.params, self.cache, jnp.zeros((n_slots,), jnp.int32),
-            jnp.zeros((n_slots,), bool), wk)[1])
+        # The decode twice, the second on the first's own tokens: what
+        # every tick after the first is handed (an output of the
+        # program, not an array the host made) is then a signature this
+        # warm-up has met, whatever sharding or commitment it carries.
+        off = jnp.full((n_slots,), _LANE_OFF, jnp.int32)
+
+        def _warm_decode():
+            tok, _, cache, _ = _decode(self.params, self.cache, off, off, wk)
+            return _decode(self.params, cache, tok, off, wk)
+
+        self._dev_tok, _, self.cache, _ = self._build(
+            "eng.decode", n_slots, _warm_decode)
         # The key split of every tick and admission is two small eager
         # programs: without this the first request built them.
         self._build("eng.keysplit", 2, lambda: tuple(jax.random.split(wk)))
@@ -878,15 +933,14 @@ class ContinuousBatcher:
         if tr is not None:
             tr.emit(ts_ns, event, *args)
 
-    def _routed(self, ts_ns: int, out: np.ndarray, n: int) -> np.ndarray:
-        """The ``n`` tokens of a program's integer output; what an
-        expert-routing program sent behind them (``_plan_forward``'s
-        ``route``) becomes the ``ENG_ROUTE`` record of that prefill or
-        decode, stamped like its ``ENG_PREFILL`` / ``ENG_DECODE``."""
-        if len(out) > n:
+    def _route_ev(self, ts_ns: int, route: np.ndarray) -> None:
+        """What an expert-routing program sent behind its tokens
+        (``_plan_forward``'s ``route``) as the ``ENG_ROUTE`` record of
+        that prefill or decode, stamped like the ``ENG_PREFILL`` /
+        ``ENG_DECODE`` of the call that read it."""
+        if len(route):
             self._ev(ts_ns, Ev.ENG_ROUTE, self._tick_seq,
-                     *(int(c) for c in out[n:]))
-        return out[:n]
+                     *(int(c) for c in route))
 
     def _split_key(self) -> jax.Array:
         """Advance the sampling key (two tiny device programs a call)."""
@@ -970,8 +1024,9 @@ class ContinuousBatcher:
                         self.params, self.cache, slot,
                         jnp.asarray(padded), len(prompt), sub)
                 t_dispatched = _ns()
-                first = int(self._routed(
-                    t_prefill, np.asarray(first).ravel(), 1)[0])
+                first = np.asarray(first).ravel()
+                self._route_ev(t_prefill, first[1:])
+                first = int(first[0])
                 self._mlp_extra_sum += float(extra) / self.cfg.n_layers
         t_synced = _ns()
         self._ev(t_prefill, Ev.ENG_PREFILL, tick, rid, slot,
@@ -1071,12 +1126,14 @@ class ContinuousBatcher:
             or (self.eos_id is not None and tok == self.eos_id))
 
     def step(self) -> list[Completion]:
-        """Admit waiting requests, decode one token for every active
-        slot, retire finished requests. Returns completions.
+        """Admit waiting requests, enqueue one decode token for every
+        active slot, book the decode the call before this one enqueued,
+        retire finished requests. Returns completions.
 
         The tick every engine shares: ``_step`` is the engine's own
-        (plain decode here, speculation in the subclass); the
-        ``ENG_TICK`` record and its annotation wrap whichever runs."""
+        (the pipelined decode here, synchronous speculation in the
+        subclass); the ``ENG_TICK`` record and its annotation wrap
+        whichever runs."""
         t0 = _ns()
         self._tick_seq = self.steps
         with _span("pbst.eng.tick"):
@@ -1088,42 +1145,129 @@ class ContinuousBatcher:
                 len(done), len(self.queue))
         return done
 
-    def _decoded(self, t_pre: int, t_enqueued: int, t_host: int) -> None:
+    def step_settled(self) -> list[Completion]:
+        """``step()``, and the decode it enqueued booked before it
+        returns: nothing is left on the device, every token the tick
+        computed is in ``slot_tokens`` and every request it finished
+        is returned. For a driver that is billed for what its call
+        leaves running (a scheduler's quantum) or reads the slot table
+        between ticks. It goes through ``step`` so that whatever wraps
+        that (a backend's spans, a benchmark's stamps) sees this tick
+        too."""
+        self._settling = True
+        try:
+            return self.step()
+        finally:
+            self._settling = False
+
+    def settle(self) -> list[Completion]:
+        """Book the decode in flight, if there is one, and return the
+        requests it finished: after it the slot table and the counters
+        say all the device has done."""
+        done: list[Completion] = []
+        fl, self._inflight = self._inflight, None
+        if fl is not None:
+            t = _ns()
+            self._route_ev(t, self._read_and_book(fl, done))
+        return done
+
+    def _decoded(self, t_pre: int, t_enqueued: int, t_host: int,
+                 overlapped: int = 0) -> None:
         """Close the tick's decode span (both engines): ``pre`` runs
         from ``t_pre`` (admission and the key split are over) until the
         program is enqueued, host-to-device copies included; ``sync``
-        until its tokens are on the host; ``post`` until now, the emit
-        and retire loops."""
+        until the tokens this call books are on the host; ``post``
+        until now, the emit and retire loops. ``overlapped``: the
+        program was enqueued while the decode before it was unread."""
         self._ev(t_pre, Ev.ENG_DECODE, self._tick_seq,
-                 t_enqueued - t_pre, t_host - t_enqueued, _ns() - t_host)
+                 t_enqueued - t_pre, t_host - t_enqueued, _ns() - t_host,
+                 overlapped)
+
+    def _read(self, fl: _InFlight) -> tuple[np.ndarray, np.ndarray]:
+        """Wait for a decode's tokens; ``(tokens, route)`` on the host
+        (``route`` empty unless the program routes tokens to experts)."""
+        self._mlp_extra_sum += float(fl.extra) / self.cfg.n_layers
+        self._mlp_extra_n += 1
+        out = np.asarray(fl.out)
+        return out[:self.n_slots], out[self.n_slots:]
+
+    def _book(self, fl: _InFlight, toks: np.ndarray,
+              done: list[Completion]) -> None:
+        """Emit a decode's tokens to the lanes it ran that still hold
+        the request they held then, and retire those that finished."""
+        for slot, rid in fl.lanes:
+            if self.slot_req[slot] == rid and \
+                    self._emit(slot, int(toks[slot])):
+                done.append(self._retire(slot))
+
+    def _read_and_book(self, fl: _InFlight,
+                       done: list[Completion]) -> np.ndarray:
+        """Both at once, where no stamp lies between them; the route."""
+        toks, route = self._read(fl)
+        self._book(fl, toks, done)
+        return route
 
     def _step(self) -> list[Completion]:
-        done, any_active = self._pre_decode()
-        if not any_active:
-            return done
-        sub = self._split_key()
-        t_pre = _ns()
-        with _span("pbst.eng.decode"):
-            nxt, self.cache, extra = self._decode_fn(
-                self.params, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.active), sub)
-        t_enqueued = _ns()
-        with _span("pbst.eng.sync"):
-            self._mlp_extra_sum += float(extra) / self.cfg.n_layers
-            self._mlp_extra_n += 1
-            nxt = self._routed(t_pre, np.asarray(nxt), self.n_slots)
+        done: list[Completion] = []
+        fl, self._inflight = self._inflight, None
+        routes = []  # of the decodes this call reads
+        if fl is not None and (self._settling or (
+                self.queue and not self.active.all())):
+            # An admission ends in a host read of the prefill's first
+            # token, which waits for everything enqueued before it:
+            # book the decode in flight first, so that its tokens are
+            # not stamped behind a prefill they did not wait for. The
+            # wait lies in the tick's rest, not in ``sync``. (A settled
+            # tick that finds a decode in flight books it here too.)
+            routes.append(self._read_and_book(fl, done))
+            fl = None
+        retired, any_active = self._pre_decode()
+        done += retired
+        # The lanes of this dispatch, decided ahead: a lane whose
+        # budget the decode in flight exhausts runs no further token.
+        # (An EOS the host has not seen yet cannot stop its lane: that
+        # lane runs one token more, which ``_book`` drops.)
+        carry = np.zeros(self.n_slots, bool)
+        if fl is not None:
+            carry[[slot for slot, _ in fl.lanes]] = True
+        mask = self.active & (self.slot_remaining > carry)
+        overlapped = int(fl is not None)
+        t_pre = t_enqueued = _ns()
+        if mask.any():
+            sub = self._split_key()
+            t_pre = _ns()
+            with _span("pbst.eng.decode"):
+                lanes = np.where(mask, np.where(
+                    carry, _LANE_CARRY, self.last_tok), _LANE_OFF)
+                self._dev_tok, out, self.cache, extra = self._decode_fn(
+                    self.params, self.cache, self._dev_tok,
+                    jnp.asarray(lanes, jnp.int32), sub)
+            t_enqueued = _ns()
+            self._inflight = _InFlight(out, extra, [
+                (int(slot), self.slot_req[slot])
+                for slot in np.flatnonzero(mask)])
+            self.ticks_overlapped += overlapped
+            self.ticks_settled += 1 - overlapped
+            if self._settling:  # this tick reads its own decode
+                fl, self._inflight = self._inflight, None
+        if fl is not None:
+            with _span("pbst.eng.sync"):
+                toks, route = self._read(fl)
         t_host = _ns()
-        for slot in range(self.n_slots):
-            if not self.active[slot]:
-                continue
-            if self._emit(slot, int(nxt[slot])):
-                done.append(self._retire(slot))
-        self.steps += 1
-        self._decoded(t_pre, t_enqueued, t_host)
+        if fl is not None:
+            self._book(fl, toks, done)
+            routes.append(route)
+        if any_active:  # else _pre_decode has counted the tick
+            self.steps += 1
+        for route in routes:  # stamped like this call's ENG_DECODE
+            self._route_ev(t_pre, route)
+        if mask.any():
+            self._decoded(t_pre, t_enqueued, t_host, overlapped)
         return done
 
     def has_work(self) -> bool:
-        return bool(self.queue) or bool(self.active.any())
+        return (bool(self.queue) or bool(self.active.any())
+                or self._inflight is not None)
 
     @staticmethod
     def _pct(values, q: float) -> float:
@@ -1141,6 +1285,10 @@ class ContinuousBatcher:
         (and what the feedback policy's BOOST class protects)."""
         return {
             "steps": self.steps,
+            # Decodes enqueued while the one before was unread, and
+            # with nothing in flight (ENG_DECODE's flag, counted).
+            "ticks_overlapped": self.ticks_overlapped,
+            "ticks_settled": self.ticks_settled,
             "active_slots": int(self.active.sum()),
             "queued": len(self.queue),
             "tokens_emitted": self.tokens_emitted,
@@ -1373,7 +1521,12 @@ def make_continuous_serve_step(engine: ContinuousBatcher,
     serving with training at token granularity). ``next_requests(step)``
     optionally feeds new (prompt, max_new) pairs each tick. The
     ``tokens`` metric is the tick's DELTA of the engine's emitted
-    counter, so the TOKENS ledger slot is exact goodput."""
+    counter, so the TOKENS ledger slot is exact goodput.
+
+    The tick is ``step_settled()``: a quantum that left a decode on
+    the device would have it billed to the next tenant's wait and would
+    change what the credit scheduler burns, so the step returns with
+    its own tokens booked and nothing in flight."""
 
     def serve_step(state):
         step = int(state["step"])
@@ -1381,7 +1534,7 @@ def make_continuous_serve_step(engine: ContinuousBatcher,
             for prompt, max_new in next_requests(step):
                 engine.submit(prompt, max_new)
         before = engine.tokens_emitted
-        done = engine.step()
+        done = engine.step_settled()
         state = {"step": step + 1,
                  "completed": state["completed"] + len(done)}
         return state, {"tokens": engine.tokens_emitted - before}

@@ -195,14 +195,22 @@ class Ev(enum.IntEnum):
     #                             a prefix hit, cached KV was installed
     #                             and no prompt forward ran)
     ENG_KEYSPLIT = 0x0A04  # args: tick, dur_ns
-    ENG_DECODE = 0x0A05  # args: tick, pre_ns (-> program enqueued),
-    #                            sync_ns (-> tokens on the host),
-    #                            post_ns (-> step returns)
+    ENG_DECODE = 0x0A05  # one a step() that enqueues a decode. args:
+    #                      tick, pre_ns (-> this call's program
+    #                      enqueued), sync_ns (-> the tokens this call
+    #                      books on the host: the decode the call before
+    #                      enqueued; step_settled(): this call's own; 0
+    #                      where the call booked ahead of an admission or
+    #                      has nothing to book), post_ns (emit and retire
+    #                      loops -> step returns), overlapped (1: the
+    #                      program was enqueued while the decode before it
+    #                      was unread; 0: the pipeline was settled first)
     ENG_RETIRE = 0x0A06  # args: tick, rid, slot, tokens, ttft_ns,
     #                            latency_ns (engine latency clock)
-    ENG_ROUTE = 0x0A07  # one a prefill and one a decode tick of a
-    #                     program that routes tokens to experts, stamped
-    #                     like that ENG_PREFILL / ENG_DECODE. args: tick,
+    ENG_ROUTE = 0x0A07  # one a prefill and one a decode of a program
+    #                     that routes tokens to experts, stamped like that
+    #                     ENG_PREFILL, or like the ENG_DECODE (ts and tick)
+    #                     of the step() that read the decode. args: tick,
     #                     tokens routed, assignments to held experts, to
     #                     absent ones, held experts touched (the last
     #                     three summed over expert layers), largest load

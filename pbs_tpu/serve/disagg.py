@@ -263,7 +263,7 @@ class DisaggServeBackend(Backend):
             return []
         inflight_before = {
             rid for rid in self.engine.slot_req if rid is not None}
-        comps = self.engine.step()
+        comps = self.engine.step_settled()
         if self.exec_hook is not None:
             for erid in sorted(
                     rid for rid in self.engine.slot_req

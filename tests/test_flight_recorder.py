@@ -436,7 +436,7 @@ def module_names(cfg, params):
     lowered = [
         eng._decode_fn.lower(eng.params, eng.cache,
                              jnp.zeros((2,), jnp.int32),
-                             jnp.zeros((2,), bool), key),
+                             jnp.zeros((2,), jnp.int32), key),
         eng._prefill_fn.lower(eng.params, eng.cache, 0,
                               jnp.zeros((8,), jnp.int32), 1, key)]
     init_opt, train_step = make_train_step(cfg, learning_rate=1e-3)

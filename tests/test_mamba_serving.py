@@ -555,7 +555,7 @@ def lowered_text() -> dict:
     return {
         "jit__decode": eng._decode_fn.lower(
             eng.params, eng.cache, jnp.zeros((2,), jnp.int32),
-            jnp.zeros((2,), bool), key).as_text(debug_info=True),
+            jnp.zeros((2,), jnp.int32), key).as_text(debug_info=True),
         "jit__prefill": eng._prefill_fn.lower(
             eng.params, eng.cache, 0, jnp.zeros((BUCKET,), jnp.int32), 1,
             key).as_text(debug_info=True)}

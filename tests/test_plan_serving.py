@@ -388,8 +388,12 @@ def test_eng_route_counts_what_the_reference_routes():
     stamp = lambda ev: {int(r[0]) for r in recs if r[1] == ev}  # noqa: E731
     routes = [r for r in recs if r[1] == Ev.ENG_ROUTE]
     pre = [r for r in routes if int(r[0]) in stamp(Ev.ENG_PREFILL)]
-    dec = [r for r in routes if int(r[0]) in stamp(Ev.ENG_DECODE)]
+    dec = [r for r in routes if int(r[0]) not in stamp(Ev.ENG_PREFILL)]
     assert len(pre) == 2 and len(dec) == 5 and len(routes) == 7
+    # A decode's route is booked by the step() after the one that
+    # enqueued it and stamped like that call's ENG_DECODE; the last is
+    # booked by the call that drains the pipeline and enqueues nothing.
+    assert sum(int(r[0]) in stamp(Ev.ENG_DECODE) for r in dec) == 4
     chosen = reference_route_counts(c, prompt)
     mine = [ch[:, :4] for ch in chosen]
     assert [int(v) for v in pre[0][3:]] == [

@@ -359,9 +359,9 @@ def test_programs_donate_the_cache(model, program, donating):
         "prefill": lambda: eng._prefill_fn(
             eng.params, old, 1, jnp.arange(8, dtype=jnp.int32), 5, key)[2],
         "install": lambda: eng._install_fn(old, 1, win, win, 5),
-        "decode": lambda: eng._decode_fn(
+        "decode": lambda: eng._decode_fn(  # lane 0 off, lane 1 on token 0
             eng.params, old, jnp.zeros((2,), jnp.int32),
-            jnp.array([False, True]), key)[1],
+            jnp.array([-2, 0], jnp.int32), key)[2],
     }[program]
     before = np.asarray(old["k"]).copy()
     out = donating(call, old["k"], old["v"])

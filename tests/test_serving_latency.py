@@ -142,12 +142,15 @@ def test_ttft_accounting_is_exact_on_virtual_clock():
     the deterministic pin of the TTFT accounting path."""
     eng, vt = _engine_on_virtual_clock()
     # One step() = admit + prefill (token 1 from the prompt's last
-    # logits) + one decode token — so 3 tokens span two steps.
+    # logits) + one decode token enqueued, booked by the next step() —
+    # so 3 tokens span three steps.
     r0 = eng.submit([1, 2, 3], max_new_tokens=3)
     vt[0] = 0.010
-    eng.step()  # admits; tokens 1-2 at t=10ms (TTFT)
+    eng.step()  # admits; token 1 at t=10ms (TTFT), token 2 enqueued
+    vt[0] = 0.020
+    assert eng.step() == []  # token 3 enqueued, token 2 booked
     vt[0] = 0.025
-    done = list(eng.step())  # token 3 -> completion at t=25ms
+    done = list(eng.step())  # token 3 booked -> completion at t=25ms
     assert [c.request_id for c in done] == [r0]
     assert done[0].ttft_s == pytest.approx(0.010, abs=1e-9)
     assert done[0].latency_s == pytest.approx(0.025, abs=1e-9)

@@ -364,7 +364,7 @@ def test_slot_cache_updated_in_place_at_the_mistral_cell():
     lowered = {
         "decode": eng._decode_fn.lower(
             eng.params, eng.cache, jnp.zeros((slots,), jnp.int32),
-            jnp.zeros((slots,), bool), key),
+            jnp.zeros((slots,), jnp.int32), key),
         "prefill": eng._prefill_fn.lower(
             eng.params, eng.cache, 0, jnp.zeros((bucket,), jnp.int32), 1,
             key),
@@ -418,7 +418,7 @@ def test_decode_reads_attention_projections_where_they_lie_in_the_stack():
                             prompt_bucket=64, max_len=cfg.max_seq)
     ops = materialised(eng._decode_fn.lower(
         eng.params, eng.cache, jnp.zeros((slots,), jnp.int32),
-        jnp.zeros((slots,), bool),
+        jnp.zeros((slots,), jnp.int32),
         jax.random.PRNGKey(0)).compile().as_text())
     # The scan is there and the reading sees into it: the MLP's
     # products write (slots, d_ff) from inside the loop.
