@@ -1,0 +1,310 @@
+"""Latent attention that selects its positions (multi-head latent
+attention, arXiv:2405.04434, under a learned indexer: DeepSeek sparse
+attention as DeepSeek-V3.2-Exp's ``Indexer`` writes it) for the slot
+engine: what a ``models.plan.MlaKind`` layer of a planned stack
+computes.
+
+A position is kept as three rows, nothing a head: the RMS-normed latent
+``ckv`` (``kv_rank``), one rotary key ``kr`` (``rope_dim``) that every
+head shares, and the indexer's key ``ik`` (``index_dim``). On the normed
+input ``h`` of position ``t``::
+
+    c_q = rmsnorm(h W_qa);   [q_n | q_r] = c_q W_qb a head;  q_r turned at t
+    [c_kv | k_r] = h W_kva;  c_kv <- rmsnorm(c_kv);          k_r turned at t
+    [k_n | v] = c_kv W_kvb a head
+    q^I = c_q W^I_q a head;  k^I = layernorm(h W^I_k);  both turned at t
+    w = h W^I_w (index_heads * index_dim)^-1/2
+    I[t, s] = sum_j w[t, j] relu(q^I[t, j] . k^I[s])           s <= t
+    S_t = the topk positions s <= t of largest I[t, s] (all, up to topk)
+    o = softmax over S_t of (q_n . k_n[s] + q_r . k_r[s]) / sqrt(qk) @ v
+
+Two programs of the engine (``models/serving.py``), in the shape of
+``models/kda.py``. :func:`mla_ingest` runs a whole prompt in the
+per-head form (keys and values read off the latent rows once, for the
+prompt), a block of queries at a time: the block's indexer scores, its
+choice, its attention under the choice, a chunk of keys at a time with
+a running maximum and sum. :func:`mla_decode` is one
+position a lane in the absorbed form: the query is carried into the
+latent space (``q_n W_kvb^T``), scores and values are read off the
+``kv_rank + rope_dim`` wide rows, which are therefore read once for all
+heads, and the result leaves through ``W_kvb``'s value half.
+
+The choice is a mask, not a gather: the ``topk``-th largest score of a
+row is found by bisection over the float's bits (:func:`top_mask`: 32
+counting passes, exact), and attention runs over every kept position
+with the others masked. The set attended is exactly ``S_t``; a tie at
+the ``topk``-th score lets every tied position in. **Padding and idle
+lanes are no-ops**: a padded position and an idle lane leave every
+cache row as it was.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from pbs_tpu.models.plan import MlaKind
+from pbs_tpu.models.quant import wload
+from pbs_tpu.models.transformer import rms_norm
+
+__all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "decode_choice", "mla_decode",
+           "mla_ingest", "top_mask"]
+
+#: Queries a block of the ingestion scores, chooses for and attends at
+#: a time: the live score tensors are ``(heads, MLA_BLOCK, keys)``, never
+#: ``(heads, prompt, prompt)``.
+MLA_BLOCK = 256
+#: Equal spans the prompt's queries are cut into, each against the keys
+#: up to its own end: the causal triangle in ``MLA_SPANS`` steps (at 4,
+#: five eighths of the square), each span one loop over its blocks.
+MLA_SPANS = 4
+#: Keys a chunk of a block's attention holds: the live float32 scores
+#: are ``(heads, MLA_BLOCK, MLA_KEYS)``, 128 MiB at 64 heads. Formed
+#: over a whole span of 6,144 or 8,192 keys at once, XLA:TPU's
+#: max-and-subtract fusion took 27 and 47 ms a block where 4,096 keys
+#: took 1.2 (PERF.md section 6, PR 41).
+MLA_KEYS = 2048
+_F32 = jnp.float32
+
+
+def _turn(x: jax.Array, cos: jax.Array, sin: jax.Array,
+          interleave: bool) -> jax.Array:
+    """Rotary on the leading ``2 * cos.shape[-1]`` dims of x's last
+    axis, the rest passed through: x (B, S, ..., D), cos and sin (B, S,
+    half). ``interleave`` turns adjacent dims ``(2i, 2i + 1)`` together,
+    else dim i with dim i + half."""
+    half = cos.shape[-1]
+    mid = (1,) * (x.ndim - 3)
+    c = cos.reshape(cos.shape[:2] + mid + (half,)).astype(x.dtype)
+    s = sin.reshape(sin.shape[:2] + mid + (half,)).astype(x.dtype)
+    rot, rest = x[..., :2 * half], x[..., 2 * half:]
+    if interleave:
+        pairs = rot.reshape(rot.shape[:-1] + (half, 2))
+        x0, x1 = pairs[..., 0], pairs[..., 1]
+        rot = jnp.stack([x0 * c - x1 * s, x1 * c + x0 * s],
+                        axis=-1).reshape(rot.shape)
+    else:
+        x0, x1 = rot[..., :half], rot[..., half:]
+        rot = jnp.concatenate([x0 * c - x1 * s, x1 * c + x0 * s], axis=-1)
+    return jnp.concatenate([rot, rest], axis=-1) if rest.shape[-1] else rot
+
+
+def top_mask(scores: jax.Array, k: int) -> jax.Array:
+    """The ``k`` largest of each row of float32 ``scores`` (..., N) as
+    a mask; every entry where ``N <= k``. Exact: the k-th largest value
+    is built bit by bit from the top (a float's bits, sign folded, order
+    as the floats do), each bit one count of the entries at or above
+    the candidate; entries equal to it are all in."""
+    if scores.shape[-1] <= k:
+        return jnp.ones(scores.shape, bool)
+    bits = jax.lax.bitcast_convert_type(scores.astype(_F32), jnp.uint32)
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def bit(i, floor):
+        cand = floor | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, cand, floor)
+
+    floor = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    return key >= floor
+
+
+def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float):
+    xf = x.astype(_F32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (xf * w.astype(_F32) + b.astype(_F32)).astype(x.dtype)
+
+
+def _rows(a: MlaKind, ap: dict, h: jax.Array, cos, sin, eps: float, dt):
+    """What a position gives, from its normed input h (B, S, d): the
+    query's no-rotary and turned parts ``q_n`` (B, S, H, nope), ``q_r``
+    (B, S, H, rope), the rows the cache keeps ``ckv`` (B, S, kv_rank),
+    ``kr`` (B, S, rope), ``ik`` (B, S, index_dim), and the indexer's
+    query ``qi`` (B, S, index_heads, index_dim) with its head weights
+    ``w`` (B, S, index_heads) float32."""
+    B, S, _ = h.shape
+    il = a.rope.interleave
+    cq = rms_norm(h @ wload(ap["wq_a"], dt), ap["q_norm"], eps)
+    q = (cq @ wload(ap["wq_b"], dt)).reshape(
+        B, S, a.n_heads, a.nope_dim + a.rope_dim)
+    q_n, q_r = q[..., :a.nope_dim], _turn(q[..., a.nope_dim:], cos, sin, il)
+    kv = h @ wload(ap["wkv_a"], dt)
+    ckv = rms_norm(kv[..., :a.kv_rank], ap["kv_norm"], eps)
+    kr = _turn(kv[..., a.kv_rank:], cos, sin, il)
+    qi = _turn((cq @ wload(ap["wi_q"], dt)).reshape(
+        B, S, a.index_heads, a.index_dim), cos, sin, il)
+    ik = _turn(_layer_norm(h @ wload(ap["wi_k"], dt), ap["ik_norm"],
+                           ap["ik_bias"], eps), cos, sin, il)
+    w = (h @ wload(ap["wi_w"], dt)).astype(_F32) \
+        / np.sqrt(a.index_heads * a.index_dim)
+    return q_n, q_r, ckv, kr, ik, qi, w
+
+
+def _halves(a: MlaKind, ap: dict, dt):
+    """``W_kvb`` as a head reads it: the key half (kv_rank, H, nope)
+    and the value half (kv_rank, H, v)."""
+    wkv = wload(ap["wkv_b"], dt).reshape(
+        a.kv_rank, a.n_heads, a.nope_dim + a.v_dim)
+    return wkv[..., :a.nope_dim], wkv[..., a.nope_dim:]
+
+
+def _put(rows: jax.Array, new: jax.Array, at: jax.Array,
+         active: jax.Array) -> jax.Array:
+    """Lane b's new row (``new``: (B, 1, W)) goes to ``rows[b, at[b]]``
+    where the lane is active; an idle lane's row is written back as it
+    was. One dynamic_update_slice a lane into the whole cache
+    (``serving._write_rows`` says why not a scatter)."""
+
+    def one(b, rows):
+        old = jax.lax.dynamic_slice(rows, (b, at[b], 0),
+                                    (1, 1) + rows.shape[2:])
+        row = jax.lax.dynamic_slice_in_dim(new, b, 1)
+        return jax.lax.dynamic_update_slice(
+            rows, jnp.where(active[b], row.astype(rows.dtype), old),
+            (b, at[b], 0))
+
+    return jax.lax.fori_loop(0, new.shape[0], one, rows)
+
+
+def decode_choice(a: MlaKind, qi: jax.Array, w: jax.Array, ik: jax.Array,
+                  row_pos: jax.Array) -> jax.Array:
+    """The positions each lane's query attends, (B, T) bool: of the
+    positions up to its own (``row_pos[b]``, whose key is in ``ik``
+    already) the ``topk`` its indexer scores highest. qi (B,
+    index_heads, index_dim), w (B, index_heads) float32, ik (B, T,
+    index_dim)."""
+    live = jnp.arange(ik.shape[1])[None, :] <= row_pos[:, None]
+    with jax.named_scope("mla.index"):
+        dots = jnp.einsum("bjd,btd->bjt", qi, ik,
+                          preferred_element_type=_F32)
+        index = jnp.einsum("bjt,bj->bt", jax.nn.relu(dots), w)
+    with jax.named_scope("mla.select"):
+        return top_mask(jnp.where(live, index, -jnp.inf), a.topk) & live
+
+
+def mla_decode(a: MlaKind, ap: dict, h: jax.Array, ckv: jax.Array,
+               kr: jax.Array, ik: jax.Array, row_pos: jax.Array,
+               active: jax.Array, cos: jax.Array, sin: jax.Array,
+               eps: float, dt):
+    """One position for every lane, absorbed: h (B, 1, d) at position
+    ``row_pos[b]``, the layer's caches ``ckv`` (B, T, kv_rank), ``kr``
+    (B, T, rope), ``ik`` (B, T, index_dim), cos and sin (B, 1, rope /
+    2). An active lane's three new rows go to its cursor; an idle
+    lane's caches come out as they went in. Returns (what the heads
+    give (B, 1, H * v), ckv, kr, ik)."""
+    B = ckv.shape[0]
+    q_n, q_r, c_new, kr_new, ik_new, qi, w = _rows(a, ap, h, cos, sin,
+                                                   eps, dt)
+    ckv, kr, ik = (_put(rows, new, row_pos, active) for rows, new in (
+        (ckv, c_new), (kr, kr_new), (ik, ik_new)))
+    chosen = decode_choice(a, qi[:, 0], w[:, 0], ik, row_pos)
+    with jax.named_scope("mla.attend"):
+        w_k, w_v = _halves(a, ap, dt)
+        q_lat = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_k)
+        scores = (jnp.einsum("bhr,btr->bht", q_lat, ckv,
+                             preferred_element_type=_F32)
+                  + jnp.einsum("bhe,bte->bht", q_r[:, 0], kr,
+                               preferred_element_type=_F32)) \
+            / np.sqrt(a.nope_dim + a.rope_dim)
+        probs = jax.nn.softmax(jnp.where(
+            chosen[:, None, :], scores, jnp.finfo(_F32).min), axis=-1)
+        o_lat = jnp.einsum("bht,btr->bhr", probs.astype(dt), ckv)
+        out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
+    return out.reshape(B, 1, a.n_heads * a.v_dim), ckv, kr, ik
+
+
+def _attend_chunks(q, k, v, seen, scale: float, dt):
+    """Softmax attention of a block of queries q (Q, H, qk) over the
+    keys ``seen`` (Q, K) marks among the first K of k (S, H, qk) and v
+    (S, H, v), ``MLA_KEYS`` keys at a time with a running maximum and
+    sum (float32), so that the live scores are ``(H, Q, MLA_KEYS)``
+    whatever K: (Q, H, v) in ``dt``. A query may see nothing in a chunk
+    (its indexer chose elsewhere): that chunk adds nothing."""
+    Q, H, _ = q.shape
+    K = seen.shape[1]
+    low = jnp.finfo(_F32).min
+    top = jnp.full((H, Q), low, _F32)
+    total = jnp.zeros((H, Q), _F32)
+    acc = jnp.zeros((H, Q, v.shape[-1]), _F32)
+    for k0 in range(0, K, MLA_KEYS):
+        k1 = min(k0 + MLA_KEYS, K)
+        mask = seen[None, :, k0:k1]
+        scores = jnp.einsum("qhd,khd->hqk", q, k[k0:k1],
+                            preferred_element_type=_F32) * scale
+        peak = jnp.maximum(top, jnp.max(jnp.where(mask, scores, low), -1))
+        probs = jnp.where(mask, jnp.exp(scores - peak[..., None]), 0.0)
+        keep = jnp.exp(top - peak)
+        total = total * keep + jnp.sum(probs, -1)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "hqk,khv->hqv", probs.astype(dt), v[k0:k1],
+            preferred_element_type=_F32)
+        top = peak
+    return jnp.swapaxes(acc / total[..., None], 0, 1).astype(dt)
+
+
+def _spans(S: int, block: int) -> list[tuple[int, int]]:
+    """(first query, queries) of each span of a prompt of S rows: equal,
+    whole blocks, at most ``MLA_SPANS``."""
+    n = max(d for d in range(1, MLA_SPANS + 1) if S % (d * block) == 0)
+    return [(i * (S // n), S // n) for i in range(n)]
+
+
+def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
+               cos: jax.Array, sin: jax.Array, eps: float, dt):
+    """One prompt's pass, per head: h (1, S, d) padded, from position 0,
+    ``valid`` (1, S) its real positions. A block of queries past the
+    prompt's end is not run. Returns (what the heads give (1, S,
+    H * v), and the prompt's rows ckv (1, S, kv_rank), kr (1, S, rope),
+    ik (1, S, index_dim): the caller keeps the valid ones)."""
+    S = h.shape[1]
+    H, plen = a.n_heads, valid.sum()
+    q_n, q_r, ckv, kr, ik, qi, w = _rows(a, ap, h, cos, sin, eps, dt)
+    w_k, w_v = _halves(a, ap, dt)
+    # a head's query and key whole, the shared rotary key behind each
+    # head's own part: one product a pair, not two summed
+    q = jnp.concatenate([q_n, q_r], axis=-1)[0]
+    k = jnp.concatenate([
+        jnp.einsum("sr,rhn->shn", ckv[0], w_k),
+        jnp.broadcast_to(kr[0][:, None, :], (S, H, a.rope_dim))], axis=-1)
+    v = jnp.einsum("sr,rhv->shv", ckv[0], w_v)
+    block = min(MLA_BLOCK, S)
+    if S % block:
+        raise ValueError(f"a prompt of {S} rows is not whole blocks of "
+                         f"{block} queries")
+    scale = 1.0 / np.sqrt(a.nope_dim + a.rope_dim)
+
+    def attend(first, keys, select):
+        """Queries [first, first + block) against keys [0, keys)."""
+        cut = lambda t: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            t, first, block)
+        seen = jnp.arange(keys)[None, :] \
+            <= first + jnp.arange(block)[:, None]              # (Q, K)
+        if select:
+            with jax.named_scope("mla.index"):
+                dots = jnp.einsum("qjd,kd->jqk", cut(qi[0]), ik[0, :keys],
+                                  preferred_element_type=_F32)
+                index = jnp.einsum("jqk,qj->qk", jax.nn.relu(dots),
+                                   cut(w[0]))
+            with jax.named_scope("mla.select"):
+                seen = top_mask(jnp.where(seen, index, -jnp.inf),
+                                a.topk) & seen
+        with jax.named_scope("mla.attend"):
+            return _attend_chunks(cut(q), k, v, seen, scale,
+                                  dt).reshape(block, H * a.v_dim)
+
+    out = []
+    for start, rows in _spans(S, block):
+        # a span whose last query sits below topk chooses everything;
+        # the loop ends with the last block that holds a real position
+        keys, select = start + rows, start + rows > a.topk
+        out.append(jax.lax.fori_loop(
+            0, jnp.clip(-(-(plen - start) // block), 0, rows // block),
+            lambda i, acc, start=start, keys=keys, select=select:
+            jax.lax.dynamic_update_slice(
+                acc, attend(start + i * block, keys, select),
+                (i * block, 0)),
+            jnp.zeros((rows, H * a.v_dim), dt)))
+    return jnp.concatenate(out)[None], ckv, kr, ik

@@ -296,6 +296,13 @@ def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
     return topv * kind.routed_scale, topi
 
 
+#: Rows of a prompt the held experts take at a time: the sorted buffer
+#: has a row of ``d`` for every assignment (``top_k`` a token, held or
+#: not), three times over, so a prompt of more rows goes through in
+#: pieces of this many (8192 rows of 6144 at 8 a token: 2.4 GB whole).
+EXPERT_ROWS = 2048
+
+
 def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     """The routed part of an expert layer, as one holder of ``kind.held``
     = (first, count) of its experts computes it: route over all
@@ -308,10 +315,30 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     result summed (``parallel/expert.py``) the layer is whole.
 
     h (T, d), ``valid`` (T,) marks rows that are tokens (idle decode
-    lanes and bucket padding route nowhere and touch no expert).
+    lanes and bucket padding route nowhere and touch no expert). More
+    than ``EXPERT_ROWS`` rows (whole multiples of it) go through that
+    many at a time.
     Returns ``(y (T, d), counts)`` with ``counts`` int32
     [assignments to held experts, to absent ones, held experts touched,
     largest load of one held expert]."""
+    T, d = h.shape
+    if T > EXPERT_ROWS and T % EXPERT_ROWS == 0:
+        y, held, absent, sizes = jax.lax.map(
+            lambda piece: _held_rows(*piece, lp, kind, dt),
+            (h.reshape(-1, EXPERT_ROWS, d), valid.reshape(-1, EXPERT_ROWS)))
+        y, held, absent, sizes = (y.reshape(T, d), held.sum(), absent.sum(),
+                                  sizes.sum(0))
+    else:
+        y, held, absent, sizes = _held_rows(h, valid, lp, kind, dt)
+    counts = jnp.stack([held, absent, jnp.sum(sizes > 0),
+                        jnp.max(sizes)]).astype(jnp.int32)
+    return y, counts
+
+
+def _held_rows(h: jax.Array, valid: jax.Array, lp: dict, kind, dt):
+    """:func:`held_expert_ffn` on rows that go through at once: ``(y,
+    assignments to held experts, to absent ones, rows each held expert
+    got (count,))``."""
     T, d = h.shape
     k = kind.top_k
     first, n = kind.held
@@ -337,10 +364,8 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
         out = out[back].reshape(T, k, d)
         y = jnp.sum(jnp.where(here[:, :, None],
                               out * w[:, :, None].astype(dt), 0), axis=1)
-    counts = jnp.stack([
-        jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
-        jnp.sum(sizes > 0), jnp.max(sizes)]).astype(jnp.int32)
-    return y.astype(dt), counts
+    return (y.astype(dt), jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
+            sizes)
 
 
 def shared_expert_ffn(h: jax.Array, lp: dict, dt) -> jax.Array:

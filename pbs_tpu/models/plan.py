@@ -21,6 +21,9 @@ tick)::
     blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, w_x, dt_norm,
                       b_norm, c_norm, w_dt, dt_bias, a_log, d_skip,
                       w_out}                                  state space
+    blocks/<NN>/attn/{attn_norm, wq_a, q_norm, wq_b, wkv_a, kv_norm,
+                      wkv_b, wo, wi_q, wi_k, ik_norm, ik_bias,
+                      wi_w}                          latent, selecting
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
     blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
                      [, ws1, ws3, ws2]}
@@ -43,9 +46,11 @@ import numpy as np
 @dataclasses.dataclass(frozen=True)
 class Rope:
     """Rotary embedding of one attention kind. ``rotary_dim`` leading
-    dims of each head rotate (half-split convention), the rest pass
-    through; ``None`` is the whole head. ``factor`` > 1 is YaRN as
-    Hugging Face's ``_compute_yarn_parameters`` writes it."""
+    dims of each head rotate (half-split convention: dim i with dim
+    i + rotary_dim / 2; with ``interleave`` adjacent dims ``(2i, 2i +
+    1)`` turn together), the rest pass through; ``None`` is the whole
+    head. ``factor`` > 1 is YaRN as Hugging Face's
+    ``_compute_yarn_parameters`` writes it."""
 
     theta: float = 10_000.0
     rotary_dim: int | None = None
@@ -54,6 +59,7 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    interleave: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +79,11 @@ class AttnKind:
         if self.head_gate and self.wide_gate:
             raise ValueError(f"attention kind {self.name!r} names two "
                              f"output gates")
+        if self.rope is not None and self.rope.interleave:
+            raise NotImplementedError(
+                f"attention kind {self.name!r}: a softmax layer's rotary "
+                f"is half-split; adjacent pairs are turned in a latent "
+                f"layer alone (models/mla.py)")
 
     @property
     def gate(self) -> str | None:
@@ -120,6 +131,41 @@ class MambaKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class MlaKind:
+    """Latent attention that selects its positions (multi-head latent
+    attention under a learned indexer: DeepSeek sparse attention). No
+    keys or values a head are kept: a position is one RMS-normed latent
+    row of ``kv_rank``, one rotary key of ``rope_dim`` shared by every
+    head, and one indexer key of ``index_dim``. A query (``n_heads`` of
+    ``nope_dim + rope_dim``, from a latent of ``q_rank``) attends the
+    ``topk`` earlier positions whose indexer score (``index_heads``
+    heads, a weight a head, relu) is largest, all of them while there
+    are no more than ``topk``; a head's key and value (``nope_dim``,
+    ``v_dim``) are read off the latent row (``models/mla.py``).
+    ``rope`` turns ``rope_dim`` dims of a query, of the shared key and
+    of the indexer's query and key (their leading ones)."""
+
+    name: str
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    index_heads: int
+    index_dim: int
+    topk: int
+    rope: Rope
+
+    def __post_init__(self):
+        if self.rope.rotary_dim != self.rope_dim:
+            raise ValueError(
+                f"attention kind {self.name!r}: its rotary turns "
+                f"{self.rope.rotary_dim} dims, its rotary key has "
+                f"{self.rope_dim}")
+
+
+@dataclasses.dataclass(frozen=True)
 class MlpKind:
     """``n_experts`` 0 is a dense SwiGLU of width ``d_ff``. Otherwise
     ``d_ff`` is one routed expert's width, the router scores all
@@ -142,7 +188,7 @@ class MlpKind:
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    attn: tuple[AttnKind | KdaKind | MambaKind, ...]
+    attn: tuple[AttnKind | KdaKind | MambaKind | MlaKind, ...]
     mlp: tuple[MlpKind, ...]
     layers: tuple[tuple[int, int], ...]  # per layer: (attn i, mlp i)
 
@@ -157,6 +203,13 @@ class LayerPlan:
         mask."""
         return any(isinstance(self.attn[a], (KdaKind, MambaKind))
                    for a, _ in self.layers)
+
+    @property
+    def select_topk(self) -> int | None:
+        """The most positions a selecting layer's query attends; None
+        where every layer attends all it keeps."""
+        return max((self.attn[a].topk for a, _ in self.layers
+                    if isinstance(self.attn[a], MlaKind)), default=None)
 
     def kinds(self, layer: int) -> tuple[AttnKind, MlpKind]:
         a, m = self.layers[layer]
@@ -246,6 +299,18 @@ def plan_shapes(cfg) -> dict:
                     "b_norm": (n,), "c_norm": (n,), "w_dt": (r, c),
                     "dt_bias": (c,), "a_log": (n, c), "d_skip": (c,),
                     "w_out": (c, d)}
+        elif isinstance(a, MlaKind):
+            H, qk = a.n_heads, a.nope_dim + a.rope_dim
+            attn = {"attn_norm": (d,), "wq_a": (d, a.q_rank),
+                    "q_norm": (a.q_rank,), "wq_b": (a.q_rank, H * qk),
+                    "wkv_a": (d, a.kv_rank + a.rope_dim),
+                    "kv_norm": (a.kv_rank,),
+                    "wkv_b": (a.kv_rank, H * (a.nope_dim + a.v_dim)),
+                    "wo": (H * a.v_dim, d),
+                    "wi_q": (a.q_rank, a.index_heads * a.index_dim),
+                    "wi_k": (d, a.index_dim), "ik_norm": (a.index_dim,),
+                    "ik_bias": (a.index_dim,),
+                    "wi_w": (d, a.index_heads)}
         else:
             attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
                     "wk": (d, nkv * hd), "wv": (d, nkv * hd),
@@ -278,7 +343,8 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
     published layer's own start), the router's bias a small normal. A
     state-space layer starts as Mamba does: ``a_log`` the log of 1 ..
     ``d_state`` a channel, the same ``dt_bias``, ``d_skip`` one, the
-    convolution's filter and bias uniform in +-1/2."""
+    convolution's filter and bias uniform in +-1/2. A selecting layer's
+    ``ik_bias`` (its indexer key's LayerNorm) a tenth of a normal."""
     shapes = plan_shapes(cfg)
     flat, treedef = jax.tree.flatten(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
@@ -313,6 +379,9 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
             continue
         if name == "router_bias":
             leaves.append(0.005 * jax.random.normal(k, shape, jnp.float32))
+            continue
+        if name == "ik_bias":
+            leaves.append(0.1 * jax.random.normal(k, shape, jnp.float32))
             continue
         w = jax.random.normal(k, shape, jnp.float32) / np.sqrt(shape[-2])
         leaves.append(w * np.sqrt(cfg.d_model) if name == "embed" else w)

@@ -54,14 +54,15 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
+from pbs_tpu.models.mla import mla_decode, mla_ingest
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
 from pbs_tpu.obs.trace import (
     Ev, TraceBuffer, host_phase, host_ring, register_ring,
 )
 from pbs_tpu.models.plan import (
-    KdaKind, MambaKind, block_name, init_plan_params, plan_of, rope_table,
-    uniform_plan)
+    KdaKind, MambaKind, MlaKind, block_name, init_plan_params, plan_of,
+    rope_table, uniform_plan)
 from pbs_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -291,10 +292,15 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     (q, k and v side by side), ``(slots, kernel - 1, 3 * H * hd)``; a
     state-space layer keeps ``ssm``, one float32 ``(slots, d_state,
     d_inner)`` matrix (the channels last, where they fill the lanes),
-    and its own ``conv``, ``(slots, kernel - 1, d_inner)``. One cursor
-    a slot serves all: which ring entries are live follows from it
-    alone, and a state needs none. ``state``, ``ssm`` and ``conv`` are
-    there only where some layer has them."""
+    and its own ``conv``, ``(slots, kernel - 1, d_inner)``; a latent
+    layer keeps every position too, but nothing a head: its RMS-normed
+    latent row ``ckv``, ``(slots, max_len, kv_rank)``, the one rotary
+    key every head shares ``kr``, ``(slots, max_len, rope_dim)``, and
+    its indexer's key ``ik``, ``(slots, max_len, index_dim)``. One
+    cursor a slot serves all: which ring entries and which latent rows
+    are live follows from it alone, and a state needs none. ``state``,
+    ``ssm``, ``conv``, ``ckv``, ``kr`` and ``ik`` are there only where
+    some layer has them."""
     plan = plan_of(cfg)
     out: dict = {"k": {}, "v": {},
                  "pos": jnp.zeros((n_slots,), jnp.int32)}
@@ -313,6 +319,12 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
             out.setdefault("conv", {})[name] = jnp.zeros(
                 (n_slots, a.conv - 1, a.d_inner), cfg.dtype)
             continue
+        if isinstance(a, MlaKind):
+            for key, width in (("ckv", a.kv_rank), ("kr", a.rope_dim),
+                               ("ik", a.index_dim)):
+                out.setdefault(key, {})[name] = jnp.zeros(
+                    (n_slots, max_len, width), cfg.dtype)
+            continue
         for kv in ("k", "v"):
             out[kv][name] = jnp.zeros(
                 (n_slots, min(a.window, max_len) if a.window else max_len,
@@ -326,6 +338,11 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
 _RECURRENT = {
     KdaKind: ("attn.kda", "state", kda_decode, kda_ingest),
     MambaKind: ("attn.mamba", "ssm", mamba_decode, mamba_ingest)}
+#: A layer kind that keeps rows of its own a position, not keys and
+#: values a head: the scope its ops carry, the cache entries that hold
+#: the rows, its decode step and its prompt ingestion.
+_LATENT = {
+    MlaKind: ("attn.mla", ("ckv", "kr", "ik"), mla_decode, mla_ingest)}
 
 
 def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
@@ -345,7 +362,10 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     (banded in a window layer) and the layer leaves the prompt's keys
     and values in that slot (a window layer its last W positions, each
     where the ring keeps it); a delta-rule or state-space layer leaves
-    the prompt's state, built from zero, over whatever the slot held.
+    the prompt's state, built from zero, over whatever the slot held. A
+    latent layer writes the rows it keeps a position (at the cursor of
+    every lane ``valid`` marks; the prompt's real positions into the
+    slot) and attends the positions its indexer picks.
 
     ``valid`` (B, S) marks real tokens: the expert layers route nothing
     else, and no state folds anything else in. Returns (logits fp32:
@@ -368,7 +388,8 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     new = {key: dict(entries) for key, entries in cache.items()
            if key != "pos"}
     ks, vs = new["k"], new["v"]
-    T = max([cfg.max_seq] + [c.shape[1] for c in ks.values()])
+    T = max([cfg.max_seq] + [c.shape[1] for key in ("k", "ckv")
+                             for c in new.get(key, {}).values()])
     abs_pos = jnp.minimum(
         row_pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :], T - 1)
     tables = {a.rope: rope_table(a.rope, hd, T) for a in plan.attn
@@ -398,6 +419,9 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                     new["conv"][name] = jax.lax.dynamic_update_slice(
                         new["conv"][name], tail, (slot, 0, 0))
             x = x + y
+        elif type(a) in _LATENT:
+            x = x + _latent_layer(a, ap, h, new, name, row_pos, valid,
+                                  abs_pos, tables, slot, cfg.norm_eps, dt)
         else:
             x = x + _softmax_layer(a, ap, h, ks, vs, name, row_pos, valid,
                                    abs_pos, tables, slot, nkv, hd, dt)
@@ -486,6 +510,35 @@ def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
     return attn.reshape(B, S, H * hd) @ wload(ap["wo"], dt)
 
 
+def _latent_layer(a, ap: dict, h: jax.Array, new: dict, name: str,
+                  row_pos, valid, abs_pos, tables: dict, slot, eps: float,
+                  dt) -> jax.Array:
+    """A latent layer of the planned stack on its normed input h (B, S,
+    d): writes the rows the layer keeps a position into its entries of
+    ``new`` (replaced in the dicts) and returns what the layer adds to
+    the stream. Padding and idle lanes change no row."""
+    scope, keys, step, ingest = _LATENT[type(a)]
+    cos, sin = (t[abs_pos] for t in tables[a.rope])
+    rows = [new[key][name] for key in keys]
+    with jax.named_scope(scope):
+        if slot is None:
+            out, *rows = step(a, ap, h, *rows, row_pos, valid[:, 0], cos,
+                              sin, eps, dt)
+        else:
+            out, *prompt = ingest(a, ap, h, valid, cos, sin, eps, dt)
+            at = (slot, 0, 0)
+            for i, fresh in enumerate(prompt):
+                K = min(fresh.shape[1], rows[i].shape[1])
+                held = jax.lax.dynamic_slice(
+                    rows[i], at, (1, K) + rows[i].shape[2:])
+                rows[i] = jax.lax.dynamic_update_slice(
+                    rows[i], jnp.where(valid[0, :K, None], fresh[:, :K],
+                                       held), at)
+        for key, r in zip(keys, rows):
+            new[key][name] = r
+        return out @ wload(ap["wo"], dt)
+
+
 class _ScanProgram:
     """Every layer alike (any dense configuration, and the ``mlp_fn``
     fixture): the layer ``lax.scan`` of ``_slot_forward`` over one
@@ -494,6 +547,8 @@ class _ScanProgram:
     #: a window of positions can be cut from, installed into and
     #: verified over every layer's cache (prefix cache, speculation)
     windows = True
+    #: no layer chooses among the positions it keeps
+    select_topk = None
 
     def __init__(self, cfg: TransformerConfig, mlp_fn=None):
         self.cfg, self.mlp_fn = cfg, mlp_fn
@@ -542,12 +597,22 @@ class _PlannedProgram:
 
     def __init__(self, cfg: TransformerConfig):
         self.cfg = cfg
+        plan = plan_of(cfg)
+        #: the most positions a selecting layer's query attends (the
+        #: engine's ``ENG_SELECT`` counts by it); None: no such layer
+        self.select_topk = plan.select_topk
         self.no_windows = (
             "a delta-rule or state-space layer keeps one recurrent state "
             "a slot, not positions: a prefix hit or a verify window would "
             "need a snapshot of that state at the window's end (ROADMAP "
             "R6, R23)"
-            if plan_of(cfg).recurrent else
+            if plan.recurrent else
+            "a latent layer keeps a latent row, a rotary key and an "
+            "indexer key a position, not keys and values a head: a "
+            "window is cut from and installed into k and v alone, and a "
+            "verify window would need the indexer's choice for k + 1 "
+            "queries a lane (ROADMAP R5, R25)"
+            if plan.select_topk is not None else
             "a window layer's ring takes one position a tick, and "
             "cutting a window from it or installing one is not written "
             "(ROADMAP R4)")
@@ -565,8 +630,9 @@ class _PlannedProgram:
             raise NotImplementedError(
                 f"a planned layer stack serves on one device, not on a "
                 f"mesh of {dict(mesh.shape)}: neither the window ring's, "
-                f"the recurrent state's nor the held experts' division "
-                f"over a tensor axis is written (ROADMAP R4, R6, R23)")
+                f"the recurrent state's, the latent rows' nor the held "
+                f"experts' division over a tensor axis is written "
+                f"(ROADMAP R4, R5, R6, R23)")
         return jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
 
     def decode(self, params, cache, last_tok, active):
@@ -942,6 +1008,19 @@ class ContinuousBatcher:
             self._ev(ts_ns, Ev.ENG_ROUTE, self._tick_seq,
                      *(int(c) for c in route))
 
+    def _select_ev(self, ts_ns: int, live: np.ndarray) -> None:
+        """``ENG_SELECT``: how many positions each of this call's
+        queries sees (``live``, one entry a busy lane or a prompt
+        token) and how many of them a layer that chooses attends, from
+        what the host knows of its slots; stamped like the call's
+        ``ENG_DECODE`` or ``ENG_PREFILL``. Nothing for a program in
+        which no layer chooses."""
+        topk = self.program.select_topk
+        if topk is not None:
+            self._ev(ts_ns, Ev.ENG_SELECT, self._tick_seq, len(live),
+                     int(live.sum()), int(np.minimum(live, topk).sum()),
+                     topk)
+
     def _split_key(self) -> jax.Array:
         """Advance the sampling key (two tiny device programs a call)."""
         t = _ns()
@@ -1026,6 +1105,7 @@ class ContinuousBatcher:
                 t_dispatched = _ns()
                 first = np.asarray(first).ravel()
                 self._route_ev(t_prefill, first[1:])
+                self._select_ev(t_prefill, np.arange(1, len(prompt) + 1))
                 first = int(first[0])
                 self._mlp_extra_sum += float(extra) / self.cfg.n_layers
         t_synced = _ns()
@@ -1232,6 +1312,13 @@ class ContinuousBatcher:
             carry[[slot for slot, _ in fl.lanes]] = True
         mask = self.active & (self.slot_remaining > carry)
         overlapped = int(fl is not None)
+        seen = None
+        if self.program.select_topk is not None:
+            # a lane's new position sees its prompt, the tokens the host
+            # has booked and the one still in flight
+            booked = np.fromiter(map(len, self.slot_tokens), np.int64,
+                                 self.n_slots)
+            seen = (self.slot_prompt_len + booked + carry)[mask]
         t_pre = t_enqueued = _ns()
         if mask.any():
             sub = self._split_key()
@@ -1262,6 +1349,8 @@ class ContinuousBatcher:
         for route in routes:  # stamped like this call's ENG_DECODE
             self._route_ev(t_pre, route)
         if mask.any():
+            if seen is not None:
+                self._select_ev(t_pre, seen)
             self._decoded(t_pre, t_enqueued, t_host, overlapped)
         return done
 

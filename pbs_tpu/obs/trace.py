@@ -215,6 +215,17 @@ class Ev(enum.IntEnum):
     #                     absent ones, held experts touched (the last
     #                     three summed over expert layers), largest load
     #                     of one expert
+    ENG_SELECT = 0x0A08  # one a prefill and one a decode of a program
+    #                      with a layer that chooses its positions
+    #                      (models/plan.MlaKind), from the host's slot
+    #                      table, no device read: stamped like that
+    #                      ENG_PREFILL, or like the ENG_DECODE (ts and
+    #                      tick) of the step() that enqueued the decode.
+    #                      args: tick, rows (a decode's busy lanes, a
+    #                      prefill's prompt tokens), live positions (a
+    #                      query sees them: summed over the rows),
+    #                      chosen positions (min(live, topk) a row,
+    #                      summed), topk; each of one such layer
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

@@ -53,8 +53,8 @@ SpecEntry = Any
 #: positionally: ``-1`` = the innermost (tensor) mesh axis.
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
-    (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm)$",
-     ()),
+    (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm"
+     r"|q_norm|kv_norm|ik_norm)$", ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -100,6 +100,16 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"/(w_in|conv_w|w_dt)$", (None, -1)),
     (r"/(w_x|w_out)$", (-1, None)),
     (r"/(conv_b|d_skip)$", (-1,)),
+    # A latent layer (models/plan.MlaKind): the cache holds nothing a
+    # head, so what is divided is the heads' own work: the up-
+    # projections out of the two latents (``wq_b``, ``wkv_b``), the
+    # indexer's query projection and its head weights by column (a head
+    # a column block), ``wo`` by the rule above. The down-projections
+    # into the latents, the indexer's one key and its LayerNorm's bias
+    # feed every head alike and are replicated (its three norms by the
+    # norms' rule).
+    (r"/(wq_b|wkv_b|wi_q|wi_w)$", (None, -1)),
+    (r"/(wq_a|wkv_a|wi_k|ik_bias)$", ()),
 )
 
 #: The canonical param paths the table must cover (the dense
@@ -172,6 +182,19 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/attn/w_dt",
     "blocks/N/attn/d_skip",
     "blocks/N/attn/w_out",
+    # a latent layer's own leaves (its attn_norm and wo are the paths
+    # above)
+    "blocks/N/attn/wq_a",
+    "blocks/N/attn/q_norm",
+    "blocks/N/attn/wq_b",
+    "blocks/N/attn/wkv_a",
+    "blocks/N/attn/kv_norm",
+    "blocks/N/attn/wkv_b",
+    "blocks/N/attn/wi_q",
+    "blocks/N/attn/wi_k",
+    "blocks/N/attn/ik_norm",
+    "blocks/N/attn/ik_bias",
+    "blocks/N/attn/wi_w",
 )
 
 

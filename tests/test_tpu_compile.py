@@ -310,6 +310,66 @@ def test_a_state_space_layer_at_published_widths(topo, case):
     assert lowered.count("tpu_custom_call") == 1
 
 
+# GLM-5's cell whole (benchmarks/configs/glm-5.json: one dense and four
+# expert layers at the published widths, 16 of 256 experts, an eighth of
+# the vocabulary; 64 slots of 10,240 positions, prompts at 4,096 and
+# 8,192 rows), through the family's own sizing programs: the engine's
+# decode and its prefill at both rungs, each donating the cache.
+DSA_PROGRAMS = ("decode L=5", "prefill L=5 rung=4096",
+                "prefill L=5 rung=8192")
+V5E_USABLE = int(15.75 * 2 ** 30)
+
+
+@pytest.mark.parametrize("name", DSA_PROGRAMS)
+def test_the_latent_cells_programs_compile_and_fit(topo, name):
+    """Each compiles for the chip, writes the latent, rotary and indexer
+    rows into the donated cache in place (the decode needs a fraction of
+    the cache beyond its arguments, and nothing but a fusion or an
+    update in place writes a tensor of a layer's latent or indexer rows;
+    the rotary keys, 64 wide, are the shape of a layer's float32 scores
+    and XLA stages them through VMEM around the row writes: PERF.md
+    section 7), and fits: its
+    peak, weights and cache included, is under the chip's usable 15.75
+    GiB. The prefill forms scores a block of 256 queries and 2,048 keys
+    at a time, never a head's (prompt, prompt) square, and no fusion is
+    left with the tiling XLA:TPU falls back to when its search gives up
+    (``estimated_cycles`` at the int64 maximum: the softmax over a
+    whole 6,144- or 8,192-key span was one, 27-47 ms a block where
+    4,096 keys took 1.2; PERF.md section 6, PR 41)."""
+    from benchmarks.harness.spec import Spec
+
+    spec = Spec()
+    c = spec.config("glm-5")
+    one = SingleDeviceSharding(topo.devices[0])
+    prog = next(p for p in spec.family(c["family"]).sizing(
+        c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)) if p["name"] == name)
+    compiled = prog["fn"].lower(*prog["args"]).compile()
+    sv = c["serve"]
+    slots, T = sv["slots"], sv["max_len"]
+    cache = 5 * slots * T * (512 + 64 + 128) * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= cache
+    assert m.peak_memory_in_bytes < V5E_USABLE
+    assert str(2 ** 63 - 1) not in compiled.as_text()
+    beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes
+    ops = materialised(compiled.as_text())
+    rows = {tuple(sorted((slots, T, w))) for w in (512, 128)}
+    moved = written(ops, rows)
+    assert all(op[1] in ("fusion", "dynamic-update-slice", "while")
+               for op in moved), moved
+    if name.startswith("decode"):
+        # float32 scores of every lane and head over the whole cache
+        # (160 MiB) and the logits; not a copy of a layer's rows
+        assert beyond < cache // 16
+    else:
+        rung = int(name.rsplit("=", 1)[1])
+        assert beyond < (3 << 30) * rung // 8192
+        assert not written(ops, {tuple(sorted((64, rung, rung))),
+                                 tuple(sorted((32, rung, rung)))})
+
+
 def test_materialised_leaves_out_fused_computations():
     hlo = """HloModule m
 
