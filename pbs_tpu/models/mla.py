@@ -31,14 +31,32 @@ heads, and the result leaves through ``W_kvb``'s value half.
 
 The choice is a mask, not a gather: the ``topk``-th largest score of a
 row is found by bisection over the float's bits (:func:`top_mask`: 32
-counting passes, exact), and attention runs over every kept position
-with the others masked. The set attended is exactly ``S_t``; a tie at
-the ``topk``-th score lets every tied position in. **Padding and idle
-lanes are no-ops**: a padded position and an idle lane leave every
-cache row as it was.
+counting passes, exact), and attention runs under the mask. The set
+attended is exactly ``S_t``; a tie at the ``topk``-th score lets every
+tied position in. **Padding and idle lanes are no-ops**: a padded
+position and an idle lane leave every cache row as it was (an idle
+lane attends its first row alone: its cursor rests where its last
+request ended, and nothing up to there is its to read).
+
+The decode's attention is, on a TPU, **one pass over a lane's live
+rows** (``ops/mla_attend.py``: a block of positions in VMEM at a time,
+scores, mask, softmax and values on the chip, the blocks past the
+lane's cursor never fetched), and anywhere else :func:`attend_rows`,
+the same arithmetic in ``jax.numpy`` over every kept row;
+:func:`_attend` chooses by the platform the program is lowered for and
+the caches' shapes, nothing a user sets. Why the mask stays and the
+chosen rows are not gathered at this cache length: a query sees ~6,600
+live rows of GLM-5's 10,240 and chooses 2,048, a third of what the
+stream reads at 710-750 GB/s; single rows of 1 KiB, and of 128 B out
+of a cache XLA:TPU keeps positions-minor, would have to come at more
+than a third of that, after a sort to turn the mask into indices of a
+fixed width that the ties do not have (``docs/SERVING.md``; PERF.md
+section 6, PR 42).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,9 +65,12 @@ import numpy as np
 from pbs_tpu.models.plan import MlaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
+from pbs_tpu.ops.mla_attend import (
+    attend_block, mla_attend, mla_attend_tiles)
 
-__all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "decode_choice", "mla_decode",
-           "mla_ingest", "top_mask"]
+__all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "attend_rows",
+           "decode_choice", "mla_decode", "mla_ingest", "streamed_block",
+           "top_mask"]
 
 #: Queries a block of the ingestion scores, chooses for and attends at
 #: a time: the live score tensors are ``(heads, MLA_BLOCK, keys)``, never
@@ -200,20 +221,63 @@ def mla_decode(a: MlaKind, ap: dict, h: jax.Array, ckv: jax.Array,
                                                    eps, dt)
     ckv, kr, ik = (_put(rows, new, row_pos, active) for rows, new in (
         (ckv, c_new), (kr, kr_new), (ik, ik_new)))
-    chosen = decode_choice(a, qi[:, 0], w[:, 0], ik, row_pos)
+    # an idle lane's cursor rests where its last request ended, and
+    # nothing up to there is its to read: it attends its first row
+    at = jnp.where(active, row_pos, 0)
+    chosen = decode_choice(a, qi[:, 0], w[:, 0], ik, at)
     with jax.named_scope("mla.attend"):
         w_k, w_v = _halves(a, ap, dt)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_k)
-        scores = (jnp.einsum("bhr,btr->bht", q_lat, ckv,
-                             preferred_element_type=_F32)
-                  + jnp.einsum("bhe,bte->bht", q_r[:, 0], kr,
-                               preferred_element_type=_F32)) \
-            / np.sqrt(a.nope_dim + a.rope_dim)
-        probs = jax.nn.softmax(jnp.where(
-            chosen[:, None, :], scores, jnp.finfo(_F32).min), axis=-1)
-        o_lat = jnp.einsum("bht,btr->bhr", probs.astype(dt), ckv)
+        o_lat = _attend(q_lat, q_r[:, 0], ckv, kr, chosen, at,
+                        scale=1.0 / np.sqrt(a.nope_dim + a.rope_dim))
         out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
     return out.reshape(B, 1, a.n_heads * a.v_dim), ckv, kr, ik
+
+
+def attend_rows(q_lat, q_r, ckv, kr, chosen, *, scale: float):
+    """What every lane's heads read off its chosen rows, in
+    ``jax.numpy`` over every kept row, the others masked: what a CPU
+    runs and what :func:`pbs_tpu.ops.mla_attend.mla_attend` is held to.
+    ``q_lat`` (B, H, kv_rank), ``q_r`` (B, H, rope), ``ckv`` (B, T,
+    kv_rank), ``kr`` (B, T, rope), ``chosen`` (B, T) bool. Returns
+    ``o_lat`` (B, H, kv_rank) in ``ckv``'s dtype."""
+    scores = (jnp.einsum("bhr,btr->bht", q_lat, ckv,
+                         preferred_element_type=_F32)
+              + jnp.einsum("bhe,bte->bht", q_r, kr,
+                           preferred_element_type=_F32)) * scale
+    probs = jax.nn.softmax(jnp.where(
+        chosen[:, None, :], scores, jnp.finfo(_F32).min), axis=-1)
+    return jnp.einsum("bht,btr->bhr", probs.astype(ckv.dtype), ckv)
+
+
+# One trace and one lowered function a cache shape, whatever the layers
+# (``models/mamba.py::_kernel_scan`` says why).
+_kernel_attend = jax.jit(mla_attend, static_argnames=("scale",))
+
+
+def _attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float):
+    """The decode's attention by the platform the program is lowered
+    for: on a TPU the one-pass kernel, where its tiling takes the
+    shapes (a latent of whole rows of 128 lanes, the cache whole blocks
+    of positions, heads by the eight); anywhere else, and for any other
+    shape, :func:`attend_rows`."""
+    if not mla_attend_tiles(q_lat.shape[1], *ckv.shape[1:]):
+        return attend_rows(q_lat, q_r, ckv, kr, chosen, scale=scale)
+    return jax.lax.platform_dependent(
+        q_lat, q_r, ckv, kr, chosen, row_pos,
+        tpu=functools.partial(_kernel_attend, scale=scale),
+        default=lambda *args: attend_rows(*args[:-1], scale=scale))
+
+
+def streamed_block(a: MlaKind, ckv: jax.Array) -> int:
+    """Positions a block of the one-pass kernel the decode runs over a
+    layer's latent rows where they lie, as :func:`_attend` decides; 0 where
+    :func:`attend_rows` runs (a shape the kernel's tiling does not
+    take, a cache on no TPU). The engine's ``ENG_SELECT`` counts the
+    blocks a tick streams by it."""
+    on_chip = all(d.platform == "tpu" for d in ckv.devices())
+    return attend_block(ckv.shape[1]) if on_chip \
+        and mla_attend_tiles(a.n_heads, *ckv.shape[1:]) else 0
 
 
 def _attend_chunks(q, k, v, seen, scale: float, dt):

@@ -54,7 +54,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
-from pbs_tpu.models.mla import mla_decode, mla_ingest
+from pbs_tpu.models.mla import mla_decode, mla_ingest, streamed_block
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
 from pbs_tpu.obs.trace import (
@@ -553,6 +553,9 @@ class _ScanProgram:
     def __init__(self, cfg: TransformerConfig, mlp_fn=None):
         self.cfg, self.mlp_fn = cfg, mlp_fn
 
+    def select_block(self, cache: dict) -> int:
+        return 0
+
     def init_params(self, key: jax.Array) -> dict:
         return init_params(self.cfg, key)
 
@@ -616,6 +619,15 @@ class _PlannedProgram:
             "a window layer's ring takes one position a tick, and "
             "cutting a window from it or installing one is not written "
             "(ROADMAP R4)")
+
+    def select_block(self, cache: dict) -> int:
+        """Positions a block of the one-pass attention a selecting
+        layer's decode runs over its rows of ``cache``
+        (``mla.streamed_block``); 0: none does."""
+        plan = plan_of(self.cfg)
+        return max((streamed_block(plan.kinds(int(name))[0], ckv)
+                    for name, ckv in cache.get("ckv", {}).items()),
+                   default=0)
 
     def init_params(self, key: jax.Array) -> dict:
         return init_plan_params(self.cfg, key)
@@ -797,6 +809,10 @@ class ContinuousBatcher:
                 jax.block_until_ready(cache)))
         self.params = params
         self.cache = cache
+        #: positions a block of a selecting layer's one-pass attention
+        #: over this cache (``ENG_SELECT`` counts a tick's blocks by
+        #: it); 0: no such layer, or its ``jax.numpy`` form runs
+        self._select_block = self.program.select_block(cache)
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
         self.queue: deque = deque()
@@ -1008,18 +1024,23 @@ class ContinuousBatcher:
             self._ev(ts_ns, Ev.ENG_ROUTE, self._tick_seq,
                      *(int(c) for c in route))
 
-    def _select_ev(self, ts_ns: int, live: np.ndarray) -> None:
+    def _select_ev(self, ts_ns: int, live: np.ndarray,
+                   block: int = 0) -> None:
         """``ENG_SELECT``: how many positions each of this call's
         queries sees (``live``, one entry a busy lane or a prompt
         token) and how many of them a layer that chooses attends, from
         what the host knows of its slots; stamped like the call's
-        ``ENG_DECODE`` or ``ENG_PREFILL``. Nothing for a program in
-        which no layer chooses."""
+        ``ENG_DECODE`` or ``ENG_PREFILL``. ``block``: the positions a
+        block of the decode's one-pass attention, which streams a
+        lane's blocks up to the one its cursor (``live - 1``) is in; 0
+        where no such kernel runs (a prefill; the ``jax.numpy`` form).
+        Nothing for a program in which no layer chooses."""
         topk = self.program.select_topk
         if topk is not None:
             self._ev(ts_ns, Ev.ENG_SELECT, self._tick_seq, len(live),
                      int(live.sum()), int(np.minimum(live, topk).sum()),
-                     topk)
+                     topk, int(((live - 1) // block + 1).sum())
+                     if block else 0)
 
     def _split_key(self) -> jax.Array:
         """Advance the sampling key (two tiny device programs a call)."""
@@ -1350,7 +1371,7 @@ class ContinuousBatcher:
             self._route_ev(t_pre, route)
         if mask.any():
             if seen is not None:
-                self._select_ev(t_pre, seen)
+                self._select_ev(t_pre, seen, self._select_block)
             self._decoded(t_pre, t_enqueued, t_host, overlapped)
         return done
 

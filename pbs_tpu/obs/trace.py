@@ -225,7 +225,11 @@ class Ev(enum.IntEnum):
     #                      prefill's prompt tokens), live positions (a
     #                      query sees them: summed over the rows),
     #                      chosen positions (min(live, topk) a row,
-    #                      summed), topk; each of one such layer
+    #                      summed), topk, blocks (the (lane, block)
+    #                      pairs the decode's one-pass attention
+    #                      streams: cursor // block + 1 a busy lane,
+    #                      summed; 0 for a prefill and where the
+    #                      jax.numpy form runs); each of one such layer
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

@@ -6,6 +6,7 @@ from pbs_tpu.ops.matmul import (
     instrumented_matmul,
     scale_stats,
 )
+from pbs_tpu.ops.mla_attend import mla_attend
 
 __all__ = [
     "MatmulStats",
@@ -13,5 +14,6 @@ __all__ = [
     "instrumented_matmul",
     "kda_state_step",
     "mamba_prompt_scan",
+    "mla_attend",
     "scale_stats",
 ]

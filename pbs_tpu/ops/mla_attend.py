@@ -1,0 +1,165 @@
+"""One pass over a lane's live latent rows a decode tick.
+
+The attention of ``models/mla.py``'s absorbed decode, per lane, over
+the rows its cache keeps (``ckv`` the latent, ``kr`` the rotary key
+every head shares) under the choice its indexer made::
+
+    s[h, t] = (q_lat[h] . ckv[t] + q_r[h] . kr[t]) / sqrt(qk)
+    o_lat[h] = softmax over the chosen t of s[h, t] @ ckv
+
+As ``jax.numpy`` XLA:TPU makes four fusions of it over *every kept
+row*: the two score products write float32 ``(lanes, heads, T)``, the
+masked softmax reads and writes them again, and the values' product
+reads ``ckv`` a second time (``models/mla.py::attend_rows``, which
+stays as the CPU lowering and as this kernel's oracle). Here a lane's
+rows are brought into VMEM a block of ``tk`` positions at a time, the
+block's scores, mask, exponentials and its part of the values' product
+are formed there with a running maximum and sum
+(``models/mla.py::_attend_chunks``' arithmetic, the block on the chip),
+and the float32 scores never leave the chip: ``ckv`` is read once.
+
+**Blocks past a lane's cursor are never fetched.** The block each
+cursor is in is scalar-prefetched; the index maps of ``ckv``, ``kr``
+and the choice clamp a grid step's block to it (a block index that
+repeats is not fetched again) and the body runs only up to it. An idle lane (``mla_decode`` rests its cursor at 0) streams one
+block. Rows behind the cursor *inside* that last block are read and
+masked, as every dead row is by the ``jax.numpy`` form: the choice is
+``decode_choice``'s own mask, so the set attended is the same
+whichever lowering ran, ties in.
+
+The caches come as they lie. XLA:TPU keeps a cache whose rows are
+narrower than a row of lanes **positions-minor** (GLM-5's ``kr``:
+``bf16[64,10240,64]{1,2,0}``, a lane's rotary keys as ``(64, T)``), so
+the kernel takes ``swapaxes(kr, 1, 2)`` in ``(rope_dim, tk)`` blocks:
+a bitcast of what lies there, dense rows of ``tk`` to fetch, and the
+rotary scores a plain product. Asked for ``(tk, rope_dim)`` blocks,
+XLA copies and pads the whole cache before every call (1.34 ms a layer
+against 0.89: PERF.md section 6, PR 42). The choice comes as an int32
+row a lane, one ``(1, tk)`` slab a block broadcast over the heads on
+the chip.
+
+At GLM-5's cell (64 lanes, 64 heads, 10,240 positions of 512 + 64,
+cursors 3,072-9,700) a layer takes 0.89 ms where the same blocks
+through the same pipeline with no arithmetic take 0.74 (713 GB/s) and
+the ``jax.numpy`` form 3.15; blocks of 512 take 1.07 (twice the grid
+steps), 2,048 the same 0.89 (fewer steps, more dead rows).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["attend_block", "mla_attend", "mla_attend_tiles"]
+
+#: Positions a block, the widest that divides the cache's length: a
+#: ``ckv`` block of 1 MiB at a latent of 512, double-buffered (the
+#: module's last paragraph has the three sizes timed).
+BLOCKS = (1024, 512)
+_F32 = jnp.float32
+
+
+def attend_block(T: int) -> int:
+    """Positions a block of a cache of ``T`` positions (0: the kernel's
+    tiling does not take that length)."""
+    return next((tk for tk in BLOCKS if T % tk == 0), 0)
+
+
+def mla_attend_tiles(heads: int, T: int, kv_rank: int) -> bool:
+    """Whether the kernel's tiling takes these caches on a chip: a
+    latent of whole rows of 128 lanes, whole blocks of positions, heads
+    by the eight (a rotary key of any width: a block holds it whole)."""
+    return kv_rank % 128 == 0 and heads % 8 == 0 and attend_block(T) > 0
+
+
+def _attend_kernel(last_ref, q_ref, qr_ref, ckv_ref, kr_ref, chosen_ref,
+                   o_ref, top_ref, total_ref, acc_ref, *, scale: float):
+    """Grid step (lane b, block j): the block's part of the lane's
+    softmax, folded into the running maximum, sum and accumulator;
+    ``last_ref[b]`` is the block the lane's cursor is in."""
+    j, last = pl.program_id(1), last_ref[pl.program_id(0)]
+    low = jnp.finfo(_F32).min
+
+    @pl.when(j == 0)
+    def _():
+        top_ref[...] = jnp.full(top_ref.shape, low, _F32)
+        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
+
+    @pl.when(j <= last)
+    def _():
+        rows = ckv_ref[0]                                       # (tk, R)
+        nt = (((1,), (1,)), ((), ()))
+        scores = (jax.lax.dot_general(q_ref[0], rows, nt,
+                                      preferred_element_type=_F32)
+                  + jnp.dot(qr_ref[0], kr_ref[0],
+                            preferred_element_type=_F32)) \
+            * scale                                             # (H, tk)
+        mask = chosen_ref[0] != 0                               # (1, tk)
+        top = top_ref[...]
+        peak = jnp.maximum(top, jnp.max(
+            jnp.where(mask, scores, low), axis=-1, keepdims=True))
+        probs = jnp.where(mask, jnp.exp(scores - peak), 0.0)
+        keep = jnp.exp(top - peak)
+        total_ref[...] = total_ref[...] * keep \
+            + jnp.sum(probs, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * keep + jnp.dot(
+            probs.astype(rows.dtype), rows, preferred_element_type=_F32)
+        top_ref[...] = peak
+
+    @pl.when(j == last)
+    def _():
+        o_ref[0] = (acc_ref[...] / total_ref[...]).astype(o_ref.dtype)
+
+
+def mla_attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float,
+               block: int | None = None, interpret: bool = False):
+    """What every lane's heads read off its chosen rows: ``q_lat`` (B,
+    H, kv_rank) and ``q_r`` (B, H, rope_dim) in the compute dtype, the
+    layer's caches ``ckv`` (B, T, kv_rank) and ``kr`` (B, T, rope_dim)
+    as they lie, ``chosen`` (B, T) bool (``decode_choice``'s: nothing
+    past ``row_pos[b]``, something at or before it), ``row_pos`` (B,)
+    each lane's cursor. Returns ``o_lat`` (B, H, kv_rank) in ``ckv``'s
+    dtype. ``models/mla.py::attend_rows`` is the same function in
+    ``jax.numpy``. Compiled, the shapes have to satisfy
+    :func:`mla_attend_tiles`; ``interpret`` (the tests) takes any,
+    and ``block`` (the tests) another block than
+    :func:`attend_block`'s."""
+    B, H, R = q_lat.shape
+    T, E = kr.shape[1:]
+    tk = block or attend_block(T)
+    if not tk or T % tk:
+        raise ValueError(f"a cache of {T} positions is not whole blocks "
+                         f"of {tk or BLOCKS}")
+    # the block a lane's cursor is in: a grid step reads none past it
+    # (the same block again, which is not fetched again)
+    last = jnp.clip(row_pos.astype(jnp.int32) // tk, 0, T // tk - 1)
+    lane = lambda b, j, last: (b, 0, 0)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_attend_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, T // tk),
+            in_specs=[
+                pl.BlockSpec((1, H, R), lane),
+                pl.BlockSpec((1, H, E), lane),
+                pl.BlockSpec((1, tk, R), lambda b, j, last: (
+                    b, jnp.minimum(j, last[b]), 0)),
+                pl.BlockSpec((1, E, tk), lambda b, j, last: (
+                    b, 0, jnp.minimum(j, last[b]))),
+                pl.BlockSpec((1, 1, tk), lambda b, j, last: (
+                    b, 0, jnp.minimum(j, last[b])))],
+            out_specs=pl.BlockSpec((1, H, R), lane),
+            scratch_shapes=[pltpu.VMEM((H, 1), _F32),
+                            pltpu.VMEM((H, 1), _F32),
+                            pltpu.VMEM((H, R), _F32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, R), ckv.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=32 << 20),
+        name="mla_attend", interpret=interpret,
+    )(last, q_lat, q_r, ckv, jnp.swapaxes(kr, 1, 2),
+      chosen.astype(jnp.int32)[:, None, :])

@@ -342,6 +342,40 @@ def test_an_idle_lane_and_a_prompts_padding_change_no_cache_row():
         assert (rows[1, plen + 1:] == after[key][1, plen + 1:]).all(), key
 
 
+def test_an_idle_lane_attends_its_first_row_alone(monkeypatch):
+    """An idle lane's cursor rests where its last request ended: the
+    decode's choice and attention are given 0 in its place (one row
+    live, one chosen, one block for the kernel to stream), an active
+    lane's its own."""
+    cfg, params, _prog, _ingest, _decode = program()
+    a = P.plan_of(cfg).kinds(0)[0]
+    ap = params["blocks"][P.block_name(0)]["attn"]
+    seen = {}
+
+    def spy(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale):
+        seen.update(chosen=np.asarray(chosen), at=np.asarray(row_pos))
+        return mla.attend_rows(q_lat, q_r, ckv, kr, chosen, scale=scale)
+
+    monkeypatch.setattr(mla, "_attend", spy)
+    B, half = 3, a.rope_dim // 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    h = jax.random.normal(ks[0], (B, 1, cfg.d_model), jnp.float32)
+    ckv, kr, ik = (
+        jax.random.normal(k, (B, MAX_LEN, w), jnp.float32)
+        for k, w in zip(ks[1:], (a.kv_rank, a.rope_dim, a.index_dim)))
+    out, *_ = mla.mla_decode(
+        a, ap, h, ckv, kr, ik, jnp.asarray([50, 70, 33]),
+        jnp.asarray([True, False, True]), jnp.ones((B, 1, half)),
+        jnp.zeros((B, 1, half)), cfg.norm_eps, jnp.float32)
+    assert seen["at"].tolist() == [50, 0, 33]
+    chosen = seen["chosen"]
+    # relu leaves many a score 0: the ties at the TOPK-th are all in
+    assert chosen[0].sum() >= TOPK and chosen[2].sum() >= TOPK
+    assert not chosen[0, 51:].any() and not chosen[2, 34:].any()
+    assert chosen[1].tolist() == [True] + [False] * (MAX_LEN - 1)
+    assert np.isfinite(np.asarray(out)).all()
+
+
 def test_the_cache_holds_latent_rows_and_nothing_a_head():
     cfg, _, prog, _, _ = program()
     cache = prog.init_cache(SLOTS, MAX_LEN)
@@ -412,12 +446,20 @@ def records(eng, event):
     return [r for r in eng.trace.peek().tolist() if r[1] == int(event)]
 
 
-def test_eng_select_counts_live_and_chosen_positions_from_the_slot_table():
+@pytest.mark.parametrize("block", [0, 16])
+def test_eng_select_counts_live_and_chosen_positions_from_the_slot_table(
+        block):
     """One record a prefill (its prompt's pairs) and one a dispatched
     decode (its busy lanes' positions), each stamped like the record of
-    the call it belongs to; sums a reader can divide."""
+    the call it belongs to; sums a reader can divide. The decode's last
+    argument counts the blocks its one-pass attention streams (a CPU
+    runs the ``jax.numpy`` form: ``block`` 0, and so the count; at 16,
+    as if the kernel ran, a lane whose cursor is 15 counts one block
+    and at 16 two), a prefill's is 0."""
     eng = engine(2)
-    lengths = (40, 9)
+    assert eng._select_block == 0
+    eng._select_block = block
+    lengths = (40, 14)
     serve(eng, prompts_of(lengths), 5)
     selects = records(eng, Ev.ENG_SELECT)
     prefills = {r[0]: r for r in records(eng, Ev.ENG_PREFILL)}
@@ -427,13 +469,16 @@ def test_eng_select_counts_live_and_chosen_positions_from_the_slot_table():
     assert len(pre) == 2 and len(pre) + len(dec) == len(selects)
     for r, n in zip(pre, lengths):
         live = np.arange(1, n + 1)
-        assert r[3:7] == [n, live.sum(), np.minimum(live, TOPK).sum(), TOPK]
+        assert r[3:8] == [n, live.sum(), np.minimum(live, TOPK).sum(), TOPK,
+                          0]
     # four decodes after each prefill's first token, both lanes busy
     assert len(dec) == 4
     for i, r in enumerate(dec):
         live = [n + 1 + i for n in lengths]
         assert r[3:7] == [2, sum(live), sum(min(v, TOPK) for v in live),
                           TOPK]
+        # cursors 40-43 lie in the third block; 14, 15 | 16, 17
+        assert r[7] == (3 + (1, 1, 2, 2)[i] if block else 0)
     assert records(ContinuousBatcher(
         *uniform_model(), n_slots=1, prompt_bucket=8, max_len=16),
         Ev.ENG_SELECT) == []

@@ -323,14 +323,21 @@ V5E_USABLE = int(15.75 * 2 ** 30)
 @pytest.mark.parametrize("name", DSA_PROGRAMS)
 def test_the_latent_cells_programs_compile_and_fit(topo, name):
     """Each compiles for the chip, writes the latent, rotary and indexer
-    rows into the donated cache in place (the decode needs a fraction of
-    the cache beyond its arguments, and nothing but a fusion or an
-    update in place writes a tensor of a layer's latent or indexer rows;
-    the rotary keys, 64 wide, are the shape of a layer's float32 scores
-    and XLA stages them through VMEM around the row writes: PERF.md
-    section 7), and fits: its
-    peak, weights and cache included, is under the chip's usable 15.75
-    GiB. The prefill forms scores a block of 256 queries and 2,048 keys
+    rows into the donated cache in place (nothing but a fusion or an
+    update in place writes a tensor of a layer's latent or indexer
+    rows; the rotary keys, 64 wide, XLA:TPU keeps positions-minor, the
+    shape of a head's float32 scores over every lane, and a prefill
+    stages them through VMEM around the row writes: PERF.md section
+    7), and fits: its peak, weights and cache included, is under the
+    chip's usable 15.75 GiB. **The decode's attention is the Mosaic
+    kernel ``mla_attend``** (the program is lowered for a TPU, whatever
+    the process's own backend), lowered once as a private function that
+    each of the five layers calls, under ``attn.mla/mla.attend``; it is
+    given the rotary keys as they lie (a bitcast, no copy), the float32
+    scores of every lane and head over the whole cache (160 MiB a
+    layer) are never formed, and the program needs 64 MiB beyond its
+    arguments where it needed 250. The prefill forms scores a block of
+    256 queries and 2,048 keys
     at a time, never a head's (prompt, prompt) square, and no fusion is
     left with the tiling XLA:TPU falls back to when its search gives up
     (``estimated_cycles`` at the int64 maximum: the softmax over a
@@ -344,7 +351,8 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
     prog = next(p for p in spec.family(c["family"]).sizing(
         c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one), tree)) if p["name"] == name)
-    compiled = prog["fn"].lower(*prog["args"]).compile()
+    lowered = prog["fn"].lower(*prog["args"])
+    compiled = lowered.compile()
     sv = c["serve"]
     slots, T = sv["slots"], sv["max_len"]
     cache = 5 * slots * T * (512 + 64 + 128) * 2
@@ -360,9 +368,21 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
     assert all(op[1] in ("fusion", "dynamic-update-slice", "while")
                for op in moved), moved
     if name.startswith("decode"):
-        # float32 scores of every lane and head over the whole cache
-        # (160 MiB) and the logits; not a copy of a layer's rows
-        assert beyond < cache // 16
+        text = lowered.as_text()
+        assert len(re.findall(r"func\.func private @\w*mla_attend",
+                              text)) == 1
+        assert len(re.findall(r"call @\w*mla_attend", text)) == 5
+        kernels = [ln for ln in compiled.as_text().splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln
+                   and re.search(r'op_name="[^"]*/attn\.mla/mla\.attend/'
+                                 r'[^"]*mla_attend', ln)]
+        assert len(kernels) == 5
+        # the logits and a layer's choice; no scores of every lane and
+        # head over the cache, no second tensor of a layer's rotary keys
+        wide = written(ops, {tuple(sorted((slots, slots, T)))})
+        assert all(op[1] in ("bitcast", "dynamic-update-slice", "while")
+                   and not op[2].startswith("f32") for op in wide), wide
+        assert beyond < 64 << 20
     else:
         rung = int(name.rsplit("=", 1)[1])
         assert beyond < (3 << 30) * rung // 8192

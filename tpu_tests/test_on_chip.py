@@ -585,3 +585,89 @@ def test_mamba_prompt_scan_compiled_at_the_cell():
           + ", ".join(f"{n} at {r} {t:.3f}" for (n, r), t in ms.items()))
     for rows in (2048, 1024):
         assert ms["mamba_prompt_scan", rows] < ms["jax.numpy scan", rows]
+
+
+def test_mla_attend_compiled_at_the_cell():
+    """The one-pass latent attention of the decode tick
+    (``ops/mla_attend.py``) compiled through Mosaic at the selecting
+    cell's size, a layer's 64 lanes of 10,240 kept positions (latent
+    512, rotary key 64, bfloat16) under 64 heads, cursors drawn
+    3,072-9,700 and two lanes at rest, the choice ``decode_choice``'s
+    over seeded indexer scores: eight sampled (lane, head) rows against
+    the softmax in float64 on the host over the same bfloat16 rows
+    (2^-6 of the lane's largest entry: the probabilities go to the
+    values' product in bfloat16, in both forms), the ``jax.numpy`` form
+    beside it; the blocks past every cursor poisoned with NaN, which
+    the kernel never reads; then five layers of it timed beside the four
+    fusions XLA makes of the ``jax.numpy`` form (PERF.md section 6, PR
+    42)."""
+    import time
+
+    from pbs_tpu.models.mla import attend_rows, top_mask
+    from pbs_tpu.ops.mla_attend import attend_block, mla_attend
+
+    B, H, T, R, E, topk, layers = 64, 64, 10240, 512, 64, 2048, 5
+    bf16, scale = jnp.bfloat16, 1.0 / 16.0
+    tk = attend_block(T)
+    rng = np.random.default_rng(42)
+    cursors = rng.integers(3072, 9700, B)
+    cursors[[5, 40]] = 0                                  # lanes at rest
+    row_pos = jnp.asarray(cursors, jnp.int32)
+
+    def layer(seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        q_lat, q_r, ckv, kr = (
+            jax.random.normal(k, s, bf16) for k, s in zip(
+                ks, ((B, H, R), (B, H, E), (B, T, R), (B, T, E))))
+        live = jnp.arange(T)[None, :] <= row_pos[:, None]
+        index = jax.random.normal(ks[4], (B, T), jnp.float32)
+        chosen = top_mask(jnp.where(live, index, -jnp.inf), topk) & live
+        return q_lat * 0.05, q_r, ckv, kr, chosen
+
+    kernel = jax.jit(lambda *a: mla_attend(*a, scale=scale))
+    numpy_way = jax.jit(lambda *a: attend_rows(*a, scale=scale))
+    args = layer(1)
+    got, ref = kernel(*args, row_pos), numpy_way(*args)
+    worst = {"mla_attend": 0.0, "jax.numpy form": 0.0}
+    for b, h in zip(rng.integers(0, B, 8), rng.integers(0, H, 8)):
+        q_lat, q_r, ckv, kr, chosen = (
+            np.asarray(t[b], np.float64) for t in args)
+        keep = chosen > 0
+        s = (ckv[keep] @ q_lat[h] + kr[keep] @ q_r[h]) * scale
+        p = np.exp(s - s.max())
+        want = (p / p.sum()) @ ckv[keep]
+        for name, out in (("mla_attend", got), ("jax.numpy form", ref)):
+            gap = np.abs(np.asarray(out[b, h], np.float64) - want).max() \
+                / np.abs(want).max()
+            worst[name] = max(worst[name], float(gap))
+    print("against float64, eight (lane, head) rows: " + ", ".join(
+        f"{n} {g:.2e}" for n, g in worst.items()))
+    assert worst["mla_attend"] < 2 ** -6, worst
+    dead = (jnp.arange(T)[None, :] // tk > row_pos[:, None] // tk)[..., None]
+    poisoned = kernel(args[0], args[1], jnp.where(dead, jnp.nan, args[2]),
+                      jnp.where(dead, jnp.nan, args[3]), args[4], row_pos)
+    assert bool(jnp.array_equal(poisoned, got))
+    del got, ref, poisoned, args, q_lat, q_r, ckv, kr, chosen
+
+    def five(attend):
+        return jax.jit(lambda ls, pos: [attend(*l, pos, scale=scale)
+                                        for l in ls])
+
+    ls = [layer(10 + i) for i in range(layers)]
+    ms = {}
+    for name, attend in (
+            ("mla_attend", mla_attend),
+            ("jax.numpy form", lambda *a, scale: attend_rows(
+                *a[:-1], scale=scale))):
+        fn = five(attend)
+        jax.block_until_ready(fn(ls, row_pos))           # compile, warm
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = fn(ls, row_pos)
+        jax.block_until_ready(out)
+        ms[name] = (time.perf_counter() - t0) / 20 * 1e3
+    blocks = int((cursors // tk + 1).sum())
+    print(f"five layers' latent attention at (64, 10240, 512 + 64), "
+          f"{blocks} of {B * T // tk} blocks of {tk} live, ms a call: "
+          + ", ".join(f"{n} {t:.2f}" for n, t in ms.items()))
+    assert ms["mla_attend"] < ms["jax.numpy form"]
