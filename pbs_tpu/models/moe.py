@@ -308,9 +308,15 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     = (first, count) of its experts computes it: route over all
     ``kind.n_experts``, keep the assignments that land on a held expert,
     sort them by expert and run one grouped matrix product a weight
-    (``jax.lax.ragged_dot``: rows of expert e against ``we*[e]``). No
+    (``jax.lax.ragged_dot``: rows of expert e against ``we*[e]``; three
+    weights an expert of the gated form, two of the ungated,
+    :func:`mlp_ffn`). No
     (T, E, C) tensor exists and no token is dropped, however the router
     concentrates: the sorted buffer has a row for every assignment.
+    Rows that make ``DENSE_PAIRS`` (row, held expert) pairs or fewer (a
+    decode tick's lanes) skip the sort and go through every held expert
+    under a weight that is zero where a row did not choose it
+    (:func:`_every_expert`): the same sum.
     What an absent expert would add is left out; with every share's
     result summed (``parallel/expert.py``) the layer is whole.
 
@@ -338,15 +344,70 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
 def _held_rows(h: jax.Array, valid: jax.Array, lp: dict, kind, dt):
     """:func:`held_expert_ffn` on rows that go through at once: ``(y,
     assignments to held experts, to absent ones, rows each held expert
-    got (count,))``."""
-    T, d = h.shape
-    k = kind.top_k
+    got (count,))``. The products are :func:`_every_expert`'s where the
+    rows times the held experts are few, :func:`_sorted_rows`' otherwise:
+    a static choice, by the shape."""
     first, n = kind.held
     with jax.named_scope("moe.route"):
         w, idx = route_top_k(h, lp["router"], kind, lp.get("router_bias"))
         local = idx - first
         ours = (local >= 0) & (local < n)
         here = ours & valid[:, None]
+    experts = _every_expert if h.shape[0] * n <= DENSE_PAIRS else _sorted_rows
+    y, sizes = experts(h, w, local, here, lp, kind, dt)
+    return (y.astype(dt), jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
+            sizes)
+
+
+#: At or under this many (row, held expert) pairs (a decode tick's
+#: lanes times a modest share of experts) every row goes through every
+#: held expert, weighted by zero where it did not choose it: the held
+#: weights are then read once each, in whole matrix products, and a
+#: row's product with them hides under that read while the rows are
+#: fewer than the chip's operations a byte (~240 on a v5e). The grouped
+#: product over sorted rows reads only the experts touched, but
+#: XLA:TPU's pays 0.05-0.1 ms a touched group whatever its few rows
+#: (PERF.md section 7, row 16): for a tick's rows that is 3-8 times the
+#: read of every held expert. The bound is the activations' and the
+#: unchosen products' size, ``pairs x width``; PERF.md section 6 (PR
+#: 43) says which cells it takes in and why no wider.
+DENSE_PAIRS = 4096
+
+
+def _every_expert(h, w, local, here, lp: dict, kind, dt):
+    """The held experts' part for few rows, without a sort: h (T, d)
+    through every held expert, (n, T, f), and out again under each
+    row's weight on each expert (0 where the row did not choose it),
+    one product over experts and width together. Returns (y (T, d),
+    rows each held expert got (n,))."""
+    n = kind.held[1]
+    with jax.named_scope("moe.route"):
+        chosen = here[:, :, None] & (local[:, :, None] == jnp.arange(n))
+        gate = jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)
+        sizes = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+    with jax.named_scope("moe.experts"):
+        def every(rows, wt):
+            if rows.ndim == 2:
+                return jnp.einsum("etd,edf->etf",
+                                  jnp.broadcast_to(rows, (n,) + rows.shape),
+                                  wload(wt, dt))
+            return jnp.einsum("etf,efd->td",
+                              rows * gate.T[:, :, None].astype(dt),
+                              wload(wt, dt))
+
+        y = mlp_ffn(h, lp["we1"], lp.get("we3"), lp["we2"], kind.form,
+                    every)
+    return y, sizes
+
+
+def _sorted_rows(h, w, local, here, lp: dict, kind, dt):
+    """The held experts' part for many rows: the assignments sorted by
+    expert, one grouped product a weight over the sorted buffer.
+    Returns (y (T, d), rows each held expert got (n,))."""
+    T, d = h.shape
+    k = kind.top_k
+    n = kind.held[1]
+    with jax.named_scope("moe.route"):
         # Held assignments sort to their expert's run; the rest behind.
         key = jnp.where(here, local, n).reshape(-1)
         order = jnp.argsort(key)
@@ -355,24 +416,39 @@ def _held_rows(h: jax.Array, valid: jax.Array, lp: dict, kind, dt):
         sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
         xs = h[order // k]
     with jax.named_scope("moe.experts"):
-        gate = jax.nn.silu(jax.lax.ragged_dot(xs, wload(lp["we1"], dt),
-                                              sizes))
-        up = jax.lax.ragged_dot(xs, wload(lp["we3"], dt), sizes)
-        out = jax.lax.ragged_dot(gate * up, wload(lp["we2"], dt), sizes)
+        def grouped(rows, wt):
+            return jax.lax.ragged_dot(rows, wload(wt, dt), sizes)
+
+        out = mlp_ffn(xs, lp["we1"], lp.get("we3"), lp["we2"], kind.form,
+                      grouped)
         # Back to (token, choice) order; rows behind the last run hold
         # whatever the grouped product left there and are never read.
         out = out[back].reshape(T, k, d)
         y = jnp.sum(jnp.where(here[:, :, None],
                               out * w[:, :, None].astype(dt), 0), axis=1)
-    return (y.astype(dt), jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
-            sizes)
+    return y, sizes
 
 
-def shared_expert_ffn(h: jax.Array, lp: dict, dt) -> jax.Array:
-    """The always-on expert every holder computes alike (added ungated)."""
+def mlp_ffn(h: jax.Array, w1, w3, w2, form: str, product) -> jax.Array:
+    """One MLP of ``form`` (``models.plan.MlpKind.form``) on its rows:
+    ``silu``, the gated ``(silu(h W1) * (h W3)) W2``, or ``relu2``, the
+    ungated ``relu(h W1)^2 W2``, which has no ``W3``. ``product(rows,
+    w)`` is the matrix product the caller's weights take (plain, or
+    grouped by expert)."""
+    if form == "silu":
+        return product(jax.nn.silu(product(h, w1)) * product(h, w3), w2)
+    if form == "relu2":
+        return product(jnp.square(jax.nn.relu(product(h, w1))), w2)
+    raise ValueError(f"unknown MLP form {form!r}")
+
+
+def shared_expert_ffn(h: jax.Array, lp: dict, dt,
+                      form: str = "silu") -> jax.Array:
+    """The always-on expert every holder computes alike (added
+    unweighted), of its layer's ``form``."""
     with jax.named_scope("moe.shared"):
-        gate = jax.nn.silu(h @ wload(lp["ws1"], dt))
-        return (gate * (h @ wload(lp["ws3"], dt))) @ wload(lp["ws2"], dt)
+        return mlp_ffn(h, lp["ws1"], lp.get("ws3"), lp["ws2"], form,
+                       lambda rows, w: rows @ wload(w, dt))
 
 
 def moe_layer_body(cfg: MoEConfig, x: jax.Array, lp: dict, cos, sin,

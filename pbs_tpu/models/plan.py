@@ -24,9 +24,16 @@ tick)::
     blocks/<NN>/attn/{attn_norm, wq_a, q_norm, wq_b, wkv_a, kv_norm,
                       wkv_b, wo, wi_q, wi_k, ik_norm, ik_bias,
                       wi_w}                          latent, selecting
+    blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, dt_bias, a_log,
+                      d_skip, g_norm, w_out}          matrix state space
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
     blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
                      [, ws1, ws3, ws2]}
+
+A block may be a mixer alone or an MLP alone (its entry of
+``LayerPlan.layers`` holds ``None`` for the half it lacks): it then has
+the one subtree, with the one norm. An MLP whose ``form`` is ``relu2``
+has no gate matrix: no ``w3``, ``we3`` or ``ws3``.
 
 A configuration without a plan is the uniform one its widths describe
 (:func:`uniform_plan`), held in the stacked ``layers/...`` tree and run
@@ -131,6 +138,43 @@ class MambaKind:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2Kind:
+    """A state-space layer whose state is a matrix a head under one
+    scalar decay (Mamba-2, arXiv:2405.21060, as ``NemotronHMamba2Mixer``
+    writes it): no keys and values are kept; a request's state is one
+    float32 ``(head_dim, d_state)`` matrix a head, the whole of it
+    decayed by ``exp(dt a)`` each token (one scalar a head: a chunk of
+    the prompt therefore goes through matrix products), and the last
+    ``conv - 1`` inputs of the short causal convolution over x, B and C
+    side by side (``models/mamba2.py``). ``B`` and ``C`` are shared by
+    the heads of a group (head j reads group ``j // (n_heads //
+    n_groups)``). The state lies ``d_state`` last: 128 states fill a
+    vector register's lanes."""
+
+    name: str
+    n_heads: int
+    head_dim: int
+    n_groups: int
+    d_state: int
+    conv: int = 4
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups:
+            raise ValueError(
+                f"attention kind {self.name!r}: {self.n_heads} heads do "
+                f"not divide into {self.n_groups} groups")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def d_conv(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+
+@dataclasses.dataclass(frozen=True)
 class MlaKind:
     """Latent attention that selects its positions (multi-head latent
     attention under a learned indexer: DeepSeek sparse attention). No
@@ -165,13 +209,20 @@ class MlaKind:
                 f"{self.rope_dim}")
 
 
+#: What an MLP computes (``MlpKind.form``).
+MLP_FORMS = ("silu", "relu2")
+
+
 @dataclasses.dataclass(frozen=True)
 class MlpKind:
-    """``n_experts`` 0 is a dense SwiGLU of width ``d_ff``. Otherwise
+    """``n_experts`` 0 is a dense MLP of width ``d_ff``. Otherwise
     ``d_ff`` is one routed expert's width, the router scores all
     ``n_experts``, and this program holds ``held`` = (first, count) of
     them: what the others would add is some other chip's to compute
-    (``parallel/expert.py``)."""
+    (``parallel/expert.py``). ``form`` is what an MLP of this kind
+    (dense, a routed expert, the shared one) computes: ``silu``, the
+    gated ``(silu(x W1) * (x W3)) W2``, or ``relu2``, the ungated
+    ``relu(x W1)^2 W2``: two matrices, not three."""
 
     name: str
     d_ff: int
@@ -184,36 +235,55 @@ class MlpKind:
     #: a learned bias (``router_bias``) that takes part in choosing the
     #: experts and not in weighting them
     scoring: str = "softmax"
+    form: str = "silu"
+
+    def __post_init__(self):
+        if self.form not in MLP_FORMS:
+            raise ValueError(f"MLP kind {self.name!r}: unknown form "
+                             f"{self.form!r}; known: {MLP_FORMS}")
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    attn: tuple[AttnKind | KdaKind | MambaKind | MlaKind, ...]
+    attn: tuple[AttnKind | KdaKind | MambaKind | Mamba2Kind | MlaKind, ...]
     mlp: tuple[MlpKind, ...]
-    layers: tuple[tuple[int, int], ...]  # per layer: (attn i, mlp i)
+    #: per layer (attn i, mlp i); None for the half a block does not
+    #: have (a mixer alone, or an MLP alone: one norm, one residual add)
+    layers: tuple[tuple[int | None, int | None], ...]
+
+    def __post_init__(self):
+        if any(a is None and m is None for a, m in self.layers):
+            raise ValueError("a block of a layer plan has a mixer, an "
+                             "MLP or both")
 
     @property
     def routed(self) -> bool:
-        return any(self.mlp[m].n_experts for _, m in self.layers)
+        return any(m is not None and self.mlp[m].n_experts
+                   for _, m in self.layers)
 
     @property
     def recurrent(self) -> bool:
         """Some layer (delta-rule or state-space) keeps a state that
         every token is folded into, not keys and values a cursor can
         mask."""
-        return any(isinstance(self.attn[a], (KdaKind, MambaKind))
-                   for a, _ in self.layers)
+        return any(a is not None and isinstance(
+            self.attn[a], (KdaKind, MambaKind, Mamba2Kind))
+            for a, _ in self.layers)
 
     @property
     def select_topk(self) -> int | None:
         """The most positions a selecting layer's query attends; None
         where every layer attends all it keeps."""
         return max((self.attn[a].topk for a, _ in self.layers
-                    if isinstance(self.attn[a], MlaKind)), default=None)
+                    if a is not None
+                    and isinstance(self.attn[a], MlaKind)), default=None)
 
-    def kinds(self, layer: int) -> tuple[AttnKind, MlpKind]:
+    def kinds(self, layer: int) -> tuple[AttnKind | None, MlpKind | None]:
+        """The layer's mixer kind and MLP kind, None for a half the
+        block does not have."""
         a, m = self.layers[layer]
-        return self.attn[a], self.mlp[m]
+        return (None if a is None else self.attn[a],
+                None if m is None else self.mlp[m])
 
 
 def block_name(layer: int) -> str:
@@ -283,56 +353,80 @@ def plan_shapes(cfg) -> dict:
         out["head"] = (d, cfg.vocab)
     for layer in range(len(plan.layers)):
         a, m = plan.kinds(layer)
-        if isinstance(a, KdaKind):
-            w, r = a.n_heads * a.head_dim, a.rank
-            attn = {"attn_norm": (d,), "wq": (d, w), "wk": (d, w),
-                    "wv": (d, w), "cq": (a.conv, w), "ck": (a.conv, w),
-                    "cv": (a.conv, w), "wa1": (d, r), "wa2": (r, w),
-                    "a_log": (a.n_heads,), "dt_bias": (w,),
-                    "wb": (d, a.n_heads), "wg1": (d, r), "wg2": (r, w),
-                    "o_norm": (a.head_dim,), "wo": (w, d)}
-        elif isinstance(a, MambaKind):
-            c, n, r = a.d_inner, a.d_state, a.dt_rank
-            attn = {"attn_norm": (d,), "w_in": (d, 2 * c),
-                    "conv_w": (a.conv, c), "conv_b": (c,),
-                    "w_x": (c, r + 2 * n), "dt_norm": (r,),
-                    "b_norm": (n,), "c_norm": (n,), "w_dt": (r, c),
-                    "dt_bias": (c,), "a_log": (n, c), "d_skip": (c,),
-                    "w_out": (c, d)}
-        elif isinstance(a, MlaKind):
-            H, qk = a.n_heads, a.nope_dim + a.rope_dim
-            attn = {"attn_norm": (d,), "wq_a": (d, a.q_rank),
-                    "q_norm": (a.q_rank,), "wq_b": (a.q_rank, H * qk),
-                    "wkv_a": (d, a.kv_rank + a.rope_dim),
-                    "kv_norm": (a.kv_rank,),
-                    "wkv_b": (a.kv_rank, H * (a.nope_dim + a.v_dim)),
-                    "wo": (H * a.v_dim, d),
-                    "wi_q": (a.q_rank, a.index_heads * a.index_dim),
-                    "wi_k": (d, a.index_dim), "ik_norm": (a.index_dim,),
-                    "ik_bias": (a.index_dim,),
-                    "wi_w": (d, a.index_heads)}
-        else:
-            attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
-                    "wk": (d, nkv * hd), "wv": (d, nkv * hd),
-                    "wo": (a.n_heads * hd, d)}
-            if a.gate:
-                attn["wg"] = (d, a.n_heads * (
-                    hd if a.gate == "elementwise" else 1))
-        f = m.d_ff
-        if not m.n_experts:
-            mlp = {"mlp_norm": (d,), "w1": (d, f), "w3": (d, f),
-                   "w2": (f, d)}
-        else:
-            n = m.held[1]
-            mlp = {"mlp_norm": (d,), "router": (d, m.n_experts),
-                   "we1": (n, d, f), "we3": (n, d, f), "we2": (n, f, d)}
-            if m.scoring == "sigmoid":
-                mlp["router_bias"] = (m.n_experts,)
-            if m.shared_d_ff:
-                s = m.shared_d_ff
-                mlp.update({"ws1": (d, s), "ws3": (d, s), "ws2": (s, d)})
-        out["blocks"][block_name(layer)] = {"attn": attn, "mlp": mlp}
+        block = out["blocks"][block_name(layer)] = {}
+        if a is not None:
+            block["attn"] = _attn_shapes(a, d, hd, nkv)
+        if m is not None:
+            block["mlp"] = _mlp_shapes(m, d)
     return out
+
+
+def _attn_shapes(a, d: int, hd: int, nkv: int) -> dict:
+    """A mixer's leaves, by its kind."""
+    if isinstance(a, KdaKind):
+        w, r = a.n_heads * a.head_dim, a.rank
+        return {"attn_norm": (d,), "wq": (d, w), "wk": (d, w),
+                "wv": (d, w), "cq": (a.conv, w), "ck": (a.conv, w),
+                "cv": (a.conv, w), "wa1": (d, r), "wa2": (r, w),
+                "a_log": (a.n_heads,), "dt_bias": (w,),
+                "wb": (d, a.n_heads), "wg1": (d, r), "wg2": (r, w),
+                "o_norm": (a.head_dim,), "wo": (w, d)}
+    if isinstance(a, MambaKind):
+        c, n, r = a.d_inner, a.d_state, a.dt_rank
+        return {"attn_norm": (d,), "w_in": (d, 2 * c),
+                "conv_w": (a.conv, c), "conv_b": (c,),
+                "w_x": (c, r + 2 * n), "dt_norm": (r,),
+                "b_norm": (n,), "c_norm": (n,), "w_dt": (r, c),
+                "dt_bias": (c,), "a_log": (n, c), "d_skip": (c,),
+                "w_out": (c, d)}
+    if isinstance(a, Mamba2Kind):
+        c, H = a.d_inner, a.n_heads
+        # w_in's columns: z (c) | x (c) | B | C (groups x d_state
+        # each) | dt (H); the convolution runs over x, B and C
+        return {"attn_norm": (d,), "w_in": (d, c + a.d_conv + H),
+                "conv_w": (a.conv, a.d_conv), "conv_b": (a.d_conv,),
+                "dt_bias": (H,), "a_log": (H,), "d_skip": (H,),
+                "g_norm": (c,), "w_out": (c, d)}
+    if isinstance(a, MlaKind):
+        H, qk = a.n_heads, a.nope_dim + a.rope_dim
+        return {"attn_norm": (d,), "wq_a": (d, a.q_rank),
+                "q_norm": (a.q_rank,), "wq_b": (a.q_rank, H * qk),
+                "wkv_a": (d, a.kv_rank + a.rope_dim),
+                "kv_norm": (a.kv_rank,),
+                "wkv_b": (a.kv_rank, H * (a.nope_dim + a.v_dim)),
+                "wo": (H * a.v_dim, d),
+                "wi_q": (a.q_rank, a.index_heads * a.index_dim),
+                "wi_k": (d, a.index_dim), "ik_norm": (a.index_dim,),
+                "ik_bias": (a.index_dim,),
+                "wi_w": (d, a.index_heads)}
+    attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
+            "wk": (d, nkv * hd), "wv": (d, nkv * hd),
+            "wo": (a.n_heads * hd, d)}
+    if a.gate:
+        attn["wg"] = (d, a.n_heads * (
+            hd if a.gate == "elementwise" else 1))
+    return attn
+
+
+def _mlp_shapes(m: MlpKind, d: int) -> dict:
+    """An MLP's leaves: a gate matrix (``w3``, ``we3``, ``ws3``) only
+    where its form has a gate."""
+
+    def matrices(stem: str, width: int, lead: tuple = ()) -> dict:
+        out = {stem + "1": lead + (d, width), stem + "2": lead + (width, d)}
+        if m.form == "silu":
+            out[stem + "3"] = lead + (d, width)
+        return out
+
+    if not m.n_experts:
+        return {"mlp_norm": (d,), **matrices("w", m.d_ff)}
+    mlp = {"mlp_norm": (d,), "router": (d, m.n_experts),
+           **matrices("we", m.d_ff, (m.held[1],))}
+    if m.scoring == "sigmoid":
+        mlp["router_bias"] = (m.n_experts,)
+    if m.shared_d_ff:
+        mlp.update(matrices("ws", m.shared_d_ff))
+    return mlp
 
 
 def init_plan_params(cfg, key: jax.Array) -> dict:
@@ -343,7 +437,9 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
     published layer's own start), the router's bias a small normal. A
     state-space layer starts as Mamba does: ``a_log`` the log of 1 ..
     ``d_state`` a channel, the same ``dt_bias``, ``d_skip`` one, the
-    convolution's filter and bias uniform in +-1/2. A selecting layer's
+    convolution's filter and bias uniform in +-1/2; one whose state is
+    a matrix a head as Mamba-2 does: ``a_log`` a head the log of
+    uniform(1, 16), the rest alike. A selecting layer's
     ``ik_bias`` (its indexer key's LayerNorm) a tenth of a normal."""
     shapes = plan_shapes(cfg)
     flat, treedef = jax.tree.flatten(

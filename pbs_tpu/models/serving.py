@@ -54,6 +54,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
+from pbs_tpu.models.mamba2 import mamba2_decode, mamba2_ingest
 from pbs_tpu.models.mla import mla_decode, mla_ingest, streamed_block
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.generate import _sample
@@ -61,8 +62,8 @@ from pbs_tpu.obs.trace import (
     Ev, TraceBuffer, host_phase, host_ring, register_ring,
 )
 from pbs_tpu.models.plan import (
-    KdaKind, MambaKind, MlaKind, block_name, init_plan_params, plan_of,
-    rope_table, uniform_plan)
+    KdaKind, Mamba2Kind, MambaKind, MlaKind, block_name, init_plan_params,
+    plan_of, rope_table, uniform_plan)
 from pbs_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -292,7 +293,11 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     (q, k and v side by side), ``(slots, kernel - 1, 3 * H * hd)``; a
     state-space layer keeps ``ssm``, one float32 ``(slots, d_state,
     d_inner)`` matrix (the channels last, where they fill the lanes),
-    and its own ``conv``, ``(slots, kernel - 1, d_inner)``; a latent
+    and its own ``conv``, ``(slots, kernel - 1, d_inner)``; one whose
+    state is a matrix a head keeps ``ssm`` too, float32 ``(slots,
+    n_heads, head_dim, d_state)`` (the states last, where they fill the
+    lanes), and ``conv`` over x, B and C side by side, ``(slots, kernel
+    - 1, d_inner + 2 groups d_state)``; a latent
     layer keeps every position too, but nothing a head: its RMS-normed
     latent row ``ckv``, ``(slots, max_len, kv_rank)``, the one rotary
     key every head shares ``kr``, ``(slots, max_len, rope_dim)``, and
@@ -300,13 +305,15 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     cursor a slot serves all: which ring entries and which latent rows
     are live follows from it alone, and a state needs none. ``state``,
     ``ssm``, ``conv``, ``ckv``, ``kr`` and ``ik`` are there only where
-    some layer has them."""
+    some layer has them; a block without a mixer keeps nothing."""
     plan = plan_of(cfg)
     out: dict = {"k": {}, "v": {},
                  "pos": jnp.zeros((n_slots,), jnp.int32)}
     for layer in range(len(plan.layers)):
         a, _ = plan.kinds(layer)
         name = block_name(layer)
+        if a is None:
+            continue
         if isinstance(a, KdaKind):
             out.setdefault("state", {})[name] = jnp.zeros(
                 (n_slots, a.n_heads, a.head_dim, a.head_dim), jnp.float32)
@@ -318,6 +325,12 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
                 (n_slots, a.d_state, a.d_inner), jnp.float32)
             out.setdefault("conv", {})[name] = jnp.zeros(
                 (n_slots, a.conv - 1, a.d_inner), cfg.dtype)
+            continue
+        if isinstance(a, Mamba2Kind):
+            out.setdefault("ssm", {})[name] = jnp.zeros(
+                (n_slots, a.n_heads, a.head_dim, a.d_state), jnp.float32)
+            out.setdefault("conv", {})[name] = jnp.zeros(
+                (n_slots, a.conv - 1, a.d_conv), cfg.dtype)
             continue
         if isinstance(a, MlaKind):
             for key, width in (("ckv", a.kv_rank), ("kr", a.rope_dim),
@@ -337,7 +350,8 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
 #: ``conv``), its decode step and its prompt ingestion.
 _RECURRENT = {
     KdaKind: ("attn.kda", "state", kda_decode, kda_ingest),
-    MambaKind: ("attn.mamba", "ssm", mamba_decode, mamba_ingest)}
+    MambaKind: ("attn.mamba", "ssm", mamba_decode, mamba_ingest),
+    Mamba2Kind: ("attn.mamba2", "ssm", mamba2_decode, mamba2_ingest)}
 #: A layer kind that keeps rows of its own a position, not keys and
 #: values a head: the scope its ops carry, the cache entries that hold
 #: the rows, its decode step and its prompt ingestion.
@@ -350,7 +364,8 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                   slot=None):
     """The planned stack over (B, S) tokens, layer by layer (a layer's
     kinds are static, so each layer is its own code over its own
-    parameters and its own cache).
+    parameters and its own cache). A block that is a mixer alone or an
+    MLP alone runs its one norm and its one half, and adds once.
 
     ``slot`` None is the decode tick: S == 1, row b at position
     ``row_pos[b]``; each layer writes its one new position (full: at
@@ -375,7 +390,8 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     experts touched (both summed over expert layers), largest load of
     one expert], None for a stack without experts). Donated, the cache
     is updated in place."""
-    from pbs_tpu.models.moe import held_expert_ffn, shared_expert_ffn
+    from pbs_tpu.models.moe import (
+        held_expert_ffn, mlp_ffn, shared_expert_ffn)
 
     plan = plan_of(cfg)
     B, S = tokens.shape
@@ -401,41 +417,48 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     for layer in range(len(plan.layers)):
         a, m = plan.kinds(layer)
         name = block_name(layer)
-        ap, mp = params["blocks"][name]["attn"], params["blocks"][name]["mlp"]
-        h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
-        if type(a) in _RECURRENT:
-            scope, key, step, ingest = _RECURRENT[type(a)]
-            with jax.named_scope(scope):
-                if decode:
-                    y, new[key][name], new["conv"][name] = step(
-                        a, ap, h, new[key][name], new["conv"][name],
-                        valid[:, 0], cfg.norm_eps, dt)
-                else:
-                    y, state, tail = ingest(a, ap, h, valid, cfg.norm_eps,
-                                            dt)
-                    new[key][name] = jax.lax.dynamic_update_slice(
-                        new[key][name], state,
-                        (slot,) + (0,) * (state.ndim - 1))
-                    new["conv"][name] = jax.lax.dynamic_update_slice(
-                        new["conv"][name], tail, (slot, 0, 0))
-            x = x + y
-        elif type(a) in _LATENT:
-            x = x + _latent_layer(a, ap, h, new, name, row_pos, valid,
-                                  abs_pos, tables, slot, cfg.norm_eps, dt)
-        else:
-            x = x + _softmax_layer(a, ap, h, ks, vs, name, row_pos, valid,
-                                   abs_pos, tables, slot, nkv, hd, dt)
+        block = params["blocks"][name]
+        if a is not None:
+            ap = block["attn"]
+            h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
+            if type(a) in _RECURRENT:
+                scope, key, step, ingest = _RECURRENT[type(a)]
+                with jax.named_scope(scope):
+                    if decode:
+                        y, new[key][name], new["conv"][name] = step(
+                            a, ap, h, new[key][name], new["conv"][name],
+                            valid[:, 0], cfg.norm_eps, dt)
+                    else:
+                        y, state, tail = ingest(a, ap, h, valid,
+                                                cfg.norm_eps, dt)
+                        new[key][name] = jax.lax.dynamic_update_slice(
+                            new[key][name], state,
+                            (slot,) + (0,) * (state.ndim - 1))
+                        new["conv"][name] = jax.lax.dynamic_update_slice(
+                            new["conv"][name], tail, (slot, 0, 0))
+                x = x + y
+            elif type(a) in _LATENT:
+                x = x + _latent_layer(a, ap, h, new, name, row_pos, valid,
+                                      abs_pos, tables, slot, cfg.norm_eps,
+                                      dt)
+            else:
+                x = x + _softmax_layer(a, ap, h, ks, vs, name, row_pos,
+                                       valid, abs_pos, tables, slot, nkv,
+                                       hd, dt)
+        if m is None:
+            continue
 
+        mp = block["mlp"]
         h = rms_norm(x, mp["mlp_norm"], cfg.norm_eps)
         if not m.n_experts:
             with jax.named_scope("mlp.dense"):
-                gate = jax.nn.silu(h @ wload(mp["w1"], dt))
-                y = (gate * (h @ wload(mp["w3"], dt))) @ wload(mp["w2"], dt)
+                y = mlp_ffn(h, mp["w1"], mp.get("w3"), mp["w2"], m.form,
+                            lambda rows, w: rows @ wload(w, dt))
         else:
             hf = h.reshape(B * S, -1)
             y, c = held_expert_ffn(hf, mp, m, flat_valid, dt)
             if m.shared_d_ff:
-                y = y + shared_expert_ffn(hf, mp, dt)
+                y = y + shared_expert_ffn(hf, mp, dt, m.form)
             y = y.reshape(B, S, -1)
             counts = jnp.concatenate(
                 [counts[:3] + c[:3], jnp.maximum(counts[3:], c[3:])])
@@ -605,6 +628,13 @@ class _PlannedProgram:
         #: engine's ``ENG_SELECT`` counts by it); None: no such layer
         self.select_topk = plan.select_topk
         self.no_windows = (
+            "a matrix-state layer keeps one float32 (head_dim, d_state) "
+            "state a head and a convolution tail a slot, not positions, "
+            "and every token of a prompt is folded into it: a prefix hit, "
+            "a preemption or a verify window would need a snapshot of "
+            "that state (megabytes a layer) at the window's end (ROADMAP "
+            "R23)"
+            if any(isinstance(a, Mamba2Kind) for a in plan.attn) else
             "a delta-rule or state-space layer keeps one recurrent state "
             "a slot, not positions: a prefix hit or a verify window would "
             "need a snapshot of that state at the window's end (ROADMAP "

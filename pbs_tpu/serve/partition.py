@@ -54,7 +54,7 @@ SpecEntry = Any
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
     (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm"
-     r"|q_norm|kv_norm|ik_norm)$", ()),
+     r"|q_norm|kv_norm|ik_norm|g_norm)$", ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -97,6 +97,13 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     # rule above (a rule reads a path, not a rank, and there they are
     # one entry a head or a channel): replicated, 340 KB a layer; the
     # step's, B's and C's small norms by the norms' rule.
+    # A matrix-state layer (models/plan.Mamba2Kind) goes by the same
+    # paths: ``w_in`` (z | x | B | C | dt side by side), the filter and
+    # its bias over x, B and C, ``d_skip`` one a head, ``w_out`` by row;
+    # its gated norm's weight ``g_norm`` by the norms' rule. Read on a
+    # tensor axis these cut ``w_in``'s columns across its parts, not by
+    # heads: a planned stack serves on one device (``place_cache``),
+    # and the state's division by heads is not written (ROADMAP R23).
     (r"/(w_in|conv_w|w_dt)$", (None, -1)),
     (r"/(w_x|w_out)$", (-1, None)),
     (r"/(conv_b|d_skip)$", (-1,)),
@@ -182,6 +189,8 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/attn/w_dt",
     "blocks/N/attn/d_skip",
     "blocks/N/attn/w_out",
+    # a matrix-state layer's own leaf (the rest are the paths above)
+    "blocks/N/attn/g_norm",
     # a latent layer's own leaves (its attn_norm and wo are the paths
     # above)
     "blocks/N/attn/wq_a",

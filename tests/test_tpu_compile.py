@@ -390,6 +390,70 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
                                  tuple(sorted((32, rung, rung)))})
 
 
+# Nemotron-3-Nano's cell whole (benchmarks/configs/
+# nemotron-3-nano-30b-a3b.json: the first 26 blocks at the published
+# widths, 12 Mamba-2 mixers, 11 expert layers of 32 held experts, 3
+# attention layers; 128 slots of 3,072 positions, prompts at 1,024 and
+# 2,048 rows), through the family's own sizing programs.
+MAMBA2_PROGRAMS = ("decode L=26", "prefill L=26 rung=1024",
+                   "prefill L=26 rung=2048")
+
+
+@pytest.mark.parametrize("name", MAMBA2_PROGRAMS)
+def test_the_matrix_state_cells_programs_compile_and_fit(topo, name):
+    """Each compiles for the chip, donates the cache whole (a float32
+    ``(slots, 64, 64, 128)`` state and a 3-row tail for each of the 12
+    Mamba-2 blocks, keys and values for the 3 attention blocks only:
+    4.18 GiB) and fits: its peak, weights and cache included, is under
+    the chip's usable 15.75 GiB. An expert is two matrices (no ``we3``
+    among the arguments). **The decode sends a tick's 128 rows through
+    every held expert** (``models/moe.DENSE_PAIRS``: 128 rows x 32
+    held experts): no grouped product
+    is in it, no instruction writes a tensor of a layer's
+    held experts (a transposed copy would be a second pass over 319
+    MB), and beyond its arguments it needs under 64 MiB: no second
+    tensor of the state. **The prefill forms the chunked scan's
+    pairwise decays a layer at a time** (64 heads x 16 chunks x 128 x
+    128 float32 = 64 MiB at the 2,048 rung) and never a head's
+    (prompt, prompt) square of them."""
+    from benchmarks.harness.spec import Spec
+
+    spec = Spec()
+    c = spec.config("nemotron-3-nano-30b-a3b")
+    one = SingleDeviceSharding(topo.devices[0])
+    prog = next(p for p in spec.family(c["family"]).sizing(
+        c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)) if p["name"] == name)
+    leaves = {jax.tree_util.keystr(path) for path, _ in
+              jax.tree_util.tree_leaves_with_path(prog["args"][0])}
+    assert any("we1" in leaf for leaf in leaves)
+    assert not any(leaf.endswith("3']") for leaf in leaves), leaves
+    compiled = prog["fn"].lower(*prog["args"]).compile()
+    sv = c["serve"]
+    slots, T = sv["slots"], sv["max_len"]
+    state = slots * 64 * 64 * 128 * 4
+    cache = 12 * (state + slots * 3 * 6144 * 2) \
+        + 3 * 2 * slots * T * 2 * 128 * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= cache
+    assert m.peak_memory_in_bytes < V5E_USABLE
+    beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes
+    hlo = compiled.as_text()
+    ops = materialised(hlo)
+    if name.startswith("decode"):
+        assert "ragged-dot" not in hlo
+        assert not written(ops, {tuple(sorted((32, 2688, 1856)))})
+        assert beyond < 64 << 20, beyond >> 20
+    else:
+        rung = int(name.rsplit("=", 1)[1])
+        assert "ragged-dot" in hlo
+        assert beyond < (1200 << 20) * rung // 2048 + (256 << 20), \
+            beyond >> 20
+        assert not written(ops, {tuple(sorted((64, rung, rung))),
+                                 tuple(sorted((8, 8, rung, rung)))})
+
+
 def test_materialised_leaves_out_fused_computations():
     hlo = """HloModule m
 
