@@ -44,6 +44,7 @@ from pbs_tpu.models.transformer import (
     shift_targets_and_weights,
     token_xent,
 )
+from pbs_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_tiles
 
 
 @dataclasses.dataclass(frozen=True)
@@ -306,19 +307,29 @@ EXPERT_ROWS = 2048
 def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     """The routed part of an expert layer, as one holder of ``kind.held``
     = (first, count) of its experts computes it: route over all
-    ``kind.n_experts``, keep the assignments that land on a held expert,
-    sort them by expert and run one grouped matrix product a weight
-    (``jax.lax.ragged_dot``: rows of expert e against ``we*[e]``; three
-    weights an expert of the gated form, two of the ungated,
-    :func:`mlp_ffn`). No
-    (T, E, C) tensor exists and no token is dropped, however the router
-    concentrates: the sorted buffer has a row for every assignment.
-    Rows that make ``DENSE_PAIRS`` (row, held expert) pairs or fewer (a
-    decode tick's lanes) skip the sort and go through every held expert
-    under a weight that is zero where a row did not choose it
-    (:func:`_every_expert`): the same sum.
-    What an absent expert would add is left out; with every share's
-    result summed (``parallel/expert.py``) the layer is whole.
+    ``kind.n_experts``, keep the assignments that land on a held
+    expert, and form each row's products with the experts it chose
+    (three matrices an expert of the gated form, two of the ungated,
+    :func:`mlp_ffn`) in one of three forms of the same sum, float32
+    accumulated, chosen by the static shape (:func:`expert_form`):
+
+    - ``every`` (:func:`_every_expert`): rows that make ``DENSE_PAIRS``
+      (row, held expert) pairs or fewer (a decode tick's lanes) skip
+      the sort and go through every held expert under a weight that is
+      zero where a row did not choose it;
+    - the assignments sorted by expert and one grouped product a matrix
+      over the sorted buffer, rows of expert e against ``we*[e]``
+      (:func:`_sorted_rows`), which is **the Pallas kernel**
+      ``ops/grouped_matmul.py`` where the program is lowered for a TPU
+      and :func:`grouped_kernel_takes` the shape (``KERNEL_ROWS_A_GROUP``
+      sorted rows a held expert or more: a prompt forward's), and
+    - ``jax.lax.ragged_dot`` for every other shape and on every other
+      platform (the CPU tests, the reference's side).
+
+    No (T, E, C) tensor exists and no token is dropped, however the
+    router concentrates: the sorted buffer has a row for every
+    assignment. What an absent expert would add is left out; with every
+    share's result summed (``parallel/expert.py``) the layer is whole.
 
     h (T, d), ``valid`` (T,) marks rows that are tokens (idle decode
     lanes and bucket padding route nowhere and touch no expert). More
@@ -328,10 +339,11 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     [assignments to held experts, to absent ones, held experts touched,
     largest load of one held expert]."""
     T, d = h.shape
-    if T > EXPERT_ROWS and T % EXPERT_ROWS == 0:
+    piece = expert_piece(T)
+    if piece < T:
         y, held, absent, sizes = jax.lax.map(
             lambda piece: _held_rows(*piece, lp, kind, dt),
-            (h.reshape(-1, EXPERT_ROWS, d), valid.reshape(-1, EXPERT_ROWS)))
+            (h.reshape(-1, piece, d), valid.reshape(-1, piece)))
         y, held, absent, sizes = (y.reshape(T, d), held.sum(), absent.sum(),
                                   sizes.sum(0))
     else:
@@ -341,22 +353,49 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
     return y, counts
 
 
+def expert_piece(rows: int) -> int:
+    """Rows of ``rows`` the held experts take at a time: all of them,
+    or ``EXPERT_ROWS`` where they are more and whole multiples of
+    it."""
+    return EXPERT_ROWS if rows > EXPERT_ROWS and rows % EXPERT_ROWS == 0 \
+        else rows
+
+
 def _held_rows(h: jax.Array, valid: jax.Array, lp: dict, kind, dt):
     """:func:`held_expert_ffn` on rows that go through at once: ``(y,
     assignments to held experts, to absent ones, rows each held expert
-    got (count,))``. The products are :func:`_every_expert`'s where the
-    rows times the held experts are few, :func:`_sorted_rows`' otherwise:
-    a static choice, by the shape."""
+    got (count,))``. The products take the form :func:`expert_form`
+    names: a static choice, by the shape."""
     first, n = kind.held
     with jax.named_scope("moe.route"):
         w, idx = route_top_k(h, lp["router"], kind, lp.get("router_bias"))
         local = idx - first
         ours = (local >= 0) & (local < n)
         here = ours & valid[:, None]
-    experts = _every_expert if h.shape[0] * n <= DENSE_PAIRS else _sorted_rows
+    form, _ = expert_form(*h.shape, kind, dt)
+    experts = _every_expert if form == "every" else _sorted_rows
     y, sizes = experts(h, w, local, here, lp, kind, dt)
     return (y.astype(dt), jnp.sum(here), jnp.sum(valid[:, None] & ~ours),
             sizes)
+
+
+def expert_form(rows: int, d: int, kind, dt) -> tuple[str, int]:
+    """Which of the three forms the held experts' products take for
+    ``rows`` rows of ``d`` at a time, and the rows one product is over:
+    ``every`` (:func:`_every_expert`, the rows themselves) at or under
+    ``DENSE_PAIRS`` (row, held expert) pairs; else the sorted buffer's
+    ``rows x top_k`` through ``grouped-kernel`` (the Pallas kernel
+    where the program is lowered for a TPU, ``ragged_dot`` anywhere
+    else) where :func:`grouped_kernel_takes` the shape, or through
+    ``ragged_dot`` on every platform. A function of the static shape
+    alone; the engine writes it into the host ring when it builds a
+    program (``models/serving.py::_plan_forward``)."""
+    n = kind.held[1]
+    if rows * n <= DENSE_PAIRS:
+        return "every", rows
+    m = rows * kind.top_k
+    return ("grouped-kernel" if grouped_kernel_takes(
+        m, n, d, kind.d_ff, dt) else "ragged_dot"), m
 
 
 #: At or under this many (row, held expert) pairs (a decode tick's
@@ -364,13 +403,21 @@ def _held_rows(h: jax.Array, valid: jax.Array, lp: dict, kind, dt):
 #: held expert, weighted by zero where it did not choose it: the held
 #: weights are then read once each, in whole matrix products, and a
 #: row's product with them hides under that read while the rows are
-#: fewer than the chip's operations a byte (~240 on a v5e). The grouped
-#: product over sorted rows reads only the experts touched, but
-#: XLA:TPU's pays 0.05-0.1 ms a touched group whatever its few rows
-#: (PERF.md section 7, row 16): for a tick's rows that is 3-8 times the
-#: read of every held expert. The bound is the activations' and the
-#: unchosen products' size, ``pairs x width``; PERF.md section 6 (PR
-#: 43) says which cells it takes in and why no wider.
+#: fewer than the chip's operations a byte (~240 on a v5e). A grouped
+#: product over sorted rows reads only the experts touched. XLA:TPU's
+#: own (``ragged-dot-*``) pays for that by the group and by the widths,
+#: not by the bytes: 0.011 ms a touched group of 6 MiB at laguna's
+#: widths, 0.04-0.05 of 10 MiB at solar's, 0.15 of 9.5 MiB at
+#: nemotron's, where neither width is a multiple of 256 and ``we1`` is
+#: copied whole first (PERF.md section 6, PR 44: 211-546 GB/s of the
+#: chip's 819). The Pallas kernel (``ops/grouped_matmul.py``) streams
+#: the touched experts at 520-730 GB/s whatever the widths, which at a
+#: tick's load (nine experts of ten touched) is the read of every held
+#: expert again: for these few pairs the batched products stay, and
+#: the sort, the gathers and the scatter back are saved. The bound is
+#: the activations' and the unchosen products' size, ``pairs x
+#: width``; PERF.md section 6 (PR 43) says which cells it takes in and
+#: why no wider.
 DENSE_PAIRS = 4096
 
 
@@ -417,7 +464,7 @@ def _sorted_rows(h, w, local, here, lp: dict, kind, dt):
         xs = h[order // k]
     with jax.named_scope("moe.experts"):
         def grouped(rows, wt):
-            return jax.lax.ragged_dot(rows, wload(wt, dt), sizes)
+            return _grouped_product(rows, wload(wt, dt), sizes)
 
         out = mlp_ffn(xs, lp["we1"], lp.get("we3"), lp["we2"], kind.form,
                       grouped)
@@ -427,6 +474,44 @@ def _sorted_rows(h, w, local, here, lp: dict, kind, dt):
         y = jnp.sum(jnp.where(here[:, :, None],
                               out * w[:, :, None].astype(dt), 0), axis=1)
     return y, sizes
+
+
+# One trace and one lowered function a shape, whatever the layers
+# (``models/mamba.py::_kernel_scan`` says why).
+_kernel_product = jax.jit(grouped_matmul)
+
+
+#: Sorted rows a held expert (assignments to held and absent experts
+#: alike: the static shape) from which the Pallas kernel takes the
+#: grouped product. Measured alone beside ``jax.lax.ragged_dot`` at the
+#: eight shapes the cells meet (PERF.md section 6, PR 44): the kernel
+#: wins 2.1-7.7 times where a held expert has 40 sorted rows or more (a
+#: prompt forward of any of the four expert configurations, and solar's
+#: tick of 256 lanes: 51) and 1.3 times at laguna's tick of 64 lanes (5
+#: sorted rows an expert, both forms bound by the weights' read), which
+#: stays where it was, the control.
+KERNEL_ROWS_A_GROUP = 16
+
+
+def grouped_kernel_takes(m: int, groups: int, k: int, n: int, dtype) -> bool:
+    """Whether the grouped product of ``m`` sorted rows with ``groups``
+    matrices of ``(k, n)`` is the Pallas kernel's where the program is
+    lowered for a TPU: a function of the static shape alone."""
+    return m >= KERNEL_ROWS_A_GROUP * groups \
+        and grouped_matmul_tiles(m, groups, k, n, dtype)
+
+
+def _grouped_product(rows, w, sizes):
+    """Rows sorted by group against each group's matrix, by the
+    platform the program is lowered for: on a TPU the one-pass kernel
+    (``ops/grouped_matmul.py``) where :func:`grouped_kernel_takes` the
+    shape; anywhere else, and for any other shape,
+    ``jax.lax.ragged_dot``. The same sum in float32 accumulators either
+    way."""
+    if not grouped_kernel_takes(rows.shape[0], *w.shape, rows.dtype):
+        return jax.lax.ragged_dot(rows, w, sizes)
+    return jax.lax.platform_dependent(
+        rows, w, sizes, tpu=_kernel_product, default=jax.lax.ragged_dot)
 
 
 def mlp_ffn(h: jax.Array, w1, w3, w2, form: str, product) -> jax.Array:

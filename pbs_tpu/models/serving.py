@@ -391,7 +391,8 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     one expert], None for a stack without experts). Donated, the cache
     is updated in place."""
     from pbs_tpu.models.moe import (
-        held_expert_ffn, mlp_ffn, shared_expert_ffn)
+        expert_form, expert_piece, held_expert_ffn, mlp_ffn,
+        shared_expert_ffn)
 
     plan = plan_of(cfg)
     B, S = tokens.shape
@@ -413,6 +414,7 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     x = embed_rows(params["embed"], tokens, dt)
     flat_valid = valid.reshape(-1)
     counts = jnp.zeros((4,), jnp.int32)
+    forms = set()
 
     for layer in range(len(plan.layers)):
         a, m = plan.kinds(layer)
@@ -456,6 +458,7 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                             lambda rows, w: rows @ wload(w, dt))
         else:
             hf = h.reshape(B * S, -1)
+            forms.add(expert_form(expert_piece(B * S), hf.shape[1], m, dt))
             y, c = held_expert_ffn(hf, mp, m, flat_valid, dt)
             if m.shared_d_ff:
                 y = y + shared_expert_ffn(hf, mp, dt, m.form)
@@ -464,6 +467,12 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                 [counts[:3] + c[:3], jnp.maximum(counts[3:], c[3:])])
         x = x + y
 
+    # One record a form of this program's expert products, as it is
+    # traced: ``experts.every | grouped-kernel | ragged_dot``, size the
+    # rows one product is over (docs/TRACING.md, ``HOST_PHASE``).
+    for form, rows in sorted(forms):
+        with host_phase(f"experts.{form}", rows):
+            pass
     if not decode:
         x = jax.lax.dynamic_index_in_dim(
             x[0], jnp.maximum(valid.sum() - 1, 0), 0, keepdims=False)

@@ -1,4 +1,5 @@
 from pbs_tpu.ops.attention import flash_attention
+from pbs_tpu.ops.grouped_matmul import grouped_matmul
 from pbs_tpu.ops.kda_step import kda_state_step
 from pbs_tpu.ops.mamba_scan import mamba_prompt_scan
 from pbs_tpu.ops.matmul import (
@@ -11,6 +12,7 @@ from pbs_tpu.ops.mla_attend import mla_attend
 __all__ = [
     "MatmulStats",
     "flash_attention",
+    "grouped_matmul",
     "instrumented_matmul",
     "kda_state_step",
     "mamba_prompt_scan",

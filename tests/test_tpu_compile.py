@@ -310,6 +310,20 @@ def test_a_state_space_layer_at_published_widths(topo, case):
     assert lowered.count("tpu_custom_call") == 1
 
 
+def _cell_programs(topo, config: str):
+    """A benchmark configuration and its family's sizing programs (the
+    engine's decode and its prefill at each rung, as the cell runs
+    them), their arguments as shapes on one described chip."""
+    from benchmarks.harness.spec import Spec
+
+    spec = Spec()
+    c = spec.config(config)
+    one = SingleDeviceSharding(topo.devices[0])
+    return c, spec.family(c["family"]).sizing(
+        c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree))
+
+
 # GLM-5's cell whole (benchmarks/configs/glm-5.json: one dense and four
 # expert layers at the published widths, 16 of 256 experts, an eighth of
 # the vocabulary; 64 slots of 10,240 positions, prompts at 4,096 and
@@ -343,14 +357,8 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
     (``estimated_cycles`` at the int64 maximum: the softmax over a
     whole 6,144- or 8,192-key span was one, 27-47 ms a block where
     4,096 keys took 1.2; PERF.md section 6, PR 41)."""
-    from benchmarks.harness.spec import Spec
-
-    spec = Spec()
-    c = spec.config("glm-5")
-    one = SingleDeviceSharding(topo.devices[0])
-    prog = next(p for p in spec.family(c["family"]).sizing(
-        c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree)) if p["name"] == name)
+    c, progs = _cell_programs(topo, "glm-5")
+    prog = next(p for p in progs if p["name"] == name)
     lowered = prog["fn"].lower(*prog["args"])
     compiled = lowered.compile()
     sv = c["serve"]
@@ -412,18 +420,19 @@ def test_the_matrix_state_cells_programs_compile_and_fit(topo, name):
     is in it, no instruction writes a tensor of a layer's
     held experts (a transposed copy would be a second pass over 319
     MB), and beyond its arguments it needs under 64 MiB: no second
-    tensor of the state. **The prefill forms the chunked scan's
+    tensor of the state. **The prefill's grouped products are the
+    Pallas kernel** (``ops/grouped_matmul.py``; the program is lowered
+    for a TPU, whatever the process's own backend) at both rungs: two
+    for each of the eleven expert layers under ``moe.experts``, no
+    ``ragged-dot``, ``we1`` taken turned as it lies (XLA:TPU's own
+    grouped product copied all 319 MB of it before each layer's first
+    product), and every instruction that reads an expert's matrix
+    under the scope the expert metrics read. **The prefill forms the chunked scan's
     pairwise decays a layer at a time** (64 heads x 16 chunks x 128 x
     128 float32 = 64 MiB at the 2,048 rung) and never a head's
     (prompt, prompt) square of them."""
-    from benchmarks.harness.spec import Spec
-
-    spec = Spec()
-    c = spec.config("nemotron-3-nano-30b-a3b")
-    one = SingleDeviceSharding(topo.devices[0])
-    prog = next(p for p in spec.family(c["family"]).sizing(
-        c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree)) if p["name"] == name)
+    c, progs = _cell_programs(topo, "nemotron-3-nano-30b-a3b")
+    prog = next(p for p in progs if p["name"] == name)
     leaves = {jax.tree_util.keystr(path) for path, _ in
               jax.tree_util.tree_leaves_with_path(prog["args"][0])}
     assert any("we1" in leaf for leaf in leaves)
@@ -441,17 +450,69 @@ def test_the_matrix_state_cells_programs_compile_and_fit(topo, name):
         - m.alias_size_in_bytes
     hlo = compiled.as_text()
     ops = materialised(hlo)
+    assert "ragged-dot" not in hlo
+    # ``we1`` lies with 2688 fastest; turned it is a bitcast, never a copy
+    assert all(op[1] == "bitcast" for op in written(
+        ops, {tuple(sorted((32, 2688, 1856)))}))
     if name.startswith("decode"):
-        assert "ragged-dot" not in hlo
-        assert not written(ops, {tuple(sorted((32, 2688, 1856)))})
+        assert 'custom_call_target="tpu_custom_call"' not in hlo
         assert beyond < 64 << 20, beyond >> 20
     else:
         rung = int(name.rsplit("=", 1)[1])
-        assert "ragged-dot" in hlo
+        assert len(_expert_kernels(hlo)) == 2 * 11
+        _expert_weights_are_read_under_their_scope(hlo, 2 * 11)
         assert beyond < (1200 << 20) * rung // 2048 + (256 << 20), \
             beyond >> 20
         assert not written(ops, {tuple(sorted((64, rung, rung))),
                                  tuple(sorted((8, 8, rung, rung)))})
+
+
+def _expert_kernels(hlo: str) -> list[str]:
+    """The compiled program's calls of the Pallas grouped product
+    (``ops/grouped_matmul.py``), each under the ``moe.experts`` scope."""
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and "grouped_matmul" in ln]
+    assert all(re.search(r'op_name="[^"]*/moe\.experts/[^"]*grouped_matmul',
+                         ln) for ln in kernels)
+    return kernels
+
+
+def _expert_weights_are_read_under_their_scope(hlo: str, least: int):
+    """Every instruction of the entry computation that reads a held
+    expert's matrix carries the ``moe.experts`` scope, by which
+    ``moe.experts_ms_p50`` and ``kernel.expert_matmul_hbm_roofline``
+    find its time (``benchmarks/tests/test_moe_mixed.py`` makes the
+    check for the ``ragged-dot-*`` ops, which those metrics find by
+    name)."""
+    entry = hlo[hlo.index("ENTRY "):]
+    readers = [ln for ln in entry.splitlines()
+               if re.search(r"\(.*%params__blocks____\d+____mlp____we[123]__",
+                            ln) and " parameter(" not in ln]
+    assert len(readers) >= least, len(readers)
+    for ln in readers:
+        scope = re.search(r'op_name="([^"]*)"', ln)
+        assert scope and "/moe.experts/" in scope.group(1), ln[:300]
+
+
+# The other cells' decode ticks that sort their rows: the shape chooses
+# (``models/moe.grouped_kernel_takes``). Laguna's 640 sorted rows over
+# 128 held experts (5 each) stay on ``jax.lax.ragged_dot`` (72% of
+# their roofline, the control); solar's 2,048 over 40 (51 each) go
+# through the kernel, three products for each of its four layers.
+SORTED_TICKS = {"laguna-s-2.1": 0, "solar-open2-250b": 3 * 4}
+
+
+@pytest.mark.parametrize("config", SORTED_TICKS)
+def test_a_ticks_sorted_rows_take_the_form_their_shape_chooses(topo, config):
+    prog = _cell_programs(topo, config)[1][0]
+    assert prog["name"].startswith("decode")
+    hlo = prog["fn"].lower(*prog["args"]).compile().as_text()
+    kernels = SORTED_TICKS[config]
+    assert len(_expert_kernels(hlo)) == kernels
+    assert ("ragged-dot" in hlo) == (not kernels)
+    if kernels:
+        _expert_weights_are_read_under_their_scope(hlo, kernels)
 
 
 def test_materialised_leaves_out_fused_computations():

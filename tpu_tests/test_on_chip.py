@@ -671,3 +671,102 @@ def test_mla_attend_compiled_at_the_cell():
           f"{blocks} of {B * T // tk} blocks of {tk} live, ms a call: "
           + ", ".join(f"{n} {t:.2f}" for n, t in ms.items()))
     assert ms["mla_attend"] < ms["jax.numpy form"]
+
+
+# The shapes ``models/moe._sorted_rows`` meets in the benchmark's cells:
+# (sorted rows, held experts, experts a token can choose among that are
+# held out of how many, hidden, expert width, the MLP's form).
+GROUPED_SHAPES = {
+    "nemotron forward 1024": (6144, 32, 128, 2688, 1856, "relu2"),
+    "nemotron forward 2048": (12288, 32, 128, 2688, 1856, "relu2"),
+    "glm-5 forward piece": (16384, 16, 256, 6144, 2048, "silu"),
+    "laguna forward 512": (5120, 128, 256, 3072, 1024, "silu"),
+    "laguna forward 1024": (10240, 128, 256, 3072, 1024, "silu"),
+    "solar forward 256, tick": (2048, 40, 320, 4096, 1280, "silu"),
+    "solar forward 512": (4096, 40, 320, 4096, 1280, "silu"),
+    "laguna tick": (640, 128, 256, 3072, 1024, "silu"),
+}
+
+
+def test_grouped_matmul_compiled_at_the_cells():
+    """The one-pass grouped product (``ops/grouped_matmul.py``) compiled
+    through Mosaic at each shape the expert layers meet, beside
+    ``jax.lax.ragged_dot``: a share ``held / experts`` of the sorted
+    rows drawn over the held experts, the rest behind them poisoned
+    with NaN, which the kernel never reads. Each of an expert's two
+    kinds of matrix alone (in, which XLA:TPU keeps turned where the
+    width is no multiple of 128, and out) against the float64 product
+    of the same bfloat16 rows over four sampled groups (2^-7 of the
+    group's largest entry: the output is bfloat16); then a layer's
+    products together as ``mlp_ffn`` chains them, timed in both forms
+    (PERF.md section 6, PR 44: where the predicate of
+    ``models/moe.grouped_kernel_takes`` comes from)."""
+    import json
+    import os
+    import time
+
+    from pbs_tpu.models.moe import mlp_ffn
+    from pbs_tpu.ops.grouped_matmul import grouped_matmul
+
+    bf16 = jnp.bfloat16
+    rng = np.random.default_rng(44)
+    table = {}
+    for name, (m, groups, experts, d, f, form) in GROUPED_SHAPES.items():
+        held = m * groups // experts
+        sizes_h = rng.multinomial(held, np.full(groups, 1.0 / groups))
+        sizes = jnp.asarray(sizes_h, jnp.int32)
+        ks = jax.random.split(jax.random.PRNGKey(len(table)), 5)
+        x = jax.random.normal(ks[0], (m, d), bf16)
+        x = jnp.where(jnp.arange(m)[:, None] < held, x, jnp.nan)
+        w1, w3 = (jax.random.normal(k, (groups, d, f), bf16) * d ** -0.5
+                  for k in ks[1:3])
+        w2 = jax.random.normal(ks[3], (groups, f, d), bf16) * f ** -0.5
+        kernel = jax.jit(grouped_matmul)
+        starts = np.concatenate([[0], np.cumsum(sizes_h)])
+        mid = jnp.where(jnp.arange(m)[:, None] < held,
+                        jax.random.normal(ks[4], (m, f), bf16), jnp.nan)
+        worst = 0.0
+        for rows, w in ((x, w1), (mid, w2)):
+            got = kernel(rows, w, sizes)
+            assert not bool(jnp.isnan(got[:held].astype(jnp.float32)).any())
+            for e in rng.choice(np.flatnonzero(sizes_h), 4):
+                lo, hi = starts[e], starts[e + 1]
+                want = np.asarray(rows[lo:hi], np.float64) \
+                    @ np.asarray(w[e], np.float64)
+                gap = np.abs(np.asarray(got[lo:hi], np.float64)
+                             - want).max() / np.abs(want).max()
+                worst = max(worst, float(gap))
+        assert worst < 2 ** -7, (name, worst)
+        del got, mid
+
+        ms = {}
+        for way, product in (("grouped_matmul", grouped_matmul),
+                             ("ragged_dot", jax.lax.ragged_dot)):
+            fn = jax.jit(lambda x, w1, w3, w2, sizes, product=product:
+                         mlp_ffn(x, w1, w3 if form == "silu" else None, w2,
+                                 form, lambda r, w: product(r, w, sizes)))
+            out = jax.block_until_ready(fn(x, w1, w3, w2, sizes))
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = fn(x, w1, w3, w2, sizes)
+            jax.block_until_ready(out)
+            ms[way] = (time.perf_counter() - t0) / 20 * 1e3
+        nbytes = (3 if form == "silu" else 2) * d * f * 2 * int(
+            (sizes_h > 0).sum())
+        table[name] = dict(
+            ms, sorted_rows=m, held_rows=held, groups=groups, hidden=d,
+            width=f, products=3 if form == "silu" else 2,
+            touched_weight_mb=nbytes / 1e6,
+            weights_at_819_gbs_ms=nbytes / 819e9 * 1e3, gap=worst)
+        print(f"{name}: {m} sorted rows, {held} on {groups} held experts "
+              f"of {d} x {f}, a layer's {table[name]['products']} products "
+              f"ms: " + ", ".join(f"{k} {v:.3f}" for k, v in ms.items())
+              + f" (the touched weights at 819 GB/s: "
+              f"{table[name]['weights_at_819_gbs_ms']:.3f}); against "
+              f"float64 {worst:.2e}", flush=True)
+        del x, w1, w3, w2, out
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/grouped_matmul.json", "w") as fh:
+        json.dump(table, fh, indent=1)
+    assert table["nemotron forward 2048"]["grouped_matmul"] \
+        < table["nemotron forward 2048"]["ragged_dot"]
