@@ -70,6 +70,7 @@ from pbs_tpu.models.transformer import (
     rms_norm,
     rope_tables,
 )
+from pbs_tpu.ops.kv_attend import attend_block, kv_attend, kv_attend_tiles
 from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
 
 
@@ -156,9 +157,91 @@ def _grouped_attention(q, k, v, mask, dt):
     return attn.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
 
 
+# One trace and one lowered function a cache shape, whatever the layers
+# (``models/mamba.py::_kernel_scan`` says why).
+_kernel_attend = jax.jit(kv_attend)
+
+
+def _cursor_attention(q, k, v, at, layer, dt):
+    """A decode tick's attention, one query position a lane: q (B, 1,
+    H, hd) over the positions ``<= at[b]`` of the layer's k and v (B,
+    K, nkv, hd), all of them where the cursor is past the last (a ring
+    that has lapped); with ``layer`` (an int32 scalar) k and v are the
+    caches of every layer, (L, B, K, nkv, hd). By the platform the
+    program is lowered for: on a TPU the one-pass kernel over each
+    lane's live blocks (``ops/kv_attend.py``; its tiling has to take
+    the shapes, :func:`_streams_live`), anywhere else
+    :func:`_grouped_attention` over the whole cache under the mask.
+    Returns (B, 1, H, hd)."""
+    index = () if layer is None else (layer,)
+
+    def numpy_way(q, k, v, at, *index):
+        if index:
+            k, v = (jax.lax.dynamic_index_in_dim(t, index[0], 0,
+                                                 keepdims=False)
+                    for t in (k, v))
+        seen = jnp.arange(k.shape[1])[None, :] <= at[:, None]
+        return _grouped_attention(q, k, v, seen[:, None, :], dt)
+
+    return jax.lax.platform_dependent(
+        q, k, v, at, *index,
+        tpu=lambda q, k, v, at, *index: _kernel_attend(
+            q[:, 0], k, v, at, *index)[:, None],
+        default=numpy_way)
+
+
+def _streams_live(S: int, k: jax.Array, window=None) -> bool:
+    """Whether a forward of S positions a lane over the cache ``k``
+    (..., K, nkv, hd) of a full layer, or of a ring of ``window``, can
+    run its attention as the one-pass kernel: a decode tick, shapes the
+    kernel's tiling takes (more than one KV head among them), and no
+    ring (all of a lapped ring is live, and its ``jax.numpy`` form is
+    the faster: PERF.md 6, PR 45). (Which lowering then runs is the
+    platform's, :func:`_cursor_attention`; a cache laid over a mesh
+    never gets here, the programs see to that.)"""
+    K, nkv, hd = k.shape[-3:]
+    return S == 1 and not window and kv_attend_tiles(nkv, hd, K)
+
+
+def _placed_on(mesh) -> tuple:
+    """The devices a program's cache lies on: the mesh's, or without
+    one the default device."""
+    return tuple(mesh.devices.flat) if mesh is not None \
+        else tuple(jax.devices()[:1])
+
+
+def _live_blocks(k, devices: tuple, layers: int = 1,
+                 window=None) -> list[tuple[int, int]]:
+    """(positions kept, positions a block) of each of the ``layers``
+    whose decode attention over the cache ``k`` (an array, traced or
+    not) on ``devices`` runs as the one-pass kernel: traced so
+    (:func:`_streams_live`, one device) and lowered so (a TPU); none
+    where the ``jax.numpy`` form runs. Both records of the form go by
+    it, a traced decode's ``HOST_PHASE`` (:func:`_say_attention`) and
+    the engine's ``ENG_ATTEND``, which counts a tick's blocks by it."""
+    if not (len(devices) == 1 and devices[0].platform == "tpu"
+            and _streams_live(1, k, window)):
+        return []
+    K, nkv = k.shape[-3:-1]
+    return [(K, attend_block(K, nkv))] * layers
+
+
+def _say_attention(kernel: int, layers: int) -> None:
+    """A traced decode program's ``HOST_PHASE`` records of no length,
+    ``attn.live-kernel`` and ``attn.jnp``, size the softmax layers over
+    keys and values that run in that form (``kernel`` of ``layers``
+    through ``ops/kv_attend.py``), as ``experts.<form>`` says an expert
+    layer's."""
+    for form, n in (("attn.live-kernel", kernel),
+                    ("attn.jnp", layers - kernel)):
+        if n:
+            with host_phase(form, n):
+                pass
+
+
 def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
-                  cache: dict, row_pos: jax.Array,
-                  mlp_fn=None) -> tuple[jax.Array, dict]:
+                  cache: dict, row_pos: jax.Array, mlp_fn=None,
+                  active=None) -> tuple[jax.Array, dict]:
     """Forward (B, S) tokens where row b sits at absolute position
     ``row_pos[b]`` (S static; per-row cursors). Writes K/V at
     ``row_pos[b] + s``; row b's query s attends cols <= row_pos[b]+s.
@@ -174,7 +257,15 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     MoE caller owns: routing shares expert capacity across every
     co-resident lane of the forward (slots, bucket padding, garbage
     lanes), so engine decode only matches the lockstep path under
-    DROPLESS capacity — watch the returned drop telemetry."""
+    DROPLESS capacity — watch the returned drop telemetry.
+
+    ``active`` (B,) bool, the decode tick's alone (S == 1, the cache on
+    one device): the lanes that hold a request. Given, a lane's query
+    attends through :func:`_cursor_attention`, which on a TPU streams
+    the lane's live blocks out of the stacked cache and slices no
+    layer; a lane that holds none attends its first position alone (its
+    cursor rests where its last request ended, and nothing up to there
+    is its to read)."""
     B, S = tokens.shape
     T = cache["k"].shape[2]
     dt = cfg.dtype
@@ -187,6 +278,7 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     abs_pos = jnp.minimum(abs_pos, T - 1)  # clamp: masked rows only
     cos = cos_full[abs_pos]  # (B, S, half)
     sin = sin_full[abs_pos]
+    live = active is not None and _streams_live(S, cache["k"])
 
     def body(carry, layer):
         # The K/V slabs (L, B, T, nkv, hd) ride in the CARRY, not as
@@ -214,12 +306,17 @@ def _slot_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         k = _rope_rows(k, cos, sin)
         ks = _write_rows(ks, k, row_pos, layer=i)
         vs = _write_rows(vs, v, row_pos, layer=i)
-        ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
-        # per-row causal horizon: row b's query s sees cols <= abs_pos
-        reach = (jnp.arange(T)[None, None, :]
-                 <= abs_pos[:, :, None])  # (B, S, T)
-        attn = _grouped_attention(q, ck, cv, reach, dt)
+        if live:
+            with jax.named_scope("attn.full"):
+                attn = _cursor_attention(
+                    q, ks, vs, jnp.where(active, abs_pos[:, 0], 0), i, dt)
+        else:
+            ck = jax.lax.dynamic_index_in_dim(ks, i, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(vs, i, 0, keepdims=False)
+            # per-row causal horizon: row b's query s sees cols <= abs_pos
+            reach = (jnp.arange(T)[None, None, :]
+                     <= abs_pos[:, :, None])  # (B, S, T)
+            attn = _grouped_attention(q, ck, cv, reach, dt)
         x = x + attn.reshape(B, S, nh * hd) @ wload(lp["wo"], dt)
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         if mlp_fn is None:
@@ -444,9 +541,10 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                                       abs_pos, tables, slot, cfg.norm_eps,
                                       dt)
             else:
-                x = x + _softmax_layer(a, ap, h, ks, vs, name, row_pos,
-                                       valid, abs_pos, tables, slot, nkv,
-                                       hd, dt)
+                x = x + _softmax_layer(
+                    a, ap, h, ks, vs, name, row_pos, valid, abs_pos, tables,
+                    slot, nkv, hd, dt,
+                    decode and _streams_live(S, ks[name], a.window))
         if m is None:
             continue
 
@@ -492,11 +590,12 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
 
 def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
                    row_pos, valid, abs_pos, tables: dict, slot, nkv: int,
-                   hd: int, dt) -> jax.Array:
+                   hd: int, dt, live: bool = False) -> jax.Array:
     """A full or window attention layer of the planned stack on its
     normed input h (B, S, d): writes the layer's new keys and values
     into ``ks[name]`` / ``vs[name]`` (replaced in the dicts) and
-    returns what the layer adds to the stream."""
+    returns what the layer adds to the stream. ``live``: a decode tick
+    whose attention goes through :func:`_cursor_attention`."""
     B, S, _ = h.shape
     H, decode = a.n_heads, slot is None
     q = (h @ wload(ap["wq"], dt)).reshape(B, S, H, hd)
@@ -511,13 +610,21 @@ def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
             at = row_pos % K if a.window else row_pos
             ks[name] = _write_rows(ks[name], k, at)
             vs[name] = _write_rows(vs[name], v, at)
-            col = jnp.arange(K)[None, :]
-            # Ring entry j holds the largest p <= cursor with
-            # p = j mod W: live once written, always after a lap.
-            seen = (col <= row_pos[:, None]) | (
-                (row_pos[:, None] >= K) if a.window else False)
-            attn = _grouped_attention(q, ks[name], vs[name],
-                                      seen[:, None, :], dt)
+            if live:
+                # an idle lane's cursor rests where its last request
+                # ended, and nothing up to there is its to read: it
+                # attends its first entry
+                attn = _cursor_attention(
+                    q, ks[name], vs[name],
+                    jnp.where(valid[:, 0], row_pos, 0), None, dt)
+            else:
+                col = jnp.arange(K)[None, :]
+                # Ring entry j holds the largest p <= cursor with
+                # p = j mod W: live once written, always after a lap.
+                seen = (col <= row_pos[:, None]) | (
+                    (row_pos[:, None] >= K) if a.window else False)
+                attn = _grouped_attention(q, ks[name], vs[name],
+                                          seen[:, None, :], dt)
         else:
             i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
             seen = (j <= i) & ((i - j < a.window) if a.window else True)
@@ -582,11 +689,21 @@ class _ScanProgram:
     #: no layer chooses among the positions it keeps
     select_topk = None
 
-    def __init__(self, cfg: TransformerConfig, mlp_fn=None):
+    def __init__(self, cfg: TransformerConfig, mlp_fn=None, mesh=None):
         self.cfg, self.mlp_fn = cfg, mlp_fn
+        #: where the cache lies: on one device the decode's attention
+        #: may be the one-pass kernel, which takes a cache whole
+        self.devices = _placed_on(mesh)
 
     def select_block(self, cache: dict) -> int:
         return 0
+
+    def attend_blocks(self, cache: dict) -> list[tuple[int, int]]:
+        """(positions kept, positions a block) of every layer whose
+        decode attention streams a lane's live blocks out of ``cache``
+        (``_cursor_attention``'s kernel); none where the ``jax.numpy``
+        form runs."""
+        return _live_blocks(cache["k"], self.devices, self.cfg.n_layers)
 
     def init_params(self, key: jax.Array) -> dict:
         return init_params(self.cfg, key)
@@ -611,9 +728,11 @@ class _ScanProgram:
         }
 
     def decode(self, params, cache, last_tok, active):
+        _say_attention(len(self.attend_blocks(cache)), self.cfg.n_layers)
+        live = len(self.devices) == 1 and _streams_live(1, cache["k"])
         logits, new, extra = _slot_forward(
             self.cfg, params, last_tok[:, None], cache, cache["pos"],
-            mlp_fn=self.mlp_fn)
+            mlp_fn=self.mlp_fn, active=active if live else None)
         return logits, new, extra, None
 
     def ingest(self, params, cache, slot, prompt, plen):
@@ -630,8 +749,10 @@ class _PlannedProgram:
     #: positions (``no_windows`` says which, for the error)
     windows = False
 
-    def __init__(self, cfg: TransformerConfig):
+    def __init__(self, cfg: TransformerConfig, mesh=None):
         self.cfg = cfg
+        #: where the cache lies (one device: ``place_cache``)
+        self.devices = _placed_on(mesh)
         plan = plan_of(cfg)
         #: the most positions a selecting layer's query attends (the
         #: engine's ``ENG_SELECT`` counts by it); None: no such layer
@@ -668,6 +789,17 @@ class _PlannedProgram:
                     for name, ckv in cache.get("ckv", {}).items()),
                    default=0)
 
+    def attend_blocks(self, cache: dict) -> list[tuple[int, int]]:
+        """(positions kept, positions a block) of every full layer
+        whose decode attention streams a lane's live blocks out of
+        ``cache`` (``_cursor_attention``'s kernel); none where the
+        ``jax.numpy`` form runs."""
+        plan = plan_of(self.cfg)
+        return [pair for name, k in cache["k"].items()
+                for pair in _live_blocks(
+                    k, self.devices,
+                    window=plan.kinds(int(name))[0].window)]
+
     def init_params(self, key: jax.Array) -> dict:
         return init_plan_params(self.cfg, key)
 
@@ -687,6 +819,7 @@ class _PlannedProgram:
         return jax.device_put(cache, NamedSharding(mesh, PartitionSpec()))
 
     def decode(self, params, cache, last_tok, active):
+        _say_attention(len(self.attend_blocks(cache)), len(cache["k"]))
         logits, new, route = _plan_forward(
             self.cfg, params, last_tok[:, None], cache, cache["pos"],
             active[:, None])
@@ -702,7 +835,7 @@ class _PlannedProgram:
         return last_logits, cache, jnp.zeros((), jnp.float32), route
 
 
-def slot_program(cfg: TransformerConfig, mlp_fn=None):
+def slot_program(cfg: TransformerConfig, mlp_fn=None, mesh=None):
     """What a configuration's layer stack gives the engine and the
     serve backend, and the one place that chooses between the two
     forms: its parameter tree (``init_params``), its cache
@@ -714,13 +847,15 @@ def slot_program(cfg: TransformerConfig, mlp_fn=None):
     windows of positions (``windows``). A configuration whose layers
     are all alike, said by its widths or by a plan, gets the stacked
     tree and the layer scan it always had (with an untied head: a tied
-    one is the planned program's to read)."""
+    one is the planned program's to read). ``mesh``: the one the cache
+    will be placed on (none: the default device), which a decode has to
+    know when it is traced."""
     if plan_of(cfg) == uniform_plan(cfg) and not cfg.tie_embeddings:
-        return _ScanProgram(cfg, mlp_fn)
+        return _ScanProgram(cfg, mlp_fn, mesh)
     if mlp_fn is not None:
         raise ValueError("a planned layer stack names its own MLP kinds; "
                          "mlp_fn swaps the FFN of a uniform stack only")
-    return _PlannedProgram(cfg)
+    return _PlannedProgram(cfg, mesh)
 
 
 def prefill_rungs(bucket: int) -> tuple[int, ...]:
@@ -815,7 +950,7 @@ class ContinuousBatcher:
         self.mlp_fn = mlp_fn
         # What the configuration's layer stack gives the engine: its
         # cache, a decode position for every slot, a prompt's ingestion.
-        self.program = slot_program(cfg, mlp_fn)
+        self.program = slot_program(cfg, mlp_fn, mesh)
         self.n_slots = n_slots
         self.bucket = prompt_bucket
         self.rungs = prefill_rungs(prompt_bucket)
@@ -852,6 +987,14 @@ class ContinuousBatcher:
         #: over this cache (``ENG_SELECT`` counts a tick's blocks by
         #: it); 0: no such layer, or its ``jax.numpy`` form runs
         self._select_block = self.program.select_block(cache)
+        #: the layers whose decode attention streams live blocks, by
+        #: (positions kept, positions a block), and how many layers of
+        #: each (``ENG_ATTEND`` counts a tick's blocks by them); empty:
+        #: the ``jax.numpy`` form runs
+        pairs, self._attend_layers = np.unique(
+            np.array(self.program.attend_blocks(cache),
+                     np.int64).reshape(-1, 2), axis=0, return_counts=True)
+        self._attend_kept, self._attend_block = pairs[:, :1], pairs[:, 1:]
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
         self.queue: deque = deque()
@@ -1080,6 +1223,26 @@ class ContinuousBatcher:
                      int(live.sum()), int(np.minimum(live, topk).sum()),
                      topk, int(((live - 1) // block + 1).sum())
                      if block else 0)
+
+    def _attend_ev(self, ts_ns: int, live: np.ndarray) -> None:
+        """``ENG_ATTEND``: the blocks of keys and values this decode's
+        one-pass attention fetches against the blocks its caches have,
+        from what the host knows of its slots (``live``: the positions
+        each busy lane's query sees); stamped like the call's
+        ``ENG_DECODE``. A busy lane streams the blocks up to the one
+        its cursor (``live - 1``) is in, a ring that has lapped all of
+        them; an idle lane one. Nothing where the ``jax.numpy`` form
+        runs."""
+        layers = self._attend_layers
+        if len(layers) and self.trace is not None:
+            cursor = np.minimum(live[None, :] - 1, self._attend_kept - 1)
+            fetched = (cursor // self._attend_block + 1).sum(axis=1) \
+                + self.n_slots - len(live)
+            have = (self._attend_kept // self._attend_block)[:, 0] \
+                * self.n_slots
+            self._ev(ts_ns, Ev.ENG_ATTEND, self._tick_seq, len(live),
+                     int(live.sum()), int(fetched @ layers),
+                     int(have @ layers), int(layers.sum()))
 
     def _split_key(self) -> jax.Array:
         """Advance the sampling key (two tiny device programs a call)."""
@@ -1373,7 +1536,8 @@ class ContinuousBatcher:
         mask = self.active & (self.slot_remaining > carry)
         overlapped = int(fl is not None)
         seen = None
-        if self.program.select_topk is not None:
+        if self.program.select_topk is not None or (
+                len(self._attend_layers) and self.trace is not None):
             # a lane's new position sees its prompt, the tokens the host
             # has booked and the one still in flight
             booked = np.fromiter(map(len, self.slot_tokens), np.int64,
@@ -1411,6 +1575,7 @@ class ContinuousBatcher:
         if mask.any():
             if seen is not None:
                 self._select_ev(t_pre, seen, self._select_block)
+                self._attend_ev(t_pre, seen)
             self._decoded(t_pre, t_enqueued, t_host, overlapped)
         return done
 

@@ -230,6 +230,21 @@ class Ev(enum.IntEnum):
     #                      streams: cursor // block + 1 a busy lane,
     #                      summed; 0 for a prefill and where the
     #                      jax.numpy form runs); each of one such layer
+    ENG_ATTEND = 0x0A09  # one a decode of a program whose softmax
+    #                      layers over keys and values stream a lane's
+    #                      live blocks (ops/kv_attend.py), from the
+    #                      host's slot table, no device read: stamped
+    #                      like the ENG_DECODE (ts and tick) of the
+    #                      step() that enqueued the decode. args: tick,
+    #                      busy lanes, live positions (a busy lane's
+    #                      query sees them: summed), blocks fetched
+    #                      (cursor // block + 1 a busy lane, no more
+    #                      than the layer keeps; 1 an idle lane; summed
+    #                      over those layers), blocks their caches
+    #                      have (lanes x kept / block a layer, summed),
+    #                      the layers. None where the jax.numpy form
+    #                      runs (a CPU, a mesh, a shape the kernel's
+    #                      tiling does not take)
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

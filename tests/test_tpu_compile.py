@@ -129,6 +129,86 @@ def test_attention_projections_are_read_where_they_lie(topo, case, rows,
     assert not gathers, gathers
 
 
+def _attend_kernels(hlo: str, scope: str = "attn.full") -> list[str]:
+    """The compiled program's calls of the one-pass attention over K
+    and V (``ops/kv_attend.py``), each under ``scope``."""
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and "kv_attend" in ln]
+    assert all(re.search(rf'op_name="[^"]*/{re.escape(scope)}/[^"]*kv_attend',
+                         ln) for ln in kernels), [ln[:300] for ln in kernels]
+    return kernels
+
+
+def _nothing_moves_a_layers_keys(ops, lanes: int, kept: int, nkv: int):
+    """No instruction but the write of the tick's new position writes
+    a tensor of one layer's K or V (a copy, a transpose to heads-major,
+    a slice out of a stack), and none a
+    float32 tensor a lane and a position long (the ``jax.numpy`` form's
+    scores and probabilities)."""
+    moved = [op for op in written(
+        ops, {tuple(sorted((lanes, kept, nkv, 128)))})
+        if "dynamic-update-slice" not in op[0]]  # the new position, in place
+    assert not moved, moved
+    wide = [op for op in ops if op[2].startswith("f32")
+            and {lanes, kept} <= set(dims(op[2]))]
+    assert not wide, wide
+
+
+@pytest.mark.parametrize("case", ["decode", "decode-no-lanes", "prefill"])
+def test_the_scans_decode_streams_live_blocks_out_of_the_stack(topo, case):
+    """The dense layer scan's decode tick as the engine runs it
+    (``_ScanProgram.decode``: mistral's widths, 16 slots of 1,024
+    positions) holds the one-pass attention ``kv_attend`` under
+    ``attn.full``, once in the scan's body, fed the stacked cache
+    itself: no instruction slices, copies or transposes a layer's K or
+    V (XLA's two ``dynamic-slice`` fusions a layer of the ``jax.numpy``
+    form are gone) and no float32 scores over 1,024 columns are
+    written. Without the lanes' word (any other caller of
+    ``_slot_forward``; a cache on a mesh) and in a prompt forward the
+    ``jax.numpy`` form stays, and no kernel."""
+    cfg = dataclasses.replace(CFG, max_seq=1024)
+    one = SingleDeviceSharding(topo.devices[0])
+    lay = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    prog = slot_program(cfg)
+    params = lay(jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(cfg.dtype),
+        prog.init_params(jax.random.PRNGKey(0)))))
+    cache = lay(jax.eval_shape(lambda: prog.init_cache(SLOTS, cfg.max_seq)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one)
+    if case == "prefill":
+        fn = lambda p, c, prompt: prog.ingest(  # noqa: E731
+            p, c, 0, prompt, 7)[:2]
+        args = (params, cache, i32(256))
+    elif case == "decode":
+        fn = lambda p, c, tok, active: prog.decode(  # noqa: E731
+            p, c, tok, active)[:2]
+        args = (params, cache, i32(SLOTS), jax.ShapeDtypeStruct(
+            (SLOTS,), bool, sharding=one))
+    else:
+        fn = lambda p, c, tok: _slot_forward(  # noqa: E731
+            cfg, p, tok, c, c["pos"])[:2]
+        args = (params, cache, i32(SLOTS, 1))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    ops = materialised(hlo)
+    if case != "decode":
+        assert "tpu_custom_call" not in hlo
+        if case == "decode-no-lanes":
+            assert written(ops, {tuple(sorted((SLOTS, 1024, 8, 128)))})
+        return
+    assert len(_attend_kernels(hlo)) == 1
+    _nothing_moves_a_layers_keys(ops, SLOTS, 1024, 8)
+    m = compiled.memory_analysis()
+    stack = 2 * cfg.n_layers * SLOTS * 1024 * 8 * 128 * 2
+    assert m.alias_size_in_bytes >= stack
+    # beyond its arguments and the logits: no second cache, no layer's
+    assert m.temp_size_in_bytes < SLOTS * 1024 * 8 * 128 * 2, \
+        m.temp_size_in_bytes >> 20
+
+
 # One delta-rule layer at Solar-Open2's published widths (64 heads of
 # 128, kernel 4, hidden 4096) over a small dense MLP: what is checked
 # is the layer's recurrent state, 4 MiB a slot.
@@ -287,6 +367,10 @@ def test_a_state_space_layer_at_published_widths(topo, case):
     assert not written(ops, {(16, 2048, 5120)})     # (T, d_state, d_inner)
     if case == "decode":
         assert "mamba_prompt_scan" not in hlo
+        # one KV head: the attention layer keeps the ``jax.numpy`` form
+        # (``kv_attend_tiles``; with the kernel in this program XLA
+        # staged the state through VMEM in copies, PERF.md 6, PR 45)
+        assert not _attend_kernels(hlo)
         return
     assert not written(ops, {(32, 16, 5120), (64, 16, 5120)})
     kernels = [ln for ln in hlo.splitlines()
@@ -455,7 +539,10 @@ def test_the_matrix_state_cells_programs_compile_and_fit(topo, name):
     assert all(op[1] == "bitcast" for op in written(
         ops, {tuple(sorted((32, 2688, 1856)))}))
     if name.startswith("decode"):
-        assert 'custom_call_target="tpu_custom_call"' not in hlo
+        # the three attention layers' one-pass kernel, and no other
+        assert len(_attend_kernels(hlo)) == hlo.count(
+            'custom_call_target="tpu_custom_call"') == 3
+        _nothing_moves_a_layers_keys(ops, slots, T, 2)
         assert beyond < 64 << 20, beyond >> 20
     else:
         rung = int(name.rsplit("=", 1)[1])
@@ -501,6 +588,11 @@ def _expert_weights_are_read_under_their_scope(hlo: str, least: int):
 # their roofline, the control); solar's 2,048 over 40 (51 each) go
 # through the kernel, three products for each of its four layers.
 SORTED_TICKS = {"laguna-s-2.1": 0, "solar-open2-250b": 3 * 4}
+#: The same ticks' full softmax layers, each the one-pass attention
+#: over the lane's live blocks (``ops/kv_attend.py``): laguna's two of
+#: five layers (its three rings stay on the ``jax.numpy`` form: a
+#: lapped ring is live whole), solar's one of four.
+FULL_LAYERS = {"laguna-s-2.1": 2, "solar-open2-250b": 1}
 
 
 @pytest.mark.parametrize("config", SORTED_TICKS)
@@ -513,6 +605,12 @@ def test_a_ticks_sorted_rows_take_the_form_their_shape_chooses(topo, config):
     assert ("ragged-dot" in hlo) == (not kernels)
     if kernels:
         _expert_weights_are_read_under_their_scope(hlo, kernels)
+    assert len(_attend_kernels(hlo)) == FULL_LAYERS[config]
+    assert "attn.window/" not in "".join(
+        ln for ln in hlo.splitlines() if "kv_attend" in ln)
+    sv = _cell_programs(topo, config)[0]["serve"]
+    _nothing_moves_a_layers_keys(materialised(hlo), sv["slots"],
+                                 sv["max_len"], 8)
 
 
 def test_materialised_leaves_out_fused_computations():

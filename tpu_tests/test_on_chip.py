@@ -770,3 +770,173 @@ def test_grouped_matmul_compiled_at_the_cells():
         json.dump(table, fh, indent=1)
     assert table["nemotron forward 2048"]["grouped_matmul"] \
         < table["nemotron forward 2048"]["ragged_dot"]
+
+
+# A layer's decode attention over K and V in the benchmark's cells:
+# (lanes, query heads, KV heads, positions kept, layers stacked (0: a
+# cache a layer), the cursors' range).
+KV_SHAPES = {
+    "mistral 16 x 1024, stacked": (16, 32, 8, 1024, 4, (128, 260)),
+    "internlm2 16 x 1024, stacked": (16, 16, 8, 1024, 4, (128, 260)),
+    "solar 256 x 2048": (256, 64, 8, 2048, 0, (256, 1024)),
+    "laguna full 64 x 2048": (64, 48, 8, 2048, 0, (512, 1280)),
+    "laguna ring 64 x 512, lapped": (64, 72, 8, 512, 0, (600, 1280)),
+    "nemotron 128 x 3072": (128, 32, 2, 3072, 0, (1024, 1500)),
+}
+
+
+def _copy_only(k, v, row_pos, layer, tk):
+    """The blocks ``kv_attend`` fetches through the same pipeline with
+    no arithmetic but one add of eight rows a block: what the stream
+    alone takes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    L, B, T, nkv, hd = k.shape
+    limit = jnp.clip(row_pos, 0, T - 1)
+
+    def body(last_ref, layer_ref, k_ref, v_ref, o_ref):
+        b, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(j == 0)
+        def _():
+            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+        @pl.when(j <= last_ref[b])
+        def _():
+            o_ref[0] += (k_ref[0, 0, :8].astype(jnp.float32)
+                         + v_ref[0, 0, :8].astype(jnp.float32))
+
+    def cache(b, j, last, layer):
+        live, more = j <= last[b], b + 1 < B
+        return (layer[0], jnp.where(live | ~more, b, b + 1),
+                jnp.where(live, j, jnp.where(more, 0, last[b])), 0)
+
+    return pl.pallas_call(
+        body,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, T // tk),
+            in_specs=[pl.BlockSpec((1, 1, tk * nkv, hd), cache)] * 2,
+            out_specs=pl.BlockSpec((1, 8, hd), lambda b, j, *_: (b, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((B, 8, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(limit // tk, jnp.asarray(layer, jnp.int32).reshape(1),
+      k.reshape(L, B, T * nkv, hd), v.reshape(L, B, T * nkv, hd))
+
+
+def test_kv_attend_compiled_at_the_cells():
+    """The one-pass attention of the decode tick over K and V
+    (``ops/kv_attend.py``) compiled through Mosaic at each cell's
+    shape, cursors drawn over the cell's range and two lanes at rest:
+    eight sampled (lane, head) rows against the softmax in float64 on
+    the host over the same bfloat16 rows (2^-6 of the row's largest
+    entry: the probabilities go to the values' product in bfloat16 in
+    both forms), the ``jax.numpy`` form beside it; the blocks past
+    every cursor poisoned with NaN, which the kernel never reads; then
+    the layer timed at each block size beside the ``jax.numpy`` form
+    (with the slice of the layer where the cache is stacked) and beside
+    a kernel that only fetches the same blocks (PERF.md section 6, PR
+    45)."""
+    import json
+    import os
+
+    from pbs_tpu.models.serving import _grouped_attention
+    from pbs_tpu.ops.kv_attend import attend_block, kv_attend
+
+    bf16, hd, reps = jnp.bfloat16, 128, 20
+    rng = np.random.default_rng(45)
+    table = {}
+
+    def numpy_way(q, k, v, at, layer):
+        k, v = (jax.lax.dynamic_index_in_dim(t, layer, 0, keepdims=False)
+                for t in (k, v))
+        seen = jnp.arange(k.shape[1])[None, :] <= at[:, None]
+        return _grouped_attention(q[:, None], k, v, seen[:, None, :],
+                                  bf16)[:, 0]
+
+    def timed(attend, q, k, v, at):
+        """ms a call of ``attend(q, k, v, at, layer)``, ``reps`` calls
+        chained through the queries inside one program."""
+        layers = k.shape[0]
+
+        @jax.jit
+        def chain(q, k, v, at):
+            def one(i, q):
+                out = attend(q, k, v, at, i % layers)
+                return q + (out[:, :1, :1] * 0).astype(q.dtype)
+            return jax.lax.fori_loop(0, reps, one, q)
+
+        jax.block_until_ready(chain(q, k, v, at))
+        import time
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(q, k, v, at))
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    for name, (B, H, nkv, T, stack, (lo, hi)) in KV_SHAPES.items():
+        L = stack or 1
+        cursors = rng.integers(lo, hi, B)
+        cursors[[1, B - 2]] = 0                           # lanes at rest
+        at = jnp.asarray(np.minimum(cursors, T - 1), jnp.int32)
+        ks = jax.random.split(jax.random.PRNGKey(len(table)), 3)
+        q = jax.random.normal(ks[0], (B, H, hd), bf16)
+        k = jax.random.normal(ks[1], (L, B, T, nkv, hd), bf16)
+        v = jax.random.normal(ks[2], (L, B, T, nkv, hd), bf16)
+        tk = attend_block(T, nkv)
+        kernel = jax.jit(kv_attend, static_argnames=("block",))
+        layer = jnp.int32(L - 1)
+        got = kernel(q, k, v, at, layer)
+        ref = jax.jit(numpy_way)(q, k, v, at, layer)
+        worst = {"kv_attend": 0.0, "jax.numpy form": 0.0}
+        for b, h in zip(rng.integers(0, B, 8), rng.integers(0, H, 8)):
+            n, live = h // (H // nkv), int(at[b]) + 1
+            keys = np.asarray(k[L - 1, b, :live, n], np.float64)
+            vals = np.asarray(v[L - 1, b, :live, n], np.float64)
+            s = keys @ np.asarray(q[b, h], np.float64) / np.sqrt(hd)
+            p = np.exp(s - s.max())
+            want = (p / p.sum()) @ vals
+            for way, out in (("kv_attend", got), ("jax.numpy form", ref)):
+                gap = np.abs(np.asarray(out[b, h], np.float64) - want).max() \
+                    / np.abs(want).max()
+                worst[way] = max(worst[way], float(gap))
+        assert worst["kv_attend"] < 2 ** -6, (name, worst)
+        dead = (jnp.arange(T)[None, :] // tk > at[:, None] // tk)
+        dead = dead[None, :, :, None, None]
+        poisoned = kernel(q, jnp.where(dead, jnp.nan, k),
+                          jnp.where(dead, jnp.nan, v), at, layer)
+        assert bool(jnp.array_equal(poisoned, got)), name
+        del poisoned, ref, got
+
+        ms = {"jax.numpy form": timed(numpy_way, q, k, v, at)}
+        for block in (tk // 2, tk, tk * 2):
+            if T % block or block % 128:
+                continue
+            try:
+                ms[f"kv_attend {block}"] = timed(
+                    lambda *a, block=block: kv_attend(*a, block=block),
+                    q, k, v, at)
+                ms[f"copy only {block}"] = timed(
+                    lambda q, k, v, at, i, block=block: _copy_only(
+                        k, v, at, i, block)[:, :1].astype(bf16),
+                    q, k, v, at)
+            except Exception as e:  # a block VMEM does not hold
+                ms[f"kv_attend {block}"] = float("nan")
+                print(f"{name}: blocks of {block}: {str(e)[:200]}")
+        fetched = int((np.asarray(at) // tk + 1).sum()) * tk * nkv * hd * 4
+        table[name] = dict(
+            ms, block=tk, lanes=B, heads=H, kv_heads=nkv, kept=T,
+            live_positions=int(np.asarray(at).sum() + B),
+            fetched_mb=fetched / 1e6,
+            fetched_at_819_gbs_ms=fetched / 819e9 * 1e3, gap=worst)
+        print(f"{name}: {H} heads on {nkv}, block {tk}, "
+              f"{fetched / 1e6:.1f} MB fetched "
+              f"({fetched / 819e9 * 1e3:.3f} ms at 819 GB/s), ms a layer: "
+              + ", ".join(f"{n} {t:.3f}" for n, t in ms.items())
+              + "; against float64 " + ", ".join(
+                  f"{n} {g:.2e}" for n, g in worst.items()), flush=True)
+        if "ring" not in name:  # all of a lapped ring is live
+            assert ms[f"kv_attend {tk}"] < ms["jax.numpy form"], name
+        del q, k, v
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kv_attend.json", "w") as fh:
+        json.dump(table, fh, indent=1)
