@@ -9,7 +9,8 @@ backend — that traffic is invisible to every one of those guarantees:
 no quota charges it, no fairness schedules it, and a backend loss
 silently drops it. Two rules, scoped to the package tree minus the
 machinery (``pbs_tpu/gateway/`` implements the door; ``models/
-serving.py`` implements the engine the door fronts) and tests:
+serving.py`` and ``models/spec_serving.py`` implement the engines the
+door fronts) and tests:
 
 - ``gw-direct-submit``: ``.submit(...)`` on an object constructed from
   ``ContinuousBatcher``/``SpeculativeBatcher`` in the same module
@@ -51,8 +52,8 @@ BUCKET_CTORS = {"TokenBucket", "LeasedBucket", "GlobalBucket"}
 #: KV-handoff path, on the far side of admission — the exact seam
 #: gateway/backends.py is exempt for. The rest of serve/ is NOT
 #: machinery and stays covered.
-MACHINERY = ("gateway", "models/serving.py", "serve/backend.py",
-             "serve/disagg.py")
+MACHINERY = ("gateway", "models/serving.py", "models/spec_serving.py",
+             "serve/backend.py", "serve/disagg.py")
 
 
 def _anchored(rel_path: str) -> list[str]:
@@ -67,8 +68,7 @@ def _exempt(rel_path: str) -> bool:
     if not parts:
         return True
     joined = "/".join(parts)
-    if parts[0] == "gateway" or joined in (
-            "models/serving.py", "serve/backend.py", "serve/disagg.py"):
+    if parts[0] == "gateway" or joined in MACHINERY[1:]:
         return True
     # Tests drive engines directly on purpose (parity/latency pins).
     norm = rel_path.replace("\\", "/")

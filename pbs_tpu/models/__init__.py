@@ -1,3 +1,5 @@
+import importlib
+
 from pbs_tpu.models.flagship import flagship_config
 from pbs_tpu.models.generate import (
     forward_with_cache,
@@ -7,12 +9,6 @@ from pbs_tpu.models.generate import (
     prefill,
 )
 from pbs_tpu.models.microstep import make_micro_train_step
-from pbs_tpu.models.serving import (
-    Completion,
-    ContinuousBatcher,
-    SpeculativeBatcher,
-    make_continuous_serve_step,
-)
 from pbs_tpu.models.moe import (
     MoEConfig,
     init_moe_params,
@@ -66,3 +62,20 @@ __all__ = [
     "quantize_weights",
     "quantized_nbytes",
 ]
+
+#: The slot engines, each imported from the module that defines it when
+#: first asked for: what a configuration's layer stack is
+#: (``models/slot_programs.py``) and every model module beside it load
+#: without the engine, as they know nothing of it.
+_ENGINES = {
+    "Completion": "pbs_tpu.models.serving",
+    "ContinuousBatcher": "pbs_tpu.models.serving",
+    "make_continuous_serve_step": "pbs_tpu.models.serving",
+    "SpeculativeBatcher": "pbs_tpu.models.spec_serving",
+}
+
+
+def __getattr__(name: str):
+    if name in _ENGINES:
+        return getattr(importlib.import_module(_ENGINES[name]), name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -9,7 +9,7 @@ inputs of a short causal convolution. Per position::
 
     S <- Diag(exp g) S;  S <- S + beta k (v - S^T k)^T;  o = S^T q
 
-Two forms of it, one a program of the engine (``models/serving.py``):
+Two forms of it, one a program of the engine (``models/slot_programs.py``):
 :func:`kda_decode`, one recurrent step for every lane of a decode tick,
 and :func:`kda_ingest`, a whole prompt from a zero state by a
 chunkwise-parallel scan (:func:`kda_chunked`). A state has no cursor to

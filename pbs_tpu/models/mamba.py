@@ -18,7 +18,7 @@ multiply-adds between the in- and out-projections. The state and
 ``(d_inner, d_state)`` a tile of 16 is padded to 128, eight times the
 bytes).
 
-Three programs of the engine (``models/serving.py``) in the shape of
+Three programs of the engine (``models/slot_programs.py``) in the shape of
 ``models/kda.py``: :func:`mamba_decode`, one recurrent step for every
 lane of a decode tick; :func:`mamba_ingest`, a whole prompt from a zero
 state by :func:`mamba_scan` or, lowered for a TPU, by the one-pass

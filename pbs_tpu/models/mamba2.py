@@ -24,7 +24,7 @@ chunk)::
              + exp(s_t) C_t H_prev
     H_next = exp(s_Q) H_prev + sum_u exp(s_Q - s_u) dt_u x_u (outer) B_u
 
-Two programs of the engine (``models/serving.py``) in the shape of
+Two programs of the engine (``models/slot_programs.py``) in the shape of
 ``models/mamba.py``: :func:`mamba2_decode`, one recurrent step for every
 lane of a decode tick; :func:`mamba2_ingest`, a whole prompt from a zero
 state in chunks. A state has no cursor to mask what was folded into it,

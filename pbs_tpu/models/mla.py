@@ -18,7 +18,7 @@ input ``h`` of position ``t``::
     S_t = the topk positions s <= t of largest I[t, s] (all, up to topk)
     o = softmax over S_t of (q_n . k_n[s] + q_r . k_r[s]) / sqrt(qk) @ v
 
-Two programs of the engine (``models/serving.py``), in the shape of
+Two programs of the engine (``models/slot_programs.py``), in the shape of
 ``models/kda.py``. :func:`mla_ingest` runs a whole prompt in the
 per-head form (keys and values read off the latent rows once, for the
 prompt), a block of queries at a time: the block's indexer scores, its
@@ -42,9 +42,10 @@ The decode's attention is, on a TPU, **one pass over a lane's live
 rows** (``ops/mla_attend.py``: a block of positions in VMEM at a time,
 scores, mask, softmax and values on the chip, the blocks past the
 lane's cursor never fetched), and anywhere else :func:`attend_rows`,
-the same arithmetic in ``jax.numpy`` over every kept row;
-:func:`_attend` chooses by the platform the program is lowered for and
-the caches' shapes, nothing a user sets. Why the mask stays and the
+the same arithmetic in ``jax.numpy`` over every kept row: by the
+platform the program is lowered for, where the program says the layer
+streams (``slot_programs.live_layers``: the caches' shapes and where
+they lie), nothing a user sets. Why the mask stays and the
 chosen rows are not gathered at this cache length: a query sees ~6,600
 live rows of GLM-5's 10,240 and chooses 2,048, a third of what the
 stream reads at 710-750 GB/s; single rows of 1 KiB, and of 128 B out
@@ -65,12 +66,10 @@ import numpy as np
 from pbs_tpu.models.plan import MlaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
-from pbs_tpu.ops.mla_attend import (
-    attend_block, mla_attend, mla_attend_tiles)
+from pbs_tpu.ops.mla_attend import mla_attend
 
 __all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "attend_rows",
-           "decode_choice", "mla_decode", "mla_ingest", "streamed_block",
-           "top_mask"]
+           "decode_choice", "mla_decode", "mla_ingest", "top_mask"]
 
 #: Queries a block of the ingestion scores, chooses for and attends at
 #: a time: the live score tensors are ``(heads, MLA_BLOCK, keys)``, never
@@ -177,7 +176,7 @@ def _put(rows: jax.Array, new: jax.Array, at: jax.Array,
     """Lane b's new row (``new``: (B, 1, W)) goes to ``rows[b, at[b]]``
     where the lane is active; an idle lane's row is written back as it
     was. One dynamic_update_slice a lane into the whole cache
-    (``serving._write_rows`` says why not a scatter)."""
+    (``slot_programs._write_rows`` says why not a scatter)."""
 
     def one(b, rows):
         old = jax.lax.dynamic_slice(rows, (b, at[b], 0),
@@ -209,13 +208,15 @@ def decode_choice(a: MlaKind, qi: jax.Array, w: jax.Array, ik: jax.Array,
 def mla_decode(a: MlaKind, ap: dict, h: jax.Array, ckv: jax.Array,
                kr: jax.Array, ik: jax.Array, row_pos: jax.Array,
                active: jax.Array, cos: jax.Array, sin: jax.Array,
-               eps: float, dt):
+               eps: float, dt, live: bool = False):
     """One position for every lane, absorbed: h (B, 1, d) at position
     ``row_pos[b]``, the layer's caches ``ckv`` (B, T, kv_rank), ``kr``
     (B, T, rope), ``ik`` (B, T, index_dim), cos and sin (B, 1, rope /
     2). An active lane's three new rows go to its cursor; an idle
-    lane's caches come out as they went in. Returns (what the heads
-    give (B, 1, H * v), ckv, kr, ik)."""
+    lane's caches come out as they went in. ``live``: the program's
+    word (``slot_programs.live_layers``) that this layer's attention
+    streams the lanes' live rows. Returns (what the heads give (B, 1,
+    H * v), ckv, kr, ik)."""
     B = ckv.shape[0]
     q_n, q_r, c_new, kr_new, ik_new, qi, w = _rows(a, ap, h, cos, sin,
                                                    eps, dt)
@@ -228,7 +229,7 @@ def mla_decode(a: MlaKind, ap: dict, h: jax.Array, ckv: jax.Array,
     with jax.named_scope("mla.attend"):
         w_k, w_v = _halves(a, ap, dt)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_k)
-        o_lat = _attend(q_lat, q_r[:, 0], ckv, kr, chosen, at,
+        o_lat = _attend(q_lat, q_r[:, 0], ckv, kr, chosen, at, live,
                         scale=1.0 / np.sqrt(a.nope_dim + a.rope_dim))
         out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
     return out.reshape(B, 1, a.n_heads * a.v_dim), ckv, kr, ik
@@ -255,29 +256,19 @@ def attend_rows(q_lat, q_r, ckv, kr, chosen, *, scale: float):
 _kernel_attend = jax.jit(mla_attend, static_argnames=("scale",))
 
 
-def _attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float):
-    """The decode's attention by the platform the program is lowered
-    for: on a TPU the one-pass kernel, where its tiling takes the
-    shapes (a latent of whole rows of 128 lanes, the cache whole blocks
-    of positions, heads by the eight); anywhere else, and for any other
-    shape, :func:`attend_rows`."""
-    if not mla_attend_tiles(q_lat.shape[1], *ckv.shape[1:]):
+def _attend(q_lat, q_r, ckv, kr, chosen, row_pos, live: bool, *,
+            scale: float):
+    """The decode's attention: where the layer streams (``live``,
+    ``slot_programs.live_layers``' answer: shapes the kernel's tiling
+    takes, on one device) by the platform the program is lowered for,
+    on a TPU the one-pass kernel and anywhere else :func:`attend_rows`;
+    where it does not, :func:`attend_rows`."""
+    if not live:
         return attend_rows(q_lat, q_r, ckv, kr, chosen, scale=scale)
     return jax.lax.platform_dependent(
         q_lat, q_r, ckv, kr, chosen, row_pos,
         tpu=functools.partial(_kernel_attend, scale=scale),
         default=lambda *args: attend_rows(*args[:-1], scale=scale))
-
-
-def streamed_block(a: MlaKind, ckv: jax.Array) -> int:
-    """Positions a block of the one-pass kernel the decode runs over a
-    layer's latent rows where they lie, as :func:`_attend` decides; 0 where
-    :func:`attend_rows` runs (a shape the kernel's tiling does not
-    take, a cache on no TPU). The engine's ``ENG_SELECT`` counts the
-    blocks a tick streams by it."""
-    on_chip = all(d.platform == "tpu" for d in ckv.devices())
-    return attend_block(ckv.shape[1]) if on_chip \
-        and mla_attend_tiles(a.n_heads, *ckv.shape[1:]) else 0
 
 
 def _attend_chunks(q, k, v, seen, scale: float, dt):

@@ -389,7 +389,7 @@ def expert_form(rows: int, d: int, kind, dt) -> tuple[str, int]:
     else) where :func:`grouped_kernel_takes` the shape, or through
     ``ragged_dot`` on every platform. A function of the static shape
     alone; the engine writes it into the host ring when it builds a
-    program (``models/serving.py::_plan_forward``)."""
+    program (``models/slot_programs.py::_plan_forward``)."""
     n = kind.held[1]
     if rows * n <= DENSE_PAIRS:
         return "every", rows

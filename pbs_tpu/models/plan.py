@@ -6,7 +6,7 @@ layers differ (full beside sliding-window attention with their own head
 counts and rotary settings; a leading dense MLP followed by routed
 experts) is described here instead: a few layer *kinds*, and per layer
 which attention kind and which MLP kind it is. The serving engine reads
-the plan (``models/serving.py``: the cache, the forward over slot rows,
+the plan (``models/slot_programs.py``: the cache, the forward over slot rows,
 the ingestion of a prompt); nothing branches on a model's name.
 
 A planned model's parameters are held a layer at a time, every weight

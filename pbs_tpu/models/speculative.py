@@ -199,7 +199,7 @@ def make_per_row_speculative_generate(
     re-verifying — tokens that faster rows already verified (its
     ``reverified`` stat). Here each row carries its own cache cursor,
     built on the continuous batcher's per-slot machinery
-    (``serving._slot_forward``: per-row rope gather, vmapped
+    (``slot_programs._slot_forward``: per-row rope gather, vmapped
     contiguous KV writes, per-row causal horizon), so re-verification
     is structurally zero and the round count is governed by each row's
     own acceptance, not the batch's worst.  Still greedy, still
@@ -217,7 +217,7 @@ def make_per_row_speculative_generate(
         raise ValueError(
             f"draft vocab {draft_cfg.vocab} != target vocab {cfg.vocab}")
 
-    from pbs_tpu.models.serving import _slot_forward, init_slot_cache
+    from pbs_tpu.models.slot_programs import _slot_forward, init_slot_cache
 
     def spec_generate(params: dict, draft_params: dict,
                       prompt: jax.Array):

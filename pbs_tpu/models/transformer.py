@@ -80,7 +80,7 @@ class TransformerConfig:
     layer_plan: Any = None
     # The logits are ``h @ embed.T`` and the tree has no ``head``. Read
     # by the serving engine, which then serves through the planned
-    # stack's program (``models.serving.slot_program``); the training
+    # stack's program (``models.slot_programs.slot_program``); the training
     # step keeps its untied head.
     tie_embeddings: bool = False
 

@@ -8,7 +8,7 @@ reads KV head ``h // g``, ``g = H / nkv``)::
     s[h, t] = q[h] . k[t, h // g] / sqrt(hd)          for t <= cursor
     o[h]    = softmax over those t of s[h, t] @ v[:, h // g]
 
-As ``jax.numpy`` (``models/serving.py::_grouped_attention``, which
+As ``jax.numpy`` (``models/slot_programs.py::_grouped_attention``, which
 stays as the CPU lowering, as the path of every prompt forward and
 mesh, and as this kernel's oracle) XLA:TPU reads **every position the
 cache has**, whatever the cursors: a transpose of K to heads-major, the
@@ -55,14 +55,11 @@ cache stacked by layer (the dense layer scan's carry) comes whole with
 the layer's index, a second prefetched scalar in the index map: no
 slice of a layer is made.
 
-**The next lane's first block is asked for early.** The pipeline looks
-one grid step ahead, and a step past a lane's cursor takes no time:
-clamped to the lane's own last block, the dead steps would put the
-next lane's first fetch one step before its use, and every lane would
-wait for a block whole. Past the cursor the index maps name the next
-lane's first block instead, so that it is fetched under this lane's
-last live step and lies there when its lane begins (solar's layer 1.54
--> 1.25 ms, mistral's 0.083 -> 0.070).
+The pipeline itself (the grid, the prefetched cursor blocks, the index
+map that names the next lane's first block on a lane's dead steps, the
+running softmax in scratch) is ``ops/live_attend.py``'s, shared with
+``ops/mla_attend.py``; here are the layout, the block size and the
+block's arithmetic.
 
 At solar's cell (256 lanes of 2,048 positions, 64 query heads on 8,
 cursors 256-1,024, two lanes at rest) a layer takes 1.25 ms where the
@@ -92,8 +89,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from pbs_tpu.ops.live_attend import live_attend
 
 __all__ = ["attend_block", "kv_attend", "kv_attend_tiles"]
 
@@ -125,52 +122,23 @@ def kv_attend_tiles(nkv: int, hd: int, T: int) -> bool:
             and attend_block(T, nkv) > 0)
 
 
-def _attend_kernel(last_ref, limit_ref, layer_ref, q_ref, heads_ref, k_ref,
-                   v_ref, o_ref, top_ref, total_ref, acc_ref, *,
-                   scale: float, nkv: int, tk: int):
-    """Grid step (lane b, block j): the block's part of the lane's
-    softmax, folded into the running maximum, sum and accumulator.
-    ``last_ref[b]`` is the block the lane's cursor is in,
-    ``limit_ref[b]`` the last position it attends."""
+def _block(b, j, limit_ref, layer_ref, q_ref, heads_ref, k_ref, v_ref, *,
+           scale: float, nkv: int, tk: int):
+    """Block j of lane b: every query head against every row of the
+    block as it lies, a row live for a head where it is the head's KV
+    head's and at or before ``limit_ref[b]``, the last position the
+    lane attends."""
     del layer_ref  # the index maps' alone
-    b, j = pl.program_id(0), pl.program_id(1)
-    last = last_ref[b]
-    low = jnp.finfo(_F32).min
-
-    @pl.when(j == 0)
-    def _():
-        top_ref[...] = jnp.full(top_ref.shape, low, _F32)
-        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
-
-    @pl.when(j <= last)
-    def _():
-        keys, values = k_ref[0, 0], v_ref[0, 0]              # (tk nkv, hd)
-        nt = (((1,), (1,)), ((), ()))
-        scores = jax.lax.dot_general(
-            q_ref[0], keys, nt, preferred_element_type=_F32) * scale
-        # row r of the block is position j tk + r // nkv, head r % nkv
-        row = jax.lax.broadcasted_iota(jnp.int32, (1, tk * nkv), 1)
-        shift = nkv.bit_length() - 1
-        live = (row >> shift) <= limit_ref[b] - j * tk       # (1, rows)
-        mask = ((row & (nkv - 1)) == heads_ref[...]) & live  # (H, rows)
-        scores = jnp.where(mask, scores, low)
-        top = top_ref[...]
-        peak = jnp.maximum(top, jnp.max(scores, axis=-1, keepdims=True))
-        # every query head has a live row of its own in every block up
-        # to the cursor's, so ``peak`` is a score and a masked entry's
-        # exponential is an exact zero
-        probs = jnp.exp(scores - peak)
-        keep = jnp.exp(top - peak)
-        total_ref[...] = total_ref[...] * keep \
-            + jnp.sum(probs, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * keep + jnp.dot(
-            probs.astype(values.dtype), values, preferred_element_type=_F32)
-        top_ref[...] = peak
-
-    @pl.when(j == last)
-    def _():
-        o_ref[0] = (acc_ref[...] / total_ref[...]).astype(o_ref.dtype)
+    keys, values = k_ref[0, 0], v_ref[0, 0]                  # (tk nkv, hd)
+    nt = (((1,), (1,)), ((), ()))
+    scores = jax.lax.dot_general(
+        q_ref[0], keys, nt, preferred_element_type=_F32) * scale
+    # row r of the block is position j tk + r // nkv, head r % nkv
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, tk * nkv), 1)
+    shift = nkv.bit_length() - 1
+    live = (row >> shift) <= limit_ref[b] - j * tk           # (1, rows)
+    mask = ((row & (nkv - 1)) == heads_ref[...]) & live      # (H, rows)
+    return scores, mask, values
 
 
 def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
@@ -182,7 +150,7 @@ def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
     (B,) each lane's cursor: positions ``<= min(row_pos[b], T - 1)``
     are attended (a ring that has lapped is live whole). Returns (B, H,
     hd) in the cache's dtype, scaled by ``1 / sqrt(hd)`` as
-    ``models/serving.py::_grouped_attention`` scales, which is the same
+    ``models/slot_programs.py::_grouped_attention`` scales, which is the same
     function in ``jax.numpy``. Compiled, the shapes have to satisfy
     :func:`kv_attend_tiles`; ``interpret`` (the tests) takes any whole
     blocks, and ``block`` (the tests) another block than
@@ -202,44 +170,18 @@ def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
     heads = np.minimum(np.arange(Hp) // (H // nkv), nkv - 1).astype(np.int32)
     if Hp != H:
         q = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0)))
-    # the last position a lane attends and the block it is in: a grid
-    # step reads none past it (the same block again, which is not
-    # fetched again)
+    # the last position a lane attends and the block it is in (the
+    # pipeline's ``last``: a grid step names no block of the lane past it)
     limit = jnp.clip(row_pos.astype(jnp.int32), 0, T - 1)
     rows = tk * nkv
-    lane = lambda b, j, *_: (b, 0, 0)  # noqa: E731
-
-    def cache(b, j, last, limit, layer):
-        # Up to the cursor's block the step's own; past it the NEXT
-        # lane's first block, which is then fetched under this lane's
-        # last live step and lies there when its lane begins (the
-        # pipeline looks one step ahead: behind a run of dead steps,
-        # which take no time, that fetch would be waited for whole).
-        # The last lane's dead steps stay on its last block.
-        live, more = j <= last[b], b + 1 < B
-        return (layer[0], jnp.where(live | ~more, b, b + 1),
-                jnp.where(live, j, jnp.where(more, 0, last[b])), 0)
-
-    out = pl.pallas_call(
-        functools.partial(_attend_kernel, scale=1.0 / np.sqrt(hd), nkv=nkv,
-                          tk=tk),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, T // tk),
-            in_specs=[
-                pl.BlockSpec((1, Hp, hd), lane),
-                pl.BlockSpec((Hp, 1), lambda b, j, *_: (0, 0)),
-                pl.BlockSpec((1, 1, rows, hd), cache),
-                pl.BlockSpec((1, 1, rows, hd), cache)],
-            out_specs=pl.BlockSpec((1, Hp, hd), lane),
-            scratch_shapes=[pltpu.VMEM((Hp, 1), _F32),
-                            pltpu.VMEM((Hp, 1), _F32),
-                            pltpu.VMEM((Hp, hd), _F32)]),
-        out_shape=jax.ShapeDtypeStruct((B, Hp, hd), k.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=48 << 20),
-        name="kv_attend", interpret=interpret,
-    )(limit // tk, limit, jnp.asarray(layer, jnp.int32).reshape(1), q,
-      jnp.asarray(heads)[:, None], k.reshape(L, B, T * nkv, hd),
-      v.reshape(L, B, T * nkv, hd))
+    cache = ((1, 1, rows, hd),
+             lambda lane, block, limit, layer: (layer[0], lane, block, 0))
+    out = live_attend(
+        functools.partial(_block, scale=1.0 / np.sqrt(hd), nkv=nkv, tk=tk),
+        limit // tk, (limit, jnp.asarray(layer, jnp.int32).reshape(1)),
+        [q], [jnp.asarray(heads)[:, None]],
+        [(k.reshape(L, B, T * nkv, hd), *cache),
+         (v.reshape(L, B, T * nkv, hd), *cache)],
+        blocks=T // tk, out=jax.ShapeDtypeStruct((B, Hp, hd), k.dtype),
+        vmem_limit_bytes=48 << 20, name="kv_attend", interpret=interpret)
     return out[:, :H]

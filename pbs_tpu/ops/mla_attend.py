@@ -20,12 +20,16 @@ and the float32 scores never leave the chip: ``ckv`` is read once.
 
 **Blocks past a lane's cursor are never fetched.** The block each
 cursor is in is scalar-prefetched; the index maps of ``ckv``, ``kr``
-and the choice clamp a grid step's block to it (a block index that
-repeats is not fetched again) and the body runs only up to it. An idle lane (``mla_decode`` rests its cursor at 0) streams one
-block. Rows behind the cursor *inside* that last block are read and
-masked, as every dead row is by the ``jax.numpy`` form: the choice is
-``decode_choice``'s own mask, so the set attended is the same
-whichever lowering ran, ties in.
+and the choice name no block of a lane past it and the body runs only
+up to it (``ops/live_attend.py``, the pipeline this kernel shares with
+``ops/kv_attend.py``: the grid, the index map that names the next
+lane's first block on a lane's dead steps, the running softmax in
+scratch; here are the layout, the block size and the block's
+arithmetic). An idle lane (``mla_decode`` rests its cursor at 0)
+streams one block. Rows behind the cursor *inside* that last block are
+read and masked, as every dead row is by the ``jax.numpy`` form: the
+choice is ``decode_choice``'s own mask, so the set attended is the
+same whichever lowering ran, ties in.
 
 The caches come as they lie. XLA:TPU keeps a cache whose rows are
 narrower than a row of lanes **positions-minor** (GLM-5's ``kr``:
@@ -51,8 +55,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from pbs_tpu.ops.live_attend import live_attend
 
 __all__ = ["attend_block", "mla_attend", "mla_attend_tiles"]
 
@@ -76,44 +80,17 @@ def mla_attend_tiles(heads: int, T: int, kv_rank: int) -> bool:
     return kv_rank % 128 == 0 and heads % 8 == 0 and attend_block(T) > 0
 
 
-def _attend_kernel(last_ref, q_ref, qr_ref, ckv_ref, kr_ref, chosen_ref,
-                   o_ref, top_ref, total_ref, acc_ref, *, scale: float):
-    """Grid step (lane b, block j): the block's part of the lane's
-    softmax, folded into the running maximum, sum and accumulator;
-    ``last_ref[b]`` is the block the lane's cursor is in."""
-    j, last = pl.program_id(1), last_ref[pl.program_id(0)]
-    low = jnp.finfo(_F32).min
-
-    @pl.when(j == 0)
-    def _():
-        top_ref[...] = jnp.full(top_ref.shape, low, _F32)
-        total_ref[...] = jnp.zeros(total_ref.shape, _F32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, _F32)
-
-    @pl.when(j <= last)
-    def _():
-        rows = ckv_ref[0]                                       # (tk, R)
-        nt = (((1,), (1,)), ((), ()))
-        scores = (jax.lax.dot_general(q_ref[0], rows, nt,
-                                      preferred_element_type=_F32)
-                  + jnp.dot(qr_ref[0], kr_ref[0],
-                            preferred_element_type=_F32)) \
-            * scale                                             # (H, tk)
-        mask = chosen_ref[0] != 0                               # (1, tk)
-        top = top_ref[...]
-        peak = jnp.maximum(top, jnp.max(
-            jnp.where(mask, scores, low), axis=-1, keepdims=True))
-        probs = jnp.where(mask, jnp.exp(scores - peak), 0.0)
-        keep = jnp.exp(top - peak)
-        total_ref[...] = total_ref[...] * keep \
-            + jnp.sum(probs, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * keep + jnp.dot(
-            probs.astype(rows.dtype), rows, preferred_element_type=_F32)
-        top_ref[...] = peak
-
-    @pl.when(j == last)
-    def _():
-        o_ref[0] = (acc_ref[...] / total_ref[...]).astype(o_ref.dtype)
+def _block(b, j, q_ref, qr_ref, ckv_ref, kr_ref, chosen_ref, *, scale: float):
+    """Block j of lane b: every head against the block's latent rows
+    and rotary keys, a row live where the lane's indexer chose it."""
+    rows = ckv_ref[0]                                           # (tk, R)
+    nt = (((1,), (1,)), ((), ()))
+    scores = (jax.lax.dot_general(q_ref[0], rows, nt,
+                                  preferred_element_type=_F32)
+              + jnp.dot(qr_ref[0], kr_ref[0],
+                        preferred_element_type=_F32)) \
+        * scale                                                 # (H, tk)
+    return scores, chosen_ref[0] != 0, rows                     # (1, tk)
 
 
 def mla_attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float,
@@ -135,31 +112,15 @@ def mla_attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float,
     if not tk or T % tk:
         raise ValueError(f"a cache of {T} positions is not whole blocks "
                          f"of {tk or BLOCKS}")
-    # the block a lane's cursor is in: a grid step reads none past it
-    # (the same block again, which is not fetched again)
+    # the block a lane's cursor is in (the pipeline's ``last``: a grid
+    # step names no block of the lane past it)
     last = jnp.clip(row_pos.astype(jnp.int32) // tk, 0, T // tk - 1)
-    lane = lambda b, j, last: (b, 0, 0)  # noqa: E731
-    return pl.pallas_call(
-        functools.partial(_attend_kernel, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B, T // tk),
-            in_specs=[
-                pl.BlockSpec((1, H, R), lane),
-                pl.BlockSpec((1, H, E), lane),
-                pl.BlockSpec((1, tk, R), lambda b, j, last: (
-                    b, jnp.minimum(j, last[b]), 0)),
-                pl.BlockSpec((1, E, tk), lambda b, j, last: (
-                    b, 0, jnp.minimum(j, last[b]))),
-                pl.BlockSpec((1, 1, tk), lambda b, j, last: (
-                    b, 0, jnp.minimum(j, last[b])))],
-            out_specs=pl.BlockSpec((1, H, R), lane),
-            scratch_shapes=[pltpu.VMEM((H, 1), _F32),
-                            pltpu.VMEM((H, 1), _F32),
-                            pltpu.VMEM((H, R), _F32)]),
-        out_shape=jax.ShapeDtypeStruct((B, H, R), ckv.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=32 << 20),
-        name="mla_attend", interpret=interpret,
-    )(last, q_lat, q_r, ckv, jnp.swapaxes(kr, 1, 2),
-      chosen.astype(jnp.int32)[:, None, :])
+    return live_attend(
+        functools.partial(_block, scale=scale), last, (), [q_lat, q_r], [],
+        [(ckv, (1, tk, R), lambda lane, block: (lane, block, 0)),
+         (jnp.swapaxes(kr, 1, 2), (1, E, tk),
+          lambda lane, block: (lane, 0, block)),
+         (chosen.astype(jnp.int32)[:, None, :], (1, 1, tk),
+          lambda lane, block: (lane, 0, block))],
+        blocks=T // tk, out=jax.ShapeDtypeStruct((B, H, R), ckv.dtype),
+        vmem_limit_bytes=32 << 20, name="mla_attend", interpret=interpret)

@@ -89,7 +89,7 @@ class ShardedServeBackend(BatcherBackend):
             raise ValueError(f"clock must be 'wall' or 'virtual', "
                              f"got {clock!r}")
         if params is None:
-            from pbs_tpu.models.serving import slot_program
+            from pbs_tpu.models.slot_programs import slot_program
 
             params = slot_program(cfg).init_params(jax.random.PRNGKey(seed))
         self.cfg = cfg

@@ -59,7 +59,8 @@ class PrefillPool:
         import jax
         import jax.numpy as jnp
 
-        from pbs_tpu.models.serving import ingest_slot_prompt, slot_program
+        from pbs_tpu.models.slot_programs import (
+            ingest_slot_prompt, slot_program)
 
         self.cfg = cfg
         self.n_lanes = int(n_lanes)

@@ -34,8 +34,9 @@ from pbs_tpu.models import plan as P
 from pbs_tpu.models.moe import (
     held_expert_ffn, route_top_k, shared_expert_ffn)
 from pbs_tpu.models.kda import KDA_CHUNK, kda_chunked
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, slot_program)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import slot_program
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.serve import ShardedServeBackend
 from pbs_tpu.serve.partition import (
     PARTITION_RULES, iter_leaf_paths, match_partition_rules)

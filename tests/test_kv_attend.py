@@ -2,7 +2,7 @@
 (``pbs_tpu/ops/kv_attend.py``) in Pallas interpret mode, at toy widths
 and blocks of 16 positions: against the ``jax.numpy`` form the CPU
 lowers and every prompt forward runs
-(``models/serving.py::_grouped_attention``) under the decode's own
+(``models/slot_programs.py::_grouped_attention``) under the decode's own
 mask, at the cells' head shapes; then through the two call sites, a toy
 scan engine and a toy planned engine, whose greedy tokens are the same
 with the kernel and without. What the chip's compiler makes of it is
@@ -11,20 +11,23 @@ with the kernel and without. What the chip's compiler makes of it is
 
 import dataclasses
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pbs_tpu.models import mla
 from pbs_tpu.models import plan as P
-from pbs_tpu.models import serving
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, _grouped_attention, slot_program)
+from pbs_tpu.models import slot_programs
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import _grouped_attention, slot_program
 from pbs_tpu.models.transformer import TransformerConfig, init_params
 from pbs_tpu.obs.trace import Ev
 from pbs_tpu.ops.kv_attend import (
     BLOCKS, attend_block, kv_attend, kv_attend_tiles)
+from pbs_tpu.ops.mla_attend import mla_attend
 
 HD, T, TK = 16, 64, 16
 TOL = 2e-6
@@ -202,6 +205,18 @@ def planned_model():
     return cfg, slot_program(cfg).init_params(jax.random.PRNGKey(1))
 
 
+def latent_model():
+    """Two latent layers that choose 8 of their positions around a full
+    layer, over dense MLPs."""
+    rope = P.Rope(rotary_dim=8, interleave=True)
+    cfg = dataclasses.replace(dense_model()[0], layer_plan=P.LayerPlan(
+        attn=(P.MlaKind("latent", 4, 16, 32, 8, 8, 8, 2, 8, 8, rope),
+              P.AttnKind("full", 4, None, P.Rope())),
+        mlp=(P.MlpKind("dense", 96),),
+        layers=((0, 0), (1, 0), (0, 0))))
+    return cfg, slot_program(cfg).init_params(jax.random.PRNGKey(2))
+
+
 def serve(engine, prompts=PROMPTS, max_new=NEW):
     done = {}
     for p in prompts:
@@ -217,22 +232,29 @@ def engine(model, bucket=BUCKET, **kw):
 
 
 def as_on_a_chip(monkeypatch, form):
-    """The predicate takes the toy shapes; ``form`` "kernel" puts the
-    interpreted kernel where a TPU's lowering puts the compiled one,
-    "numpy" leaves ``_cursor_attention`` to lower for the CPU (the
-    branch a TPU would take is traced all the same, at blocks of 16)."""
+    """The predicate (``slot_programs.live_layers``) takes the toy
+    shapes at blocks of 16; ``form`` "kernel" puts the interpreted
+    kernel where a TPU's lowering puts the compiled one, "numpy" leaves
+    ``_cursor_attention`` to lower for the CPU (the branch a TPU would
+    take is traced all the same, at blocks of 16)."""
     calls = []
-    monkeypatch.setattr(serving, "kv_attend_tiles",
-                        lambda nkv, hd, kept: kept % TK == 0)
-    monkeypatch.setattr(serving, "_kernel_attend", functools.partial(
-        kv_attend, block=TK, interpret=True))
+    for name, fn in (
+            ("kv_attend_tiles", lambda nkv, hd, kept: kept % TK == 0),
+            ("kv_attend_block", lambda kept, nkv: TK),
+            ("mla_attend_tiles", lambda heads, kept, rank: kept % TK == 0),
+            ("mla_attend_block", lambda kept: TK),
+            ("_kernel_attend", functools.partial(
+                kv_attend, block=TK, interpret=True))):
+        monkeypatch.setattr(slot_programs, name, fn)
+    monkeypatch.setattr(mla, "_kernel_attend", functools.partial(
+        mla_attend, block=TK, interpret=True))
     if form == "kernel":
         def attend(q, k, v, at, layer, dt):
             calls.append((k.shape, layer is not None))
             return kv_attend(q[:, 0], k, v, at, layer, block=TK,
                              interpret=True)[:, None]
 
-        monkeypatch.setattr(serving, "_cursor_attention", attend)
+        monkeypatch.setattr(slot_programs, "_cursor_attention", attend)
     return calls
 
 
@@ -272,7 +294,7 @@ def test_a_mesh_of_more_devices_keeps_the_numpy_form(monkeypatch):
     mesh = make_serve_mesh(tp=2, dp=1)
     eng = engine((cfg, place(params, mesh)), mesh=mesh)
     assert len(eng.program.devices) == 2 and calls == []
-    assert eng.program.attend_blocks(eng.cache) == []
+    assert eng.program.live_layers(eng.cache) == {}
     one = make_serve_mesh(tp=1, dp=1)
     assert len(engine((cfg, place(params, one)),
                       mesh=one).program.devices) == 1
@@ -292,6 +314,13 @@ class _Chip:
     platform = "tpu"
 
 
+def mesh_of_chips(n: int):
+    """All a program asks of the mesh its cache will lie on, the
+    devices: ``n`` TPUs."""
+    return types.SimpleNamespace(
+        devices=np.array([_Chip() for _ in range(n)], dtype=object))
+
+
 @pytest.mark.parametrize("model,takes,chips,want", [
     (dense_model, False, 1, {("attn.jnp", 3)}),
     (dense_model, True, 0, {("attn.jnp", 3)}),
@@ -307,18 +336,17 @@ def test_a_decode_says_the_form_its_attention_runs_in(
     """One ``HOST_PHASE`` record of no length a form, ``attn.live-kernel``
     or ``attn.jnp`` with the layers it covers, each time a decode
     program is traced; a prompt forward writes none. The kernel's name
-    is said where ``attend_blocks`` (the engine's ``ENG_ATTEND``) says
-    it runs: shapes its tiling takes on one TPU, not the CPU these
-    tests run on (``chips`` 0), whatever was traced."""
+    is said where ``live_layers`` as lowered (the engine's
+    ``ENG_ATTEND``) says it runs: shapes its tiling takes on one TPU,
+    not the CPU these tests run on (``chips`` 0), whatever was
+    traced."""
     from tests.test_setup_records import _now
 
     if takes:
         as_on_a_chip(monkeypatch, "numpy")
     cfg, params = model()
-    prog = slot_program(cfg)
-    assert [d.platform for d in prog.devices] == ["cpu"]
-    if chips:
-        prog.devices = (_Chip(),) * chips
+    prog = slot_program(cfg, mesh=mesh_of_chips(chips) if chips else None)
+    assert [d.platform for d in prog.devices] == (["tpu"] * chips or ["cpu"])
     cache = jax.eval_shape(lambda: prog.init_cache(3, MAX_LEN))
     since = _now()
     jax.eval_shape(prog.ingest, params, cache, 0,
@@ -327,34 +355,52 @@ def test_a_decode_says_the_form_its_attention_runs_in(
     jax.eval_shape(prog.decode, params, cache, jnp.zeros((3,), jnp.int32),
                    jnp.ones((3,), bool))
     assert host_marks(since) == want
-    assert len(prog.attend_blocks(cache)) == sum(
+    assert len(prog.live_layers(cache, lowered=True)) == sum(
         n for form, n in want if form == "attn.live-kernel")
+    # the forwards go by the same answer less the platform
+    assert len(prog.live_layers(cache)) == (
+        0 if chips > 1 or not takes else 3 if model is dense_model else 2)
 
 
 def records(eng, event):
     return [r for r in eng.trace.peek().tolist() if r[1] == int(event)]
 
 
-def test_eng_attend_counts_the_blocks_fetched_from_the_slot_table():
+@pytest.mark.parametrize("model", ["told", "scan", "latent"])
+def test_eng_attend_counts_the_blocks_fetched_from_the_slot_table(
+        model, monkeypatch):
     """One record a dispatched decode, stamped like its ``ENG_DECODE``:
     busy lanes, their live positions, the (lane, block) pairs the
     kernel fetches over its layers (a cursor at 15 counts one block of
     16 and at 16 two; an idle lane one; a ring of 32 no more than two)
     and the pairs the caches have. A CPU runs the ``jax.numpy`` form
-    and writes none; here the engine is told of two layers of 48
-    positions and one ring of 32 as if a chip held them."""
-    eng = engine(dense_model(), bucket=40)
-    assert eng.program.attend_blocks(eng.cache) == []
+    and writes none. ``told``: the engine is told of two layers of 48
+    positions and one ring of 32 as if a chip held them. ``scan`` and
+    ``latent``: the table is ``live_layers``' own answer for a cache on
+    one TPU (three layers of 48 positions; two latent layers around a
+    full one), and a latent layer's ``ENG_SELECT`` blocks come from the
+    same count as a full layer's ``ENG_ATTEND``."""
+    make = latent_model if model == "latent" else dense_model
+    eng = engine(make(), bucket=40)
+    assert eng.program.live_layers(eng.cache, lowered=True) == {} == eng._live
     serve(eng, [[1] * 14], 5)
     assert records(eng, Ev.ENG_ATTEND) == []
-    eng = engine(dense_model(), bucket=40)
-    eng._attend_kept = np.array([[32], [48]])
-    eng._attend_block = np.array([[16], [16]])
-    eng._attend_layers = np.array([1, 2])
+    assert all(r[7] == 0 for r in records(eng, Ev.ENG_SELECT))
+    if model == "told":
+        eng = engine(make(), bucket=40)
+        eng._live = {("kv", 32, 16): 1, ("kv", 48, 16): 2}
+    else:
+        as_on_a_chip(monkeypatch, "numpy")
+        monkeypatch.setattr(slot_programs, "_placed_on",
+                            lambda mesh: (_Chip(),))
+        eng = engine(make(), bucket=40)
+        assert eng._live == {"scan": {("kv", 48, 16): 3}, "latent": {
+            ("kv", 48, 16): 1, ("latent", 48, 16): 2}}[model]
     lengths = (40, 14)
     serve(eng, [[1] * n for n in lengths], 5)
     attends = records(eng, Ev.ENG_ATTEND)
     decodes = {r[0] for r in records(eng, Ev.ENG_DECODE)}
+    selects = [r for r in records(eng, Ev.ENG_SELECT) if r[0] in decodes]
     # four decodes after each prefill's first token, two of three lanes
     assert len(attends) == 4 and {r[0] for r in attends} <= decodes
     for i, r in enumerate(attends):
@@ -362,5 +408,14 @@ def test_eng_attend_counts_the_blocks_fetched_from_the_slot_table():
         # cursors 40-43 lie in the third block (the ring's second);
         # 14, 15 | 16, 17; the idle lane's one block a layer
         short = (1, 1, 2, 2)[i]
-        assert r[3:8] == [2, sum(live), (2 + short + 1) + 2 * (3 + short + 1),
-                          3 * (2 + 2 * 3), 3]
+        full = 3 + short + 1
+        assert r[3:8] == [2, sum(live)] + {
+            "told": [(2 + short + 1) + 2 * full, 3 * (2 + 2 * 3), 3],
+            "scan": [3 * full, 3 * 3 * 3, 3],
+            "latent": [full, 3 * 3, 1]}[model]
+    if model == "latent":  # the busy lanes' blocks of one latent layer
+        assert [r[0] for r in selects] == [r[0] for r in attends]
+        assert [r[7] for r in selects] == [
+            r[5] - 1 for r in attends] == [4, 4, 5, 5]
+    else:
+        assert records(eng, Ev.ENG_SELECT) == []

@@ -39,8 +39,9 @@ from benchmarks.run import overlay
 from pbs_tpu.models import mamba2
 from pbs_tpu.models import plan as P
 from pbs_tpu.models.moe import held_expert_ffn, mlp_ffn, shared_expert_ffn
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, _plan_forward, slot_program)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import _plan_forward, slot_program
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.models.transformer import TransformerConfig
 from pbs_tpu.serve import ShardedServeBackend
 from pbs_tpu.serve.partition import (

@@ -35,8 +35,9 @@ from benchmarks.harness.spec import ROOT, Spec
 from benchmarks.run import overlay
 from pbs_tpu.models import plan as P
 from pbs_tpu.models.mamba import MAMBA_CHUNK, mamba_scan
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, _grouped_attention, slot_program)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import _grouped_attention, slot_program
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.serve import ShardedServeBackend
 from pbs_tpu.serve.partition import (
     PARTITION_RULES, iter_leaf_paths, match_partition_rules)

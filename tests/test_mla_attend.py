@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from pbs_tpu.models.mla import attend_rows, top_mask
+from pbs_tpu.ops.kv_attend import kv_attend
 from pbs_tpu.ops.mla_attend import attend_block, mla_attend, mla_attend_tiles
 
 H, R, E, T, TOPK = 8, 32, 16, 64, 12
@@ -165,3 +166,72 @@ def test_a_cache_of_no_whole_blocks_is_refused():
     with pytest.raises(ValueError, match="whole blocks"):
         mla_attend(q_lat, q_r, ckv, kr, index > 0, jnp.zeros(1, jnp.int32),
                    scale=SCALE, block=24, interpret=True)
+
+
+class _Specs(Exception):
+    """What a kernel asked ``pallas_call`` for."""
+
+
+def _asked(call, monkeypatch):
+    """The grid spec and the prefetched scalars of the ``pallas_call``
+    that ``call()`` makes (which is not run)."""
+    from jax.experimental import pallas as pl
+
+    def caught(kernel, *, grid_spec, **kw):
+        def operands(*args):
+            raise _Specs(grid_spec, args[:grid_spec.num_scalar_prefetch])
+        return operands
+
+    monkeypatch.setattr(pl, "pallas_call", caught)
+    with pytest.raises(_Specs) as asked:
+        call()
+    return asked.value.args
+
+
+@pytest.mark.parametrize("kernel", ["kv_attend", "mla_attend"])
+def test_a_dead_step_names_the_next_lanes_first_block(kernel, monkeypatch):
+    """The index map of every streamed operand (the ones whose block
+    follows the grid step while a lane is live), evaluated on cursors:
+    up to the cursor's block a step names its own lane and block; past
+    it the NEXT lane's first block, so that the pipeline, which looks
+    one step ahead, fetches it under this lane's last live step; the
+    last lane's dead steps stay on its own last block. One map, in
+    ``ops/live_attend.py``, under both kernels."""
+    tk, row_pos = 16, (20, 0, 63, 37)           # the cursors' blocks: 1 0 3 2
+    if kernel == "kv_attend":
+        q, k, v = (jnp.zeros(s, jnp.float32) for s in (
+            (4, 4, 16), (3, 4, T, 2, 16), (3, 4, T, 2, 16)))
+        call = functools.partial(
+            kv_attend, q, k, v, jnp.asarray(row_pos), jnp.int32(2),
+            block=tk, interpret=True)
+    else:
+        q_lat, q_r, ckv, kr, index = rows(4)
+        call = functools.partial(
+            mla_attend, q_lat, q_r, ckv, kr, index > 0, jnp.asarray(row_pos),
+            scale=SCALE, block=tk, interpret=True)
+    spec, scalars = _asked(call, monkeypatch)
+    scalars = [np.asarray(x) for x in scalars]
+    lanes, blocks = spec.grid
+    assert (lanes, blocks) == (4, T // tk)
+    assert scalars[0].tolist() == [1, 0, 3, 2]  # the block each cursor is in
+
+    def named(b, j):
+        return [tuple(int(i) for i in s.index_map(
+                    np.int32(b), np.int32(j), *scalars))
+                for s in spec.in_specs]
+
+    # the streamed operands: the ones a live step moves along
+    streamed = [i for i, (here, there) in enumerate(zip(
+        named(2, 0), named(2, 1))) if here != there]
+    assert len(streamed) == (2 if kernel == "kv_attend" else 3)
+    for i in streamed:
+        where = {(b, j): named(b, j)[i]
+                 for b in range(lanes) for j in range(blocks)}
+        for b, last in enumerate(scalars[0]):
+            for j in range(last + 1, blocks):   # the lane's dead steps
+                assert where[b, j] == (where[b + 1, 0] if b + 1 < lanes
+                                       else where[b, last]), (i, b, j)
+        # and the live steps name lanes and blocks of their own
+        live = [where[b, j] for b, last in enumerate(scalars[0])
+                for j in range(last + 1)]
+        assert len(set(live)) == len(live)

@@ -38,8 +38,9 @@ from pbs_tpu.models import mla
 from pbs_tpu.models import moe
 from pbs_tpu.models import plan as P
 from pbs_tpu.models.moe import held_expert_ffn, shared_expert_ffn
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, slot_program)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import slot_program
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.obs.trace import Ev
 from pbs_tpu.serve import ShardedServeBackend
 from pbs_tpu.serve.partition import (
@@ -296,7 +297,7 @@ def test_top_mask_is_the_k_largest_with_ties_in(k):
 def test_the_rotary_turns_the_pairs_it_is_told_to(interleave):
     """Adjacent pairs against the reference's own ``turn``; the
     half-split form against the engine's rotary for the other kinds."""
-    from pbs_tpu.models.serving import _rope_leading
+    from pbs_tpu.models.slot_programs import _rope_leading
 
     S, rot = 9, 4
     x = jax.random.normal(jax.random.PRNGKey(1), (1, S, 3, 10), jnp.float32)
@@ -352,7 +353,7 @@ def test_an_idle_lane_attends_its_first_row_alone(monkeypatch):
     ap = params["blocks"][P.block_name(0)]["attn"]
     seen = {}
 
-    def spy(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale):
+    def spy(q_lat, q_r, ckv, kr, chosen, row_pos, live, *, scale):
         seen.update(chosen=np.asarray(chosen), at=np.asarray(row_pos))
         return mla.attend_rows(q_lat, q_r, ckv, kr, chosen, scale=scale)
 
@@ -457,8 +458,9 @@ def test_eng_select_counts_live_and_chosen_positions_from_the_slot_table(
     as if the kernel ran, a lane whose cursor is 15 counts one block
     and at 16 two), a prefill's is 0."""
     eng = engine(2)
-    assert eng._select_block == 0
-    eng._select_block = block
+    assert eng._live == {}
+    if block:
+        eng._live = {("latent", MAX_LEN, block): N_LAYERS}
     lengths = (40, 14)
     serve(eng, prompts_of(lengths), 5)
     selects = records(eng, Ev.ENG_SELECT)

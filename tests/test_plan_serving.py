@@ -30,9 +30,11 @@ from benchmarks.run import overlay
 from pbs_tpu.gateway import Gateway, TenantQuota
 from pbs_tpu.models import plan as P
 from pbs_tpu.models.moe import held_expert_ffn, shared_expert_ffn
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, _rope_leading, _ScanProgram,
-    _slot_forward, ingest_slot_prompt, init_slot_cache, slot_program)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import (
+    _rope_leading, _ScanProgram, _slot_forward, ingest_slot_prompt,
+    init_slot_cache, slot_program)
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.models.transformer import TransformerConfig, init_params
 from pbs_tpu.obs.trace import Ev
 from pbs_tpu.serve import ShardedServeBackend

@@ -1,5 +1,5 @@
 """The prefill ladder: a prompt is padded to the smaller of two
-compiled lengths that holds it (``serving.prefill_rungs``: the bucket
+compiled lengths that holds it (``slot_programs.prefill_rungs``: the bucket
 and its half), not always to ``prompt_bucket``.
 
 What has to hold: the ladder follows from the bucket alone; the tokens
@@ -21,8 +21,9 @@ import pytest
 from benchmarks.harness.spec import Spec
 from benchmarks.reference import moe_mixed_attn as ref
 from benchmarks.run import overlay
-from pbs_tpu.models.serving import (
-    ContinuousBatcher, SpeculativeBatcher, prefill_rungs)
+from pbs_tpu.models.serving import ContinuousBatcher
+from pbs_tpu.models.slot_programs import prefill_rungs
+from pbs_tpu.models.spec_serving import SpeculativeBatcher
 from pbs_tpu.models.transformer import TransformerConfig, init_params
 from pbs_tpu.obs.trace import Ev
 

@@ -282,7 +282,8 @@ def _engine_of(kind, form, through):
     for the stacked trees; a planned stack serves on one device."""
     from pbs_tpu.models import MoEConfig
     from pbs_tpu.models.moe import init_moe_params, moe_slot_mlp
-    from pbs_tpu.models.serving import ContinuousBatcher, slot_program
+    from pbs_tpu.models.serving import ContinuousBatcher
+    from pbs_tpu.models.slot_programs import slot_program
 
     kw = dict(n_slots=2, prompt_bucket=8, max_len=32)
     if kind == "planned":
@@ -338,15 +339,12 @@ def test_a_placed_tree_reaches_the_engine_untouched(kind, form, through):
                    for _, leaf in went_in)
 
 
-def test_the_engine_names_no_parameter_table():
-    """models/serving.py places its cache and nothing else: it imports
-    nothing of ``pbs_tpu.serve`` and, of ``pbs_tpu.parallel``, only
-    the cache's own sharding."""
+def _imports_of(module) -> list:
+    """Every ``(module, name)`` a module's source imports, at its top
+    or inside a function."""
     import ast
 
-    import pbs_tpu.models.serving as serving
-
-    with open(serving.__file__) as f:
+    with open(module.__file__) as f:
         tree = ast.parse(f.read())
     seen = []
     for node in ast.walk(tree):
@@ -354,12 +352,55 @@ def test_the_engine_names_no_parameter_table():
             seen += [(a.name, None) for a in node.names]
         elif isinstance(node, ast.ImportFrom) and node.module:
             seen += [(node.module, a.name) for a in node.names]
-    layered = [(m, n) for m, n in seen
-               if m.startswith(("pbs_tpu.serve", "pbs_tpu.parallel"))]
-    assert layered == [("pbs_tpu.parallel.sharding",
-                        "slot_cache_kv_sharding")]
-    for form in (serving._ScanProgram, serving._PlannedProgram):
+    return seen
+
+
+def test_the_engine_names_no_parameter_table():
+    """The engine places nothing itself and its programs
+    (models/slot_programs.py) their cache and nothing else: neither
+    imports anything of ``pbs_tpu.serve``, and of ``pbs_tpu.parallel``
+    the programs import the cache's own sharding alone."""
+    import pbs_tpu.models.serving as serving
+    import pbs_tpu.models.slot_programs as slot_programs
+
+    for module, wants in ((serving, []), (slot_programs, [
+            ("pbs_tpu.parallel.sharding", "slot_cache_kv_sharding")])):
+        assert [(m, n) for m, n in _imports_of(module) if m.startswith(
+            ("pbs_tpu.serve", "pbs_tpu.parallel"))] == wants
+    for form in (slot_programs._ScanProgram, slot_programs._PlannedProgram):
         assert not hasattr(form, "place") and callable(form.place_cache)
+
+
+def test_the_slot_programs_know_no_engine():
+    """The arrow points one way: ``models/slot_programs.py`` imports
+    nothing of the engine, the serve layer or the gateway, and loading
+    it (in a fresh interpreter) loads none of them; the names the
+    benchmark imports from ``models/serving.py`` resolve there."""
+    import os
+    import subprocess
+    import sys
+
+    import pbs_tpu.models.serving as serving
+    import pbs_tpu.models.slot_programs as slot_programs
+
+    above = ("pbs_tpu.models.serving", "pbs_tpu.models.spec_serving",
+             "pbs_tpu.serve", "pbs_tpu.gateway")
+    assert [m for m, _ in _imports_of(slot_programs)
+            if m.startswith(above)] == []
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import pbs_tpu.models.slot_programs; "
+         f"print([m for m in sys.modules if m.startswith({above!r})])"],
+        capture_output=True, text=True, timeout=120, check=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.dirname(
+            slot_programs.__file__))))
+    assert out.stdout.strip() == "[]", out.stdout
+    for name in ("ContinuousBatcher", "slot_program", "prefill_rungs",
+                 "_slot_forward", "ingest_slot_prompt", "init_slot_cache"):
+        assert callable(getattr(serving, name)), name
+    for name in ("slot_program", "prefill_rungs", "_slot_forward",
+                 "ingest_slot_prompt", "init_slot_cache"):
+        assert getattr(serving, name) is getattr(slot_programs, name)
 
 
 # -- shard / gather ----------------------------------------------------------

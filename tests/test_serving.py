@@ -496,7 +496,7 @@ def test_layer_scan_equals_a_plain_loop_over_unstacked_weights(S, leaf, seam):
     heads on two kv heads, every row at its own cursor, the cache full
     of another tenant's rows beforehand."""
     from pbs_tpu.models.quant import quantize_weights
-    from pbs_tpu.models.serving import _slot_forward
+    from pbs_tpu.models.slot_programs import _slot_forward
 
     cfg = TransformerConfig(**GQA)
     params = init_params(cfg, jax.random.PRNGKey(3))

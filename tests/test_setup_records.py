@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from pbs_tpu.models import serving
+from pbs_tpu.models.slot_programs import slot_program
 from pbs_tpu.models.transformer import TransformerConfig
 from pbs_tpu.obs import trace as T
 from pbs_tpu.obs.trace import Ev
@@ -59,11 +60,12 @@ jax.devices()
 setup_compilation_cache()
 setup_compilation_cache()
 from pbs_tpu.models import serving
+from pbs_tpu.models.slot_programs import slot_program
 from pbs_tpu.models.transformer import TransformerConfig
 from pbs_tpu.obs import trace as T
 cfg = TransformerConfig(vocab=64, d_model=16, n_heads=2, n_kv_heads=1,
                         n_layers=1, d_ff=32, max_seq=32)
-params = serving.slot_program(cfg).init_params(jax.random.PRNGKey(0))
+params = slot_program(cfg).init_params(jax.random.PRNGKey(0))
 serving.ContinuousBatcher(cfg, params, n_slots=2, prompt_bucket=8,
                           max_len=24)
 from jax._src import monitoring
@@ -199,7 +201,7 @@ CFG = TransformerConfig(vocab=96, d_model=16, n_heads=2, n_kv_heads=1,
 
 @pytest.fixture(scope="module")
 def params():
-    return serving.slot_program(CFG).init_params(jax.random.PRNGKey(3))
+    return slot_program(CFG).init_params(jax.random.PRNGKey(3))
 
 
 def _builds(since: int) -> list[tuple[str, int]]:

@@ -18,7 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 from pbs_tpu.models import TransformerConfig, init_params
 from pbs_tpu.models import plan as P
 from pbs_tpu.models.quant import quantize_weights
-from pbs_tpu.models.serving import (
+from pbs_tpu.models.slot_programs import (
     _slot_forward, ingest_slot_prompt, init_slot_cache, slot_program)
 from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
 from pbs_tpu.serve.partition import make_serve_mesh, rule_shardings

@@ -792,6 +792,8 @@ def _copy_only(k, v, row_pos, layer, tk):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from pbs_tpu.ops.live_attend import live_block
+
     L, B, T, nkv, hd = k.shape
     limit = jnp.clip(row_pos, 0, T - 1)
 
@@ -808,9 +810,8 @@ def _copy_only(k, v, row_pos, layer, tk):
                          + v_ref[0, 0, :8].astype(jnp.float32))
 
     def cache(b, j, last, layer):
-        live, more = j <= last[b], b + 1 < B
-        return (layer[0], jnp.where(live | ~more, b, b + 1),
-                jnp.where(live, j, jnp.where(more, 0, last[b])), 0)
+        lane, block = live_block(b, j, last, B)
+        return (layer[0], lane, block, 0)
 
     return pl.pallas_call(
         body,
@@ -841,7 +842,7 @@ def test_kv_attend_compiled_at_the_cells():
     import json
     import os
 
-    from pbs_tpu.models.serving import _grouped_attention
+    from pbs_tpu.models.slot_programs import _grouped_attention
     from pbs_tpu.ops.kv_attend import attend_block, kv_attend
 
     bf16, hd, reps = jnp.bfloat16, 128, 20
