@@ -279,7 +279,8 @@ def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
     ``kind.scoring``: a ``softmax`` over the router's logits, or the
     ``sigmoid`` of each, where ``bias`` (one an expert) is added for
     the choice of the k largest and left out of their weights. The
-    chosen scores are renormalised to sum to one and scaled by
+    chosen scores are renormalised to sum to one (the sum plus
+    ``kind.renorm_eps`` where the kind has one) and scaled by
     ``kind.routed_scale``. No capacity: every token keeps every choice.
     ``kind`` is a ``models.plan.MlpKind``."""
     logits = h.astype(jnp.float32) @ router.astype(jnp.float32)
@@ -293,8 +294,10 @@ def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
                                    kind.top_k)
     else:
         raise ValueError(f"unknown router scoring {kind.scoring!r}")
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    return topv * kind.routed_scale, topi
+    total = jnp.sum(topv, axis=-1, keepdims=True)
+    if kind.renorm_eps:
+        total = total + kind.renorm_eps
+    return topv / total * kind.routed_scale, topi
 
 
 #: Rows of a prompt the held experts take at a time: the sorted buffer

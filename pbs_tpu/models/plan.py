@@ -15,7 +15,8 @@ of the stack before each use: 768 MB a routed-expert weight, every
 tick)::
 
     embed, final_norm[, head]           (no head: the embedding is tied)
-    blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]}        softmax
+    blocks/<NN>/attn/{attn_norm, wq, wk, wv, wo[, wg]
+                      [, q_norm, k_norm]}                     softmax
     blocks/<NN>/attn/{attn_norm, wq, wk, wv, cq, ck, cv, wa1, wa2,
                       a_log, dt_bias, wb, wg1, wg2, o_norm, wo}  delta rule
     blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, w_x, dt_norm,
@@ -26,6 +27,7 @@ tick)::
                       wi_w}                          latent, selecting
     blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, dt_bias, a_log,
                       d_skip, g_norm, w_out}          matrix state space
+    blocks/<NN>/attn/{attn_norm, w_in, conv_w, w_out}   gated convolution
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
     blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
                      [, ws1, ws3, ws2]}
@@ -81,6 +83,10 @@ class AttnKind:
     # callers construct with; ``gate`` reads them as one word).
     head_gate: bool = False    # one scalar a query head
     wide_gate: bool = False    # one scalar a channel of every head
+    #: queries and keys are RMS-normed a head before the rotary, one
+    #: weight of ``head_dim`` shared by every head (``q_norm``,
+    #: ``k_norm``)
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.head_gate and self.wide_gate:
@@ -175,6 +181,21 @@ class Mamba2Kind:
 
 
 @dataclasses.dataclass(frozen=True)
+class ConvKind:
+    """A gated short convolution and nothing else (LFM2's mixer, as
+    ``Lfm2MoeShortConv`` writes it): no keys and values, no state
+    matrix, no decay and no step size. The in-projection gives three
+    rows of ``channels``, ``B | C | u``; ``g = B * u`` goes through a
+    causal depthwise filter of ``conv`` taps and ``C`` gates what comes
+    out (``models/shortconv.py``). A request keeps the last ``conv -
+    1`` rows of ``g``, whatever its length."""
+
+    name: str
+    channels: int
+    conv: int = 3
+
+
+@dataclasses.dataclass(frozen=True)
 class MlaKind:
     """Latent attention that selects its positions (multi-head latent
     attention under a learned indexer: DeepSeek sparse attention). No
@@ -236,6 +257,9 @@ class MlpKind:
     #: experts and not in weighting them
     scoring: str = "softmax"
     form: str = "silu"
+    #: added to the sum the chosen experts' scores are renormalised by
+    #: (``lfm2_moe`` divides by ``sum + 1e-6``)
+    renorm_eps: float = 0.0
 
     def __post_init__(self):
         if self.form not in MLP_FORMS:
@@ -245,7 +269,8 @@ class MlpKind:
 
 @dataclasses.dataclass(frozen=True)
 class LayerPlan:
-    attn: tuple[AttnKind | KdaKind | MambaKind | Mamba2Kind | MlaKind, ...]
+    attn: tuple[AttnKind | KdaKind | MambaKind | Mamba2Kind | ConvKind
+                | MlaKind, ...]
     mlp: tuple[MlpKind, ...]
     #: per layer (attn i, mlp i); None for the half a block does not
     #: have (a mixer alone, or an MLP alone: one norm, one residual add)
@@ -263,11 +288,11 @@ class LayerPlan:
 
     @property
     def recurrent(self) -> bool:
-        """Some layer (delta-rule or state-space) keeps a state that
-        every token is folded into, not keys and values a cursor can
-        mask."""
+        """Some layer (delta-rule, state-space or gated convolution)
+        keeps a state or a tail that every token is folded into, not
+        keys and values a cursor can mask."""
         return any(a is not None and isinstance(
-            self.attn[a], (KdaKind, MambaKind, Mamba2Kind))
+            self.attn[a], (KdaKind, MambaKind, Mamba2Kind, ConvKind))
             for a, _ in self.layers)
 
     @property
@@ -387,6 +412,11 @@ def _attn_shapes(a, d: int, hd: int, nkv: int) -> dict:
                 "conv_w": (a.conv, a.d_conv), "conv_b": (a.d_conv,),
                 "dt_bias": (H,), "a_log": (H,), "d_skip": (H,),
                 "g_norm": (c,), "w_out": (c, d)}
+    if isinstance(a, ConvKind):
+        # w_in's columns: B | C | u; no bias anywhere
+        c = a.channels
+        return {"attn_norm": (d,), "w_in": (d, 3 * c),
+                "conv_w": (a.conv, c), "w_out": (c, d)}
     if isinstance(a, MlaKind):
         H, qk = a.n_heads, a.nope_dim + a.rope_dim
         return {"attn_norm": (d,), "wq_a": (d, a.q_rank),
@@ -405,6 +435,8 @@ def _attn_shapes(a, d: int, hd: int, nkv: int) -> dict:
     if a.gate:
         attn["wg"] = (d, a.n_heads * (
             hd if a.gate == "elementwise" else 1))
+    if a.qk_norm:
+        attn["q_norm"], attn["k_norm"] = (hd,), (hd,)
     return attn
 
 
@@ -437,9 +469,10 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
     published layer's own start), the router's bias a small normal. A
     state-space layer starts as Mamba does: ``a_log`` the log of 1 ..
     ``d_state`` a channel, the same ``dt_bias``, ``d_skip`` one, the
-    convolution's filter and bias uniform in +-1/2; one whose state is
-    a matrix a head as Mamba-2 does: ``a_log`` a head the log of
-    uniform(1, 16), the rest alike. A selecting layer's
+    convolution's filter uniform in +-1/sqrt(taps) and its bias in
+    +-1/2; one whose state is a matrix a head as Mamba-2 does:
+    ``a_log`` a head the log of uniform(1, 16), the rest alike; a gated
+    convolution's filter alike (it has no bias). A selecting layer's
     ``ik_bias`` (its indexer key's LayerNorm) a tenth of a normal."""
     shapes = plan_shapes(cfg)
     flat, treedef = jax.tree.flatten(
@@ -465,8 +498,10 @@ def init_plan_params(cfg, key: jax.Array) -> dict:
             leaves.append(jnp.ones(shape, jnp.float32))
             continue
         if name in ("conv_w", "conv_b"):
+            # a Conv1d's start at its fan-in, the taps: +-1/2 at four
+            bound = 1.0 / np.sqrt(shape[0]) if name == "conv_w" else 0.5
             leaves.append(jax.random.uniform(k, shape, jnp.float32,
-                                             -0.5, 0.5))
+                                             -bound, bound))
             continue
         if name == "dt_bias":
             dt = jnp.exp(jax.random.uniform(

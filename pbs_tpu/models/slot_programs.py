@@ -43,9 +43,10 @@ from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
 from pbs_tpu.models.mamba2 import mamba2_decode, mamba2_ingest
 from pbs_tpu.models.mla import mla_decode, mla_ingest
 from pbs_tpu.models.plan import (
-    AttnKind, KdaKind, Mamba2Kind, MambaKind, MlaKind, block_name,
+    AttnKind, ConvKind, KdaKind, Mamba2Kind, MambaKind, MlaKind, block_name,
     init_plan_params, plan_of, rope_table, uniform_plan)
 from pbs_tpu.models.quant import embed_rows, wload
+from pbs_tpu.models.shortconv import conv_decode, conv_ingest
 from pbs_tpu.models.transformer import (
     TransformerConfig,
     init_params,
@@ -109,16 +110,18 @@ def _write_rows(rows, new, at, layer=None):
     return jax.lax.fori_loop(0, B, one, rows)
 
 
-def _grouped_attention(q, k, v, mask, dt):
+def _grouped_attention(q, k, v, mask, dt, width=None):
     """q (B, S, H, hd) against k, v (B, K, nkv, hd); query head g reads
     kv head g // (H / nkv); ``mask`` (B or 1, S, K) says what a query
-    sees. Softmax in float32. Returns (B, S, H, hd)."""
+    sees. Softmax in float32. Returns (B, S, H, hd). ``width``: the
+    head width the scores are scaled by where it is not ``hd`` (the
+    rows of a packed cache hold several heads: :func:`kv_pack`)."""
     B, S, H, hd = q.shape
     nkv = k.shape[2]
     qg = q.reshape(B, S, nkv, H // nkv, hd).transpose(0, 2, 3, 1, 4)
     kt = k.transpose(0, 2, 1, 3)  # (B, nkv, K, hd)
     vt = v.transpose(0, 2, 1, 3)
-    scores = jnp.einsum("bngqh,bnkh->bngqk", qg, kt) / np.sqrt(hd)
+    scores = jnp.einsum("bngqh,bnkh->bngqk", qg, kt) / np.sqrt(width or hd)
     mask = jnp.broadcast_to(mask[:, None, None, :, :], scores.shape)
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dt)
@@ -128,10 +131,10 @@ def _grouped_attention(q, k, v, mask, dt):
 
 # One trace and one lowered function a cache shape, whatever the layers
 # (``models/mamba.py::_kernel_scan`` says why).
-_kernel_attend = jax.jit(kv_attend)
+_kernel_attend = jax.jit(kv_attend, static_argnames=("scale",))
 
 
-def _cursor_attention(q, k, v, at, layer, dt):
+def _cursor_attention(q, k, v, at, layer, dt, width=None):
     """A decode tick's attention, one query position a lane: q (B, 1,
     H, hd) over the positions ``<= at[b]`` of the layer's k and v (B,
     K, nkv, hd), all of them where the cursor is past the last (a ring
@@ -141,8 +144,10 @@ def _cursor_attention(q, k, v, at, layer, dt):
     lane's live blocks (``ops/kv_attend.py``; its tiling has to take
     the shapes, :func:`live_layers`), anywhere else
     :func:`_grouped_attention` over the whole cache under the mask.
-    Returns (B, 1, H, hd)."""
+    ``width`` as :func:`_grouped_attention` takes it. Returns (B, 1, H,
+    hd)."""
     index = () if layer is None else (layer,)
+    scale = {} if width is None else {"scale": 1.0 / math.sqrt(width)}
 
     def numpy_way(q, k, v, at, *index):
         if index:
@@ -150,13 +155,53 @@ def _cursor_attention(q, k, v, at, layer, dt):
                                                  keepdims=False)
                     for t in (k, v))
         seen = jnp.arange(k.shape[1])[None, :] <= at[:, None]
-        return _grouped_attention(q, k, v, seen[:, None, :], dt)
+        return _grouped_attention(q, k, v, seen[:, None, :], dt, width)
 
     return jax.lax.platform_dependent(
         q, k, v, at, *index,
         tpu=lambda q, k, v, at, *index: _kernel_attend(
-            q[:, 0], k, v, at, *index)[:, None],
+            q[:, 0], k, v, at, *index, **scale)[:, None],
         default=numpy_way)
+
+
+def kv_pack(nkv: int, hd: int) -> int:
+    """KV heads a row of a planned softmax layer's cache holds side by
+    side. XLA:TPU lays the last axis of an array over rows of 128 lanes:
+    a cache ``(slots, T, nkv, 64)`` would pay a row of 128 for every
+    head of 64, twice what it holds. So where a head is narrower than a
+    row and whole heads fill it, the cache keeps ``128 / hd`` KV heads
+    to a row, ``(slots, T, nkv / pack, pack * hd)``: a position costs
+    what it holds, and a row is what ``ops/kv_attend.py`` streams. 1:
+    a head a row, the cache as it always lay."""
+    per = 128 // hd if hd < 128 and 128 % hd == 0 else 1
+    return per if nkv % per == 0 else 1
+
+
+def _own_part(H: int, nkv: int, pack: int) -> np.ndarray:
+    """(H, pack) bool: the part of a packed row query head h's KV head
+    lies in (KV head ``h // (H / nkv)``, the ``% pack``-th of its
+    row)."""
+    part = (np.arange(H) // (H // nkv)) % pack
+    return part[:, None] == np.arange(pack)[None, :]
+
+
+def _to_packed(q: jax.Array, nkv: int, pack: int) -> jax.Array:
+    """q (B, S, H, hd) as rows of ``pack * hd``, each head in the part
+    its KV head has of a packed row and zeros in the others: its dot
+    product with a packed row of keys is its score with its own KV
+    head, to the bit (the other parts add exact zeros)."""
+    B, S, H, hd = q.shape
+    own = jnp.asarray(_own_part(H, nkv, pack), q.dtype)
+    return (q[..., None, :] * own[:, :, None]).reshape(B, S, H, pack * hd)
+
+
+def _from_packed(o: jax.Array, nkv: int, pack: int) -> jax.Array:
+    """The part of each head's (B, S, H, pack * hd) output that its own
+    KV head's values gave: (B, S, H, hd)."""
+    B, S, H, w = o.shape
+    own = jnp.asarray(_own_part(H, nkv, pack), o.dtype)
+    return jnp.sum(o.reshape(B, S, H, pack, w // pack) * own[:, :, None],
+                   axis=-2)
 
 
 def _placed_on(mesh) -> tuple:
@@ -388,9 +433,13 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     key every head shares ``kr``, ``(slots, max_len, rope_dim)``, and
     its indexer's key ``ik``, ``(slots, max_len, index_dim)``. One
     cursor a slot serves all: which ring entries and which latent rows
-    are live follows from it alone, and a state needs none. ``state``,
-    ``ssm``, ``conv``, ``ckv``, ``kr`` and ``ik`` are there only where
-    some layer has them; a block without a mixer keeps nothing."""
+    are live follows from it alone, and a state needs none. A gated
+    convolution keeps its ``conv`` tail alone, ``(slots, kernel - 1,
+    channels)``. ``state``, ``ssm``, ``conv``, ``ckv``, ``kr`` and
+    ``ik`` are there only where some layer has them; a block without a
+    mixer keeps nothing. Heads narrower than a row of 128 lanes lie
+    several to a row (:func:`kv_pack`): ``(slots, max_len or W, nkv /
+    pack, pack * hd)``."""
     plan = plan_of(cfg)
     out: dict = {"k": {}, "v": {},
                  "pos": jnp.zeros((n_slots,), jnp.int32)}
@@ -417,26 +466,35 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
             out.setdefault("conv", {})[name] = jnp.zeros(
                 (n_slots, a.conv - 1, a.d_conv), cfg.dtype)
             continue
+        if isinstance(a, ConvKind):
+            out.setdefault("conv", {})[name] = jnp.zeros(
+                (n_slots, a.conv - 1, a.channels), cfg.dtype)
+            continue
         if isinstance(a, MlaKind):
             for key, width in (("ckv", a.kv_rank), ("kr", a.rope_dim),
                                ("ik", a.index_dim)):
                 out.setdefault(key, {})[name] = jnp.zeros(
                     (n_slots, max_len, width), cfg.dtype)
             continue
+        pack = kv_pack(cfg.n_kv_heads, cfg.head_dim)
         for kv in ("k", "v"):
             out[kv][name] = jnp.zeros(
                 (n_slots, min(a.window, max_len) if a.window else max_len,
-                 cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+                 cfg.n_kv_heads // pack, pack * cfg.head_dim), cfg.dtype)
     return out
 
 
-#: A layer kind that keeps a recurrent state, not positions: the scope
-#: its ops carry, the cache entry that holds the state (its tail is
-#: ``conv``), its decode step and its prompt ingestion.
+#: A layer kind that every token is folded into, not positions a
+#: cursor can mask: the scope its ops carry, the cache entries that
+#: hold what it keeps a slot (a state and the tail of its short
+#: convolution, or the tail alone), its decode step and its prompt
+#: ingestion, which take and return the entries in that order.
 _RECURRENT = {
-    KdaKind: ("attn.kda", "state", kda_decode, kda_ingest),
-    MambaKind: ("attn.mamba", "ssm", mamba_decode, mamba_ingest),
-    Mamba2Kind: ("attn.mamba2", "ssm", mamba2_decode, mamba2_ingest)}
+    KdaKind: ("attn.kda", ("state", "conv"), kda_decode, kda_ingest),
+    MambaKind: ("attn.mamba", ("ssm", "conv"), mamba_decode, mamba_ingest),
+    Mamba2Kind: ("attn.mamba2", ("ssm", "conv"), mamba2_decode,
+                 mamba2_ingest),
+    ConvKind: ("attn.conv", ("conv",), conv_decode, conv_ingest)}
 #: A layer kind that keeps rows of its own a position, not keys and
 #: values a head: the scope its ops carry, the cache entries that hold
 #: the rows, its decode step and its prompt ingestion.
@@ -513,20 +571,20 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             ap = block["attn"]
             h = rms_norm(x, ap["attn_norm"], cfg.norm_eps)
             if type(a) in _RECURRENT:
-                scope, key, step, ingest = _RECURRENT[type(a)]
+                scope, keys, step, ingest = _RECURRENT[type(a)]
                 with jax.named_scope(scope):
                     if decode:
-                        y, new[key][name], new["conv"][name] = step(
-                            a, ap, h, new[key][name], new["conv"][name],
+                        y, *kept = step(
+                            a, ap, h, *(new[key][name] for key in keys),
                             valid[:, 0], cfg.norm_eps, dt)
                     else:
-                        y, state, tail = ingest(a, ap, h, valid,
-                                                cfg.norm_eps, dt)
-                        new[key][name] = jax.lax.dynamic_update_slice(
-                            new[key][name], state,
-                            (slot,) + (0,) * (state.ndim - 1))
-                        new["conv"][name] = jax.lax.dynamic_update_slice(
-                            new["conv"][name], tail, (slot, 0, 0))
+                        y, *fresh = ingest(a, ap, h, valid, cfg.norm_eps, dt)
+                        kept = [jax.lax.dynamic_update_slice(
+                            new[key][name], entry,
+                            (slot,) + (0,) * (entry.ndim - 1))
+                            for key, entry in zip(keys, fresh)]
+                    for key, entry in zip(keys, kept):
+                        new[key][name] = entry
                 x = x + y
             elif type(a) in _LATENT:
                 x = x + _latent_layer(a, ap, h, new, name, row_pos, valid,
@@ -535,7 +593,7 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
             else:
                 x = x + _softmax_layer(
                     a, ap, h, ks, vs, name, row_pos, valid, abs_pos, tables,
-                    slot, nkv, hd, dt, layer in live)
+                    slot, nkv, hd, cfg.norm_eps, dt, layer in live)
         if m is None:
             continue
 
@@ -581,33 +639,49 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
 
 def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
                    row_pos, valid, abs_pos, tables: dict, slot, nkv: int,
-                   hd: int, dt, live: bool = False) -> jax.Array:
+                   hd: int, eps: float, dt, live: bool = False) -> jax.Array:
     """A full or window attention layer of the planned stack on its
     normed input h (B, S, d): writes the layer's new keys and values
     into ``ks[name]`` / ``vs[name]`` (replaced in the dicts) and
     returns what the layer adds to the stream. ``live``: a decode tick
-    whose attention goes through :func:`_cursor_attention`."""
+    whose attention goes through :func:`_cursor_attention`.
+
+    Where the cache lies packed (:func:`kv_pack`: the entries' last
+    axis holds ``pack`` heads), a tick's queries go against the rows as
+    they lie, each head zero but for the part its KV head has of a row
+    (:func:`_to_packed`: the same scores and, off its own part of the
+    output, the same values as a head at a time), so no view of the
+    cache a head is ever made; a prompt attends its own keys and values
+    a head, and they are written as the rows."""
     B, S, _ = h.shape
     H, decode = a.n_heads, slot is None
     q = (h @ wload(ap["wq"], dt)).reshape(B, S, H, hd)
     k = (h @ wload(ap["wk"], dt)).reshape(B, S, nkv, hd)
     v = (h @ wload(ap["wv"], dt)).reshape(B, S, nkv, hd)
+    if a.qk_norm:
+        q = rms_norm(q, ap["q_norm"], eps)
+        k = rms_norm(k, ap["k_norm"], eps)
     if a.rope is not None:
         cos, sin = (t[abs_pos] for t in tables[a.rope])
         q, k = _rope_leading(q, cos, sin), _rope_leading(k, cos, sin)
-    K = ks[name].shape[1]
+    K, rows = ks[name].shape[1], ks[name].shape[2:]
+    pack = rows[1] // hd
     with jax.named_scope("attn.window" if a.window else "attn.full"):
         if decode:
             at = row_pos % K if a.window else row_pos
-            ks[name] = _write_rows(ks[name], k, at)
-            vs[name] = _write_rows(vs[name], v, at)
+            ks[name] = _write_rows(ks[name], k.reshape((B, S) + rows), at)
+            vs[name] = _write_rows(vs[name], v.reshape((B, S) + rows), at)
+            # the scores' scale is the head's, whatever a row holds
+            width = hd if pack > 1 else None
+            if pack > 1:
+                q = _to_packed(q, nkv, pack)
             if live:
                 # an idle lane's cursor rests where its last request
                 # ended, and nothing up to there is its to read: it
                 # attends its first entry
                 attn = _cursor_attention(
                     q, ks[name], vs[name],
-                    jnp.where(valid[:, 0], row_pos, 0), None, dt)
+                    jnp.where(valid[:, 0], row_pos, 0), None, dt, width)
             else:
                 col = jnp.arange(K)[None, :]
                 # Ring entry j holds the largest p <= cursor with
@@ -615,7 +689,9 @@ def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
                 seen = (col <= row_pos[:, None]) | (
                     (row_pos[:, None] >= K) if a.window else False)
                 attn = _grouped_attention(q, ks[name], vs[name],
-                                          seen[:, None, :], dt)
+                                          seen[:, None, :], dt, width)
+            if pack > 1:
+                attn = _from_packed(attn, nkv, pack)
         else:
             i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
             seen = (j <= i) & ((i - j < a.window) if a.window else True)
@@ -629,9 +705,9 @@ def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
             else:
                 k, v = k[:, :K], v[:, :K]
             ks[name] = jax.lax.dynamic_update_slice(
-                ks[name], k, (slot, 0, 0, 0))
+                ks[name], k.reshape(k.shape[:2] + rows), (slot, 0, 0, 0))
             vs[name] = jax.lax.dynamic_update_slice(
-                vs[name], v, (slot, 0, 0, 0))
+                vs[name], v.reshape(v.shape[:2] + rows), (slot, 0, 0, 0))
         if a.gate == "per_head":
             attn = attn * jax.nn.sigmoid(h @ wload(ap["wg"], dt))[..., None]
         elif a.gate == "elementwise":
@@ -786,6 +862,12 @@ class _PlannedProgram(_LiveLayers):
             "that state (megabytes a layer) at the window's end (ROADMAP "
             "R23)"
             if any(isinstance(a, Mamba2Kind) for a in plan.attn) else
+            "a gated convolution keeps the last rows of its gated input "
+            "a slot (two of them at three taps), not positions, and every "
+            "token of a prompt is shifted through them: a prefix hit, a "
+            "preemption or a verify window would need that tail as it "
+            "stood at the window's end (ROADMAP R23)"
+            if any(isinstance(a, ConvKind) for a in plan.attn) else
             "a delta-rule or state-space layer keeps one recurrent state "
             "a slot, not positions: a prefix hit or a verify window would "
             "need a snapshot of that state at the window's end (ROADMAP "

@@ -214,7 +214,8 @@ class Ev(enum.IntEnum):
     #                     tokens routed, assignments to held experts, to
     #                     absent ones, held experts touched (the last
     #                     three summed over expert layers), largest load
-    #                     of one expert
+    #                     of one expert. A layer held whole (held = all
+    #                     the router scores) counts no absent assignment
     ENG_SELECT = 0x0A08  # one a prefill and one a decode of a program
     #                      with a layer that chooses its positions
     #                      (models/plan.MlaKind), from the host's slot
@@ -244,7 +245,13 @@ class Ev(enum.IntEnum):
     #                      have (lanes x kept / block a layer, summed),
     #                      the layers. None where the jax.numpy form
     #                      runs (a CPU, a mesh, a shape the kernel's
-    #                      tiling does not take)
+    #                      tiling does not take). Heads narrower than a
+    #                      row of 128 lanes lie several to a row
+    #                      (slot_programs.kv_pack): the blocks are of
+    #                      those rows. The recurrent kinds' scopes
+    #                      (attn.kda, attn.mamba, attn.mamba2, attn.conv)
+    #                      add no record: their bytes follow from the
+    #                      busy lanes
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

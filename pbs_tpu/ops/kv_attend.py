@@ -141,8 +141,8 @@ def _block(b, j, limit_ref, layer_ref, q_ref, heads_ref, k_ref, v_ref, *,
     return scores, mask, values
 
 
-def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
-              interpret: bool = False):
+def kv_attend(q, k, v, row_pos, layer=None, *, scale: float | None = None,
+              block: int | None = None, interpret: bool = False):
     """What every lane's query heads read off its cache: ``q`` (B, H,
     hd) in the compute dtype, the layer's ``k`` and ``v`` (B, T, nkv,
     hd) as they lie, or with ``layer`` (an int32 scalar) the caches of
@@ -151,10 +151,20 @@ def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
     are attended (a ring that has lapped is live whole). Returns (B, H,
     hd) in the cache's dtype, scaled by ``1 / sqrt(hd)`` as
     ``models/slot_programs.py::_grouped_attention`` scales, which is the same
-    function in ``jax.numpy``. Compiled, the shapes have to satisfy
+    function in ``jax.numpy``, or by ``scale`` where a row is not one
+    head (below). Compiled, the shapes have to satisfy
     :func:`kv_attend_tiles`; ``interpret`` (the tests) takes any whole
     blocks, and ``block`` (the tests) another block than
-    :func:`attend_block`'s."""
+    :func:`attend_block`'s.
+
+    **Heads narrower than a row.** A cache of 64-wide heads lies two
+    KV heads to a row of 128 lanes (``slot_programs.kv_pack``): to this
+    kernel it is a cache of half as many heads of 128. A query head
+    comes zero but for the half its KV head has of a row, so its
+    product with a row is its score with its own KV head and the other
+    half's values land in the half of its output the caller drops;
+    ``scale`` is then ``1 / sqrt(64)``, the head's and not the
+    row's."""
     if layer is None:
         k, v, layer = k[None], v[None], 0
     B, H, hd = q.shape
@@ -177,7 +187,8 @@ def kv_attend(q, k, v, row_pos, layer=None, *, block: int | None = None,
     cache = ((1, 1, rows, hd),
              lambda lane, block, limit, layer: (layer[0], lane, block, 0))
     out = live_attend(
-        functools.partial(_block, scale=1.0 / np.sqrt(hd), nkv=nkv, tk=tk),
+        functools.partial(_block, scale=scale or 1.0 / np.sqrt(hd), nkv=nkv,
+                          tk=tk),
         limit // tk, (limit, jnp.asarray(layer, jnp.int32).reshape(1)),
         [q], [jnp.asarray(heads)[:, None]],
         [(k.reshape(L, B, T * nkv, hd), *cache),

@@ -54,7 +54,7 @@ SpecEntry = Any
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
     (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm"
-     r"|q_norm|kv_norm|ik_norm|g_norm)$", ()),
+     r"|q_norm|k_norm|kv_norm|ik_norm|g_norm)$", ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -104,6 +104,11 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     # tensor axis these cut ``w_in``'s columns across its parts, not by
     # heads: a planned stack serves on one device (``place_cache``),
     # and the state's division by heads is not written (ROADMAP R23).
+    # A gated convolution (models/plan.ConvKind) has these three paths
+    # and no other: ``w_in`` (B | C | u side by side) and the filter by
+    # column, ``w_out`` by row, along the channels its tail would be
+    # divided on (read on a tensor axis ``w_in``'s cut falls across its
+    # three parts, as above: one device).
     (r"/(w_in|conv_w|w_dt)$", (None, -1)),
     (r"/(w_x|w_out)$", (-1, None)),
     (r"/(conv_b|d_skip)$", (-1,)),
@@ -191,6 +196,10 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/attn/w_out",
     # a matrix-state layer's own leaf (the rest are the paths above)
     "blocks/N/attn/g_norm",
+    # a gated convolution's leaves are three of the state-space
+    # layer's paths (w_in, conv_w, w_out); a softmax layer whose heads
+    # are normed has two of its own (q_norm is also a latent layer's)
+    "blocks/N/attn/k_norm",
     # a latent layer's own leaves (its attn_norm and wo are the paths
     # above)
     "blocks/N/attn/wq_a",

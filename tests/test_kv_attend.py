@@ -249,9 +249,10 @@ def as_on_a_chip(monkeypatch, form):
     monkeypatch.setattr(mla, "_kernel_attend", functools.partial(
         mla_attend, block=TK, interpret=True))
     if form == "kernel":
-        def attend(q, k, v, at, layer, dt):
+        def attend(q, k, v, at, layer, dt, width=None):
             calls.append((k.shape, layer is not None))
             return kv_attend(q[:, 0], k, v, at, layer, block=TK,
+                             scale=width and width ** -0.5,
                              interpret=True)[:, None]
 
         monkeypatch.setattr(slot_programs, "_cursor_attention", attend)

@@ -51,8 +51,9 @@ def planned_params():
     full layer with a dense MLP, a gated window layer with routed
     experts and a shared one, a delta-rule layer with experts behind a
     sigmoid router, a state-space layer, a latent layer that selects,
-    and two blocks of one half each: a matrix-state mixer alone, ungated
-    relu^2 experts alone."""
+    two blocks of one half each (a matrix-state mixer alone, ungated
+    relu^2 experts alone), a gated convolution, and a full layer whose
+    heads are normed."""
     from pbs_tpu.models import plan as P
 
     plan = P.LayerPlan(
@@ -62,7 +63,9 @@ def planned_params():
               P.MambaKind("mamba", 32, 4, 3, conv=4),
               P.MlaKind("mla", 2, 8, 8, 4, 4, 8, 2, 8, 4,
                         P.Rope(rotary_dim=4, interleave=True)),
-              P.Mamba2Kind("mamba2", 4, 8, 2, 8, conv=4)),
+              P.Mamba2Kind("mamba2", 4, 8, 2, 8, conv=4),
+              P.ConvKind("conv", 16, conv=3),
+              P.AttnKind("normed", 2, None, P.Rope(), qk_norm=True)),
         mlp=(P.MlpKind("dense", 32),
              P.MlpKind("experts", 8, n_experts=4, top_k=2, held=(0, 2),
                        shared_d_ff=8),
@@ -71,8 +74,8 @@ def planned_params():
              P.MlpKind("relu2", 8, n_experts=4, top_k=2, held=(0, 2),
                        shared_d_ff=16, scoring="sigmoid", form="relu2")),
         layers=((0, 0), (1, 1), (2, 2), (3, 0), (4, 0), (5, None),
-                (None, 3)))
-    cfg = TransformerConfig(**dict(TINY, n_layers=7, head_size=8,
+                (None, 3), (6, 0), (7, 0)))
+    cfg = TransformerConfig(**dict(TINY, n_layers=9, head_size=8,
                                    layer_plan=plan))
     return P.init_plan_params(cfg, jax.random.PRNGKey(0))
 

@@ -554,6 +554,59 @@ def test_the_matrix_state_cells_programs_compile_and_fit(topo, name):
                                  tuple(sorted((8, 8, rung, rung)))})
 
 
+# LFM2-24B-A2B's cell whole (benchmarks/configs/lfm2-24b-a2b.json:
+# layers 0-9 at the published widths, 8 gated convolutions, 2 attention
+# layers of 64-wide normed heads, 2 dense and 8 expert layers of all 64
+# experts; 256 slots of 3,072 positions, prompts at 512 and 1,024
+# rows), through the family's own sizing programs.
+CONV_PROGRAMS = ("decode L=10", "prefill L=10 rung=1024")
+
+
+@pytest.mark.parametrize("name", CONV_PROGRAMS)
+def test_the_convolution_cells_programs_compile_and_fit(topo, name):
+    """Each compiles for the chip, donates the cache whole (keys and
+    values of 64-wide heads **two to a row of 128 lanes**, ``(256,
+    3072, 4, 128)``: 2 KiB a position a layer, 3.0 GiB, where a head a
+    row would be padded to twice that and would not fit; a two-row
+    tail for each of the 8 convolutions: 16 MiB) and fits under the
+    chip's usable 15.75 GiB with its 9.81 GiB of weights. **The
+    decode's two attention layers are the one-pass kernel over each
+    lane's live blocks** (the packed rows as they lie: no instruction
+    writes a tensor of a layer's keys but the tick's new position, and
+    no float32 scores a lane and a position long exist), **and its
+    1,024 sorted rows on 64 held experts go through the Pallas grouped
+    product** (exactly ``KERNEL_ROWS_A_GROUP`` rows an expert): three
+    for each of the eight expert layers, no ``ragged-dot``, and beyond
+    its arguments the tick needs under 64 MiB. The prefill's grouped
+    products are the same kernel."""
+    c, progs = _cell_programs(topo, "lfm2-24b-a2b")
+    prog = next(p for p in progs if p["name"] == name)
+    compiled = prog["fn"].lower(*prog["args"]).compile()
+    sv = c["serve"]
+    slots, T = sv["slots"], sv["max_len"]
+    assert c["sizing"]["cache_bytes"] == 2 * 2 * slots * T * 4 * 128 * 2 \
+        + 8 * slots * 2 * 2048 * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= c["sizing"]["cache_bytes"]
+    assert m.argument_size_in_bytes < c["sizing"]["weights_bytes"] \
+        + c["sizing"]["cache_bytes"] + (1 << 20)
+    assert m.peak_memory_in_bytes < V5E_USABLE
+    beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes
+    hlo = compiled.as_text()
+    ops = materialised(hlo)
+    assert "ragged-dot" not in hlo
+    assert len(_expert_kernels(hlo)) == 3 * 8
+    _expert_weights_are_read_under_their_scope(hlo, 3 * 8)
+    if name.startswith("decode"):
+        assert len(_attend_kernels(hlo)) == 2
+        _nothing_moves_a_layers_keys(ops, slots, T, 4)
+        assert beyond < 64 << 20, beyond >> 20
+    else:
+        assert not _attend_kernels(hlo)
+        assert beyond < 256 << 20, beyond >> 20
+
+
 def _expert_kernels(hlo: str) -> list[str]:
     """The compiled program's calls of the Pallas grouped product
     (``ops/grouped_matmul.py``), each under the ``moe.experts`` scope."""

@@ -941,3 +941,105 @@ def test_kv_attend_compiled_at_the_cells():
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/kv_attend.json", "w") as fh:
         json.dump(table, fh, indent=1)
+
+
+def test_kv_attend_reads_64_wide_heads_two_to_a_row():
+    """LFM2's attention layer at the cell's shape: 256 lanes of 3,072
+    positions, 32 query heads on 8 KV heads of **64**, the cache packed
+    two heads to a row of 128 lanes (``slot_programs.kv_pack``: ``(256,
+    3072, 4, 128)``), the queries zero outside their own head's half
+    and the kernel as it stands told the head's scale. Eight sampled
+    (lane, head) rows against the softmax in float64 on the host over
+    the head's own 64-wide keys and values (2^-6 of the row's largest
+    entry, the bound of ``test_kv_attend_compiled_at_the_cells``), the
+    ``jax.numpy`` form over the packed rows beside it; the blocks past
+    every cursor poisoned with NaN; then a layer timed in both forms
+    (PERF.md section 6, PR 47)."""
+    import json
+    import os
+    import time
+
+    from pbs_tpu.models.slot_programs import (
+        _from_packed, _grouped_attention, _to_packed, kv_pack)
+    from pbs_tpu.ops.kv_attend import attend_block, kv_attend
+
+    bf16, reps = jnp.bfloat16, 20
+    B, H, nkv, hd, T = 256, 32, 8, 64, 3072
+    pack = kv_pack(nkv, hd)
+    assert pack == 2
+    rows = (B, T, nkv // pack, pack * hd)
+    rng = np.random.default_rng(47)
+    cursors = rng.integers(200, 2400, B)
+    cursors[[1, B - 2]] = 0                               # lanes at rest
+    at = jnp.asarray(cursors, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(47), 3)
+    q = jax.random.normal(ks[0], (B, 1, H, hd), bf16)
+    k = jax.random.normal(ks[1], (B, T, nkv, hd), bf16)
+    v = jax.random.normal(ks[2], (B, T, nkv, hd), bf16)
+    kp, vp = k.reshape(rows), v.reshape(rows)
+    tk = attend_block(T, nkv // pack)
+    assert tk == 512
+
+    @jax.jit
+    def kernel(q, kp, vp, at):
+        out = kv_attend(_to_packed(q, nkv, pack)[:, 0], kp, vp, at,
+                        scale=hd ** -0.5)
+        return _from_packed(out[:, None], nkv, pack)[:, 0]
+
+    @jax.jit
+    def numpy_way(q, kp, vp, at):
+        seen = jnp.arange(T)[None, :] <= at[:, None]
+        out = _grouped_attention(_to_packed(q, nkv, pack), kp, vp,
+                                 seen[:, None, :], bf16, hd)
+        return _from_packed(out, nkv, pack)[:, 0]
+
+    got, ref = kernel(q, kp, vp, at), numpy_way(q, kp, vp, at)
+    worst = {"kv_attend": 0.0, "jax.numpy form": 0.0}
+    for b, h in zip(rng.integers(0, B, 8), rng.integers(0, H, 8)):
+        n, live = h // (H // nkv), int(at[b]) + 1
+        keys = np.asarray(k[b, :live, n], np.float64)
+        vals = np.asarray(v[b, :live, n], np.float64)
+        s = keys @ np.asarray(q[b, 0, h], np.float64) / np.sqrt(hd)
+        p = np.exp(s - s.max())
+        want = (p / p.sum()) @ vals
+        for way, out in (("kv_attend", got), ("jax.numpy form", ref)):
+            gap = np.abs(np.asarray(out[b, h], np.float64) - want).max() \
+                / np.abs(want).max()
+            worst[way] = max(worst[way], float(gap))
+    assert worst["kv_attend"] < 2 ** -6, worst
+    dead = (jnp.arange(T)[None, :] // tk > at[:, None] // tk)[:, :, None,
+                                                             None]
+    poisoned = kernel(q, jnp.where(dead, jnp.nan, kp),
+                      jnp.where(dead, jnp.nan, vp), at)
+    assert bool(jnp.array_equal(poisoned, got))
+    del poisoned
+
+    def timed(attend):
+        @jax.jit
+        def chain(q, kp, vp, at):
+            def one(i, q):
+                out = attend(q, kp, vp, at)
+                return q + (out[:, None, :, :1] * 0).astype(q.dtype)
+            return jax.lax.fori_loop(0, reps, one, q)
+
+        jax.block_until_ready(chain(q, kp, vp, at))
+        t0 = time.perf_counter()
+        jax.block_until_ready(chain(q, kp, vp, at))
+        return (time.perf_counter() - t0) / reps * 1e3
+
+    ms = {"kv_attend 512": timed(kernel), "jax.numpy form": timed(numpy_way)}
+    fetched = int((cursors // tk + 1).sum()) * tk * nkv * hd * 4
+    table = dict(ms, lanes=B, heads=H, kv_heads=nkv, head_dim=hd, kept=T,
+                 live_positions=int(cursors.sum() + B),
+                 fetched_mb=fetched / 1e6,
+                 fetched_at_819_gbs_ms=fetched / 819e9 * 1e3, gap=worst)
+    print(f"lfm2 256 x 3072, 32 heads on 8 of 64, two to a row: "
+          f"{fetched / 1e6:.1f} MB fetched "
+          f"({fetched / 819e9 * 1e3:.3f} ms at 819 GB/s), ms a layer: "
+          + ", ".join(f"{n} {t:.3f}" for n, t in ms.items())
+          + "; against float64 " + ", ".join(
+              f"{n} {g:.2e}" for n, g in worst.items()), flush=True)
+    assert ms["kv_attend 512"] < ms["jax.numpy form"]
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/kv_attend_packed.json", "w") as fh:
+        json.dump(table, fh, indent=1)
