@@ -21,9 +21,17 @@ input ``h`` of position ``t``::
 Two programs of the engine (``models/slot_programs.py``), in the shape of
 ``models/kda.py``. :func:`mla_ingest` runs a whole prompt in the
 per-head form (keys and values read off the latent rows once, for the
-prompt), a block of queries at a time: the block's indexer scores, its
-choice, its attention under the choice, a chunk of keys at a time with
-a running maximum and sum. :func:`mla_decode` is one
+prompt, heads-major), a block of queries at a time: the block's indexer
+scores, its choice, its attention under the choice. That attention is,
+on a TPU, **one pass over the key blocks the block of queries can see**
+(``ops/mla_ingest_attend.py``: a head's keys and values a block in VMEM
+at a time, float32 scores, mask, running maximum and sum and the
+values' product on the chip, no key block past the query block's own
+end fetched), and anywhere else :func:`_attend_chunks`, the same
+arithmetic in ``jax.numpy`` a chunk of keys at a time: by the platform
+the program is lowered for, where the program says the layer streams
+(``slot_programs.live_ingest``: head dims, the rung and where the cache
+lies), nothing a user sets. :func:`mla_decode` is one
 position a lane in the absorbed form: the query is carried into the
 latent space (``q_n W_kvb^T``), scores and values are read off the
 ``kv_rank + rope_dim`` wide rows, which are therefore read once for all
@@ -67,23 +75,34 @@ from pbs_tpu.models.plan import MlaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
 from pbs_tpu.ops.mla_attend import mla_attend
+from pbs_tpu.ops.mla_ingest_attend import (
+    ingest_attend, ingest_attend_tiles, ingest_block)
 
 __all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "attend_rows",
-           "decode_choice", "mla_decode", "mla_ingest", "top_mask"]
+           "decode_choice", "ingest_pairs", "ingest_tiles", "mla_decode",
+           "mla_ingest", "top_mask"]
 
 #: Queries a block of the ingestion scores, chooses for and attends at
-#: a time: the live score tensors are ``(heads, MLA_BLOCK, keys)``, never
-#: ``(heads, prompt, prompt)``.
+#: a time: the indexer's live tensors are ``(index_heads, MLA_BLOCK,
+#: keys)``, never ``(heads, prompt, prompt)``, and the kernel's query
+#: tile is a block (a head's ``(MLA_BLOCK, qk)`` whole in VMEM).
 MLA_BLOCK = 256
 #: Equal spans the prompt's queries are cut into, each against the keys
-#: up to its own end: the causal triangle in ``MLA_SPANS`` steps (at 4,
-#: five eighths of the square), each span one loop over its blocks.
+#: up to its own end, each span one loop over its blocks: what bounds
+#: the tensors of ``mla.index`` and ``mla.select`` (a block's scores
+#: and choice over a span's keys, not the prompt's) and the kernel's
+#: grid (a span's key blocks; of them a block of queries runs those up
+#: to its own). The ``jax.numpy`` attention runs the span whole: the
+#: causal triangle in ``MLA_SPANS`` steps (at 4, five eighths of the
+#: square).
 MLA_SPANS = 4
-#: Keys a chunk of a block's attention holds: the live float32 scores
-#: are ``(heads, MLA_BLOCK, MLA_KEYS)``, 128 MiB at 64 heads. Formed
-#: over a whole span of 6,144 or 8,192 keys at once, XLA:TPU's
-#: max-and-subtract fusion took 27 and 47 ms a block where 4,096 keys
-#: took 1.2 (PERF.md section 6, PR 41).
+#: Keys a chunk of the ``jax.numpy`` attention (:func:`_attend_chunks`:
+#: a CPU's and a mesh's lowering, the kernel's oracle) holds: its live
+#: float32 scores are ``(heads, MLA_BLOCK, MLA_KEYS)``, 128 MiB at 64
+#: heads. Formed over a whole span of 6,144 or 8,192 keys at once,
+#: XLA:TPU's max-and-subtract fusion took 27 and 47 ms a block where
+#: 4,096 keys took 1.2 (PERF.md section 6, PR 41). The kernel's key
+#: block is ``ops/mla_ingest_attend.py``'s own.
 MLA_KEYS = 2048
 _F32 = jnp.float32
 
@@ -272,13 +291,15 @@ def _attend(q_lat, q_r, ckv, kr, chosen, row_pos, live: bool, *,
 
 
 def _attend_chunks(q, k, v, seen, scale: float, dt):
-    """Softmax attention of a block of queries q (Q, H, qk) over the
-    keys ``seen`` (Q, K) marks among the first K of k (S, H, qk) and v
-    (S, H, v), ``MLA_KEYS`` keys at a time with a running maximum and
-    sum (float32), so that the live scores are ``(H, Q, MLA_KEYS)``
-    whatever K: (Q, H, v) in ``dt``. A query may see nothing in a chunk
-    (its indexer chose elsewhere): that chunk adds nothing."""
-    Q, H, _ = q.shape
+    """Softmax attention of a block of queries q (H, Q, qk) over the
+    keys ``seen`` (Q, K) marks among the first K of k (H, S, qk) and v
+    (H, S, v), all heads-major, ``MLA_KEYS`` keys at a time with a
+    running maximum and sum (float32), so that the live scores are
+    ``(H, Q, MLA_KEYS)`` whatever K: (H, Q, v) in ``dt``. A query may
+    see nothing in a chunk (its indexer chose elsewhere): that chunk
+    adds nothing. What a CPU and a mesh run, and what
+    :func:`pbs_tpu.ops.mla_ingest_attend.ingest_attend` is held to."""
+    H, Q, _ = q.shape
     K = seen.shape[1]
     low = jnp.finfo(_F32).min
     top = jnp.full((H, Q), low, _F32)
@@ -287,17 +308,37 @@ def _attend_chunks(q, k, v, seen, scale: float, dt):
     for k0 in range(0, K, MLA_KEYS):
         k1 = min(k0 + MLA_KEYS, K)
         mask = seen[None, :, k0:k1]
-        scores = jnp.einsum("qhd,khd->hqk", q, k[k0:k1],
+        scores = jnp.einsum("hqd,hkd->hqk", q, k[:, k0:k1],
                             preferred_element_type=_F32) * scale
         peak = jnp.maximum(top, jnp.max(jnp.where(mask, scores, low), -1))
         probs = jnp.where(mask, jnp.exp(scores - peak[..., None]), 0.0)
         keep = jnp.exp(top - peak)
         total = total * keep + jnp.sum(probs, -1)
         acc = acc * keep[..., None] + jnp.einsum(
-            "hqk,khv->hqv", probs.astype(dt), v[k0:k1],
+            "hqk,hkv->hqv", probs.astype(dt), v[:, k0:k1],
             preferred_element_type=_F32)
         top = peak
-    return jnp.swapaxes(acc / total[..., None], 0, 1).astype(dt)
+    return (acc / total[..., None]).astype(dt)
+
+
+# One trace and one lowered function a span's shapes, whatever the
+# layers (``models/mamba.py::_kernel_scan`` says why).
+_kernel_ingest = jax.jit(ingest_attend, static_argnames=("scale",))
+
+
+def _attend_block(q, k, v, seen, first, live: bool, *, scale: float, dt):
+    """The ingestion's attention of one block of queries: where the
+    layer streams (``live``, ``slot_programs.live_ingest``' answer:
+    shapes the kernel's tiling takes, on one device) by the platform
+    the program is lowered for, on a TPU the one-pass kernel over the
+    key blocks up to the query block's own and anywhere else
+    :func:`_attend_chunks`; where it does not, :func:`_attend_chunks`."""
+    if not live:
+        return _attend_chunks(q, k, v, seen, scale, dt)
+    return jax.lax.platform_dependent(
+        q, k, v, seen, first,
+        tpu=functools.partial(_kernel_ingest, scale=scale),
+        default=lambda *args: _attend_chunks(*args[:-1], scale, dt))
 
 
 def _spans(S: int, block: int) -> list[tuple[int, int]]:
@@ -307,24 +348,50 @@ def _spans(S: int, block: int) -> list[tuple[int, int]]:
     return [(i * (S // n), S // n) for i in range(n)]
 
 
+def ingest_tiles(a: MlaKind, S: int) -> bool:
+    """Whether the one-pass kernel's tiling takes layer ``a``'s
+    ingestion of a prompt of ``S`` rows (a rung): whole blocks of
+    queries, head dims whole rows of lanes, every span's keys whole
+    key blocks (``ops/mla_ingest_attend.py``)."""
+    block = min(MLA_BLOCK, S)
+    return S % block == 0 and ingest_attend_tiles(
+        block, a.nope_dim + a.rope_dim, a.v_dim, _spans(S, block)[0][1])
+
+
+def ingest_pairs(S: int, plen: int) -> int:
+    """The (query block, key block) pairs the kernel runs in one layer
+    for a prompt of ``plen`` tokens at a rung of ``S`` rows: every
+    query block that holds a token, against the key blocks up to the
+    one its last position lies in (``ENG_SELECT``'s ``blocks`` of a
+    prefill)."""
+    block = min(MLA_BLOCK, S)
+    return sum((first + block - 1) // ingest_block(start + rows) + 1
+               for start, rows in _spans(S, block)
+               for first in range(start, min(start + rows, plen), block))
+
+
 def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
-               cos: jax.Array, sin: jax.Array, eps: float, dt):
+               cos: jax.Array, sin: jax.Array, eps: float, dt,
+               live: bool = False):
     """One prompt's pass, per head: h (1, S, d) padded, from position 0,
     ``valid`` (1, S) its real positions. A block of queries past the
-    prompt's end is not run. Returns (what the heads give (1, S,
-    H * v), and the prompt's rows ckv (1, S, kv_rank), kr (1, S, rope),
-    ik (1, S, index_dim): the caller keeps the valid ones)."""
+    prompt's end is not run. ``live``: the program's word
+    (``slot_programs.live_ingest``) that this layer's attention streams
+    the key blocks a query block can see. Returns (what the heads give
+    (1, S, H * v), and the prompt's rows ckv (1, S, kv_rank), kr (1, S,
+    rope), ik (1, S, index_dim): the caller keeps the valid ones)."""
     S = h.shape[1]
     H, plen = a.n_heads, valid.sum()
     q_n, q_r, ckv, kr, ik, qi, w = _rows(a, ap, h, cos, sin, eps, dt)
     w_k, w_v = _halves(a, ap, dt)
     # a head's query and key whole, the shared rotary key behind each
-    # head's own part: one product a pair, not two summed
-    q = jnp.concatenate([q_n, q_r], axis=-1)[0]
+    # head's own part: one product a pair, not two summed; heads-major,
+    # so that a (head, block of keys) is one dense tile
+    q = jnp.swapaxes(jnp.concatenate([q_n, q_r], axis=-1)[0], 0, 1)
     k = jnp.concatenate([
-        jnp.einsum("sr,rhn->shn", ckv[0], w_k),
-        jnp.broadcast_to(kr[0][:, None, :], (S, H, a.rope_dim))], axis=-1)
-    v = jnp.einsum("sr,rhv->shv", ckv[0], w_v)
+        jnp.einsum("sr,rhn->hsn", ckv[0], w_k),
+        jnp.broadcast_to(kr[0][None], (H, S, a.rope_dim))], axis=-1)
+    v = jnp.einsum("sr,rhv->hsv", ckv[0], w_v)
     block = min(MLA_BLOCK, S)
     if S % block:
         raise ValueError(f"a prompt of {S} rows is not whole blocks of "
@@ -332,9 +399,10 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
     scale = 1.0 / np.sqrt(a.nope_dim + a.rope_dim)
 
     def attend(first, keys, select):
-        """Queries [first, first + block) against keys [0, keys)."""
-        cut = lambda t: jax.lax.dynamic_slice_in_dim(  # noqa: E731
-            t, first, block)
+        """Queries [first, first + block) against keys [0, keys):
+        (H, block, v)."""
+        cut = lambda t, axis=0: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            t, first, block, axis)
         seen = jnp.arange(keys)[None, :] \
             <= first + jnp.arange(block)[:, None]              # (Q, K)
         if select:
@@ -347,8 +415,8 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
                 seen = top_mask(jnp.where(seen, index, -jnp.inf),
                                 a.topk) & seen
         with jax.named_scope("mla.attend"):
-            return _attend_chunks(cut(q), k, v, seen, scale,
-                                  dt).reshape(block, H * a.v_dim)
+            return _attend_block(cut(q, 1), k, v, seen, first, live,
+                                 scale=scale, dt=dt)
 
     out = []
     for start, rows in _spans(S, block):
@@ -360,6 +428,7 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
             lambda i, acc, start=start, keys=keys, select=select:
             jax.lax.dynamic_update_slice(
                 acc, attend(start + i * block, keys, select),
-                (i * block, 0)),
-            jnp.zeros((rows, H * a.v_dim), dt)))
-    return jnp.concatenate(out)[None], ckv, kr, ik
+                (0, i * block, 0)),
+            jnp.zeros((H, rows, a.v_dim), dt)))
+    heads = jnp.swapaxes(jnp.concatenate(out, axis=1), 0, 1)
+    return heads.reshape(1, S, H * a.v_dim), ckv, kr, ik

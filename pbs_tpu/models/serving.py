@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from pbs_tpu.models.generate import _sample
+from pbs_tpu.models.mla import ingest_pairs
 from pbs_tpu.models.slot_programs import prefill_rungs, slot_program
 # benchmarks/families/dense-gqa.py (``sizing``) imports these three from
 # here; the next ``benchmark`` PR points it at models/slot_programs.py
@@ -171,6 +172,13 @@ class ContinuousBatcher:
         #: it; empty where the ``jax.numpy`` forms run
         self._live = dict(sorted(Counter(
             self.program.live_layers(cache, lowered=True).values()).items()))
+        #: the rungs at which a prefill's latent layers attend through
+        #: the kernel that streams the key blocks a block of queries can
+        #: see (``live_ingest``, lowered): a prefill's ``ENG_SELECT``
+        #: counts its blocks by it
+        self._ingest_live = frozenset(
+            rung for rung in self.rungs
+            if self.program.live_ingest(rung, lowered=True))
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
         self.queue: deque = deque()
@@ -371,22 +379,28 @@ class ContinuousBatcher:
                 if k == kind]
 
     def _select_ev(self, ts_ns: int, live: np.ndarray,
-                   decode: bool = False) -> None:
+                   rung: int = 0) -> None:
         """``ENG_SELECT``: how many positions each of this call's
         queries sees (``live``, one entry a busy lane or a prompt
         token) and how many of them a layer that chooses attends, from
         what the host knows of its slots; stamped like the call's
         ``ENG_DECODE`` or ``ENG_PREFILL``. Last, the blocks one latent
-        layer's one-pass attention streams in a ``decode``
-        (:meth:`_blocks_fetched`); 0 where no such kernel runs (a
-        prefill; the ``jax.numpy`` form). Nothing for a program in
-        which no layer chooses."""
+        layer's one-pass attention streams: a decode's (``rung`` 0) the
+        (lane, block) pairs of :meth:`_blocks_fetched`, a prefill's at
+        ``rung`` rows the (query block, key block) pairs of
+        ``mla.ingest_pairs``; 0 where the ``jax.numpy`` form runs.
+        Nothing for a program in which no layer chooses."""
         topk = self.program.select_topk
         if topk is not None:
-            rows = self._blocks_fetched(live, "latent") if decode else []
+            if rung:
+                blocks = ingest_pairs(rung, len(live)) \
+                    if rung in self._ingest_live else 0
+            else:
+                rows = self._blocks_fetched(live, "latent")
+                blocks = rows[-1][0] if rows else 0
             self._ev(ts_ns, Ev.ENG_SELECT, self._tick_seq, len(live),
                      int(live.sum()), int(np.minimum(live, topk).sum()),
-                     topk, rows[-1][0] if rows else 0)
+                     topk, blocks)
 
     def _attend_ev(self, ts_ns: int, live: np.ndarray) -> None:
         """``ENG_ATTEND``: the blocks of keys and values this decode's
@@ -485,7 +499,8 @@ class ContinuousBatcher:
                 t_dispatched = _ns()
                 first = np.asarray(first).ravel()
                 self._route_ev(t_prefill, first[1:])
-                self._select_ev(t_prefill, np.arange(1, len(prompt) + 1))
+                self._select_ev(t_prefill, np.arange(1, len(prompt) + 1),
+                                rows)
                 first = int(first[0])
                 self._mlp_extra_sum += float(extra) / self.cfg.n_layers
         t_synced = _ns()
@@ -726,7 +741,7 @@ class ContinuousBatcher:
             self._route_ev(t_pre, route)
         if mask.any():
             if seen is not None:
-                self._select_ev(t_pre, seen, decode=True)
+                self._select_ev(t_pre, seen)
                 self._attend_ev(t_pre, seen)
             self._decoded(t_pre, t_enqueued, t_host, overlapped)
         return done

@@ -41,7 +41,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from pbs_tpu.models.kda import kda_decode, kda_ingest
 from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
 from pbs_tpu.models.mamba2 import mamba2_decode, mamba2_ingest
-from pbs_tpu.models.mla import mla_decode, mla_ingest
+from pbs_tpu.models.mla import ingest_tiles, mla_decode, mla_ingest
 from pbs_tpu.models.plan import (
     AttnKind, ConvKind, KdaKind, Mamba2Kind, MambaKind, MlaKind, block_name,
     init_plan_params, plan_of, rope_table, uniform_plan)
@@ -255,6 +255,26 @@ def live_layers(plan, cache: dict, devices: tuple,
             if kv_attend_tiles(nkv, hd, kept):
                 out[layer] = ("kv", kept, kv_attend_block(kept, nkv))
     return out
+
+
+def live_ingest(plan, rung: int, devices: tuple,
+                lowered: bool = False) -> frozenset[int]:
+    """Which latent layers' attention of a prompt forward at ``rung``
+    rows streams the key blocks a block of queries can see
+    (``ops/mla_ingest_attend.py``), decided as :func:`live_layers`
+    decides a decode's: the cache (and so the prompt's keys and values)
+    on one device, shapes the kernel's tiling takes
+    (``mla.ingest_tiles``: head dims whole rows of lanes, the rung's
+    spans whole key blocks). Traced so, a layer's attention goes
+    through ``jax.lax.platform_dependent``; ``lowered`` asks for the
+    layers that *run* the kernel besides, the one device a TPU
+    (``ENG_SELECT``'s ``blocks`` of a prefill go by that)."""
+    if len(devices) != 1 or (lowered and devices[0].platform != "tpu"):
+        return frozenset()
+    kinds = ((layer, plan.kinds(layer)[0])
+             for layer in range(len(plan.layers)))
+    return frozenset(layer for layer, a in kinds
+                     if isinstance(a, MlaKind) and ingest_tiles(a, rung))
 
 
 def _say_attention(live: dict, layers: int) -> None:
@@ -525,9 +545,10 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     every lane ``valid`` marks; the prompt's real positions into the
     slot) and attends the positions its indexer picks.
 
-    ``live``: the layers whose decode attention streams a lane's live
-    blocks (:func:`live_layers`' answer for this program and cache; the
-    decode tick's alone).
+    ``live``: the layers whose attention streams live blocks: a decode
+    tick's over a lane's (:func:`live_layers`' answer for this program
+    and cache), a prompt's latent layers' over a block of queries'
+    (:func:`live_ingest`'s for this program and rung).
 
     ``valid`` (B, S) marks real tokens: the expert layers route nothing
     else, and no state folds anything else in. Returns (logits fp32:
@@ -722,8 +743,9 @@ def _latent_layer(a, ap: dict, h: jax.Array, new: dict, name: str,
     """A latent layer of the planned stack on its normed input h (B, S,
     d): writes the rows the layer keeps a position into its entries of
     ``new`` (replaced in the dicts) and returns what the layer adds to
-    the stream. Padding and idle lanes change no row. ``live``: a
-    decode tick whose attention streams the lane's live rows."""
+    the stream. Padding and idle lanes change no row. ``live``: the
+    layer's attention streams live blocks (a decode tick's the lane's
+    live rows, a prompt's the key blocks a block of queries sees)."""
     scope, keys, step, ingest = _LATENT[type(a)]
     cos, sin = (t[abs_pos] for t in tables[a.rope])
     rows = [new[key][name] for key in keys]
@@ -732,7 +754,7 @@ def _latent_layer(a, ap: dict, h: jax.Array, new: dict, name: str,
             out, *rows = step(a, ap, h, *rows, row_pos, valid[:, 0], cos,
                               sin, eps, dt, live)
         else:
-            out, *prompt = ingest(a, ap, h, valid, cos, sin, eps, dt)
+            out, *prompt = ingest(a, ap, h, valid, cos, sin, eps, dt, live)
             at = (slot, 0, 0)
             for i, fresh in enumerate(prompt):
                 K = min(fresh.shape[1], rows[i].shape[1])
@@ -761,6 +783,12 @@ class _LiveLayers:
         decode is traced, or with ``lowered`` as it runs (the records'
         question)."""
         return live_layers(plan_of(self.cfg), cache, self.devices, lowered)
+
+    def live_ingest(self, rung: int, lowered: bool = False) -> frozenset:
+        """:func:`live_ingest` of this program at a prompt of ``rung``
+        rows: as a prefill is traced, or with ``lowered`` as it
+        runs."""
+        return live_ingest(plan_of(self.cfg), rung, self.devices, lowered)
 
 
 class _ScanProgram(_LiveLayers):
@@ -914,7 +942,8 @@ class _PlannedProgram(_LiveLayers):
         valid = (jnp.arange(prompt.shape[0]) < plen)[None, :]
         last_logits, new, route = _plan_forward(
             self.cfg, params, prompt[None, :], cache,
-            jnp.zeros((1,), jnp.int32), valid, slot=slot)
+            jnp.zeros((1,), jnp.int32), valid, slot=slot,
+            live=self.live_ingest(prompt.shape[0]))
         cache = dict(new, pos=cache["pos"].at[slot].set(plen))
         return last_logits, cache, jnp.zeros((), jnp.float32), route
 

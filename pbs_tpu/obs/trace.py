@@ -226,11 +226,13 @@ class Ev(enum.IntEnum):
     #                      prefill's prompt tokens), live positions (a
     #                      query sees them: summed over the rows),
     #                      chosen positions (min(live, topk) a row,
-    #                      summed), topk, blocks (the (lane, block)
-    #                      pairs the decode's one-pass attention
-    #                      streams: cursor // block + 1 a busy lane,
-    #                      summed; 0 for a prefill and where the
-    #                      jax.numpy form runs); each of one such layer
+    #                      summed), topk, blocks (a decode's: the (lane,
+    #                      block) pairs its one-pass attention streams,
+    #                      cursor // block + 1 a busy lane, summed; a
+    #                      prefill's: the (query block, key block)
+    #                      pairs its one-pass attention runs,
+    #                      mla.ingest_pairs; 0 where the jax.numpy
+    #                      form runs); each of one such layer
     ENG_ATTEND = 0x0A09  # one a decode of a program whose softmax
     #                      layers over keys and values stream a lane's
     #                      live blocks (ops/kv_attend.py), from the

@@ -397,15 +397,17 @@ def test_a_state_space_layer_at_published_widths(topo, case):
 def _cell_programs(topo, config: str):
     """A benchmark configuration and its family's sizing programs (the
     engine's decode and its prefill at each rung, as the cell runs
-    them), their arguments as shapes on one described chip."""
+    them), their arguments as shapes on one described chip; with no
+    ``topo``, on the process's own default device."""
     from benchmarks.harness.spec import Spec
 
     spec = Spec()
     c = spec.config(config)
-    one = SingleDeviceSharding(topo.devices[0])
+    where = {} if topo is None else {
+        "sharding": SingleDeviceSharding(topo.devices[0])}
     return c, spec.family(c["family"]).sizing(
         c, lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree))
+            x.shape, x.dtype, **where), tree))
 
 
 # GLM-5's cell whole (benchmarks/configs/glm-5.json: one dense and four
@@ -434,9 +436,13 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
     given the rotary keys as they lie (a bitcast, no copy), the float32
     scores of every lane and head over the whole cache (160 MiB a
     layer) are never formed, and the program needs 64 MiB beyond its
-    arguments where it needed 250. The prefill forms scores a block of
-    256 queries and 2,048 keys
-    at a time, never a head's (prompt, prompt) square, and no fusion is
+    arguments where it needed 250. **The prefill's attention is the
+    Mosaic kernel ``mla_ingest_attend``**, a block of 256 queries
+    against the key blocks up to its own, under ``attn.mla/mla.attend``
+    in each of the five layers (one private function a span's length
+    of keys, each called by every layer): no float32 scores of a block
+    over a chunk of 2,048 keys (128 MiB) and no head's (prompt, prompt)
+    square is formed, and no fusion is
     left with the tiling XLA:TPU falls back to when its search gives up
     (``estimated_cycles`` at the int64 maximum: the softmax over a
     whole 6,144- or 8,192-key span was one, 27-47 ms a block where
@@ -480,6 +486,31 @@ def test_the_latent_cells_programs_compile_and_fit(topo, name):
         assert beyond < (3 << 30) * rung // 8192
         assert not written(ops, {tuple(sorted((64, rung, rung))),
                                  tuple(sorted((32, rung, rung)))})
+        spans = 4
+        text = lowered.as_text()
+        assert len(re.findall(r"func\.func private @\w*ingest_attend",
+                              text)) == spans
+        assert len(re.findall(r"call @\w*ingest_attend", text)) == 5 * spans
+        kernels = [ln for ln in compiled.as_text().splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln
+                   and re.search(r'op_name="[^"]*/attn\.mla/[^"]*mla\.attend/'
+                                 r'[^"]*mla_ingest_attend', ln)]
+        assert len(kernels) == 5 * spans
+        scores = written(ops, {tuple(sorted((64, 256, 2048)))})
+        assert not [op for op in scores if op[2].startswith("f32")], scores
+
+
+@pytest.mark.parametrize("name", DSA_PROGRAMS[1:])
+def test_the_latent_prefill_lowered_for_a_cpu_holds_no_kernel(name):
+    """The same program traced the same way and lowered for the
+    process's own CPU: ``platform_dependent`` leaves the ``jax.numpy``
+    form alone in it (``_attend_chunks``), no Mosaic call."""
+    _, progs = _cell_programs(None, "glm-5")
+    prog = next(p for p in progs if p["name"] == name)
+    lowered = prog["fn"].lower(*prog["args"])
+    text = lowered.as_text()
+    assert "tpu_custom_call" not in text and "ingest_attend" not in text
+    assert "mla.attend" in lowered.as_text(debug_info=True)
 
 
 # Nemotron-3-Nano's cell whole (benchmarks/configs/

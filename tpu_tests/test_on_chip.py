@@ -673,6 +673,73 @@ def test_mla_attend_compiled_at_the_cell():
     assert ms["mla_attend"] < ms["jax.numpy form"]
 
 
+def test_mla_ingest_attend_compiled_at_the_cell():
+    """The one-pass latent attention of a prompt's ingestion
+    (``ops/mla_ingest_attend.py``) compiled through Mosaic at the
+    selecting cell's size: a block of 256 queries of 64 heads (192 + 64
+    and 256 wide, bfloat16) that ends an 8,192-row prompt, against all
+    8,192 keys under a seeded choice of 2,048 a query
+    (``top_mask``'s): the worst gap to the ``jax.numpy`` form
+    (``_attend_chunks``) over the whole block under 2^-6 of its largest
+    entry (the probabilities go to the values' product in bfloat16, in
+    both forms, against another maximum); a block in mid-prompt with
+    the key blocks past its own end poisoned with NaN, which the kernel
+    never reads; then the pass timed beside the four chunks of fusions
+    XLA makes of the ``jax.numpy`` form (PERF.md section 6, PR 48)."""
+    import time
+
+    from pbs_tpu.models.mla import _attend_chunks, top_mask
+    from pbs_tpu.ops.mla_ingest_attend import ingest_attend, ingest_block
+
+    H, Q, S, D, V, topk = 64, 256, 8192, 256, 256, 2048
+    bf16, scale = jnp.bfloat16, 1.0 / 16.0
+    tk = ingest_block(S)
+    ks = jax.random.split(jax.random.PRNGKey(48), 4)
+    q = jax.random.normal(ks[0], (H, S, D), bf16)
+    k = jax.random.normal(ks[1], (H, S, D), bf16)
+    v = jax.random.normal(ks[2], (H, S, V), bf16)
+    index = jax.random.normal(ks[3], (Q, S), jnp.float32)
+
+    def block(first):
+        seen = jnp.arange(S)[None, :] <= first + jnp.arange(Q)[:, None]
+        return (jax.lax.dynamic_slice_in_dim(q, first, Q, 1), k, v,
+                top_mask(jnp.where(seen, index, -jnp.inf), topk) & seen)
+
+    kernel = jax.jit(lambda *a: ingest_attend(*a, scale=scale))
+    numpy_way = jax.jit(lambda *a: _attend_chunks(*a, scale, bf16))
+    args = block(S - Q)
+    assert int(args[3].sum(-1).min()) == topk
+    got, ref = (np.asarray(t, np.float32)
+                for t in (kernel(*args, S - Q), numpy_way(*args)))
+    gap = float(np.abs(got - ref).max() / np.abs(ref).max())
+    print(f"a block of 256 queries over 8192 keys, worst gap to the "
+          f"jax.numpy form {gap:.2e}")
+    assert np.isfinite(got).all() and gap < 2 ** -6, gap
+    first = 3072 + Q
+    mid = block(first)
+    dead = (jnp.arange(S) // tk > (first + Q - 1) // tk)[None, :, None]
+    assert bool(dead.any())
+    clean = kernel(*mid, first)
+    poisoned = kernel(mid[0], jnp.where(dead, jnp.nan, k),
+                      jnp.where(dead, jnp.nan, v), mid[3], first)
+    assert bool(jnp.isfinite(clean.astype(jnp.float32)).all())
+    assert bool(jnp.array_equal(poisoned, clean))
+
+    ms = {}
+    for name, fn in (("mla_ingest_attend", lambda: kernel(*args, S - Q)),
+                     ("jax.numpy form", lambda: numpy_way(*args))):
+        jax.block_until_ready(fn())
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = fn()
+        jax.block_until_ready(out)
+        ms[name] = (time.perf_counter() - t0) / 20 * 1e3
+    print(f"a (block of 256 queries, 8192 keys) pass over 64 heads, "
+          f"{S // tk} key blocks of {tk}, ms: "
+          + ", ".join(f"{n} {t:.3f}" for n, t in ms.items()))
+    assert ms["mla_ingest_attend"] < ms["jax.numpy form"]
+
+
 # The shapes ``models/moe._sorted_rows`` meets in the benchmark's cells:
 # (sorted rows, held experts, experts a token can choose among that are
 # held out of how many, hidden, expert width, the MLP's form).
