@@ -324,10 +324,11 @@ def held_expert_ffn(h: jax.Array, lp: dict, kind, valid: jax.Array, dt):
       over the sorted buffer, rows of expert e against ``we*[e]``
       (:func:`_sorted_rows`), which is **the Pallas kernel**
       ``ops/grouped_matmul.py`` where the program is lowered for a TPU
-      and :func:`grouped_kernel_takes` the shape (``KERNEL_ROWS_A_GROUP``
-      sorted rows a held expert or more: a prompt forward's), and
-    - ``jax.lax.ragged_dot`` for every other shape and on every other
-      platform (the CPU tests, the reference's side).
+      and :func:`grouped_kernel_takes` the shape (every product its
+      tiling fits: a prompt forward's and a tick's alike, however few
+      rows a held expert has), and
+    - ``jax.lax.ragged_dot`` for the shapes the tiling refuses and on
+      every other platform (the CPU tests, the reference's side).
 
     No (T, E, C) tensor exists and no token is dropped, however the
     router concentrates: the sorted buffer has a row for every
@@ -390,9 +391,10 @@ def expert_form(rows: int, d: int, kind, dt) -> tuple[str, int]:
     ``rows x top_k`` through ``grouped-kernel`` (the Pallas kernel
     where the program is lowered for a TPU, ``ragged_dot`` anywhere
     else) where :func:`grouped_kernel_takes` the shape, or through
-    ``ragged_dot`` on every platform. A function of the static shape
-    alone; the engine writes it into the host ring when it builds a
-    program (``models/slot_programs.py::_plan_forward``)."""
+    ``ragged_dot`` on every platform where the kernel's tiling does
+    not. A function of the static shape alone; the engine writes it
+    into the host ring when it builds a program
+    (``models/slot_programs.py::_plan_forward``)."""
     n = kind.held[1]
     if rows * n <= DENSE_PAIRS:
         return "every", rows
@@ -484,24 +486,21 @@ def _sorted_rows(h, w, local, here, lp: dict, kind, dt):
 _kernel_product = jax.jit(grouped_matmul)
 
 
-#: Sorted rows a held expert (assignments to held and absent experts
-#: alike: the static shape) from which the Pallas kernel takes the
-#: grouped product. Measured alone beside ``jax.lax.ragged_dot`` at the
-#: eight shapes the cells meet (PERF.md section 6, PR 44): the kernel
-#: wins 2.1-7.7 times where a held expert has 40 sorted rows or more (a
-#: prompt forward of any of the four expert configurations, and solar's
-#: tick of 256 lanes: 51) and 1.3 times at laguna's tick of 64 lanes (5
-#: sorted rows an expert, both forms bound by the weights' read), which
-#: stays where it was, the control.
-KERNEL_ROWS_A_GROUP = 16
-
-
 def grouped_kernel_takes(m: int, groups: int, k: int, n: int, dtype) -> bool:
     """Whether the grouped product of ``m`` sorted rows with ``groups``
     matrices of ``(k, n)`` is the Pallas kernel's where the program is
-    lowered for a TPU: a function of the static shape alone."""
-    return m >= KERNEL_ROWS_A_GROUP * groups \
-        and grouped_matmul_tiles(m, groups, k, n, dtype)
+    lowered for a TPU: a function of the static shape alone, and the
+    kernel's tiling is the whole of it. No floor of rows a group:
+    measured alone beside ``jax.lax.ragged_dot`` at the eight shapes
+    the cells meet (PERF.md section 6, PR 44) the kernel is ahead 2.1-7.7
+    times where a held expert has 40 sorted rows or more and 1.3 times
+    at laguna's tick of 64 lanes (5 sorted rows an expert, both forms
+    bound by the weights' read), least where a group has fewest rows.
+    Below that it holds too: a visit costs its weight block's read,
+    which few rows' arithmetic hides under, so the kernel is a stream
+    of the touched experts where ``ragged-dot-*`` adds a fixed cost a
+    group (PERF.md section 6, PR 49: the tick in its program)."""
+    return grouped_matmul_tiles(m, groups, k, n, dtype)
 
 
 def _grouped_product(rows, w, sizes):

@@ -54,9 +54,12 @@ against 5.08 / 4.26; glm-5's piece (16,384, 1,024 on 16 of 6144 x
 tick and 256-row forward (2,048, 256 on 40 of 4096 x 1280) 0.62 /
 0.61 against 1.99 / 1.56; laguna's 1024-row forward (10,240, 5,120 on
 128 of 3072 x 1024) 1.31 / 1.38 against 2.93 / 2.83 and its tick (640,
-320) 1.02 / 1.03 against 1.36 / 1.26: 520-730 GB/s of the touched
-weights whatever the widths. Tiles of 64 and 256 rows read within 7%
-of 128's at every shape.
+320: 2.5 held rows an expert, ~118 of 128 touched) 1.02 / 1.03 against
+1.36 / 1.26: 520-730 GB/s of the touched weights whatever the widths,
+and ahead at every shape, least where a group has fewest rows, so
+``models/moe.grouped_kernel_takes`` asks for nothing but the tiling
+(PR 49). Tiles of 64 and 256 rows read within 7% of 128's at every
+shape.
 """
 
 from __future__ import annotations

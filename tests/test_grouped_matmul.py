@@ -77,6 +77,33 @@ def test_the_kernel_forms_ragged_dots_sum(case, widths, dtype, monkeypatch):
     assert np.array_equal(np.asarray(poisoned[:held], np.float32), got)
 
 
+def test_a_ticks_few_rows_a_group_are_ragged_dots_sum():
+    """Laguna's tick in small (640 sorted rows on 128 held experts, half
+    of them held, ~118 touched): 144 sorted rows on 32 groups of 2 or 3
+    held rows each, a tenth of the groups empty, the half of the buffer
+    behind the last group poisoned. Six or seven groups share a tile of
+    16 rows, a visit each; the held rows equal ``ragged_dot``'s and the
+    tiles behind them are never visited."""
+    groups, m, k, n = 32, 144, 168, 116
+    sizes = np.where(np.arange(groups) % 2, 3, 2)
+    sizes[[4, 13, 27]] = 0
+    held = int(sizes.sum())
+    assert 2 * held == m
+    keys = jax.random.split(jax.random.PRNGKey(49), 2)
+    x = jax.random.normal(keys[0], (m, k), jnp.float32).astype(jnp.bfloat16)
+    x = jnp.where(jnp.arange(m)[:, None] >= held, jnp.nan, x)
+    w = jax.random.normal(keys[1], (groups, k, n),
+                          jnp.float32).astype(jnp.bfloat16)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    _, tile, _, count = gm._visits(sizes, m // TILE, TILE)
+    assert int(count[0]) > groups - 3 and int(tile.max()) == (held - 1) // TILE
+    want = np.asarray(jax.lax.ragged_dot(x[:held], w, sizes), np.float32)
+    got = np.asarray(gm.grouped_matmul(x, w, sizes, tile=TILE,
+                                       interpret=True)[:held], np.float32)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max()
+
+
 def test_a_visit_is_a_tile_a_group_has_rows_in():
     """The grid's map for sizes (3, 0, 40, 9) in tiles of 16: group 0
     in tile 0, group 2 in tiles 0-2, group 3 in tiles 2-3; the steps
@@ -120,7 +147,7 @@ CELL_SHAPES = {
     "laguna forward 1024": (10240, 128, 3072, 1024, True),
     "solar forward 256 and tick": (2048, 40, 4096, 1280, True),
     "solar forward 512": (4096, 40, 4096, 1280, True),
-    "laguna tick": (640, 128, 3072, 1024, False),
+    "laguna tick": (640, 128, 3072, 1024, True),
 }
 
 
@@ -128,8 +155,13 @@ CELL_SHAPES = {
 def test_the_static_shape_chooses_the_form(shape):
     """``grouped_kernel_takes`` at the eight shapes of PERF.md section
     6 (PR 44), for an expert's matrices in and out alike, and
-    ``expert_form``'s name for it; a tick's few (row, held expert)
-    pairs go through every expert whatever the kernel would take."""
+    ``expert_form``'s name for it. The rule is the kernel's tiling and
+    nothing else (``grouped_matmul_tiles``: a 16-bit or 32-bit float
+    type, ``k`` whole sublane tiles, a weight block that fits): no
+    floor of rows a group, so laguna's tick of 5 sorted rows a held
+    expert takes the kernel as the forwards do, and int8 takes
+    ``ragged_dot``; a tick's few (row, held expert) pairs go through
+    every expert whatever the kernel would take."""
     from pbs_tpu.models import plan as P
 
     m, groups, d, f, takes = CELL_SHAPES[shape]
@@ -188,22 +220,28 @@ def test_sorted_rows_through_the_kernel_are_the_other_forms_sum(
     assert not np.asarray(got)[::5].any()
 
 
-@pytest.mark.parametrize("dense_pairs,want", [
-    (4096, {("experts.every", 48), ("experts.every", 3)}),
-    (0, {("experts.grouped-kernel", 144), ("experts.ragged_dot", 9)}),
-], ids=["few pairs", "sorted rows"])
+@pytest.mark.parametrize("dense_pairs,block_bytes,want", [
+    (4096, None, {("experts.every", 48), ("experts.every", 3)}),
+    (0, None, {("experts.grouped-kernel", 144),
+               ("experts.grouped-kernel", 9)}),
+    (0, 0, {("experts.ragged_dot", 144), ("experts.ragged_dot", 9)}),
+], ids=["few pairs", "sorted rows", "sorted rows no block fits"])
 def test_a_program_says_its_experts_form_as_it_is_traced(
-        dense_pairs, want, monkeypatch):
+        dense_pairs, block_bytes, want, monkeypatch):
     """One ``HOST_PHASE`` record of no length a form, named
     ``experts.<form>`` with the rows one product is over, each time a
     planned program with expert layers is traced: the toy's prompt
     forward (48 rows, top-3: 144 sorted rows over 4 held experts) and
-    its tick (3 lanes)."""
+    its tick (3 lanes: 9 sorted rows, two a held expert, the kernel's
+    as the forward's are); ``ragged_dot`` is the name where the
+    kernel's tiling refuses the shape (here: no weight block fits)."""
     from pbs_tpu.obs import trace as T
     from tests.test_mamba2_serving import BUCKET, MAX_LEN, SLOTS, program
     from tests.test_setup_records import _host_records, _now
 
     monkeypatch.setattr(moe, "DENSE_PAIRS", dense_pairs)
+    if block_bytes is not None:
+        monkeypatch.setattr(gm, "BLOCK_BYTES", block_bytes)
     _, params, prog, _, _ = program()
     cache = jax.eval_shape(lambda: prog.init_cache(SLOTS, MAX_LEN))
     since = _now()

@@ -606,10 +606,9 @@ def test_the_convolution_cells_programs_compile_and_fit(topo, name):
     writes a tensor of a layer's keys but the tick's new position, and
     no float32 scores a lane and a position long exist), **and its
     1,024 sorted rows on 64 held experts go through the Pallas grouped
-    product** (exactly ``KERNEL_ROWS_A_GROUP`` rows an expert): three
-    for each of the eight expert layers, no ``ragged-dot``, and beyond
-    its arguments the tick needs under 64 MiB. The prefill's grouped
-    products are the same kernel."""
+    product** (16 rows an expert): three for each of the eight expert
+    layers, no ``ragged-dot``, and beyond its arguments the tick needs
+    under 64 MiB. The prefill's grouped products are the same kernel."""
     c, progs = _cell_programs(topo, "lfm2-24b-a2b")
     prog = next(p for p in progs if p["name"] == name)
     compiled = prog["fn"].lower(*prog["args"]).compile()
@@ -666,12 +665,13 @@ def _expert_weights_are_read_under_their_scope(hlo: str, least: int):
         assert scope and "/moe.experts/" in scope.group(1), ln[:300]
 
 
-# The other cells' decode ticks that sort their rows: the shape chooses
-# (``models/moe.grouped_kernel_takes``). Laguna's 640 sorted rows over
-# 128 held experts (5 each) stay on ``jax.lax.ragged_dot`` (72% of
-# their roofline, the control); solar's 2,048 over 40 (51 each) go
-# through the kernel, three products for each of its four layers.
-SORTED_TICKS = {"laguna-s-2.1": 0, "solar-open2-250b": 3 * 4}
+# The other cells' decode ticks that sort their rows: every product
+# the kernel's tiling fits is the kernel's (``models/moe.
+# grouped_kernel_takes``), however few rows a held expert has. Laguna's
+# 640 sorted rows over 128 held experts (5 each) and solar's 2,048 over
+# 40 (51 each) both go through it, three products for each of their
+# four expert layers, and neither tick holds a ``ragged-dot``.
+SORTED_TICKS = {"laguna-s-2.1": 3 * 4, "solar-open2-250b": 3 * 4}
 #: The same ticks' full softmax layers, each the one-pass attention
 #: over the lane's live blocks (``ops/kv_attend.py``): laguna's two of
 #: five layers (its three rings stay on the ``jax.numpy`` form: a
@@ -686,9 +686,8 @@ def test_a_ticks_sorted_rows_take_the_form_their_shape_chooses(topo, config):
     hlo = prog["fn"].lower(*prog["args"]).compile().as_text()
     kernels = SORTED_TICKS[config]
     assert len(_expert_kernels(hlo)) == kernels
-    assert ("ragged-dot" in hlo) == (not kernels)
-    if kernels:
-        _expert_weights_are_read_under_their_scope(hlo, kernels)
+    assert "ragged-dot" not in hlo
+    _expert_weights_are_read_under_their_scope(hlo, kernels)
     assert len(_attend_kernels(hlo)) == FULL_LAYERS[config]
     assert "attn.window/" not in "".join(
         ln for ln in hlo.splitlines() if "kv_attend" in ln)
