@@ -1,8 +1,15 @@
-"""Latent attention that selects its positions (multi-head latent
-attention, arXiv:2405.04434, under a learned indexer: DeepSeek sparse
-attention as DeepSeek-V3.2-Exp's ``Indexer`` writes it) for the slot
-engine: what a ``models.plan.MlaKind`` layer of a planned stack
-computes.
+"""Latent attention (multi-head latent attention, arXiv:2405.04434),
+with or without a learned indexer that selects its positions (DeepSeek
+sparse attention as DeepSeek-V3.2-Exp's ``Indexer`` writes it) for the
+slot engine: what a ``models.plan.MlaKind`` layer of a planned stack
+computes. What follows is the selecting kind; **a kind without an
+indexer** (DeepSeek-V3's; ``MlaKind.selects`` false) has no ``wi_*``
+leaves, keeps no ``ik`` row, scores and chooses nothing and attends
+every position up to its own, its scores scaled by ``MlaKind.scale``
+(YaRN's ``mscale`` squared in it), and its decode takes **a window of
+queries a lane** (the verify window of a plan that drafts for itself:
+query ``s`` at the cursor plus ``s``, each seeing the rows the earlier
+ones wrote; ``ops/mla_attend.py::mla_attend_window``).
 
 A position is kept as three rows, nothing a head: the RMS-normed latent
 ``ckv`` (``kv_rank``), one rotary key ``kr`` (``rope_dim``) that every
@@ -74,13 +81,13 @@ import numpy as np
 from pbs_tpu.models.plan import MlaKind
 from pbs_tpu.models.quant import wload
 from pbs_tpu.models.transformer import rms_norm
-from pbs_tpu.ops.mla_attend import mla_attend
+from pbs_tpu.ops.mla_attend import mla_attend, mla_attend_window
 from pbs_tpu.ops.mla_ingest_attend import (
     ingest_attend, ingest_attend_tiles, ingest_block)
 
 __all__ = ["MLA_BLOCK", "MLA_KEYS", "MLA_SPANS", "attend_rows",
            "decode_choice", "ingest_pairs", "ingest_tiles", "mla_decode",
-           "mla_ingest", "top_mask"]
+           "mla_ingest", "top_mask", "window_rows"]
 
 #: Queries a block of the ingestion scores, chooses for and attends at
 #: a time: the indexer's live tensors are ``(index_heads, MLA_BLOCK,
@@ -163,7 +170,8 @@ def _rows(a: MlaKind, ap: dict, h: jax.Array, cos, sin, eps: float, dt):
     (B, S, H, rope), the rows the cache keeps ``ckv`` (B, S, kv_rank),
     ``kr`` (B, S, rope), ``ik`` (B, S, index_dim), and the indexer's
     query ``qi`` (B, S, index_heads, index_dim) with its head weights
-    ``w`` (B, S, index_heads) float32."""
+    ``w`` (B, S, index_heads) float32; the last three None for a kind
+    without an indexer."""
     B, S, _ = h.shape
     il = a.rope.interleave
     cq = rms_norm(h @ wload(ap["wq_a"], dt), ap["q_norm"], eps)
@@ -173,6 +181,8 @@ def _rows(a: MlaKind, ap: dict, h: jax.Array, cos, sin, eps: float, dt):
     kv = h @ wload(ap["wkv_a"], dt)
     ckv = rms_norm(kv[..., :a.kv_rank], ap["kv_norm"], eps)
     kr = _turn(kv[..., a.kv_rank:], cos, sin, il)
+    if not a.selects:
+        return q_n, q_r, ckv, kr, None, None, None
     qi = _turn((cq @ wload(ap["wi_q"], dt)).reshape(
         B, S, a.index_heads, a.index_dim), cos, sin, il)
     ik = _turn(_layer_norm(h @ wload(ap["wi_k"], dt), ap["ik_norm"],
@@ -192,14 +202,19 @@ def _halves(a: MlaKind, ap: dict, dt):
 
 def _put(rows: jax.Array, new: jax.Array, at: jax.Array,
          active: jax.Array) -> jax.Array:
-    """Lane b's new row (``new``: (B, 1, W)) goes to ``rows[b, at[b]]``
-    where the lane is active; an idle lane's row is written back as it
-    was. One dynamic_update_slice a lane into the whole cache
+    """Lane b's new rows (``new``: (B, S, W), one a tick or a verify
+    window's) go to ``rows[b, at[b]:at[b] + S]`` where the lane is
+    active (``active`` (B,), or (B, S) a row of the window); an idle
+    lane's rows are written back as they were. One
+    dynamic_update_slice a lane into the whole cache
     (``slot_programs._write_rows`` says why not a scatter)."""
+    S = new.shape[1]
+    if active.ndim == 2:
+        active = active[:, :, None]
 
     def one(b, rows):
         old = jax.lax.dynamic_slice(rows, (b, at[b], 0),
-                                    (1, 1) + rows.shape[2:])
+                                    (1, S) + rows.shape[2:])
         row = jax.lax.dynamic_slice_in_dim(new, b, 1)
         return jax.lax.dynamic_update_slice(
             rows, jnp.where(active[b], row.astype(rows.dtype), old),
@@ -224,34 +239,55 @@ def decode_choice(a: MlaKind, qi: jax.Array, w: jax.Array, ik: jax.Array,
         return top_mask(jnp.where(live, index, -jnp.inf), a.topk) & live
 
 
-def mla_decode(a: MlaKind, ap: dict, h: jax.Array, ckv: jax.Array,
-               kr: jax.Array, ik: jax.Array, row_pos: jax.Array,
-               active: jax.Array, cos: jax.Array, sin: jax.Array,
-               eps: float, dt, live: bool = False):
+def mla_decode(a: MlaKind, ap: dict, h: jax.Array, caches,
+               row_pos: jax.Array, active: jax.Array, cos: jax.Array,
+               sin: jax.Array, eps: float, dt, live: bool = False):
     """One position for every lane, absorbed: h (B, 1, d) at position
-    ``row_pos[b]``, the layer's caches ``ckv`` (B, T, kv_rank), ``kr``
-    (B, T, rope), ``ik`` (B, T, index_dim), cos and sin (B, 1, rope /
-    2). An active lane's three new rows go to its cursor; an idle
-    lane's caches come out as they went in. ``live``: the program's
-    word (``slot_programs.live_layers``) that this layer's attention
-    streams the lanes' live rows. Returns (what the heads give (B, 1,
-    H * v), ckv, kr, ik)."""
-    B = ckv.shape[0]
-    q_n, q_r, c_new, kr_new, ik_new, qi, w = _rows(a, ap, h, cos, sin,
-                                                   eps, dt)
-    ckv, kr, ik = (_put(rows, new, row_pos, active) for rows, new in (
-        (ckv, c_new), (kr, kr_new), (ik, ik_new)))
+    ``row_pos[b]``, ``caches`` the layer's in the order of ``a.rows``,
+    ``ckv`` (B, T, kv_rank), ``kr`` (B, T, rope) and under an indexer
+    ``ik`` (B, T, index_dim), ``active`` (B,), cos and sin (B, 1, rope
+    / 2). An active lane's new rows go to its cursor; an idle lane's
+    caches come out as they went in. ``live``: the program's word
+    (``slot_programs.live_layers``) that this layer's attention streams
+    the lanes' live rows.
+
+    **A kind without an indexer takes a window**: h (B, S, d), query
+    ``s`` of lane b at position ``row_pos[b] + s`` (cos and sin (B, S,
+    rope / 2)), ``active`` (B, S) the rows of the window that are
+    tokens (a lane is busy where its first is); the window's rows are
+    written first, so query ``s`` sees what the queries before it
+    wrote, and each attends every row up to its own.
+
+    Returns (what the heads give (B, S, H * v), then the caches)."""
+    B, S = h.shape[:2]
+    if a.selects and S != 1:
+        raise NotImplementedError(
+            f"attention kind {a.name!r}: an indexer chooses for one "
+            f"query a lane a tick, not for a window of {S}")
+    q_n, q_r, *new, qi, w = _rows(a, ap, h, cos, sin, eps, dt)
+    caches = [_put(rows, fresh, row_pos, active)
+              for rows, fresh in zip(caches, new)]
+    ckv, kr = caches[:2]
     # an idle lane's cursor rests where its last request ended, and
     # nothing up to there is its to read: it attends its first row
-    at = jnp.where(active, row_pos, 0)
-    chosen = decode_choice(a, qi[:, 0], w[:, 0], ik, at)
+    busy = active if active.ndim == 1 else active[:, 0]
+    at = jnp.where(busy, row_pos, 0)
+    if not a.selects:
+        with jax.named_scope("mla.attend"):
+            w_k, w_v = _halves(a, ap, dt)
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_n, w_k)
+            o_lat = _attend_window(q_lat, q_r, ckv, kr, at, live,
+                                   scale=a.scale)
+            out = jnp.einsum("bshr,rhv->bshv", o_lat, w_v)
+        return (out.reshape(B, S, a.n_heads * a.v_dim), *caches)
+    chosen = decode_choice(a, qi[:, 0], w[:, 0], caches[2], at)
     with jax.named_scope("mla.attend"):
         w_k, w_v = _halves(a, ap, dt)
         q_lat = jnp.einsum("bhn,rhn->bhr", q_n[:, 0], w_k)
         o_lat = _attend(q_lat, q_r[:, 0], ckv, kr, chosen, at, live,
-                        scale=1.0 / np.sqrt(a.nope_dim + a.rope_dim))
+                        scale=a.scale)
         out = jnp.einsum("bhr,rhv->bhv", o_lat, w_v)
-    return out.reshape(B, 1, a.n_heads * a.v_dim), ckv, kr, ik
+    return (out.reshape(B, 1, a.n_heads * a.v_dim), *caches)
 
 
 def attend_rows(q_lat, q_r, ckv, kr, chosen, *, scale: float):
@@ -259,14 +295,18 @@ def attend_rows(q_lat, q_r, ckv, kr, chosen, *, scale: float):
     ``jax.numpy`` over every kept row, the others masked: what a CPU
     runs and what :func:`pbs_tpu.ops.mla_attend.mla_attend` is held to.
     ``q_lat`` (B, H, kv_rank), ``q_r`` (B, H, rope), ``ckv`` (B, T,
-    kv_rank), ``kr`` (B, T, rope), ``chosen`` (B, T) bool. Returns
-    ``o_lat`` (B, H, kv_rank) in ``ckv``'s dtype."""
+    kv_rank), ``kr`` (B, T, rope), ``chosen`` (B, T) bool, or (B, H,
+    T) where the rows do not share it (a window's queries side by side
+    in ``H``: ``mla_attend_window``'s oracle). Returns ``o_lat`` (B, H,
+    kv_rank) in ``ckv``'s dtype."""
     scores = (jnp.einsum("bhr,btr->bht", q_lat, ckv,
                          preferred_element_type=_F32)
               + jnp.einsum("bhe,bte->bht", q_r, kr,
                            preferred_element_type=_F32)) * scale
+    if chosen.ndim == 2:
+        chosen = chosen[:, None, :]
     probs = jax.nn.softmax(jnp.where(
-        chosen[:, None, :], scores, jnp.finfo(_F32).min), axis=-1)
+        chosen, scores, jnp.finfo(_F32).min), axis=-1)
     return jnp.einsum("bht,btr->bhr", probs.astype(ckv.dtype), ckv)
 
 
@@ -288,6 +328,38 @@ def _attend(q_lat, q_r, ckv, kr, chosen, row_pos, live: bool, *,
         q_lat, q_r, ckv, kr, chosen, row_pos,
         tpu=functools.partial(_kernel_attend, scale=scale),
         default=lambda *args: attend_rows(*args[:-1], scale=scale))
+
+
+def window_rows(q_lat, q_r, ckv, kr, row_pos, *, scale: float):
+    """:func:`pbs_tpu.ops.mla_attend.mla_attend_window` in
+    ``jax.numpy``: :func:`attend_rows` over a window's queries side by
+    side, query ``s`` of lane b seeing the rows up to ``row_pos[b] +
+    s``. ``q_lat`` (B, S, H, kv_rank), ``q_r`` (B, S, H, rope); returns
+    (B, S, H, kv_rank)."""
+    B, S, H, R = q_lat.shape
+    seen = jnp.arange(ckv.shape[1])[None, None, :] \
+        <= (row_pos[:, None] + jnp.arange(S))[:, :, None]       # (B, S, T)
+    seen = jnp.broadcast_to(seen[:, :, None, :], (B, S, H, ckv.shape[1]))
+    return attend_rows(
+        q_lat.reshape(B, S * H, R), q_r.reshape(B, S * H, -1), ckv, kr,
+        seen.reshape(B, S * H, -1), scale=scale).reshape(B, S, H, R)
+
+
+_kernel_window = jax.jit(mla_attend_window, static_argnames=("scale",))
+
+
+def _attend_window(q_lat, q_r, ckv, kr, row_pos, live: bool, *,
+                   scale: float):
+    """The decode's attention of a kind without an indexer, as
+    :func:`_attend` chooses: the one-pass kernel where the layer
+    streams and the program is lowered for a TPU, :func:`window_rows`
+    anywhere else."""
+    if not live:
+        return window_rows(q_lat, q_r, ckv, kr, row_pos, scale=scale)
+    return jax.lax.platform_dependent(
+        q_lat, q_r, ckv, kr, row_pos,
+        tpu=functools.partial(_kernel_window, scale=scale),
+        default=functools.partial(window_rows, scale=scale))
 
 
 def _attend_chunks(q, k, v, seen, scale: float, dt):
@@ -378,8 +450,11 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
     prompt's end is not run. ``live``: the program's word
     (``slot_programs.live_ingest``) that this layer's attention streams
     the key blocks a query block can see. Returns (what the heads give
-    (1, S, H * v), and the prompt's rows ckv (1, S, kv_rank), kr (1, S,
-    rope), ik (1, S, index_dim): the caller keeps the valid ones)."""
+    (1, S, H * v), and the prompt's rows in the order of ``a.rows``:
+    ckv (1, S, kv_rank), kr (1, S, rope) and under an indexer ik (1, S,
+    index_dim): the caller keeps the valid ones). A kind without an
+    indexer scores and chooses nothing: every block attends what it
+    sees."""
     S = h.shape[1]
     H, plen = a.n_heads, valid.sum()
     q_n, q_r, ckv, kr, ik, qi, w = _rows(a, ap, h, cos, sin, eps, dt)
@@ -396,7 +471,7 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
     if S % block:
         raise ValueError(f"a prompt of {S} rows is not whole blocks of "
                          f"{block} queries")
-    scale = 1.0 / np.sqrt(a.nope_dim + a.rope_dim)
+    scale = a.scale
 
     def attend(first, keys, select):
         """Queries [first, first + block) against keys [0, keys):
@@ -422,7 +497,7 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
     for start, rows in _spans(S, block):
         # a span whose last query sits below topk chooses everything;
         # the loop ends with the last block that holds a real position
-        keys, select = start + rows, start + rows > a.topk
+        keys, select = start + rows, a.selects and start + rows > a.topk
         out.append(jax.lax.fori_loop(
             0, jnp.clip(-(-(plen - start) // block), 0, rows // block),
             lambda i, acc, start=start, keys=keys, select=select:
@@ -431,4 +506,5 @@ def mla_ingest(a: MlaKind, ap: dict, h: jax.Array, valid: jax.Array,
                 (0, i * block, 0)),
             jnp.zeros((H, rows, a.v_dim), dt)))
     heads = jnp.swapaxes(jnp.concatenate(out, axis=1), 0, 1)
-    return heads.reshape(1, S, H * a.v_dim), ckv, kr, ik
+    return (heads.reshape(1, S, H * a.v_dim), ckv, kr) + (
+        (ik,) if a.selects else ())

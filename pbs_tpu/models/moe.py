@@ -278,7 +278,12 @@ def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
     (weights (T, k) fp32, expert ids (T, k)). Scores are float32, by
     ``kind.scoring``: a ``softmax`` over the router's logits, or the
     ``sigmoid`` of each, where ``bias`` (one an expert) is added for
-    the choice of the k largest and left out of their weights. The
+    the choice of the k largest and left out of their weights; under a
+    group limit (``kind.n_group`` > 1: the experts are that many runs
+    of consecutive ones) a run's score is the sum of its two largest
+    ``score + bias``, the ``kind.topk_group`` best runs are kept and
+    the k are chosen among their experts alone (float32, as the scores
+    are; ties go to the lower number, a run's as an expert's). The
     chosen scores are renormalised to sum to one (the sum plus
     ``kind.renorm_eps`` where the kind has one) and scaled by
     ``kind.routed_scale``. No capacity: every token keeps every choice.
@@ -286,8 +291,16 @@ def route_top_k(h: jax.Array, router: jax.Array, kind, bias=None):
     logits = h.astype(jnp.float32) @ router.astype(jnp.float32)
     if kind.scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32),
-                                kind.top_k)
+        biased = scores + bias.astype(jnp.float32)
+        if kind.n_group > 1:
+            runs = biased.reshape(biased.shape[:-1] + (kind.n_group, -1))
+            best = jnp.sum(jax.lax.top_k(runs, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(best, kind.topk_group)
+            keep = jnp.any(kept[..., None] == jnp.arange(kind.n_group),
+                           axis=-2)
+            biased = jnp.where(keep[..., None], runs,
+                               -jnp.inf).reshape(biased.shape)
+        _, topi = jax.lax.top_k(biased, kind.top_k)
         topv = jnp.take_along_axis(scores, topi, axis=-1)
     elif kind.scoring == "softmax":
         topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),

@@ -23,14 +23,16 @@ tick)::
                       b_norm, c_norm, w_dt, dt_bias, a_log, d_skip,
                       w_out}                                  state space
     blocks/<NN>/attn/{attn_norm, wq_a, q_norm, wq_b, wkv_a, kv_norm,
-                      wkv_b, wo, wi_q, wi_k, ik_norm, ik_bias,
-                      wi_w}                          latent, selecting
+                      wkv_b, wo[, wi_q, wi_k, ik_norm, ik_bias,
+                      wi_w]}            latent[, selecting: an indexer]
     blocks/<NN>/attn/{attn_norm, w_in, conv_w, conv_b, dt_bias, a_log,
                       d_skip, g_norm, w_out}          matrix state space
     blocks/<NN>/attn/{attn_norm, w_in, conv_w, w_out}   gated convolution
     blocks/<NN>/mlp/{mlp_norm, w1, w3, w2}                    dense
     blocks/<NN>/mlp/{mlp_norm, router[, router_bias], we1, we3, we2
                      [, ws1, ws3, ws2]}
+    blocks/mtp/{enorm, hnorm, eh_proj, head_norm, attn/..., mlp/...}
+                                  the drafting block (``LayerPlan.draft``)
 
 A block may be a mixer alone or an MLP alone (its entry of
 ``LayerPlan.layers`` holds ``None`` for the half it lacks): it then has
@@ -197,18 +199,23 @@ class ConvKind:
 
 @dataclasses.dataclass(frozen=True)
 class MlaKind:
-    """Latent attention that selects its positions (multi-head latent
-    attention under a learned indexer: DeepSeek sparse attention). No
-    keys or values a head are kept: a position is one RMS-normed latent
-    row of ``kv_rank``, one rotary key of ``rope_dim`` shared by every
-    head, and one indexer key of ``index_dim``. A query (``n_heads`` of
-    ``nope_dim + rope_dim``, from a latent of ``q_rank``) attends the
-    ``topk`` earlier positions whose indexer score (``index_heads``
-    heads, a weight a head, relu) is largest, all of them while there
-    are no more than ``topk``; a head's key and value (``nope_dim``,
-    ``v_dim``) are read off the latent row (``models/mla.py``).
-    ``rope`` turns ``rope_dim`` dims of a query, of the shared key and
-    of the indexer's query and key (their leading ones)."""
+    """Latent attention (multi-head latent attention), with or without
+    a learned indexer that selects its positions (DeepSeek sparse
+    attention). No keys or values a head are kept: a position is one
+    RMS-normed latent row of ``kv_rank`` and one rotary key of
+    ``rope_dim`` shared by every head; under an indexer one indexer key
+    of ``index_dim`` besides. A query (``n_heads`` of ``nope_dim +
+    rope_dim``, from a latent of ``q_rank``) attends every earlier
+    position, or under an indexer (``index_heads``, ``index_dim`` and
+    ``topk`` all given) the ``topk`` earlier positions whose indexer
+    score (``index_heads`` heads, a weight a head, relu) is largest,
+    all of them while there are no more than ``topk``; a head's key and
+    value (``nope_dim``, ``v_dim``) are read off the latent row
+    (``models/mla.py``). ``rope`` turns ``rope_dim`` dims of a query,
+    of the shared key and of the indexer's query and key (their leading
+    ones). ``mscale`` is YaRN's magnitude correction where it sits in
+    the softmax's scale and not on the tables (DeepSeek-V3: ``0.1
+    mscale_all_dim ln(factor) + 1``, squared in :attr:`scale`)."""
 
     name: str
     n_heads: int
@@ -217,17 +224,38 @@ class MlaKind:
     nope_dim: int
     rope_dim: int
     v_dim: int
-    index_heads: int
-    index_dim: int
-    topk: int
-    rope: Rope
+    index_heads: int = 0
+    index_dim: int = 0
+    topk: int = 0
+    rope: Rope | None = None
+    mscale: float = 1.0
 
     def __post_init__(self):
-        if self.rope.rotary_dim != self.rope_dim:
+        if self.rope is None or self.rope.rotary_dim != self.rope_dim:
             raise ValueError(
                 f"attention kind {self.name!r}: its rotary turns "
-                f"{self.rope.rotary_dim} dims, its rotary key has "
-                f"{self.rope_dim}")
+                f"{self.rope and self.rope.rotary_dim} dims, its rotary "
+                f"key has {self.rope_dim}")
+        indexer = (self.index_heads, self.index_dim, self.topk)
+        if any(indexer) and not all(indexer):
+            raise ValueError(
+                f"attention kind {self.name!r}: an indexer has heads, a "
+                f"width and a topk, all three or none: {indexer}")
+
+    @property
+    def selects(self) -> bool:
+        """An indexer chooses the positions a query attends."""
+        return self.topk > 0
+
+    @property
+    def scale(self) -> float:
+        """What the softmax's scores are multiplied by."""
+        return self.mscale ** 2 / math.sqrt(self.nope_dim + self.rope_dim)
+
+    @property
+    def rows(self) -> tuple[str, ...]:
+        """The cache entries that hold what a position keeps."""
+        return ("ckv", "kr", "ik") if self.selects else ("ckv", "kr")
 
 
 #: What an MLP computes (``MlpKind.form``).
@@ -260,11 +288,25 @@ class MlpKind:
     #: added to the sum the chosen experts' scores are renormalised by
     #: (``lfm2_moe`` divides by ``sum + 1e-6``)
     renorm_eps: float = 0.0
+    #: the group limit of a sigmoid router (``noaux_tc``): the experts
+    #: are ``n_group`` runs of consecutive ones, a run's score is the
+    #: sum of its two largest ``score + bias``, and a token chooses
+    #: among the ``topk_group`` best runs alone. 1 and 1: no limit
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.form not in MLP_FORMS:
             raise ValueError(f"MLP kind {self.name!r}: unknown form "
                              f"{self.form!r}; known: {MLP_FORMS}")
+        if self.n_group > 1 and (
+                self.scoring != "sigmoid" or self.n_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.n_experts // self.n_group < 2):
+            raise ValueError(
+                f"MLP kind {self.name!r}: a group limit ({self.n_group} "
+                f"groups, {self.topk_group} kept) is a sigmoid router's, "
+                f"over whole groups of two experts or more")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,6 +317,15 @@ class LayerPlan:
     #: per layer (attn i, mlp i); None for the half a block does not
     #: have (a mixer alone, or an MLP alone: one norm, one residual add)
     layers: tuple[tuple[int | None, int | None], ...]
+    #: the drafting block (a multi-token-prediction module of depth
+    #: one, DeepSeek-V3's): (attn i, mlp i) of one more block of this
+    #: plan's kinds, which reads the stack's normed output at a position
+    #: beside the embedding of the token after it, through two norms
+    #: and one ``(2 d, d)`` projection, and predicts the token after
+    #: that through a norm of its own and the stack's head. A plan that
+    #: has one drafts for itself (``models/serving.py``); None: no such
+    #: block
+    draft: tuple[int, int] | None = None
 
     def __post_init__(self):
         if any(a is None and m is None for a, m in self.layers):
@@ -282,9 +333,25 @@ class LayerPlan:
                              "MLP or both")
 
     @property
+    def draft_kinds(self):
+        """The drafting block's mixer kind and MLP kind; None without
+        one."""
+        return None if self.draft is None else (
+            self.attn[self.draft[0]], self.mlp[self.draft[1]])
+
+    @property
+    def mixers(self) -> tuple:
+        """The kind of every mixer a tick runs: the layers', then the
+        drafting block's."""
+        return tuple(self.attn[a] for a, _ in self.layers
+                     if a is not None) + (
+            () if self.draft is None else (self.attn[self.draft[0]],))
+
+    @property
     def routed(self) -> bool:
         return any(m is not None and self.mlp[m].n_experts
-                   for _, m in self.layers)
+                   for _, m in self.layers + (
+                       () if self.draft is None else (self.draft,)))
 
     @property
     def recurrent(self) -> bool:
@@ -299,9 +366,25 @@ class LayerPlan:
     def select_topk(self) -> int | None:
         """The most positions a selecting layer's query attends; None
         where every layer attends all it keeps."""
-        return max((self.attn[a].topk for a, _ in self.layers
-                    if a is not None
-                    and isinstance(self.attn[a], MlaKind)), default=None)
+        return max((a.topk for a in self.mixers
+                    if isinstance(a, MlaKind) and a.selects), default=None)
+
+    @property
+    def latent(self) -> bool:
+        """Some layer keeps latent rows a position."""
+        return any(isinstance(a, MlaKind) for a in self.mixers)
+
+    @property
+    def takes_window(self) -> bool:
+        """A decode tick can verify a window of more than one position
+        a lane: every mixer is latent attention without an indexer,
+        whose rows a cursor masks and a later tick writes over (a ring
+        takes one position a tick, a recurrent state folds every token
+        in, an indexer would have to choose for every query of the
+        window; a softmax layer over keys and values could, and is not
+        written)."""
+        return all(isinstance(a, MlaKind) and not a.selects
+                   for a in self.mixers)
 
     def kinds(self, layer: int) -> tuple[AttnKind | None, MlpKind | None]:
         """The layer's mixer kind and MLP kind, None for a half the
@@ -313,6 +396,11 @@ class LayerPlan:
 
 def block_name(layer: int) -> str:
     return f"{layer:02d}"
+
+
+#: The drafting block's name: where its leaves lie under ``blocks/``
+#: and its rows in the cache's entries.
+DRAFT_BLOCK = "mtp"
 
 
 def uniform_plan(cfg) -> LayerPlan:
@@ -383,6 +471,12 @@ def plan_shapes(cfg) -> dict:
             block["attn"] = _attn_shapes(a, d, hd, nkv)
         if m is not None:
             block["mlp"] = _mlp_shapes(m, d)
+    if plan.draft is not None:
+        a, m = plan.draft_kinds
+        out["blocks"][DRAFT_BLOCK] = {
+            "enorm": (d,), "hnorm": (d,), "eh_proj": (2 * d, d),
+            "attn": _attn_shapes(a, d, hd, nkv), "mlp": _mlp_shapes(m, d),
+            "head_norm": (d,)}
     return out
 
 
@@ -419,16 +513,19 @@ def _attn_shapes(a, d: int, hd: int, nkv: int) -> dict:
                 "conv_w": (a.conv, c), "w_out": (c, d)}
     if isinstance(a, MlaKind):
         H, qk = a.n_heads, a.nope_dim + a.rope_dim
-        return {"attn_norm": (d,), "wq_a": (d, a.q_rank),
-                "q_norm": (a.q_rank,), "wq_b": (a.q_rank, H * qk),
-                "wkv_a": (d, a.kv_rank + a.rope_dim),
-                "kv_norm": (a.kv_rank,),
-                "wkv_b": (a.kv_rank, H * (a.nope_dim + a.v_dim)),
-                "wo": (H * a.v_dim, d),
-                "wi_q": (a.q_rank, a.index_heads * a.index_dim),
-                "wi_k": (d, a.index_dim), "ik_norm": (a.index_dim,),
-                "ik_bias": (a.index_dim,),
-                "wi_w": (d, a.index_heads)}
+        out = {"attn_norm": (d,), "wq_a": (d, a.q_rank),
+               "q_norm": (a.q_rank,), "wq_b": (a.q_rank, H * qk),
+               "wkv_a": (d, a.kv_rank + a.rope_dim),
+               "kv_norm": (a.kv_rank,),
+               "wkv_b": (a.kv_rank, H * (a.nope_dim + a.v_dim)),
+               "wo": (H * a.v_dim, d)}
+        if a.selects:
+            out.update({"wi_q": (a.q_rank, a.index_heads * a.index_dim),
+                        "wi_k": (d, a.index_dim),
+                        "ik_norm": (a.index_dim,),
+                        "ik_bias": (a.index_dim,),
+                        "wi_w": (d, a.index_heads)})
+        return out
     attn = {"attn_norm": (d,), "wq": (d, a.n_heads * hd),
             "wk": (d, nkv * hd), "wv": (d, nkv * hd),
             "wo": (a.n_heads * hd, d)}

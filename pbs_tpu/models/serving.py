@@ -109,6 +109,18 @@ class ContinuousBatcher:
     the device has run. ``has_work()`` stays true until the last tick
     is booked; ``settle()`` books it now; ``step_settled()`` is a tick
     that leaves nothing in flight.
+
+    **A plan with a drafting block drafts for itself**
+    (``program.drafts``; docs/SERVING.md "The drafting tick"): the
+    decode verifies two positions a lane (the lane's last token and
+    the token drafted to follow it) and a lane advances by one token or
+    two, which the host learns when it reads. The lanes' cursors, last
+    tokens and drafts stay on the device (in the cache), so the next
+    dispatch does not wait for that; the host's lane mask allows for a
+    decode in flight having spent up to two of a lane's budget, and a
+    second token past a budget or behind an EOS is dropped when it is
+    booked, never served. Greedy only. Nothing switches it but the
+    plan.
     """
 
     def __init__(self, cfg: TransformerConfig, params: dict,
@@ -135,6 +147,14 @@ class ContinuousBatcher:
         # What the configuration's layer stack gives the engine: its
         # cache, a decode position for every slot, a prompt's ingestion.
         self.program = slot_program(cfg, mlp_fn, mesh)
+        #: tokens a lane can emit a decode: 2 where the plan has a
+        #: drafting block (the program verifies a window of two), else 1
+        self._window = 2 if self.program.drafts else 1
+        if self._window > 1 and temperature != 0.0:
+            raise ValueError(
+                "a plan that drafts for itself is served greedy "
+                "(temperature=0): a draft is accepted where it is the "
+                "stack's own argmax")
         self.n_slots = n_slots
         self.bucket = prompt_bucket
         self.rungs = prefill_rungs(prompt_bucket)
@@ -179,6 +199,15 @@ class ContinuousBatcher:
         self._ingest_live = frozenset(
             rung for rung in self.rungs
             if self.program.live_ingest(rung, lowered=True))
+        #: what ``ENG_SELECT`` gives as the most positions a query
+        #: attends: a selecting layer's ``topk``; where latent layers
+        #: choose nothing, all the cache keeps; None: no latent layer
+        self._select_topk = self.program.select_topk or (
+            self.max_len if self.program.latent else None)
+        # What a drafting engine counts (``stats()``, ``ENG_DRAFT``).
+        self.drafts_proposed = 0
+        self.drafts_accepted = 0
+        self.draft_tokens_dropped = 0
         self._key = jax.random.PRNGKey(seed)
         self._ids = itertools.count()
         self.queue: deque = deque()
@@ -251,8 +280,10 @@ class ContinuousBatcher:
             cache)."""
             last_logits, cache, extra, route = self.program.ingest(
                 params, cache, slot, prompt, plen)
-            first = _sample(last_logits[None, :], key,
-                            self.temperature)[0]
+            # a drafting program took the (greedy) first token itself,
+            # to draft the one behind it
+            first = cache["cur"][slot] if self._window > 1 else _sample(
+                last_logits[None, :], key, self.temperature)[0]
             if route is not None:  # rides to the host with the token
                 first = jnp.concatenate([first[None], route])
             return first, last_logits, cache, extra
@@ -287,6 +318,25 @@ class ContinuousBatcher:
             out = nxt if route is None else jnp.concatenate([nxt, route])
             return nxt, out, new_cache, extra
 
+        def _decode_window(params, cache, prev_tok, lanes, key):
+            """The decode of a plan that drafts for itself, under the
+            same name and arguments: one token or two for every slot
+            (``program.draft_tick``). A lane's last token and its draft
+            are in ``cache`` (the prefill and the tick before left them
+            there), so ``lanes`` says only which lanes run and
+            ``prev_tok`` passes through; greedy, so ``key`` is not
+            drawn from. What goes to the host is ``(slots, 2)`` tokens
+            flat, -1 where a lane emitted none, the route behind."""
+            del key
+            toks, new_cache, route, *_ = self.program.draft_tick(
+                params, cache, lanes > _LANE_OFF)
+            out = toks.reshape(-1)
+            if route is not None:
+                out = jnp.concatenate([out, route])
+            return prev_tok, out, new_cache, jnp.zeros((), jnp.float32)
+
+        if self._window > 1:
+            _decode = jax.jit(_decode_window, donate_argnums=(1,))
         self._prefill_fn = _prefill
         self._install_fn = _install
         self._decode_fn = _decode
@@ -379,7 +429,7 @@ class ContinuousBatcher:
                 if k == kind]
 
     def _select_ev(self, ts_ns: int, live: np.ndarray,
-                   rung: int = 0) -> None:
+                   rung: int = 0, lanes: np.ndarray | None = None) -> None:
         """``ENG_SELECT``: how many positions each of this call's
         queries sees (``live``, one entry a busy lane or a prompt
         token) and how many of them a layer that chooses attends, from
@@ -389,14 +439,20 @@ class ContinuousBatcher:
         (lane, block) pairs of :meth:`_blocks_fetched`, a prefill's at
         ``rung`` rows the (query block, key block) pairs of
         ``mla.ingest_pairs``; 0 where the ``jax.numpy`` form runs.
-        Nothing for a program in which no layer chooses."""
-        topk = self.program.select_topk
+        ``lanes``: what each busy lane's *last* query sees, where a
+        lane has more than one (a drafting tick's window: ``live``
+        then has an entry a query, and a lane's blocks are streamed
+        once, up to that last one). Nothing for a program without a
+        latent layer; one whose latent layers choose nothing gives
+        chosen = seen and, for ``topk``, the cache's length."""
+        topk = self._select_topk
         if topk is not None:
             if rung:
                 blocks = ingest_pairs(rung, len(live)) \
                     if rung in self._ingest_live else 0
             else:
-                rows = self._blocks_fetched(live, "latent")
+                rows = self._blocks_fetched(
+                    live if lanes is None else lanes, "latent")
                 blocks = rows[-1][0] if rows else 0
             self._ev(ts_ns, Ev.ENG_SELECT, self._tick_seq, len(live),
                      int(live.sum()), int(np.minimum(live, topk).sum()),
@@ -436,8 +492,14 @@ class ContinuousBatcher:
             # prefill always samples one token; a zero-budget request
             # would still emit it and break caller-side accounting
             raise ValueError("max_new_tokens must be >= 1")
-        if len(prompt) + max_new_tokens > self.max_len:
-            raise ValueError("prompt + max_new_tokens exceeds max_len")
+        # a drafting tick writes the rows of its whole window, accepted
+        # or not: that many rows of room stay behind the last token
+        room = self._window if self._window > 1 else 0
+        if len(prompt) + max_new_tokens + room > self.max_len:
+            raise ValueError(
+                "prompt + max_new_tokens exceeds max_len" + (
+                    f" less the {room} rows a drafting tick's window "
+                    f"writes" if room else ""))
         rid = next(self._ids)
         self.queue.append((rid, prompt, int(max_new_tokens)))
         self._submitted_step[rid] = self.steps
@@ -659,16 +721,49 @@ class ContinuousBatcher:
         self._mlp_extra_sum += float(fl.extra) / self.cfg.n_layers
         self._mlp_extra_n += 1
         out = np.asarray(fl.out)
-        return out[:self.n_slots], out[self.n_slots:]
+        n = self.n_slots * self._window
+        toks = out[:n]
+        if self._window > 1:  # (slots, window), -1 where none emitted
+            toks = toks.reshape(self.n_slots, self._window)
+        return toks, out[n:]
 
     def _book(self, fl: _InFlight, toks: np.ndarray,
               done: list[Completion]) -> None:
         """Emit a decode's tokens to the lanes it ran that still hold
         the request they held then, and retire those that finished."""
+        if self._window > 1:
+            return self._book_window(fl, toks, done)
         for slot, rid in fl.lanes:
             if self.slot_req[slot] == rid and \
                     self._emit(slot, int(toks[slot])):
                 done.append(self._retire(slot))
+
+    def _book_window(self, fl: _InFlight, toks: np.ndarray,
+                     done: list[Completion]) -> None:
+        """:meth:`_book` for a drafting decode: a lane emitted one
+        token or two (``toks`` (slots, 2), -1 for none). They are
+        booked in order, and one behind the token that finished its
+        request (the budget's last, or an EOS) is dropped, never
+        served. ``ENG_DRAFT`` says what this decode proposed, what was
+        accepted and what was booked and dropped."""
+        proposed = accepted = booked = dropped = 0
+        for slot, rid in fl.lanes:
+            if self.slot_req[slot] != rid:
+                continue
+            row = [int(t) for t in toks[slot] if t >= 0]
+            proposed += 1
+            accepted += len(row) - 1
+            for i, tok in enumerate(row):
+                booked += 1
+                if self._emit(slot, tok):
+                    dropped += len(row) - 1 - i
+                    done.append(self._retire(slot))
+                    break
+        self.drafts_proposed += proposed
+        self.drafts_accepted += accepted
+        self.draft_tokens_dropped += dropped
+        self._ev(_ns(), Ev.ENG_DRAFT, self._tick_seq, len(fl.lanes),
+                 proposed, accepted, booked, dropped)
 
     def _read_and_book(self, fl: _InFlight,
                        done: list[Completion]) -> np.ndarray:
@@ -696,17 +791,21 @@ class ContinuousBatcher:
         # The lanes of this dispatch, decided ahead: a lane whose
         # budget the decode in flight exhausts runs no further token.
         # (An EOS the host has not seen yet cannot stop its lane: that
-        # lane runs one token more, which ``_book`` drops.)
+        # lane runs one token more, which ``_book`` drops.) A drafting
+        # decode in flight may have spent up to two of a lane's budget:
+        # a lane it may have finished sits this dispatch out, and runs
+        # in the next if the host then finds that it has not.
         carry = np.zeros(self.n_slots, bool)
         if fl is not None:
             carry[[slot for slot, _ in fl.lanes]] = True
-        mask = self.active & (self.slot_remaining > carry)
+        mask = self.active & (self.slot_remaining > carry * self._window)
         overlapped = int(fl is not None)
         seen = None
-        if self.program.select_topk is not None or (
+        if self._select_topk is not None or (
                 self._live and self.trace is not None):
             # a lane's new position sees its prompt, the tokens the host
-            # has booked and the one still in flight
+            # has booked and the one still in flight (a drafting decode
+            # in flight counts as one: whether it accepted is not known)
             booked = np.fromiter(map(len, self.slot_tokens), np.int64,
                                  self.n_slots)
             seen = (self.slot_prompt_len + booked + carry)[mask]
@@ -740,7 +839,13 @@ class ContinuousBatcher:
         for route in routes:  # stamped like this call's ENG_DECODE
             self._route_ev(t_pre, route)
         if mask.any():
-            if seen is not None:
+            if seen is not None and self._window > 1:
+                # a query a position of the window, each seeing one
+                # row more than the one before it
+                self._select_ev(t_pre, np.concatenate(
+                    [seen + i for i in range(self._window)]),
+                    lanes=seen + self._window - 1)
+            elif seen is not None:
                 self._select_ev(t_pre, seen)
                 self._attend_ev(t_pre, seen)
             self._decoded(t_pre, t_enqueued, t_host, overlapped)
@@ -783,6 +888,13 @@ class ContinuousBatcher:
             "mlp_extra_mean": round(
                 self._mlp_extra_sum / self._mlp_extra_n, 6)
             if self._mlp_extra_n else 0.0,
+            # A drafting engine (``ENG_DRAFT``, summed): drafts the
+            # booked decodes proposed, those the stack's own argmax
+            # confirmed, and tokens computed past a budget or behind an
+            # EOS and not served. All zero without a drafting block.
+            "drafts_proposed": self.drafts_proposed,
+            "drafts_accepted": self.drafts_accepted,
+            "draft_tokens_dropped": self.draft_tokens_dropped,
         }
 
 

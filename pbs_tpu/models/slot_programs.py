@@ -43,8 +43,8 @@ from pbs_tpu.models.mamba import mamba_decode, mamba_ingest
 from pbs_tpu.models.mamba2 import mamba2_decode, mamba2_ingest
 from pbs_tpu.models.mla import ingest_tiles, mla_decode, mla_ingest
 from pbs_tpu.models.plan import (
-    AttnKind, ConvKind, KdaKind, Mamba2Kind, MambaKind, MlaKind, block_name,
-    init_plan_params, plan_of, rope_table, uniform_plan)
+    DRAFT_BLOCK, AttnKind, ConvKind, KdaKind, Mamba2Kind, MambaKind, MlaKind,
+    block_name, init_plan_params, plan_of, rope_table, uniform_plan)
 from pbs_tpu.models.quant import embed_rows, wload
 from pbs_tpu.models.shortconv import conv_decode, conv_ingest
 from pbs_tpu.models.transformer import (
@@ -58,6 +58,7 @@ from pbs_tpu.ops.kv_attend import attend_block as kv_attend_block
 from pbs_tpu.ops.kv_attend import kv_attend, kv_attend_tiles
 from pbs_tpu.ops.mla_attend import attend_block as mla_attend_block
 from pbs_tpu.ops.mla_attend import mla_attend_tiles
+from pbs_tpu.models.speculative import greedy_accept_window
 from pbs_tpu.parallel.sharding import slot_cache_kv_sharding
 
 
@@ -237,13 +238,13 @@ def live_layers(plan, cache: dict, devices: tuple,
     with nothing passed. ``lowered`` asks for the layers that *run* the
     kernel besides: the one device a TPU. The records go by that (a
     traced decode's ``attn.*`` marks, the engine's ``ENG_SELECT`` and
-    ``ENG_ATTEND`` block counts); the forwards by the first."""
+    ``ENG_ATTEND`` block counts); the forwards by the first. A
+    drafting block's mixer is one more layer, numbered behind the
+    stack's last."""
     if len(devices) != 1 or (lowered and devices[0].platform != "tpu"):
         return {}
     out = {}
-    for layer in range(len(plan.layers)):
-        a, _ = plan.kinds(layer)
-        name = block_name(layer)
+    for layer, name, a in _mixers(plan):
         if isinstance(a, MlaKind):
             kept, rank = cache["ckv"][name].shape[1:]
             if mla_attend_tiles(a.n_heads, kept, rank):
@@ -255,6 +256,17 @@ def live_layers(plan, cache: dict, devices: tuple,
             if kv_attend_tiles(nkv, hd, kept):
                 out[layer] = ("kv", kept, kv_attend_block(kept, nkv))
     return out
+
+
+def _mixers(plan):
+    """(layer, name, mixer kind) of every block that has a mixer: the
+    stack's by their number, then the drafting block's (where the plan
+    has one) as one more layer behind the last, under its own name."""
+    out = [(layer, block_name(layer), plan.kinds(layer)[0])
+           for layer in range(len(plan.layers))]
+    if plan.draft is not None:
+        out.append((len(plan.layers), DRAFT_BLOCK, plan.draft_kinds[0]))
+    return [entry for entry in out if entry[2] is not None]
 
 
 def live_ingest(plan, rung: int, devices: tuple,
@@ -271,9 +283,7 @@ def live_ingest(plan, rung: int, devices: tuple,
     (``ENG_SELECT``'s ``blocks`` of a prefill go by that)."""
     if len(devices) != 1 or (lowered and devices[0].platform != "tpu"):
         return frozenset()
-    kinds = ((layer, plan.kinds(layer)[0])
-             for layer in range(len(plan.layers)))
-    return frozenset(layer for layer, a in kinds
+    return frozenset(layer for layer, _, a in _mixers(plan)
                      if isinstance(a, MlaKind) and ingest_tiles(a, rung))
 
 
@@ -451,7 +461,11 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     layer keeps every position too, but nothing a head: its RMS-normed
     latent row ``ckv``, ``(slots, max_len, kv_rank)``, the one rotary
     key every head shares ``kr``, ``(slots, max_len, rope_dim)``, and
-    its indexer's key ``ik``, ``(slots, max_len, index_dim)``. One
+    under an indexer its key ``ik``, ``(slots, max_len, index_dim)``. A
+    plan's drafting block keeps its mixer's rows beside the layers',
+    under ``plan.DRAFT_BLOCK``, and what a lane carries between ticks
+    beside the cursors: ``cur`` the last token it emitted and ``dr``
+    the token drafted to follow it, int32 a slot. One
     cursor a slot serves all: which ring entries and which latent rows
     are live follows from it alone, and a state needs none. A gated
     convolution keeps its ``conv`` tail alone, ``(slots, kernel - 1,
@@ -463,11 +477,10 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
     plan = plan_of(cfg)
     out: dict = {"k": {}, "v": {},
                  "pos": jnp.zeros((n_slots,), jnp.int32)}
-    for layer in range(len(plan.layers)):
-        a, _ = plan.kinds(layer)
-        name = block_name(layer)
-        if a is None:
-            continue
+    if plan.draft is not None:
+        out["cur"] = jnp.zeros((n_slots,), jnp.int32)
+        out["dr"] = jnp.zeros((n_slots,), jnp.int32)
+    for _, name, a in _mixers(plan):
         if isinstance(a, KdaKind):
             out.setdefault("state", {})[name] = jnp.zeros(
                 (n_slots, a.n_heads, a.head_dim, a.head_dim), jnp.float32)
@@ -491,8 +504,8 @@ def init_plan_cache(cfg: TransformerConfig, n_slots: int,
                 (n_slots, a.conv - 1, a.channels), cfg.dtype)
             continue
         if isinstance(a, MlaKind):
-            for key, width in (("ckv", a.kv_rank), ("kr", a.rope_dim),
-                               ("ik", a.index_dim)):
+            for key, width in zip(a.rows, (a.kv_rank, a.rope_dim,
+                                           a.index_dim)):
                 out.setdefault(key, {})[name] = jnp.zeros(
                     (n_slots, max_len, width), cfg.dtype)
             continue
@@ -516,22 +529,27 @@ _RECURRENT = {
                  mamba2_ingest),
     ConvKind: ("attn.conv", ("conv",), conv_decode, conv_ingest)}
 #: A layer kind that keeps rows of its own a position, not keys and
-#: values a head: the scope its ops carry, the cache entries that hold
-#: the rows, its decode step and its prompt ingestion.
-_LATENT = {
-    MlaKind: ("attn.mla", ("ckv", "kr", "ik"), mla_decode, mla_ingest)}
+#: values a head (the cache entries that hold them are the kind's own
+#: ``rows``): the scope its ops carry, its decode step and its prompt
+#: ingestion.
+_LATENT = {MlaKind: ("attn.mla", mla_decode, mla_ingest)}
+#: What a drafting plan's cache carries a slot beside its cursor.
+_LANE_STATE = ("pos", "cur", "dr")
 
 
 def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
                   cache: dict, row_pos: jax.Array, valid: jax.Array,
-                  slot=None, live=()):
+                  slot=None, live=(), hidden: bool = False):
     """The planned stack over (B, S) tokens, layer by layer (a layer's
     kinds are static, so each layer is its own code over its own
     parameters and its own cache). A block that is a mixer alone or an
     MLP alone runs its one norm and its one half, and adds once.
 
     ``slot`` None is the decode tick: S == 1, row b at position
-    ``row_pos[b]``; each layer writes its one new position (full: at
+    ``row_pos[b]`` (or, where every mixer is latent attention without
+    an indexer, ``plan.takes_window``, a verify window of S positions
+    a lane, row b's query s at ``row_pos[b] + s``, each seeing the rows
+    the ones before it wrote); each layer writes its one new position (full: at
     the cursor; window: at cursor mod W, rotary already applied) and
     attends over its cache; a delta-rule or state-space layer takes one
     recurrent step in every lane that ``valid`` marks and leaves the
@@ -557,21 +575,24 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     routed, assignments to held experts, to absent experts, held
     experts touched (both summed over expert layers), largest load of
     one expert], None for a stack without experts). Donated, the cache
-    is updated in place."""
-    from pbs_tpu.models.moe import (
-        expert_form, expert_piece, held_expert_ffn, mlp_ffn,
-        shared_expert_ffn)
+    is updated in place.
 
+    ``hidden`` (a plan that drafts for itself asks): a fourth result,
+    the stack's output after its final norm at every position, (B, S,
+    d), which the drafting block reads (:func:`_draft_forward`)."""
     plan = plan_of(cfg)
     B, S = tokens.shape
     dt, hd, nkv = cfg.dtype, cfg.head_dim, cfg.n_kv_heads
     decode = slot is None
-    if decode and S != 1:
+    if decode and S != 1 and not plan.takes_window:
         raise NotImplementedError(
-            "a planned stack decodes one position a tick: a window "
-            "layer's ring cannot take a multi-token verify window")
+            "this planned stack decodes one position a tick: a verify "
+            "window of several is written for a stack whose mixers are "
+            "all latent attention without an indexer (a ring takes one "
+            "position a tick, a recurrent state folds every token in, "
+            "an indexer chooses for one query a lane)")
     new = {key: dict(entries) for key, entries in cache.items()
-           if key != "pos"}
+           if key not in _LANE_STATE}
     ks, vs = new["k"], new["v"]
     T = max([cfg.max_seq] + [c.shape[1] for key in ("k", "ckv")
                              for c in new.get(key, {}).values()])
@@ -618,21 +639,8 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
         if m is None:
             continue
 
-        mp = block["mlp"]
-        h = rms_norm(x, mp["mlp_norm"], cfg.norm_eps)
-        if not m.n_experts:
-            with jax.named_scope("mlp.dense"):
-                y = mlp_ffn(h, mp["w1"], mp.get("w3"), mp["w2"], m.form,
-                            lambda rows, w: rows @ wload(w, dt))
-        else:
-            hf = h.reshape(B * S, -1)
-            forms.add(expert_form(expert_piece(B * S), hf.shape[1], m, dt))
-            y, c = held_expert_ffn(hf, mp, m, flat_valid, dt)
-            if m.shared_d_ff:
-                y = y + shared_expert_ffn(hf, mp, dt, m.form)
-            y = y.reshape(B, S, -1)
-            counts = jnp.concatenate(
-                [counts[:3] + c[:3], jnp.maximum(counts[3:], c[3:])])
+        y, counts = _mlp_half(m, block["mlp"], x, flat_valid, counts,
+                              forms, cfg.norm_eps, dt)
         x = x + y
 
     # One record a form of this program's expert products, as it is
@@ -641,21 +649,108 @@ def _plan_forward(cfg: TransformerConfig, params: dict, tokens: jax.Array,
     for form, rows in sorted(forms):
         with host_phase(f"experts.{form}", rows):
             pass
+    if hidden:
+        # normed at every position, for the drafting block; the logits
+        # of a prompt are still its last position's alone
+        out = x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if not decode:
         x = jax.lax.dynamic_index_in_dim(
             x[0], jnp.maximum(valid.sum() - 1, 0), 0, keepdims=False)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if not hidden:
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    logits = _head_logits(cfg, params, x)
+    route = jnp.concatenate(
+        [valid.sum().astype(jnp.int32)[None], counts]) \
+        if plan.routed else None
+    return (logits, new, route, out) if hidden else (logits, new, route)
+
+
+def _head_logits(cfg: TransformerConfig, params: dict, x: jax.Array):
+    """The head's float32 logits of normed rows x (..., d)."""
+    dt = cfg.dtype
     if cfg.tie_embeddings:
         # the embedding read where it lies, rows against rows: no
         # (d, vocab) copy of it is made
         logits = jnp.einsum("...d,vd->...v", x, wload(params["embed"], dt))
     else:
         logits = x @ wload(params["head"], dt)
-    logits = logits.astype(jnp.float32)
-    route = jnp.concatenate(
-        [valid.sum().astype(jnp.int32)[None], counts]) \
-        if plan.routed else None
-    return logits, new, route
+    return logits.astype(jnp.float32)
+
+
+def _mlp_half(m, mp: dict, x: jax.Array, flat_valid: jax.Array, counts,
+              forms: set, eps: float, dt):
+    """A block's MLP half on the stream x (B, S, d): its norm, then the
+    dense MLP or the held experts (with the shared one where the kind
+    has it) over the rows ``flat_valid`` (B * S,) marks. Returns (what
+    it adds to the stream, ``counts`` with this layer's folded in:
+    :func:`_plan_forward`'s ``route`` less its first entry); the form
+    an expert layer's products take is added to ``forms``."""
+    from pbs_tpu.models.moe import (
+        expert_form, expert_piece, held_expert_ffn, mlp_ffn,
+        shared_expert_ffn)
+
+    B, S, _ = x.shape
+    h = rms_norm(x, mp["mlp_norm"], eps)
+    if not m.n_experts:
+        with jax.named_scope("mlp.dense"):
+            return mlp_ffn(h, mp["w1"], mp.get("w3"), mp["w2"], m.form,
+                           lambda rows, w: rows @ wload(w, dt)), counts
+    hf = h.reshape(B * S, -1)
+    forms.add(expert_form(expert_piece(B * S), hf.shape[1], m, dt))
+    y, c = held_expert_ffn(hf, mp, m, flat_valid, dt)
+    if m.shared_d_ff:
+        y = y + shared_expert_ffn(hf, mp, dt, m.form)
+    return y.reshape(B, S, -1), jnp.concatenate(
+        [counts[:3] + c[:3], jnp.maximum(counts[3:], c[3:])])
+
+
+def _draft_forward(cfg: TransformerConfig, params: dict, hidden: jax.Array,
+                   tokens: jax.Array, new: dict, row_pos: jax.Array,
+                   valid: jax.Array, slot=None, live: bool = False):
+    """The plan's drafting block (``LayerPlan.draft``; DeepSeek-V3's
+    multi-token-prediction module, depth one) over (B, S) pairs: row
+    (b, s) is the stack's normed output ``hidden`` (B, S, d) at
+    position ``row_pos[b] + s`` beside ``tokens`` (B, S), the token
+    that *follows* that position:
+
+        u = [rmsnorm_e(embed(token)) ; rmsnorm_h(hidden)] W_eh
+        y = block(u)    (the plan's own mixer and MLP kinds, the mixer's
+                         rows under ``plan.DRAFT_BLOCK`` in the cache,
+                         row i at rotary position i, one cursor a slot)
+        draft logits = head(rmsnorm_s(y))
+
+    which predict the token after ``tokens``. ``slot`` None: a tick's
+    window at each lane's cursor; else one prompt's pairs from position
+    0 into that slot. ``valid`` (B, S) marks the pairs that are real
+    (the others change no row and route nowhere). ``new`` is
+    :func:`_plan_forward`'s cache dict, whose entries are replaced.
+    Returns (float32 logits (B, S, V), ``counts``: the block's expert
+    layer's, as :func:`_mlp_half` folds them from zero). The form its
+    expert products take is the stack's own at these rows, and is not
+    said again. Everything under the scope ``mtp.draft``."""
+    plan = plan_of(cfg)
+    a, m = plan.draft_kinds
+    B, S = tokens.shape
+    dt, eps = cfg.dtype, cfg.norm_eps
+    block = params["blocks"][DRAFT_BLOCK]
+    with jax.named_scope("mtp.draft"):
+        T = max(cfg.max_seq, new["ckv"][DRAFT_BLOCK].shape[1])
+        abs_pos = jnp.minimum(
+            row_pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :], T - 1)
+        tables = {a.rope: rope_table(a.rope, cfg.head_dim, T)}
+        u = jnp.concatenate(
+            [rms_norm(embed_rows(params["embed"], tokens, dt),
+                      block["enorm"], eps),
+             rms_norm(hidden, block["hnorm"], eps)], axis=-1) \
+            @ wload(block["eh_proj"], dt)
+        ap = block["attn"]
+        x = u + _latent_layer(
+            a, ap, rms_norm(u, ap["attn_norm"], eps), new, DRAFT_BLOCK,
+            row_pos, valid, abs_pos, tables, slot, eps, dt, live)
+        y, counts = _mlp_half(m, block["mlp"], x, valid.reshape(-1),
+                              jnp.zeros((4,), jnp.int32), set(), eps, dt)
+        x = rms_norm(x + y, block["head_norm"], eps)
+        return _head_logits(cfg, params, x), counts
 
 
 def _softmax_layer(a, ap: dict, h: jax.Array, ks: dict, vs: dict, name: str,
@@ -746,13 +841,16 @@ def _latent_layer(a, ap: dict, h: jax.Array, new: dict, name: str,
     the stream. Padding and idle lanes change no row. ``live``: the
     layer's attention streams live blocks (a decode tick's the lane's
     live rows, a prompt's the key blocks a block of queries sees)."""
-    scope, keys, step, ingest = _LATENT[type(a)]
+    scope, step, ingest = _LATENT[type(a)]
+    keys = a.rows
     cos, sin = (t[abs_pos] for t in tables[a.rope])
     rows = [new[key][name] for key in keys]
     with jax.named_scope(scope):
         if slot is None:
-            out, *rows = step(a, ap, h, *rows, row_pos, valid[:, 0], cos,
-                              sin, eps, dt, live)
+            # a tick's one position a lane, or a verify window's rows
+            active = valid[:, 0] if valid.shape[1] == 1 else valid
+            out, *rows = step(a, ap, h, rows, row_pos, active, cos, sin,
+                              eps, dt, live)
         else:
             out, *prompt = ingest(a, ap, h, valid, cos, sin, eps, dt, live)
             at = (slot, 0, 0)
@@ -799,8 +897,11 @@ class _ScanProgram(_LiveLayers):
     #: a window of positions can be cut from, installed into and
     #: verified over every layer's cache (prefix cache, speculation)
     windows = True
-    #: no layer chooses among the positions it keeps
+    #: no layer chooses among the positions it keeps, none keeps
+    #: latent rows, and no drafting block rides behind the stack
     select_topk = None
+    latent = False
+    drafts = False
 
     def __init__(self, cfg: TransformerConfig, mlp_fn=None, mesh=None):
         super().__init__(cfg, mesh)
@@ -882,6 +983,23 @@ class _PlannedProgram(_LiveLayers):
         #: the most positions a selecting layer's query attends (the
         #: engine's ``ENG_SELECT`` counts by it); None: no such layer
         self.select_topk = plan.select_topk
+        #: some layer keeps latent rows a position (``ENG_SELECT`` is
+        #: written for such a program whether it chooses or not)
+        self.latent = plan.latent
+        #: the plan has a drafting block: a tick verifies two positions
+        #: a lane and advances it by one token or two
+        #: (:meth:`draft_tick`), and the lanes' last tokens and drafts
+        #: live in the cache beside the cursors
+        self.drafts = plan.draft is not None
+        if self.drafts and not (plan.takes_window
+                                and plan.draft_kinds[1] is not None):
+            raise NotImplementedError(
+                "a plan drafts for itself where every mixer, its "
+                "drafting block's too, is latent attention without an "
+                "indexer (a verify window of two positions a lane: a "
+                "ring takes one position a tick, a recurrent state folds "
+                "every token in, an indexer chooses for one query a "
+                "lane; ROADMAP R7)")
         self.no_windows = (
             "a matrix-state layer keeps one float32 (head_dim, d_state) "
             "state a head and a convolution tail a slot, not positions, "
@@ -907,6 +1025,12 @@ class _PlannedProgram(_LiveLayers):
             "verify window would need the indexer's choice for k + 1 "
             "queries a lane (ROADMAP R5, R25)"
             if plan.select_topk is not None else
+            "a latent layer keeps a latent row and a rotary key a "
+            "position, not keys and values a head: a window is cut from "
+            "and installed into k and v alone (a decode's verify window "
+            "over latent rows is written, ``draft_tick``; a prompt "
+            "window's cut and install are not: ROADMAP R5)"
+            if plan.latent else
             "a window layer's ring takes one position a tick, and "
             "cutting a window from it or installing one is not written "
             "(ROADMAP R4)")
@@ -935,10 +1059,15 @@ class _PlannedProgram(_LiveLayers):
         logits, new, route = _plan_forward(
             self.cfg, params, last_tok[:, None], cache, cache["pos"],
             active[:, None], live=self.live_layers(cache))
-        return (logits, dict(new, pos=cache["pos"]),
+        return (logits, dict(new, **{key: cache[key] for key in _LANE_STATE
+                                     if key in cache}),
                 jnp.zeros((), jnp.float32), route)
 
     def ingest(self, params, cache, slot, prompt, plen):
+        if self.drafts:
+            last_logits, cache, route, _ = self.ingest_drafts(
+                params, cache, slot, prompt, plen)
+            return last_logits, cache, jnp.zeros((), jnp.float32), route
         valid = (jnp.arange(prompt.shape[0]) < plen)[None, :]
         last_logits, new, route = _plan_forward(
             self.cfg, params, prompt[None, :], cache,
@@ -946,6 +1075,107 @@ class _PlannedProgram(_LiveLayers):
             live=self.live_ingest(prompt.shape[0]))
         cache = dict(new, pos=cache["pos"].at[slot].set(plen))
         return last_logits, cache, jnp.zeros((), jnp.float32), route
+
+    def ingest_drafts(self, params, cache, slot, prompt, plen):
+        """The ingestion of one prompt by a plan that drafts: the
+        stack, the request's first token from its last position
+        (greedy), then the drafting block over the pairs ``(h_i,
+        t_{i+1})``, the prompt shifted by one with the first token
+        last; its rows stay in the slot beside the stack's and its last
+        row gives the first draft. The slot's cursor, first token and
+        draft are set in the cache. Returns (the stack's logits at the
+        last position, the cache, ``route`` with the drafting block's
+        expert layer counted in, the drafting block's logits at every
+        row of the prompt (S, V))."""
+        valid = (jnp.arange(prompt.shape[0]) < plen)[None, :]
+        live = self.live_ingest(prompt.shape[0])
+        zero = jnp.zeros((1,), jnp.int32)
+        last_logits, new, route, hidden = _plan_forward(
+            self.cfg, params, prompt[None, :], cache, zero, valid,
+            slot=slot, live=live, hidden=True)
+        first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        last = jnp.maximum(plen - 1, 0)
+        follows = jnp.roll(prompt, -1).at[last].set(first)
+        draft_logits, counts = _draft_forward(
+            self.cfg, params, hidden, follows[None, :], new, zero, valid,
+            slot=slot, live=len(plan_of(self.cfg).layers) in live)
+        draft = jnp.argmax(jax.lax.dynamic_index_in_dim(
+            draft_logits[0], last, 0, keepdims=False), -1).astype(jnp.int32)
+        cache = dict(new, pos=cache["pos"].at[slot].set(plen),
+                     cur=cache["cur"].at[slot].set(first),
+                     dr=cache["dr"].at[slot].set(draft))
+        return (last_logits, cache, _route_with(route, counts),
+                draft_logits[0])
+
+    def draft_tick(self, params, cache, active):
+        """One tick of a plan that drafts for itself, greedy, every
+        shape static. A lane carries its cursor ``p`` (rows ``0..p-1``
+        valid in every layer and in the drafting block alike), its last
+        emitted token ``cur = t_p`` and its draft ``dr`` for
+        ``t_{p+1}``, all in ``cache``; ``active`` (B,) the lanes that
+        hold a request.
+
+        1. verify: the stack over ``[cur, dr]`` at ``p, p + 1`` (both
+           rows written in every layer, the second query seeing the
+           first's row): logits ``g0, g1``, normed outputs ``h_p,
+           h_{p+1}``;
+        2. accept (``mtp.verify``): ``a = argmax g0 == dr``; the lane
+           emits ``t_{p+1} = argmax g0`` and, where ``a``, ``t_{p+2} =
+           argmax g1``; its cursor moves to ``p + 1 + a`` (a rejected
+           row ``p + 1`` is not counted, and the next tick writes over
+           it);
+        3. draft (``mtp.draft``): the drafting block over the pairs
+           ``(h_p, t_{p+1})`` at row ``p`` and ``(h_{p+1}, t_{p+2})``
+           at row ``p + 1`` (the second out of its cache and its
+           experts where not accepted); the new ``dr`` is its argmax
+           at row ``p + a``.
+
+        Returns (``toks`` (B, 2) int32: what each lane emits, -1 where
+        it emits nothing: a lane that is not active, and the second of
+        a lane that did not accept; the cache, lane state advanced;
+        ``route`` as :func:`_plan_forward`'s with the drafting block's
+        expert layer counted in; the window's logits (B, 2, V) and the
+        drafting block's (B, 2, V), float32: what the tests hold to
+        the reference, and a caller that drops them pays nothing)."""
+        cfg, pos = self.cfg, cache["pos"]
+        live = self.live_layers(cache)
+        _say_attention(self.live_layers(cache, lowered=True),
+                       len(cache["k"]))
+        cur, dr = cache["cur"], cache["dr"]
+        window = active[:, None] & jnp.ones((1, 2), bool)
+        logits, new, route, hidden = _plan_forward(
+            cfg, params, jnp.stack([cur, dr], axis=1), cache, pos, window,
+            live=live, hidden=True)
+        with jax.named_scope("mtp.verify"):
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (B, 2)
+            toks, took, last = greedy_accept_window(dr[:, None], g)
+            took = jnp.where(active, took, 0)
+            emitted = jnp.arange(2)[None, :] <= took[:, None]
+            toks = jnp.where(active[:, None] & emitted, toks, -1)
+        # the pairs' tokens are the window's own argmaxes: g0 = t_{p+1}
+        # always, g1 = t_{p+2} where the draft was accepted
+        draft_logits, counts = _draft_forward(
+            cfg, params, hidden, g, new, pos,
+            active[:, None] & emitted, live=len(plan_of(cfg).layers) in live)
+        with jax.named_scope("mtp.verify"):
+            drafted = jnp.argmax(draft_logits, axis=-1).astype(jnp.int32)
+            draft = jnp.take_along_axis(drafted, took[:, None], axis=1)[:, 0]
+            cache = dict(
+                new, pos=pos + jnp.where(active, took + 1, 0),
+                cur=jnp.where(active, last, cur),
+                dr=jnp.where(active, draft, dr))
+        return (toks, cache, _route_with(route, counts), logits,
+                draft_logits)
+
+
+def _route_with(route, counts):
+    """A stack's ``route`` with the drafting block's expert layer's
+    ``counts`` folded in: assignments and experts touched added, the
+    largest load the larger (the tokens routed stay the stack's)."""
+    if route is None:
+        return None
+    return jnp.concatenate([route[:1], route[1:4] + counts[:3],
+                            jnp.maximum(route[4:], counts[3:])])
 
 
 def slot_program(cfg: TransformerConfig, mlp_fn=None, mesh=None):

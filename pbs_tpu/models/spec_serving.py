@@ -1,8 +1,17 @@
 """Continuous batching with speculative decoding: the slot engine
-(``models/serving.py``) with a draft model beside the target.
+(``models/serving.py``) with a *second model* as the draft beside the
+target.
 
 No benchmark cell runs it and it takes uniform layer stacks only
-(ROADMAP D14 decides its life: this file and ``models/speculative.py``).
+(ROADMAP D14 decides its life). A model that drafts for *itself* (a
+plan with a drafting block: ``plan.LayerPlan.draft``, DeepSeek-V3's
+multi-token-prediction module) is not this engine's: that lives in
+``ContinuousBatcher``'s own pipelined tick, switched by the plan, and
+has a cell (docs/SERVING.md "The drafting tick"). The two share the
+window's acceptance (``models/speculative.greedy_accept_window``,
+which therefore stays whatever becomes of this file) and duplicate the
+rest: the rows of room ``submit`` keeps, the greedy-only refusal, the
+booking of several tokens a lane a tick (ROADMAP D14 lists it).
 """
 
 from __future__ import annotations

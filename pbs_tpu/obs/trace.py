@@ -254,6 +254,20 @@ class Ev(enum.IntEnum):
     #                      (attn.kda, attn.mamba, attn.mamba2, attn.conv)
     #                      add no record: their bytes follow from the
     #                      busy lanes
+    ENG_DRAFT = 0x0A0A  # one a decode of a program that drafts for
+    #                     itself (a plan with a drafting block), written
+    #                     when the host READS that decode and books its
+    #                     tokens (ContinuousBatcher._book_window), ts
+    #                     the booking's own: that is when the host
+    #                     learns what the tick accepted. args: tick (of
+    #                     the step() that read it), lanes (that the
+    #                     decode ran), drafts proposed (one a lane of
+    #                     them that still holds the request it held
+    #                     then: the others book nothing), drafts
+    #                     accepted (lanes the device advanced by two),
+    #                     tokens booked, tokens dropped (computed behind
+    #                     the token that finished a request: past its
+    #                     budget or behind its EOS; never served)
     # executed step (0x0Bxx) — TpuBackend._invoke (telemetry/source.py):
     # one record per host-callable unit, inside its SCHED_PICK..DESCHED.
     EXEC_STEP = 0x0B01  # args: ctx_slot, dispatch_ns (fn returns),

@@ -42,6 +42,17 @@ against 0.89: PERF.md section 6, PR 42). The choice comes as an int32
 row a lane, one ``(1, tk)`` slab a block broadcast over the heads on
 the chip.
 
+**A layer without an indexer, and a window of queries a lane**
+(:func:`mla_attend_window`): where nothing chooses, what a query
+attends is every row up to its own, so the mask is the block's
+positions against the lane's cursor, formed on the chip from the
+scalar-prefetched cursors, and no ``(lanes, T)`` choice is read. A
+lane's ``S`` queries (a verify window: the token at the cursor and the
+drafted ones behind it, query ``s`` at position ``cursor + s``) ride as
+``S x heads`` rows of one product against the block, row ``s x heads +
+h`` masked at its own position: the lane's rows are read once for all
+of them, and the pipeline runs to the block the last query is in.
+
 At GLM-5's cell (64 lanes, 64 heads, 10,240 positions of 512 + 64,
 cursors 3,072-9,700) a layer takes 0.89 ms where the same blocks
 through the same pipeline with no arithmetic take 0.74 (713 GB/s) and
@@ -58,7 +69,8 @@ import jax.numpy as jnp
 
 from pbs_tpu.ops.live_attend import live_attend
 
-__all__ = ["attend_block", "mla_attend", "mla_attend_tiles"]
+__all__ = ["attend_block", "mla_attend", "mla_attend_tiles",
+           "mla_attend_window"]
 
 #: Positions a block, the widest that divides the cache's length: a
 #: ``ckv`` block of 1 MiB at a latent of 512, double-buffered (the
@@ -124,3 +136,53 @@ def mla_attend(q_lat, q_r, ckv, kr, chosen, row_pos, *, scale: float,
           lambda lane, block: (lane, 0, block))],
         blocks=T // tk, out=jax.ShapeDtypeStruct((B, H, R), ckv.dtype),
         vmem_limit_bytes=32 << 20, name="mla_attend", interpret=interpret)
+
+
+def _window_block(b, j, pos_ref, q_ref, qr_ref, ckv_ref, kr_ref, *,
+                  scale: float, heads: int, tk: int):
+    """Block j of lane b: every head of every query of the lane's
+    window against the block's latent rows and rotary keys, a row live
+    up to the query's own position (query ``s``'s: ``pos[b] + s``)."""
+    rows = ckv_ref[0]                                           # (tk, R)
+    nt = (((1,), (1,)), ((), ()))
+    scores = (jax.lax.dot_general(q_ref[0], rows, nt,
+                                  preferred_element_type=_F32)
+              + jnp.dot(qr_ref[0], kr_ref[0],
+                        preferred_element_type=_F32)) \
+        * scale                                             # (S H, tk)
+    at = j * tk + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    query = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) // heads
+    return scores, at <= pos_ref[b] + query, rows
+
+
+def mla_attend_window(q_lat, q_r, ckv, kr, row_pos, *, scale: float,
+                      block: int | None = None, interpret: bool = False):
+    """What every head of each lane's ``S`` queries reads off the rows
+    up to the query's own position, no indexer between: ``q_lat`` (B,
+    S, H, kv_rank) and ``q_r`` (B, S, H, rope_dim), query ``s`` of lane
+    b at position ``row_pos[b] + s`` (whose rows are in the caches
+    already), ``ckv`` (B, T, kv_rank) and ``kr`` (B, T, rope_dim) as
+    they lie. Returns ``o_lat`` (B, S, H, kv_rank) in ``ckv``'s dtype.
+    ``models/mla.py::attend_rows`` under the causal mask is the same
+    function in ``jax.numpy``. Tiling, ``block`` and ``interpret`` as
+    :func:`mla_attend`'s."""
+    B, S, H, R = q_lat.shape
+    T, E = kr.shape[1:]
+    tk = block or attend_block(T)
+    if not tk or T % tk:
+        raise ValueError(f"a cache of {T} positions is not whole blocks "
+                         f"of {tk or BLOCKS}")
+    pos = row_pos.astype(jnp.int32)
+    # the block the window's last query is in
+    last = jnp.clip((pos + S - 1) // tk, 0, T // tk - 1)
+    out = live_attend(
+        functools.partial(_window_block, scale=scale, heads=H, tk=tk),
+        last, (pos,),
+        [q_lat.reshape(B, S * H, R), q_r.reshape(B, S * H, E)], [],
+        [(ckv, (1, tk, R), lambda lane, block, pos: (lane, block, 0)),
+         (jnp.swapaxes(kr, 1, 2), (1, E, tk),
+          lambda lane, block, pos: (lane, 0, block))],
+        blocks=T // tk, out=jax.ShapeDtypeStruct((B, S * H, R), ckv.dtype),
+        vmem_limit_bytes=32 << 20, name="mla_attend_window",
+        interpret=interpret)
+    return out.reshape(B, S, H, R)

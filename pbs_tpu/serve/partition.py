@@ -54,7 +54,7 @@ SpecEntry = Any
 PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     (r"^embed$", (-1, None)),
     (r"(^|/)(attn_norm|mlp_norm|final_norm|o_norm|dt_norm|b_norm|c_norm"
-     r"|q_norm|k_norm|kv_norm|ik_norm|g_norm)$", ()),
+     r"|q_norm|k_norm|kv_norm|ik_norm|g_norm|enorm|hnorm|head_norm)$", ()),
     (r"/w[qkv]$", (None, None, -1)),
     (r"/wo$", (None, -1, None)),
     (r"/w[13]$", (None, None, -1)),
@@ -122,6 +122,13 @@ PARTITION_RULES: tuple[tuple[str, tuple], ...] = (
     # norms' rule).
     (r"/(wq_b|wkv_b|wi_q|wi_w)$", (None, -1)),
     (r"/(wq_a|wkv_a|wi_k|ik_bias)$", ()),
+    # A plan's drafting block (``LayerPlan.draft``) lies under
+    # ``blocks/mtp/``: its mixer and its MLP by their kinds' paths
+    # above; its three norms (``enorm``, ``hnorm``, ``head_norm``) by
+    # the norms' rule; the ``(2 d, d)`` projection of the embedding
+    # beside the stack's output by column, like any projection into the
+    # stream's width.
+    (r"/eh_proj$", (None, -1)),
 )
 
 #: The canonical param paths the table must cover (the dense
@@ -213,6 +220,12 @@ TEMPLATE_PATHS: tuple[str, ...] = (
     "blocks/N/attn/ik_norm",
     "blocks/N/attn/ik_bias",
     "blocks/N/attn/wi_w",
+    # a drafting block's own leaves (its mixer's and its MLP's are the
+    # paths above, under ``blocks/mtp/``)
+    "blocks/mtp/enorm",
+    "blocks/mtp/hnorm",
+    "blocks/mtp/eh_proj",
+    "blocks/mtp/head_norm",
 )
 
 

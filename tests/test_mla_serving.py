@@ -365,7 +365,7 @@ def test_an_idle_lane_attends_its_first_row_alone(monkeypatch):
         jax.random.normal(k, (B, MAX_LEN, w), jnp.float32)
         for k, w in zip(ks[1:], (a.kv_rank, a.rope_dim, a.index_dim)))
     out, *_ = mla.mla_decode(
-        a, ap, h, ckv, kr, ik, jnp.asarray([50, 70, 33]),
+        a, ap, h, (ckv, kr, ik), jnp.asarray([50, 70, 33]),
         jnp.asarray([True, False, True]), jnp.ones((B, 1, half)),
         jnp.zeros((B, 1, half)), cfg.norm_eps, jnp.float32)
     assert seen["at"].tolist() == [50, 0, 33]

@@ -74,7 +74,8 @@ def planned_params():
              P.MlpKind("relu2", 8, n_experts=4, top_k=2, held=(0, 2),
                        shared_d_ff=16, scoring="sigmoid", form="relu2")),
         layers=((0, 0), (1, 1), (2, 2), (3, 0), (4, 0), (5, None),
-                (None, 3), (6, 0), (7, 0)))
+                (None, 3), (6, 0), (7, 0)),
+        draft=(4, 2))
     cfg = TransformerConfig(**dict(TINY, n_layers=9, head_size=8,
                                    layer_plan=plan))
     return P.init_plan_params(cfg, jax.random.PRNGKey(0))
@@ -96,8 +97,9 @@ def moe_model():
 
 
 def tree_paths(*trees):
-    """Leaf paths of the trees, a planned tree's layer number as N."""
-    return [re.sub(r"^blocks/\d+/", "blocks/N/", p)
+    """Leaf paths of the trees, a planned tree's layer number as N (a
+    drafting block's mixer and MLP are a layer's)."""
+    return [re.sub(r"^blocks/(\d+|mtp(?=/(attn|mlp)/))/", "blocks/N/", p)
             for tree in trees for p, _ in iter_leaf_paths(tree)]
 
 

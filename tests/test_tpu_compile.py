@@ -637,6 +637,69 @@ def test_the_convolution_cells_programs_compile_and_fit(topo, name):
         assert beyond < 256 << 20, beyond >> 20
 
 
+# DeepSeek-V3's cell whole (benchmarks/configs/deepseek-v3.json: one
+# dense and four expert layers at the published widths, 16 of 256
+# experts, an eighth of the vocabulary, the drafting module behind
+# them; 128 slots of 2,560 positions, prompts at 512 and 1,024 rows),
+# through the family's own sizing programs: the drafting tick and the
+# prefill at its wider rung, each donating the cache.
+MTP_PROGRAMS = ("decode L=5+mtp", "prefill L=5+mtp rung=1024")
+
+
+@pytest.mark.parametrize("name", MTP_PROGRAMS)
+def test_the_drafting_cells_programs_compile_and_fit(topo, name):
+    """Each compiles for the chip, donates the cache whole (a latent
+    row and a rotary key a position in six layers, the drafting block's
+    among them: 2.11 GiB, and no indexer's key) and fits under the
+    chip's usable 15.75 GiB with its 10.44 GiB of weights. **The tick's
+    six latent layers are the one-pass window kernel** (``ops/
+    mla_attend.py::mla_attend_window``: a lane's two queries, 256 rows
+    of heads, over one read of its live rows; the cache operand a
+    bitcast of what lies there), one of them under ``mtp.draft``; the
+    accept-and-advance arithmetic carries ``mtp.verify``; 256 rows on
+    16 held experts go through every held expert (4,096 pairs), and
+    beyond its arguments the tick needs under 128 MiB. The prompt
+    forward's attention is the ``jax.numpy`` form (heads of 192 are no
+    whole rows of lanes: ``ingest_attend_tiles``), its grouped products
+    the Pallas kernel."""
+    c, progs = _cell_programs(topo, "deepseek-v3")
+    prog = next(p for p in progs if p["name"] == name)
+    compiled = prog["fn"].lower(*prog["args"]).compile()
+    sv = c["serve"]
+    slots, T = sv["slots"], sv["max_len"]
+    assert c["sizing"]["cache_bytes"] == 6 * slots * T * (512 + 64) * 2
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= c["sizing"]["cache_bytes"]
+    assert m.argument_size_in_bytes < c["sizing"]["weights_bytes"] \
+        + c["sizing"]["cache_bytes"] + (1 << 20)
+    assert m.peak_memory_in_bytes < V5E_USABLE
+    beyond = m.temp_size_in_bytes + m.output_size_in_bytes \
+        - m.alias_size_in_bytes
+    hlo = compiled.as_text()
+    kernels = [ln for ln in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln
+               and "mla_attend_window" in ln]
+    assert "ragged-dot" not in hlo
+    if name.startswith("decode"):
+        assert len(kernels) == 6
+        assert sum("/mtp.draft/" in ln for ln in kernels) == 1
+        assert all(re.search(r'op_name="[^"]*/attn\.mla/mla\.attend/',
+                             ln) for ln in kernels)
+        assert re.search(r'op_name="[^"]*/mtp\.verify/', hlo)
+        assert not _expert_kernels(hlo)        # every held expert
+        # no float32 scores a lane and a position long exist: the
+        # window's are the kernel's, on the chip
+        assert not [shape for _, _, shape in materialised(hlo)
+                    if shape.startswith("f32[") and T in dims(shape)
+                    and slots in dims(shape)]
+        assert beyond < 128 << 20, beyond >> 20
+    else:
+        assert not kernels and "mla_ingest_attend" not in hlo
+        assert len(_expert_kernels(hlo)) == 3 * 5
+        assert re.search(r'op_name="[^"]*/mtp\.draft/attn\.mla/', hlo)
+        assert beyond < 512 << 20, beyond >> 20
+
+
 def _expert_kernels(hlo: str) -> list[str]:
     """The compiled program's calls of the Pallas grouped product
     (``ops/grouped_matmul.py``), each under the ``moe.experts`` scope."""

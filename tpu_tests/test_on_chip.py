@@ -673,6 +673,98 @@ def test_mla_attend_compiled_at_the_cell():
     assert ms["mla_attend"] < ms["jax.numpy form"]
 
 
+def test_mla_attend_window_compiled_at_the_drafting_cell():
+    """The one-pass latent attention of a drafting tick's verify window
+    (``ops/mla_attend.py::mla_attend_window``) compiled through Mosaic
+    at the drafting cell's size: a layer's 128 lanes of 2,560 kept
+    positions (latent 512, rotary key 64, bfloat16) under 128 heads,
+    **two queries a lane** (256 rows of one product, the second seeing
+    the row the first wrote), cursors drawn 64-2,500 (some a row short
+    of a block's end, so that the window straddles two blocks) and two
+    lanes at rest, no choice: against ``attend_rows`` under the causal
+    mask (``mla.window_rows``, the ``jax.numpy`` form a CPU runs) and,
+    for eight sampled (lane, query, head) rows, against the softmax in
+    float64 on the host over the same bfloat16 rows (2^-6 of the row's
+    largest entry); the blocks past every window poisoned with NaN,
+    which the kernel never reads; then six layers of it timed beside
+    the ``jax.numpy`` form."""
+    import time
+
+    from pbs_tpu.models.mla import window_rows
+    from pbs_tpu.ops.mla_attend import attend_block, mla_attend_window
+
+    B, S, H, T, R, E, layers = 128, 2, 128, 2560, 512, 64, 6
+    bf16 = jnp.bfloat16
+    scale = 1.3689 ** 2 / np.sqrt(192.0)
+    tk = attend_block(T)
+    assert tk == 512
+    rng = np.random.default_rng(50)
+    cursors = rng.integers(64, 2500, B)
+    cursors[[3, 77]] = (tk - 1, 3 * tk - 1)     # the window straddles
+    cursors[[9, 100]] = 0                       # lanes at rest
+    row_pos = jnp.asarray(cursors, jnp.int32)
+
+    def layer(seed):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        q_lat, q_r, ckv, kr = (
+            jax.random.normal(k, s, bf16) for k, s in zip(
+                ks, ((B, S, H, R), (B, S, H, E), (B, T, R), (B, T, E))))
+        return q_lat * 0.05, q_r, ckv, kr
+
+    kernel = jax.jit(lambda *a: mla_attend_window(*a, scale=scale))
+    numpy_way = jax.jit(lambda *a: window_rows(*a, scale=scale))
+    args = layer(1)
+    got, ref = kernel(*args, row_pos), numpy_way(*args, row_pos)
+    gap = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - ref.astype(jnp.float32)))
+                / jnp.max(jnp.abs(ref.astype(jnp.float32))))
+    worst = {"mla_attend_window": 0.0, "jax.numpy form": 0.0}
+    for b, q, h in zip(rng.integers(0, B, 8), rng.integers(0, S, 8),
+                       rng.integers(0, H, 8)):
+        q_lat, q_r, ckv, kr = (np.asarray(t[b], np.float64) for t in args)
+        n = cursors[b] + q + 1
+        s = (ckv[:n] @ q_lat[q, h] + kr[:n] @ q_r[q, h]) * scale
+        p = np.exp(s - s.max())
+        want = (p / p.sum()) @ ckv[:n]
+        for name, out in (("mla_attend_window", got),
+                          ("jax.numpy form", ref)):
+            err = np.abs(np.asarray(out[b, q, h], np.float64) - want).max() \
+                / np.abs(want).max()
+            worst[name] = max(worst[name], float(err))
+    print(f"window kernel against attend_rows {gap:.2e}; against float64, "
+          "eight (lane, query, head) rows: " + ", ".join(
+              f"{n} {g:.2e}" for n, g in worst.items()))
+    assert gap < 2 ** -5 and worst["mla_attend_window"] < 2 ** -6, worst
+    dead = (jnp.arange(T)[None, :] // tk
+            > (row_pos[:, None] + S - 1) // tk)[..., None]
+    poisoned = kernel(args[0], args[1], jnp.where(dead, jnp.nan, args[2]),
+                      jnp.where(dead, jnp.nan, args[3]), row_pos)
+    assert bool(jnp.array_equal(poisoned, got))
+    del got, ref, poisoned, args, q_lat, q_r, ckv, kr
+
+    def six(attend):
+        return jax.jit(lambda ls, pos: [attend(*l, pos, scale=scale)
+                                        for l in ls])
+
+    ls = [layer(10 + i) for i in range(layers)]
+    ms = {}
+    for name, attend in (("mla_attend_window", mla_attend_window),
+                         ("jax.numpy form", window_rows)):
+        fn = six(attend)
+        jax.block_until_ready(fn(ls, row_pos))           # compile, warm
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = fn(ls, row_pos)
+        jax.block_until_ready(out)
+        ms[name] = (time.perf_counter() - t0) / 20 * 1e3
+    blocks = int(((cursors + S - 1) // tk + 1).sum())
+    rows = int((cursors + S).sum())
+    print(f"six layers' window attention at (128 x 2, 2560, 512 + 64), "
+          f"{blocks} of {B * T // tk} blocks of {tk} live ({rows} rows), "
+          f"ms a call: " + ", ".join(f"{n} {t:.2f}" for n, t in ms.items()))
+    assert ms["mla_attend_window"] < ms["jax.numpy form"]
+
+
 def test_mla_ingest_attend_compiled_at_the_cell():
     """The one-pass latent attention of a prompt's ingestion
     (``ops/mla_ingest_attend.py``) compiled through Mosaic at the
