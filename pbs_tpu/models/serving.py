@@ -85,6 +85,24 @@ class _InFlight:
     lanes: list[tuple[int, int]]  # (slot, request id)
 
 
+@dataclasses.dataclass
+class _Admission:
+    """A request seated in its slot whose first token is enqueued and
+    not yet read: what the prompt forward will send to the host, and
+    the stamps its ``ENG_PREFILL`` and ``ENG_ADMIT`` are written from
+    once the token is there."""
+    slot: int
+    rid: int
+    plen: int
+    rows: int         # the rung the forward ran at; 0: a prefix hit
+    first: jax.Array  # the token, the program's route behind it
+    extra: jax.Array | None  # the FFN's auxiliary sum (None: a hit)
+    wait_ns: int      # submit -> slot, on the engine's latency clock
+    t_admit: int
+    t_prefill: int
+    t_dispatched: int
+
+
 class ContinuousBatcher:
     """The slot engine. Host-side control, two compiled programs.
 
@@ -106,9 +124,13 @@ class ContinuousBatcher:
     tick"): a ``step()`` enqueues its decode and then books the decode
     of the ``step()`` before it, so what it returns, and what
     ``slot_tokens`` holds when it returns, is one dispatch behind what
-    the device has run. ``has_work()`` stays true until the last tick
-    is booked; ``settle()`` books it now; ``step_settled()`` is a tick
-    that leaves nothing in flight.
+    the device has run. An admission rides in that pipeline: its prompt
+    forward is enqueued behind the decode in flight, leaves the first
+    token on the device for the decode this call enqueues behind it,
+    and the host reads it (and books it, so ``slot_tokens`` holds it
+    when the call returns) once both are on the queue. ``has_work()``
+    stays true until the last tick is booked; ``settle()`` books it
+    now; ``step_settled()`` is a tick that leaves nothing in flight.
 
     **A plan with a drafting block drafts for itself**
     (``program.drafts``; docs/SERVING.md "The drafting tick"): the
@@ -232,6 +254,7 @@ class ContinuousBatcher:
         self._settling = False  # inside step_settled()
         self.ticks_overlapped = 0
         self.ticks_settled = 0
+        self.admissions_overlapped = 0  # forwards enqueued behind a decode
         self.steps = 0
         self.tokens_emitted = 0
         self.requests_completed = 0
@@ -272,21 +295,31 @@ class ContinuousBatcher:
         self.prefill_rows = 0  # rows they ran at (each one's rung)
         self.prefill_prompt_tokens = 0  # rows of those that were prompt
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def _prefill(params, cache, slot, prompt, plen, key):
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def _prefill(params, cache, prev_tok, slot, prompt, plen, key):
             """Write one request's prompt into ``slot`` and sample its
             first token. prompt: (rung,) padded; plen: real length.
-            Also returns the last-position logits (for the prefix
-            cache)."""
+            ``prev_tok`` is the lanes' token vector as the decode keeps
+            it on the device: it comes back with the first token at
+            ``slot``, where the decode enqueued behind this forward
+            takes it (``_LANE_CARRY``) before the host has read it.
+            Also returns the first token as what goes to the host (a
+            routing program's ``route`` rides behind it) and the
+            last-position logits (for the prefix cache)."""
             last_logits, cache, extra, route = self.program.ingest(
                 params, cache, slot, prompt, plen)
-            # a drafting program took the (greedy) first token itself,
-            # to draft the one behind it
-            first = cache["cur"][slot] if self._window > 1 else _sample(
-                last_logits[None, :], key, self.temperature)[0]
+            if self._window > 1:
+                # a drafting program took the (greedy) first token
+                # itself, to draft the one behind it: both are in the
+                # cache, where its decode reads them
+                first, tok = cache["cur"][slot], prev_tok
+            else:
+                first = _sample(
+                    last_logits[None, :], key, self.temperature)[0]
+                tok = prev_tok.at[slot].set(first.astype(prev_tok.dtype))
             if route is not None:  # rides to the host with the token
                 first = jnp.concatenate([first[None], route])
-            return first, last_logits, cache, extra
+            return tok, first, last_logits, cache, extra
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def _install(cache, slot, kwin, vwin, plen):
@@ -351,24 +384,12 @@ class ContinuousBatcher:
         # A recurrent state has no cursor to hide behind: a prompt is
         # ingested from a zero state over whatever the slot held, and a
         # lane that is not active keeps its state bit for bit.
-        # Every rung is its own instance of the prefill: all of them
-        # now, so that none compiles under a request.
         wk = jax.random.PRNGKey(0)
-        for rung in self.rungs:
-            self.cache = self._build(
-                f"eng.prefill@{rung}", rung, lambda: _prefill(
-                    self.params, self.cache, 0,
-                    jnp.zeros((rung,), jnp.int32), 0, wk)[2])
-        if prefix_cache_size:
-            win = jnp.zeros((cfg.n_layers, 1, self.bucket,
-                             cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
-            self.cache = self._build(
-                "eng.install", self.bucket,
-                lambda: _install(self.cache, 0, win, win, 0))
         # The decode twice, the second on the first's own tokens: what
-        # every tick after the first is handed (an output of the
-        # program, not an array the host made) is then a signature this
-        # warm-up has met, whatever sharding or commitment it carries.
+        # every tick after the first is handed (an output of a program,
+        # not an array the host made) is then a signature this warm-up
+        # has met, whatever sharding or commitment it carries. The
+        # prefills below take that vector and hand it back in turn.
         off = jnp.full((n_slots,), _LANE_OFF, jnp.int32)
 
         def _warm_decode():
@@ -377,6 +398,19 @@ class ContinuousBatcher:
 
         self._dev_tok, _, self.cache, _ = self._build(
             "eng.decode", n_slots, _warm_decode)
+        # Every rung is its own instance of the prefill: all of them
+        # now, so that none compiles under a request.
+        for rung in self.rungs:
+            self._dev_tok, _, _, self.cache, _ = self._build(
+                f"eng.prefill@{rung}", rung, lambda: _prefill(
+                    self.params, self.cache, self._dev_tok, 0,
+                    jnp.zeros((rung,), jnp.int32), 0, wk))
+        if prefix_cache_size:
+            win = jnp.zeros((cfg.n_layers, 1, self.bucket,
+                             cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+            self.cache = self._build(
+                "eng.install", self.bucket,
+                lambda: _install(self.cache, 0, win, win, 0))
         # The key split of every tick and admission is two small eager
         # programs: without this the first request built them.
         self._build("eng.keysplit", 2, lambda: tuple(jax.random.split(wk)))
@@ -510,25 +544,45 @@ class ContinuousBatcher:
 
     # -- the engine tick --------------------------------------------------
 
-    def _admit(self) -> None:
+    def _admit(self, unread: list,
+               done: list[Completion]) -> list[np.ndarray]:
+        """Seat waiting requests in free slots and enqueue their prompt
+        forwards behind whatever is ``unread`` (the decode in flight).
+        The last admission is left unread, appended to ``unread``: the
+        caller enqueues its decode behind it and lands them together.
+        An earlier one of the same call is landed before the next is
+        dispatched, so that each forward's execution lies inside its
+        own ``ENG_PREFILL`` record and no other's
+        (``benchmarks/readers/bucket_prefill_ms.py`` joins them so);
+        so is a prefix hit, whose first token no program leaves on the
+        device. Returns the routes of the decodes it landed."""
         # (slot, padded_prompt, plen) of this tick's admissions, each
-        # padded to its rung — the hook subclasses use to mirror work
+        # padded to its rung: the hook subclasses use to mirror work
         # per new tenant (the speculative engine draft-prefills the
         # same prompt, at the same rung by its shape).
         self._admitted = []
-        for slot in range(self.n_slots):
-            if self.active[slot] or not self.queue:
-                continue
+        routes: list[np.ndarray] = []
+        for slot in np.flatnonzero(~self.active)[:len(self.queue)]:
+            if unread and isinstance(unread[-1], _Admission):
+                routes += self._land(unread, done)[1]
+            behind = bool(unread)  # the decode in flight, if any
             with _span("pbst.eng.admit"):
-                self._admit_one(slot)
+                unread.append(self._admit_one(int(slot)))
+            if unread[-1].rows:
+                self.admissions_overlapped += behind
+            else:
+                routes += self._land(unread, done)[1]
+        return routes
 
     def _rung(self, plen: int) -> int:
         """The padded length a prompt of ``plen`` tokens runs at."""
         return next(r for r in self.rungs if r >= plen)
 
-    def _admit_one(self, slot: int) -> None:
+    def _admit_one(self, slot: int) -> _Admission:
+        """Seat the next request in ``slot`` and enqueue its prompt
+        forward (a prefix hit: the cached window's install). Its first
+        token is not waited for: ``_seat`` books it once it is read."""
         t_admit = _ns()
-        tick = self._tick_seq
         rid, prompt, max_new = self.queue.popleft()
         t_slot = self._now()
         if self.admit_hook is not None:
@@ -541,6 +595,7 @@ class ContinuousBatcher:
         pkey = prompt.tobytes()
         ent = (self._prefix_cache.get(pkey)
                if self.prefix_cache_size else None)
+        extra = None
         t_prefill = _ns()
         with _span("pbst.eng.prefill"):
             if ent is not None:
@@ -550,27 +605,16 @@ class ContinuousBatcher:
                 self.cache = self._install_fn(
                     self.cache, slot, ent["k"], ent["v"],
                     int(ent["plen"]))
-                t_dispatched = _ns()
-                first = int(_sample(
-                    ent["logits"][None, :], sub, self.temperature)[0])
+                first = _sample(
+                    ent["logits"][None, :], sub, self.temperature)
+                rows = 0
             else:
-                first, last_logits, self.cache, extra = \
+                self._dev_tok, first, last_logits, self.cache, extra = \
                     self._prefill_fn(
-                        self.params, self.cache, slot,
+                        self.params, self.cache, self._dev_tok, slot,
                         jnp.asarray(padded), len(prompt), sub)
-                t_dispatched = _ns()
-                first = np.asarray(first).ravel()
-                self._route_ev(t_prefill, first[1:])
-                self._select_ev(t_prefill, np.arange(1, len(prompt) + 1),
-                                rows)
-                first = int(first[0])
-                self._mlp_extra_sum += float(extra) / self.cfg.n_layers
-        t_synced = _ns()
-        self._ev(t_prefill, Ev.ENG_PREFILL, tick, rid, slot,
-                 t_dispatched - t_prefill, t_synced - t_dispatched,
-                 rows if ent is None else 0)
+        t_dispatched = _ns()
         if ent is None:
-            self._mlp_extra_n += 1
             self.prefill_count += 1
             self.prefill_rows += rows
             self.prefill_prompt_tokens += len(prompt)
@@ -585,22 +629,46 @@ class ContinuousBatcher:
                     logits=last_logits, plen=len(prompt))
                 while len(self._prefix_cache) > self.prefix_cache_size:
                     self._prefix_cache.popitem(last=False)
+        # The slot is the request's from here; its tokens follow.
         self.slot_req[slot] = rid
-        self.slot_tokens[slot] = [first]
+        self.slot_tokens[slot] = []
         self.slot_prompt_len[slot] = len(prompt)
         self.slot_remaining[slot] = max_new - 1
         self.slot_waited[slot] = (
             self.steps - self._submitted_step.pop(rid, self.steps))
-        now = self._now()
-        t_submit = self._submitted_t.pop(rid, now)
+        t_submit = self._submitted_t.pop(rid, t_slot)
         self.slot_submit_t[slot] = t_submit
-        self.slot_ttft[slot] = now - t_submit  # first token sampled
         self.active[slot] = True
-        self.last_tok[slot] = first
+        return _Admission(
+            slot, rid, len(prompt), rows, first, extra,
+            max(0, round((t_slot - t_submit) * 1e9)),
+            t_admit, t_prefill, t_dispatched)
+
+    def _seat(self, adm: _Admission, first: np.ndarray, t_host: int,
+              done: list[Completion]) -> None:
+        """Book an admission whose first token is on the host since
+        ``t_host``: its records (the ``ENG_PREFILL``'s wait and the
+        ``ENG_ADMIT``'s span both end at that stamp), its token, and
+        its retirement where that token was all it had to say (a
+        budget of one, an EOS)."""
+        slot, tick = adm.slot, self._tick_seq
+        if adm.rows:
+            self._route_ev(adm.t_prefill, first[1:])
+            self._select_ev(adm.t_prefill, np.arange(1, adm.plen + 1),
+                            adm.rows)
+        self._ev(adm.t_prefill, Ev.ENG_PREFILL, tick, adm.rid, slot,
+                 adm.t_dispatched - adm.t_prefill,
+                 t_host - adm.t_dispatched, adm.rows)
+        tok = int(first[0])
+        self.slot_tokens[slot] = [tok]
+        self.last_tok[slot] = tok
         self.tokens_emitted += 1
-        self._ev(t_admit, Ev.ENG_ADMIT, tick, rid, slot, len(prompt),
-                 max(0, round((t_slot - t_submit) * 1e9)),
-                 _ns() - t_admit)
+        # first token on the host
+        self.slot_ttft[slot] = self._now() - self.slot_submit_t[slot]
+        self._ev(adm.t_admit, Ev.ENG_ADMIT, tick, adm.rid, slot, adm.plen,
+                 adm.wait_ns, t_host - adm.t_admit)
+        if self.slot_remaining[slot] <= 0 or tok == self.eos_id:
+            done.append(self._retire(slot))
 
     def _retire(self, slot: int) -> Completion:
         lat = self._now() - float(self.slot_submit_t[slot])
@@ -626,24 +694,6 @@ class ContinuousBatcher:
         self.slot_tokens[slot] = []
         self.active[slot] = False
         return comp
-
-    def _pre_decode(self) -> tuple[list[Completion], bool]:
-        """The tick prologue every engine shares: admit waiting
-        requests, retire already-finished slots (prefill-only budgets,
-        EOS sampled at admission). Returns (completions, any_active);
-        when nothing is active the tick is already accounted."""
-        self._admit()
-        done: list[Completion] = []
-        for slot in range(self.n_slots):
-            if self.active[slot] and (
-                    self.slot_remaining[slot] <= 0
-                    or (self.eos_id is not None
-                        and self.last_tok[slot] == self.eos_id)):
-                done.append(self._retire(slot))
-        if not self.active.any():
-            self.steps += 1
-            return done, False
-        return done, True
 
     def _emit(self, slot: int, tok: int) -> bool:
         """Book one decoded token into ``slot``; True if the slot just
@@ -700,17 +750,19 @@ class ContinuousBatcher:
         fl, self._inflight = self._inflight, None
         if fl is not None:
             t = _ns()
-            self._route_ev(t, self._read_and_book(fl, done))
+            for route in self._land([fl], done)[1]:
+                self._route_ev(t, route)
         return done
 
     def _decoded(self, t_pre: int, t_enqueued: int, t_host: int,
                  overlapped: int = 0) -> None:
         """Close the tick's decode span (both engines): ``pre`` runs
-        from ``t_pre`` (admission and the key split are over) until the
-        program is enqueued, host-to-device copies included; ``sync``
-        until the tokens this call books are on the host; ``post``
-        until now, the emit and retire loops. ``overlapped``: the
-        program was enqueued while the decode before it was unread."""
+        from ``t_pre`` (the admissions' dispatches and the key split
+        are over) until the program is enqueued, host-to-device copies
+        included; ``sync`` until everything this call books is on the
+        host; ``post`` until now, the emit and retire loops.
+        ``overlapped``: the program was enqueued behind work the host
+        had not waited for (the decode before it, a prompt forward)."""
         self._ev(t_pre, Ev.ENG_DECODE, self._tick_seq,
                  t_enqueued - t_pre, t_host - t_enqueued, _ns() - t_host,
                  overlapped)
@@ -765,47 +817,74 @@ class ContinuousBatcher:
         self._ev(_ns(), Ev.ENG_DRAFT, self._tick_seq, len(fl.lanes),
                  proposed, accepted, booked, dropped)
 
-    def _read_and_book(self, fl: _InFlight,
-                       done: list[Completion]) -> np.ndarray:
-        """Both at once, where no stamp lies between them; the route."""
-        toks, route = self._read(fl)
-        self._book(fl, toks, done)
-        return route
+    def _read_first(self, adm: _Admission) -> np.ndarray:
+        """Wait for an admission's first token; the token on the host,
+        the program's route behind it."""
+        if adm.extra is not None:
+            self._mlp_extra_sum += float(adm.extra) / self.cfg.n_layers
+            self._mlp_extra_n += 1
+        return np.asarray(adm.first).ravel()
+
+    def _land(self, unread: list,
+              done: list[Completion]) -> tuple[int, list[np.ndarray]]:
+        """Wait for everything in ``unread`` (decodes and admissions,
+        in the order the device runs them), take ONE stamp when all of
+        it is on the host, and book it in that order: a decode's tokens
+        to its lanes, an admission's first token to its slot. Every
+        wait this call's records carry ends at that stamp. Empties
+        ``unread``; returns the stamp and the decodes' routes."""
+        with _span("pbst.eng.sync"):
+            got = [self._read(u) if isinstance(u, _InFlight)
+                   else self._read_first(u) for u in unread]
+        t_host = _ns()
+        routes = []
+        for u, read in zip(unread, got):
+            if isinstance(u, _InFlight):
+                toks, route = read
+                self._book(u, toks, done)
+                routes.append(route)
+            else:
+                self._seat(u, read, t_host, done)
+        unread.clear()
+        return t_host, routes
 
     def _step(self) -> list[Completion]:
         done: list[Completion] = []
-        fl, self._inflight = self._inflight, None
-        routes = []  # of the decodes this call reads
-        if fl is not None and (self._settling or (
-                self.queue and not self.active.all())):
-            # An admission ends in a host read of the prefill's first
-            # token, which waits for everything enqueued before it:
-            # book the decode in flight first, so that its tokens are
-            # not stamped behind a prefill they did not wait for. The
-            # wait lies in the tick's rest, not in ``sync``. (A settled
-            # tick that finds a decode in flight books it here too.)
-            routes.append(self._read_and_book(fl, done))
-            fl = None
-        retired, any_active = self._pre_decode()
-        done += retired
+        # What is enqueued and not read, in the device's order: the
+        # decode in flight, then this call's admission, then (a settled
+        # tick) this call's own decode. Nothing of it is waited for
+        # until this call's programs are on the queue behind it, so the
+        # device runs while the host pads, splits keys and dispatches.
+        unread: list = []
+        if self._inflight is not None:
+            unread.append(self._inflight)
+            self._inflight = None
+        routes = self._admit(unread, done)  # of the decodes this call reads
         # The lanes of this dispatch, decided ahead: a lane whose
         # budget the decode in flight exhausts runs no further token.
         # (An EOS the host has not seen yet cannot stop its lane: that
-        # lane runs one token more, which ``_book`` drops.) A drafting
-        # decode in flight may have spent up to two of a lane's budget:
-        # a lane it may have finished sits this dispatch out, and runs
-        # in the next if the host then finds that it has not.
-        carry = np.zeros(self.n_slots, bool)
-        if fl is not None:
-            carry[[slot for slot, _ in fl.lanes]] = True
-        mask = self.active & (self.slot_remaining > carry * self._window)
-        overlapped = int(fl is not None)
+        # lane runs one token more, which ``_book`` drops; so does a
+        # lane whose unread first token is one.) A drafting decode in
+        # flight may have spent up to two of a lane's budget: a lane it
+        # may have finished sits this dispatch out, and runs in the
+        # next if the host then finds that it has not.
+        flying = np.zeros(self.n_slots, bool)  # a token in flight
+        carry = np.zeros(self.n_slots, bool)   # last token not on the host
+        for u in unread:
+            if isinstance(u, _InFlight):
+                flying[[slot for slot, _ in u.lanes]] = True
+            else:
+                carry[u.slot] = True
+        carry |= flying
+        mask = self.active & (self.slot_remaining > flying * self._window)
+        overlapped = int(bool(unread))
         seen = None
         if self._select_topk is not None or (
                 self._live and self.trace is not None):
             # a lane's new position sees its prompt, the tokens the host
-            # has booked and the one still in flight (a drafting decode
-            # in flight counts as one: whether it accepted is not known)
+            # has booked and the one it has not read yet (a drafting
+            # decode in flight counts as one: whether it accepted is
+            # not known)
             booked = np.fromiter(map(len, self.slot_tokens), np.int64,
                                  self.n_slots)
             seen = (self.slot_prompt_len + booked + carry)[mask]
@@ -826,17 +905,11 @@ class ContinuousBatcher:
             self.ticks_overlapped += overlapped
             self.ticks_settled += 1 - overlapped
             if self._settling:  # this tick reads its own decode
-                fl, self._inflight = self._inflight, None
-        if fl is not None:
-            with _span("pbst.eng.sync"):
-                toks, route = self._read(fl)
-        t_host = _ns()
-        if fl is not None:
-            self._book(fl, toks, done)
-            routes.append(route)
-        if any_active:  # else _pre_decode has counted the tick
-            self.steps += 1
-        for route in routes:  # stamped like this call's ENG_DECODE
+                unread.append(self._inflight)
+                self._inflight = None
+        t_host, landed = self._land(unread, done)
+        self.steps += 1
+        for route in routes + landed:  # stamped like this call's ENG_DECODE
             self._route_ev(t_pre, route)
         if mask.any():
             if seen is not None and self._window > 1:
@@ -862,10 +935,13 @@ class ContinuousBatcher:
         (and what the feedback policy's BOOST class protects)."""
         return {
             "steps": self.steps,
-            # Decodes enqueued while the one before was unread, and
-            # with nothing in flight (ENG_DECODE's flag, counted).
+            # Decodes enqueued behind work the host had not waited for
+            # (the decode before, a prompt forward), and onto a drained
+            # device (ENG_DECODE's flag, counted); prompt forwards
+            # enqueued behind a decode in flight.
             "ticks_overlapped": self.ticks_overlapped,
             "ticks_settled": self.ticks_settled,
+            "admissions_overlapped": self.admissions_overlapped,
             "active_slots": int(self.active.sum()),
             "queued": len(self.queue),
             "tokens_emitted": self.tokens_emitted,

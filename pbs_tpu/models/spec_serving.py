@@ -170,7 +170,13 @@ class SpeculativeBatcher(ContinuousBatcher):
         return super().submit(prompt, max_new_tokens)
 
     def _step(self) -> list[Completion]:
-        done, any_active = self._pre_decode()
+        # The round is fed ``last_tok`` from the host: every admission's
+        # first token is read (and a request it finished retired) before
+        # it, none rides behind a decode.
+        unread: list = []
+        done: list[Completion] = []
+        self._admit(unread, done)
+        self._land(unread, done)
         t_pre = _ns()
         for slot, padded, plen in self._admitted:
             self.dcache, d_extra = self._draft_prefill_fn(
@@ -179,7 +185,8 @@ class SpeculativeBatcher(ContinuousBatcher):
             self._draft_extra_sum += \
                 float(d_extra) / self.draft_cfg.n_layers
             self._draft_extra_n += 1
-        if not any_active:
+        if not self.active.any():
+            self.steps += 1
             return done
         with _span("pbst.eng.decode"):
             (toks, counts, self.cache, self.dcache, prop, acc, extra,

@@ -187,24 +187,33 @@ class Ev(enum.IntEnum):
     #                          retired, queue_len
     ENG_ADMIT = 0x0A02  # args: tick, rid, slot, prompt_len, wait_ns
     #                           (submit -> slot, engine latency clock),
-    #                           dur_ns
+    #                           dur_ns (-> first token on the host: the
+    #                           stamp its ENG_PREFILL's sync_ns ends at)
     ENG_PREFILL = 0x0A03  # args: tick, rid, slot, dispatch_ns (call
-    #                             returns), sync_ns (first token on the
-    #                             host), rows (the padded length the
-    #                             prompt forward ran at, its rung; 0:
+    #                             returns), sync_ns (-> first token on
+    #                             the host: the last admission of a call
+    #                             is read once the call's decode is
+    #                             enqueued behind its forward, so this
+    #                             holds that dispatch and ends where the
+    #                             ENG_DECODE's sync_ns ends), rows (the
+    #                             padded length the prompt forward ran
+    #                             at, its rung; 0:
     #                             a prefix hit, cached KV was installed
     #                             and no prompt forward ran)
     ENG_KEYSPLIT = 0x0A04  # args: tick, dur_ns
     ENG_DECODE = 0x0A05  # one a step() that enqueues a decode. args:
     #                      tick, pre_ns (-> this call's program
-    #                      enqueued), sync_ns (-> the tokens this call
+    #                      enqueued), sync_ns (-> everything this call
     #                      books on the host: the decode the call before
-    #                      enqueued; step_settled(): this call's own; 0
-    #                      where the call booked ahead of an admission or
-    #                      has nothing to book), post_ns (emit and retire
+    #                      enqueued and the first token of the admission
+    #                      this call left unread; step_settled(): this
+    #                      call's own decode too; 0 where there is
+    #                      nothing to book), post_ns (emit and retire
     #                      loops -> step returns), overlapped (1: the
-    #                      program was enqueued while the decode before it
-    #                      was unread; 0: the pipeline was settled first)
+    #                      program was enqueued behind work the host had
+    #                      not waited for, the decode before it or this
+    #                      call's prompt forward; 0: onto a device the
+    #                      host had drained)
     ENG_RETIRE = 0x0A06  # args: tick, rid, slot, tokens, ttft_ns,
     #                            latency_ns (engine latency clock)
     ENG_ROUTE = 0x0A07  # one a prefill and one a decode of a program

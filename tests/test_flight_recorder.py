@@ -186,11 +186,15 @@ def test_one_tick_two_admissions_one_retirement(cfg, params):
     assert [c.request_id for c in done] == [a]
     recs = engine_records(eng)
     names = [Ev(r[1]).name for r in recs]
+    # a is read before b is dispatched, and retires there; b's forward
+    # is read behind the decode's dispatch, and its records are written
+    # then (a record is written when its span ends)
     assert names == [
-        "ENG_KEYSPLIT", "ENG_PREFILL", "ENG_ADMIT",     # a
-        "ENG_KEYSPLIT", "ENG_PREFILL", "ENG_ADMIT",     # b
-        "ENG_RETIRE",                                   # a
-        "ENG_KEYSPLIT", "ENG_DECODE", "ENG_TICK"]
+        "ENG_KEYSPLIT", "ENG_PREFILL", "ENG_ADMIT", "ENG_RETIRE",   # a
+        "ENG_KEYSPLIT",                                 # b's
+        "ENG_KEYSPLIT",                                 # the decode's
+        "ENG_PREFILL", "ENG_ADMIT",                     # b
+        "ENG_DECODE", "ENG_TICK"]
     tick = recs[-1]
     t0, dur, seq, busy, admitted, retired, queued = tick[0], *tick[2:]
     assert (seq, busy, admitted, retired, queued) == (0, 2, 2, 1, 0)
@@ -209,7 +213,11 @@ def test_one_tick_two_admissions_one_retirement(cfg, params):
     # A prefill and its key split lie inside their admission.
     for adm, pre in zip(admits, prefills):
         assert adm[0] <= pre[0] and pre[0] + pre[5] + pre[6] <= adm[0] + adm[7]
-    retire = recs[6]
+    # b's wait, its admission and the decode's wait end at one stamp
+    dec = recs[-2]
+    assert admits[1][0] + admits[1][7] == dec[0] + dec[3] + dec[4] \
+        == prefills[1][0] + prefills[1][5] + prefills[1][6]
+    retire = recs[3]
     assert retire[3:6] == [a, 0, 1] and retire[7] >= retire[6] > 0
     # The second tick admits nothing and says so.
     eng.step()
@@ -437,7 +445,8 @@ def module_names(cfg, params):
         eng._decode_fn.lower(eng.params, eng.cache,
                              jnp.zeros((2,), jnp.int32),
                              jnp.zeros((2,), jnp.int32), key),
-        eng._prefill_fn.lower(eng.params, eng.cache, 0,
+        eng._prefill_fn.lower(eng.params, eng.cache,
+                              jnp.zeros((2,), jnp.int32), 0,
                               jnp.zeros((8,), jnp.int32), 1, key)]
     init_opt, train_step = make_train_step(cfg, learning_rate=1e-3)
     state = (params, init_opt(params), 0)

@@ -3,14 +3,18 @@ tick n+1 before it reads tick n, and books tick n while the device runs
 tick n+1 (docs/SERVING.md "The pipelined tick").
 
 What is held here: the pipelined engine serves the tokens the settled
-engine serves (``step_settled()``: every tick read before it returns,
-the order of operations the engine had before it was pipelined), for a
-dense, a sparse and two recurrent layer stacks, greedy and sampled, with
-budgets and an ``eos_id`` that end requests mid-run; the lane mask is
-decided ahead from the budgets; ``has_work()`` / ``settle()`` see the
+engine serves (``step_settled()``: every tick read before it returns),
+for a dense, a sparse, two recurrent and a drafting layer stack, greedy
+and sampled, with budgets and an ``eos_id`` that end requests mid-run
+and requests that arrive mid-stream; the lane mask is decided ahead
+from the budgets; an admission rides in the pipeline (its prompt forward
+enqueued behind the decode in flight, its first token read once the
+decode behind it is enqueued too); ``has_work()`` / ``settle()`` see the
 tick in flight; the scheduler's wrapper and the disaggregated backend
 leave nothing in flight; and the ring's records keep what the
-benchmark's readers assume of them. Toy sizes, CPU, float32.
+benchmark's readers assume of them (``tests/test_admission_records.py``
+holds the records of an admission to the readers' own code). Toy sizes,
+CPU, float32.
 """
 
 import copy
@@ -38,7 +42,10 @@ TINY = dict(vocab=64, d_model=32, n_layers=2, n_heads=2, n_kv_heads=2,
 #: family -> (configuration, its family in the benchmark's spec)
 PLANNED = {"moe": ("laguna-s-2.1", "moe-mixed-gqa"),
            "kda": ("solar-open2-250b", "moe-kda-gqa"),
-           "mamba": ("ai21-jamba2-3b", "dense-mamba-mqa")}
+           "mamba": ("ai21-jamba2-3b", "dense-mamba-mqa"),
+           "mtp": ("deepseek-v3", "moe-mla-mtp")}
+#: the families that sample; a plan that drafts for itself is greedy
+SAMPLED = ["dense", "moe", "kda", "mamba"]
 FAMILIES = ["dense", *PLANNED]
 
 
@@ -48,7 +55,8 @@ def model(family: str):
     dense scan, or the rehearsal preset of a planned stack's
     configuration file (window and full attention over experts; KDA
     state beside a softmax layer, experts too; Mamba state beside
-    attention)."""
+    attention; latent attention over experts with a drafting block,
+    whose decode verifies two positions a lane)."""
     if family == "dense":
         cfg = TransformerConfig(**TINY)
         return cfg, init_params(cfg, jax.random.PRNGKey(0))
@@ -106,13 +114,16 @@ def run(eng, script, settled: bool):
     return [done[r] for r in rids], calls
 
 
-#: Six requests at once into three slots: every slot is reused, budgets
-#: from prefill-only (1) to the longest, two of them equal.
-BUDGETS = (6, 1, 9, 4, 12, 6)
+#: Six requests at once into three slots, and two that arrive
+#: mid-stream (one of them prefill-only): every slot is reused, budgets
+#: from prefill-only (1) to the longest, two of them equal; admissions
+#: land alone and two to a call, onto an idle device and behind a decode.
+BUDGETS = (6, 1, 9, 4, 12, 6, 5, 1)
+ARRIVALS = (0, 0, 0, 0, 0, 0, 3, 5)
 
 
 def crowd(vocab: int):
-    return [(0, p, b) for p, b in zip(prompts(vocab, len(BUDGETS)), BUDGETS)]
+    return list(zip(ARRIVALS, prompts(vocab, len(BUDGETS)), BUDGETS))
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -132,32 +143,47 @@ def test_greedy_tokens_equal_the_settled_engines(family):
     want, n_settled = run(fresh(eng, eos), script, settled=True)
     assert len(want[4].tokens) <= 5 < BUDGETS[4]
     assert want[4].tokens[-1] == eos
+    before = eng.stats()["admissions_overlapped"]
     got, n_piped = run(fresh(eng, eos), script, settled=False)
     assert [c.tokens for c in got] == [c.tokens for c in want]
     assert [c.prompt_len for c in got] == [c.prompt_len for c in want]
     assert n_piped > n_settled  # the booking is one call behind
     assert eng._inflight is None and not eng.active.any()
+    # and prompts were ingested behind a decode in flight
+    assert eng.stats()["admissions_overlapped"] > before
 
 
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", SAMPLED)
 def test_sampled_tokens_equal_the_settled_engines(family):
     """temperature > 0, a fixed seed: the same draws. Sampled tokens
-    depend on the key a dispatch is handed, so on the order of splits:
-    it is the settled engine's wherever both admit a request in the
-    same call, which this script arranges (no request waits for a slot:
-    ``steps_waited`` 0; a lane is busy throughout, so no tick is
-    skipped where only an EOS the host has not seen would keep one)."""
+    depend on the key a dispatch is handed and on the lane they are
+    drawn in, so on the order of splits and on the slot: they are the
+    settled engine's wherever both admit a request in the same call
+    into the same slot, which this script arranges (no request waits
+    for a slot: ``steps_waited`` 0; the pipelined engine frees a slot a
+    call later, so a slot is asked for two calls after its tenant's
+    last token; a lane is busy throughout, so no tick is skipped where
+    only an EOS the host has not seen would keep one). The third and
+    fourth requests arrive mid-stream in one call: one forward is read
+    before the next is dispatched, the last rides behind the decode."""
     eng = engine(family, 0.8)
-    p = prompts(eng.cfg.vocab, 5)
-    script = [(0, p[0], 24), (0, p[1], 3), (2, p[2], 7), (6, p[3], 1),
-              (8, p[4], 9)]
+    p = prompts(eng.cfg.vocab, 6)
+    script = [(0, p[0], 24), (0, p[1], 2), (2, p[2], 7), (2, p[5], 3),
+              (6, p[3], 1), (8, p[4], 9)]
     plain, _ = run(fresh(eng), script, settled=True)
-    eos = plain[2].tokens[3]  # ends the third request mid-run
+    # ends the third request mid-run, and never the first (lane 0 draws
+    # from the same keys with and without it)
+    eos = next(t for t in plain[2].tokens[1:6] if t not in plain[0].tokens)
     want, _ = run(fresh(eng, eos), script, settled=True)
-    assert len(want[2].tokens) <= 4 < 7
+    assert 2 <= len(want[2].tokens) <= 6 < 7
+    assert want[2].tokens[-1] == eos and len(want[0].tokens) == 24
+    before = eng.stats()["admissions_overlapped"]
     got, _ = run(fresh(eng, eos), script, settled=False)
-    assert [c.steps_waited for c in want + got] == [0] * 10
+    assert [c.steps_waited for c in want + got] == [0] * 12
     assert [c.tokens for c in got] == [c.tokens for c in want]
+    # behind a decode: the first of the two at call 2 (the second is
+    # dispatched once that decode has been read) and the two after
+    assert eng.stats()["admissions_overlapped"] - before == 3
     # and they are draws: the greedy engine says something else
     greedy, _ = run(fresh(engine(family), eos), script, settled=False)
     assert [c.tokens for c in greedy] != [c.tokens for c in got]
@@ -216,6 +242,31 @@ def test_a_tick_whose_lanes_are_gone_is_still_work_until_read():
 # -- (c) the mask is the host's, decided ahead --------------------------------
 
 
+def spied(eng) -> list:
+    """Log what ``eng`` enqueues and reads, in order: ``("prefill",
+    slot)``, ``("decode", lanes)`` and ``("read", kinds)`` (the kinds of
+    what one wait covered: ``_InFlight`` / ``_Admission``)."""
+    log = []
+    prefill, decode, land = eng._prefill_fn, eng._decode_fn, eng._land
+
+    def spy_prefill(params, cache, prev_tok, slot, *a):
+        log.append(("prefill", slot))
+        return prefill(params, cache, prev_tok, slot, *a)
+
+    def spy_decode(params, cache, prev_tok, lanes, key):
+        log.append(("decode", np.asarray(lanes).tolist()))
+        return decode(params, cache, prev_tok, lanes, key)
+
+    def spy_land(unread, done):
+        if unread:
+            log.append(("read", [type(u).__name__ for u in unread]))
+        return land(unread, done)
+
+    eng._prefill_fn, eng._decode_fn, eng._land = (
+        spy_prefill, spy_decode, spy_land)
+    return log
+
+
 def test_a_lane_whose_budget_ends_in_flight_is_off_in_the_next_dispatch():
     eng = dense()
     seen = []  # (lanes handed to the program, active, remaining) a dispatch
@@ -223,7 +274,7 @@ def test_a_lane_whose_budget_ends_in_flight_is_off_in_the_next_dispatch():
 
     def spy(params, cache, prev_tok, lanes, key):
         seen.append((np.asarray(lanes).tolist(), eng.active.tolist(),
-                     eng.slot_remaining.tolist()))
+                     eng.slot_remaining.tolist(), eng.last_tok.tolist()))
         return decode(params, cache, prev_tok, lanes, key)
 
     eng._decode_fn = spy
@@ -233,8 +284,9 @@ def test_a_lane_whose_budget_ends_in_flight_is_off_in_the_next_dispatch():
     while eng.has_work():
         done += eng.step()
     lanes = [s[0] for s in seen]
-    # Dispatch 0 follows the admissions: the host has both tokens.
-    assert min(lanes[0]) >= 0
+    # Dispatch 0 follows the admissions: the first one's token was read
+    # before the second was dispatched, the second's is on the device.
+    assert lanes[0] == [seen[0][3][0], _LANE_CARRY] and lanes[0][0] >= 0
     assert lanes[1] == [_LANE_CARRY, _LANE_CARRY]
     # Dispatch 2: slot 0's last token is in flight (dispatch 1). The
     # lane is still active on the host, one token from its budget, and
@@ -244,29 +296,178 @@ def test_a_lane_whose_budget_ends_in_flight_is_off_in_the_next_dispatch():
     assert lanes[3:] == [[_LANE_OFF, _LANE_CARRY]] * 2 and len(lanes) == 5
     assert sorted(len(c.tokens) for c in done) == [3, 6]
     st = eng.stats()
-    assert (st["ticks_overlapped"], st["ticks_settled"]) == (4, 1)
+    # every decode went behind something unread: the first behind the
+    # second admission's forward, the others behind the decode before
+    assert (st["ticks_overlapped"], st["ticks_settled"]) == (5, 0)
     flags = [r[6] for r in records(eng, Ev.ENG_DECODE)]
-    assert flags == [0, 1, 1, 1, 1]
+    assert flags == [1, 1, 1, 1, 1]
     assert st["tokens_emitted"] == 9 and st["completed"] == 2
 
 
-def test_an_admission_settles_the_pipeline_first():
-    """A request waiting and a slot free: the tick in flight is booked
-    before the prefill, the dispatch behind the admission has every
-    token from the host and counts as settled."""
+def test_an_admission_rides_behind_the_decode_in_flight():
+    """A request waiting and a slot free: the prompt forward is enqueued
+    behind the decode in flight and this call's decode behind the
+    forward, before anything is read; one wait then covers the older
+    decode and the first token, and both are booked when the call
+    returns. The decode counts as overlapped."""
     eng = dense()
     eng.submit([1, 2, 3], 9)
     eng.step(), eng.step()
-    assert eng.stats()["ticks_overlapped"] == 1
+    st = eng.stats()
+    assert (st["ticks_overlapped"], st["ticks_settled"]) == (2, 0)
+    assert st["admissions_overlapped"] == 0  # it found the device idle
     before = len(eng.slot_tokens[0])
-    eng.submit([7, 8], 4)
-    eng.step()
-    # both of the earlier ticks are booked, and the new lane's first
+    log = spied(eng)
+    rid = eng.submit([7, 8], 4)
+    assert eng.step() == []
+    assert log == [("prefill", 1), ("decode", [_LANE_CARRY, _LANE_CARRY]),
+                   ("read", ["_InFlight", "_Admission"])]
+    # the older decode's token and the new lane's first are booked
     assert len(eng.slot_tokens[0]) == before + 1
-    assert len(eng.slot_tokens[1]) == 1 and eng._inflight is not None
-    assert eng.stats()["ticks_settled"] == 2
-    assert records(eng, Ev.ENG_DECODE)[-1][6] == 0
+    assert eng.slot_req[1] == rid and len(eng.slot_tokens[1]) == 1
+    assert eng.last_tok[1] == eng.slot_tokens[1][0]
+    assert eng._inflight is not None
+    assert [lane for lane, _ in eng._inflight.lanes] == [0, 1]
+    st = eng.stats()
+    assert (st["ticks_overlapped"], st["ticks_settled"]) == (3, 0)
+    assert st["admissions_overlapped"] == 1
+    assert records(eng, Ev.ENG_DECODE)[-1][6] == 1
+    # one stamp closes the three waits of the call
+    (*_, admit), (*_, prefill), (*_, dec) = (
+        records(eng, ev) for ev in (Ev.ENG_ADMIT, Ev.ENG_PREFILL,
+                                    Ev.ENG_DECODE))
+    assert admit[0] + admit[7] == prefill[0] + prefill[5] + prefill[6] \
+        == dec[0] + dec[3] + dec[4]
+    assert admit[2] == prefill[2] == dec[2] == 2  # the tick
+    # served as the settled engine serves them
+    got = {c.request_id: c.tokens for c in eng.settle()}
+    while eng.has_work():
+        got.update({c.request_id: c.tokens for c in eng.step()})
+    want, _ = run(dense(), [(0, [1, 2, 3], 9), (2, [7, 8], 4)], settled=True)
+    assert [got[0], got[1]] == [c.tokens for c in want]
+
+
+def test_an_admission_into_an_idle_engine_enqueues_its_decode_behind_it():
+    """Nothing in flight: the forward goes onto an idle device, the
+    decode behind it before the first token is read."""
+    eng = dense()
+    log = spied(eng)
+    eng.submit([1, 2, 3], 5)
+    assert eng.step() == []
+    assert log == [("prefill", 0), ("decode", [_LANE_CARRY, _LANE_OFF]),
+                   ("read", ["_Admission"])]
+    assert len(eng.slot_tokens[0]) == 1 and eng._inflight is not None
+    st = eng.stats()
+    assert st["admissions_overlapped"] == 0 and st["ticks_overlapped"] == 1
+    (done,) = [c for _ in range(5) for c in eng.step()]
+    (want,), _ = run(dense(), [(0, [1, 2, 3], 5)], settled=True)
+    assert done.tokens == want.tokens and not eng.has_work()
+
+
+def test_two_admissions_in_one_tick_are_read_one_after_the_other():
+    """Each forward must lie inside its own ``ENG_PREFILL`` record
+    (``bucket_prefill_ms.forwards``): the first of a call's admissions
+    is read, with the decode it rode behind, before the second is
+    dispatched; only the last is unread when the decode is enqueued."""
+    eng = dense(n_slots=3)
+    eng.submit([1, 2, 3], 9)
+    eng.step(), eng.step()
+    log = spied(eng)
+    eng.submit([7, 8], 4), eng.submit([9], 3)
+    assert eng.step() == []
+    host = eng.slot_tokens[1][0]
+    assert log == [("prefill", 1), ("read", ["_InFlight", "_Admission"]),
+                   ("prefill", 2),
+                   ("decode", [eng.slot_tokens[0][-1], host, _LANE_CARRY]),
+                   ("read", ["_Admission"])]
+    assert [len(t) for t in eng.slot_tokens] == [3, 1, 1]
+    assert eng.stats()["admissions_overlapped"] == 1
+    pre = records(eng, Ev.ENG_PREFILL)[-2:]
+    # the first forward's record has ended when the second's starts
+    assert pre[0][0] + pre[0][5] + pre[0][6] <= pre[1][0]
+    got = {c.request_id: c.tokens for c in eng.settle()}
+    while eng.has_work():
+        got.update({c.request_id: c.tokens for c in eng.step()})
+    want, _ = run(dense(n_slots=3), [(0, [1, 2, 3], 9), (2, [7, 8], 4),
+                                     (2, [9], 3)], settled=True)
+    assert [got[i] for i in range(3)] == [c.tokens for c in want]
+
+
+@pytest.mark.parametrize("settled", [False, True])
+def test_a_budget_of_one_runs_no_decode(settled):
+    """``max_new == 1``: the lane is off in the decode enqueued behind
+    its forward (the host knows the budget without the token), and the
+    request retires in the call that admitted it."""
+    eng = dense()
+    eng.submit([1, 2, 3], 9)
+    eng.step(), eng.step()
+    log = spied(eng)
+    rid = eng.submit([7, 8], 1)
+    tick = eng.step_settled if settled else eng.step
+    (done,) = tick()
+    assert done.request_id == rid and len(done.tokens) == 1
+    assert [e for e in log if e[0] == "decode"] == [
+        ("decode", [_LANE_CARRY, _LANE_OFF])]
+    assert not eng.active[1] and eng.slot_req[1] is None
+    # alone in the engine it runs no decode at all
+    lone = dense()
+    log = spied(lone)
+    lone.submit([7, 8], 1)
+    (only,) = tick.__func__(lone)
+    assert only.tokens == done.tokens and not lone.has_work()
+    assert log == [("prefill", 0), ("read", ["_Admission"])]
+    assert lone.stats()["steps"] == 1
     eng.settle()
+
+
+def test_an_eos_as_first_token_costs_one_dropped_token():
+    """The host does not know the first token when it enqueues the
+    decode behind the forward: the lane runs one token, which is dropped
+    when it is booked (as for an EOS in flight), and the slot is free
+    for the next call's admission."""
+    (whole,), _ = run(dense(n_slots=1), [(0, [4, 5, 6], 8)], settled=True)
+    eos = whole.tokens[0]
+    eng = dense(eos_id=eos)
+    eng.submit([1, 2, 3], 12)
+    eng.step(), eng.step()
+    log = spied(eng)
+    rid = eng.submit([4, 5, 6], 8)
+    (done,) = eng.step()
+    assert (done.request_id, done.tokens) == (rid, [eos])
+    assert log[1] == ("decode", [_LANE_CARRY, _LANE_CARRY])  # it ran
+    assert (1, rid) in eng._inflight.lanes and not eng.active[1]
+    emitted = eng.tokens_emitted
+    nxt = eng.submit([4, 5, 6], 8)  # the slot is taken again at once
+    (again,) = eng.step()
+    assert (again.request_id, again.tokens) == (nxt, [eos])
+    assert log[3] == ("prefill", 1)
+    # the call booked lane 0's token and the first token, not lane 1's
+    assert eng.tokens_emitted == emitted + 2
+    eng.eos_id = None
+    eng.settle()
+
+
+def test_a_settled_tick_with_an_admission_leaves_nothing_in_flight():
+    """``step_settled()``: the forward, the decode behind it, and one
+    wait for both (and for a decode it found in flight); every token
+    booked and nothing on the device when it returns."""
+    eng = dense()
+    eng.submit([1, 2, 3], 9)
+    eng.step(), eng.step()  # leaves a decode in flight
+    log = spied(eng)
+    eng.submit([7, 8], 4)
+    assert eng.step_settled() == []
+    assert log == [("prefill", 1), ("decode", [_LANE_CARRY, _LANE_CARRY]),
+                   ("read", ["_InFlight", "_Admission", "_InFlight"])]
+    assert eng._inflight is None
+    assert [len(t) for t in eng.slot_tokens] == [4, 2]
+    assert eng.tokens_emitted == 6
+    got = {}
+    while eng.has_work():
+        got.update({c.request_id: c.tokens for c in eng.step_settled()})
+        assert eng._inflight is None
+    want, _ = run(dense(), [(0, [1, 2, 3], 9), (2, [7, 8], 4)], settled=True)
+    assert [got[0], got[1]] == [c.tokens for c in want]
 
 
 # -- (d) a quantum is whole: the scheduler's wrapper and disagg ---------------
@@ -288,11 +489,15 @@ def test_the_serve_step_wrapper_leaves_nothing_in_flight():
         total += metrics["tokens"]
     assert state["completed"] == 2 and total == 7 and not eng.has_work()
     st = eng.stats()
-    assert st["ticks_overlapped"] == 0 and st["ticks_settled"] > 0
     # Each tick read its own tokens: a wait and an emit loop a record.
-    for _ts, _ev, _tick, pre, sync, post, flag, _ in records(
-            eng, Ev.ENG_DECODE):
-        assert pre > 0 and sync > 0 and post > 0 and flag == 0
+    # Only the two decodes behind an admission's forward had anything
+    # unread ahead of them on the device.
+    decodes = records(eng, Ev.ENG_DECODE)
+    for _ts, _ev, _tick, pre, sync, post, _flag, _ in decodes:
+        assert pre > 0 and sync > 0 and post > 0
+    assert [r[6] for r in decodes] == [1, 0, 1, 0]
+    assert (st["ticks_overlapped"], st["ticks_settled"]) == (2, 2)
+    assert st["admissions_overlapped"] == 0
 
 
 def test_a_settled_tick_books_a_decode_it_finds_in_flight():
@@ -314,15 +519,20 @@ def test_the_speculative_engine_ticks_synchronously():
     cfg, params = model("dense")
     spec = SpeculativeBatcher(cfg, params, cfg, params, k=2, n_slots=2,
                               prompt_bucket=BUCKET, max_len=MAX_LEN)
-    spec.submit([1, 2, 3], 6)
-    got = []
-    while spec.has_work():
-        got += spec.step()
-        assert spec._inflight is None and spec.settle() == []
-    (want,), _ = run(dense(), [(0, [1, 2, 3], 6)], settled=False)
-    assert [c.tokens for c in got] == [want.tokens]
+    script = [(0, [1, 2, 3], 6), (1, [7, 8], 1), (2, [9, 4], 5)]
+    log = spied(spec)
+    got, _ = run(spec, script, settled=False)
+    # its round is fed from the host: every first token is read before
+    # the next program is enqueued, and no decode of the plain engine's
+    # runs
+    assert [e[0] for e in log] == ["prefill", "read"] * 3
+    assert all(e[1] == ["_Admission"] for e in log[1::2])
+    assert spec._inflight is None and spec.settle() == []
+    want, _ = run(dense(), script, settled=False)
+    assert [c.tokens for c in got] == [c.tokens for c in want]
     st = spec.stats()
     assert st["ticks_overlapped"] == st["ticks_settled"] == 0
+    assert st["admissions_overlapped"] == 0
 
 
 def test_the_disaggregated_backend_ticks_synchronously():
@@ -397,9 +607,10 @@ def test_route_and_decode_records_share_stamp_and_tick(settled):
 
 
 def test_no_program_is_built_under_traffic():
-    """The token vector a dispatch is handed is the program's own
-    output: the warm-up has met that signature (under a mesh it is a
-    second instance of the decode), and traffic builds nothing."""
+    """The token vector a dispatch is handed is a program's own output
+    (the decode's, or a prefill's with a first token written into it):
+    the warm-up has met that signature (under a mesh it is a second
+    instance of the decode), and traffic builds nothing."""
     from pbs_tpu.parallel import make_mesh
     from pbs_tpu.serve.partition import place
 
@@ -410,6 +621,11 @@ def test_no_program_is_built_under_traffic():
             n_slots=2, prompt_bucket=BUCKET, max_len=MAX_LEN, mesh=mesh)
         built = eng._decode_fn._cache_size()
         assert built == (1 if mesh is None else 2)
-        run(eng, crowd(cfg.vocab)[:4], settled=False)
+        # the prefill takes that vector too (and hands it on with the
+        # first token in it): one instance a rung, met by the warm-up
+        assert eng._prefill_fn._cache_size() == len(eng.rungs)
+        run(eng, crowd(cfg.vocab), settled=False)
         assert eng.stats()["ticks_overlapped"] > 0
+        assert eng.stats()["admissions_overlapped"] > 0
         assert eng._decode_fn._cache_size() == built
+        assert eng._prefill_fn._cache_size() == len(eng.rungs)

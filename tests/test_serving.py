@@ -357,7 +357,8 @@ def test_programs_donate_the_cache(model, program, donating):
                    cfg.dtype)
     call = {
         "prefill": lambda: eng._prefill_fn(
-            eng.params, old, 1, jnp.arange(8, dtype=jnp.int32), 5, key)[2],
+            eng.params, old, eng._dev_tok, 1,
+            jnp.arange(8, dtype=jnp.int32), 5, key)[3],
         "install": lambda: eng._install_fn(old, 1, win, win, 5),
         "decode": lambda: eng._decode_fn(  # lane 0 off, lane 1 on token 0
             eng.params, old, jnp.zeros((2,), jnp.int32),

@@ -224,7 +224,8 @@ def test_an_engine_writes_a_build_for_every_program_and_none_later(
     n0 = _now()
     eng = serving.ContinuousBatcher(CFG, params, n_slots=2,
                                     prompt_bucket=8, max_len=24)
-    assert _builds(n0) == [("eng.prefill@8", 8), ("eng.decode", 2),
+    # the decode first: the prefills take the token vector it leaves
+    assert _builds(n0) == [("eng.decode", 2), ("eng.prefill@8", 8),
                            ("eng.keysplit", 2)]
     phases = _host_records(Ev.HOST_PHASE, n0)
     cache = [r for r in phases if r[2] == T.job_tag("eng.cache")]
@@ -259,8 +260,8 @@ def test_an_engine_writes_a_build_for_every_program_and_none_later(
     n2 = _now()
     eng2 = serving.ContinuousBatcher(CFG, params, n_slots=3,
                                      prompt_bucket=16, max_len=40)
-    assert _builds(n2) == [("eng.prefill@8", 8), ("eng.prefill@16", 16),
-                           ("eng.decode", 3), ("eng.keysplit", 2)]
+    assert _builds(n2) == [("eng.decode", 3), ("eng.prefill@8", 8),
+                           ("eng.prefill@16", 16), ("eng.keysplit", 2)]
     n3 = _now()
     _drive(eng2, ([1, 2, 3], list(range(1, 13)), [5] * 8))
     _drive(eng, ([7, 8],))

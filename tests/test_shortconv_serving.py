@@ -818,7 +818,8 @@ def lowered_text() -> dict:
             eng.params, eng.cache, jnp.zeros((2,), jnp.int32),
             jnp.zeros((2,), jnp.int32), key).as_text(debug_info=True),
         "jit__prefill": eng._prefill_fn.lower(
-            eng.params, eng.cache, 0, jnp.zeros((BUCKET,), jnp.int32), 1,
+            eng.params, eng.cache, jnp.zeros((2,), jnp.int32), 0,
+            jnp.zeros((BUCKET,), jnp.int32), 1,
             key).as_text(debug_info=True)}
 
 

@@ -366,8 +366,8 @@ def test_slot_cache_updated_in_place_at_the_mistral_cell():
             eng.params, eng.cache, jnp.zeros((slots,), jnp.int32),
             jnp.zeros((slots,), jnp.int32), key),
         "prefill": eng._prefill_fn.lower(
-            eng.params, eng.cache, 0, jnp.zeros((bucket,), jnp.int32), 1,
-            key),
+            eng.params, eng.cache, jnp.zeros((slots,), jnp.int32), 0,
+            jnp.zeros((bucket,), jnp.int32), 1, key),
     }
     for name, low in lowered.items():
         m = low.compile().memory_analysis()
